@@ -284,7 +284,7 @@ def simulate(arrivals: list[float], hosts: list[dict],
 def _load_roofline():
     """utils/roofline.py loaded standalone by file path (its module level is
     stdlib-only and free of package-relative imports by contract) — the twin
-    must predict without jax, over a wedged tunnel, from just the ledger."""
+    must predict without jax, from just the ledger."""
     import importlib.util
 
     path = os.path.join(

@@ -60,19 +60,22 @@ def pin_params_host(params, device=None):
 
     dev = device if device is not None else jax.devices()[0]
     try:
-        sharding = jax.sharding.SingleDeviceSharding(
-            dev, memory_kind="pinned_host"
-        )
-        # Probe with one tiny transfer before committing the whole pytree —
-        # backends without pinned_host raise here, not at tree scale.
-        jax.block_until_ready(jax.device_put(np.zeros((1,)), sharding))
-        return streamed_tree_put(params, lambda _: sharding)
-    except Exception:
+        kinds = {m.kind for m in dev.addressable_memories()}
+    except Exception:  # backend without memory kinds
+        kinds = set()
+    if "pinned_host" not in kinds:
+        if dev.platform == "tpu":
+            raise RuntimeError(
+                f"{dev} reports no pinned_host memory space ({sorted(kinds)}); "
+                f"weight streaming on a TPU needs it"
+            )
         get_logger().info(
             "pinned_host memory kind unavailable on %s; keeping weights as "
-            "host numpy arrays", getattr(dev, "platform", dev),
+            "host numpy arrays", dev.platform,
         )
         return jax.tree.map(np.asarray, params)
+    sharding = jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
+    return streamed_tree_put(params, lambda _: sharding)
 
 
 def carve_ranges(sizes: "list[int] | tuple[int, ...]",

@@ -56,14 +56,21 @@ class UNetConfig:
 
 
 def sd15_config(**overrides) -> UNetConfig:
-    return dataclasses.replace(UNetConfig(), **overrides)
+    """SD1.x UNet (v1-inference.yaml): attention at the three shallow levels
+    and — like every ldm UNet — a depth-1 transformer in the middle block,
+    which the per-level tuple cannot express (the deepest level has none)."""
+    return dataclasses.replace(
+        UNetConfig(transformer_depth_middle=1), **overrides
+    )
 
 
 def sd21_config(**overrides) -> UNetConfig:
     """SD2.x UNet: OpenCLIP-H context (1024) and fixed 64-dim heads. The 512
     base checkpoints are eps; the 768-v ones v-prediction — pass
     ``prediction="v"`` (or use the node family "sd21-v")."""
-    base = UNetConfig(context_dim=1024, num_heads=-1)
+    base = UNetConfig(
+        context_dim=1024, num_heads=-1, transformer_depth_middle=1
+    )
     return dataclasses.replace(base, **overrides)
 
 

@@ -314,11 +314,19 @@ class VAE:
             )
         return fn
 
+    def _run(self, method, x, *rest):
+        # A latent straight out of a device chain is still sharded over it:
+        # the partitioned program must be called under that mesh.
+        from ..parallel.mesh import mesh_context, sharded_mesh_of
+
+        with mesh_context(sharded_mesh_of(x)):
+            return self._jitted(method)(self.params, x, *rest)
+
     def encode(self, x, rng=None):
-        return self._jitted(AutoencoderKL.encode)(self.params, x, rng)
+        return self._run(AutoencoderKL.encode, x, rng)
 
     def decode(self, z):
-        return self._jitted(AutoencoderKL.decode)(self.params, z)
+        return self._run(AutoencoderKL.decode, z)
 
     @property
     def spatial_factor(self) -> int:

@@ -2405,9 +2405,11 @@ class SaveLatent:
         # Channels-last (..., H, W, C) → the stock file's channels-first
         # (..., C, H, W): axis -1 moves to position 1 for any latent rank
         # (NHWC image and NTHWC video alike).
-        arr = _np.moveaxis(
+        # Contiguous copy: safetensors writes the raw buffer, so a moveaxis
+        # view would store NHWC bytes under the NCHW shape.
+        arr = _np.ascontiguousarray(_np.moveaxis(
             _np.asarray(samples["samples"], dtype=_np.float32), -1, 1
-        )
+        ))
         save_file(
             {
                 "latent_tensor": arr,

@@ -46,9 +46,9 @@ def _initial_backend() -> str:
     """Startup backend from ``PA_TPU_ATTENTION_BACKEND``
     (auto/xla/xla_chunked/pallas).
 
-    The env override exists so a *driving process* (watchdog, bench harness, a
-    hosted workflow run) can force the safe XLA path for every child it spawns
-    when the fused kernel fails a hardware probe — without touching code. An
+    The env override exists so a *driving process* (bench harness, a hosted
+    workflow run) can force one backend for every child it spawns — without
+    touching code. An
     invalid value falls back to "auto" rather than erroring at import time.
     """
     name = os.environ.get("PA_TPU_ATTENTION_BACKEND", "auto")
@@ -130,24 +130,21 @@ def _xla_attention(q, k, v, scale, logits_dtype=jnp.float32):
 # chunking is the only way those workloads fit a chip at all.
 _CHUNK_THRESHOLD = 2**27
 
-# Measured chunk tuning (the sd15_16 MFU-budget fixes, BASELINE.md): the
-# watchdog's chunk sweep benches {threshold × softmax-dtype} combos on
-# hardware and persists the winner here; env vars override per-process for
-# the sweep itself. Read at trace time — bench children are fresh processes.
-_CHUNK_TUNING_PATH = os.environ.get("PA_ATTN_CHUNK_TUNING") or os.path.join(
-    os.path.dirname(os.path.abspath(__file__)), "attn_chunk.json"
-)
+# Chunk tuning: a {threshold × softmax-dtype} sweep persists its winner to the
+# JSON file ``$PA_ATTN_CHUNK_TUNING`` names; env vars override per-process for
+# the sweep itself. Read at trace time. There is no default file: a fresh
+# clone and a checkout an earlier run wrote into behave the same.
+_CHUNK_TUNING_PATH = os.environ.get("PA_ATTN_CHUNK_TUNING")
 
 
 @functools.cache
 def _chunk_tuning() -> dict:
     import json
 
-    try:
-        with open(_CHUNK_TUNING_PATH) as f:
-            return json.load(f)
-    except (OSError, ValueError):
+    if not _CHUNK_TUNING_PATH:
         return {}
+    with open(_CHUNK_TUNING_PATH) as f:
+        return json.load(f)
 
 
 # Degradation-ladder override (utils/degrade.py "attn-chunk-shrink" rung):
@@ -269,20 +266,48 @@ def _pallas_jax_attention(q, k, v, scale):
         flash_attention as jax_flash,
     )
 
-    qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-    out = jax_flash(qt, kt, vt, sm_scale=float(scale))
-    return out.transpose(0, 2, 1, 3)
+    from .pallas.flash_attention import over_data_axis
+
+    def bshd(q, k, v):
+        qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        return jax_flash(qt, kt, vt, sm_scale=float(scale)).transpose(0, 2, 1, 3)
+
+    # Like the in-repo kernel: a Mosaic call must be shard_mapped to live in
+    # a program partitioned over the data axis.
+    return over_data_axis(bshd, q, k, v)
 
 
 @functools.cache
 def _pallas_available() -> bool:
-    from ..devices.discovery import is_tpu_device
+    """Whether the default backend is a TPU — the only place the fused
+    kernels are compiled rather than interpreted."""
+    return jax.default_backend() == "tpu"
 
-    try:
-        devs = jax.devices()
-    except RuntimeError:
-        return False
-    return any(is_tpu_device(d) for d in devs)
+
+@functools.cache
+def _log_interpreted_once() -> None:
+    from ..utils.logging import get_logger
+
+    get_logger().warning(
+        "attention backend 'pallas' forced on the %s backend: the flash "
+        "kernel runs in the Pallas INTERPRETER (test-only; orders of "
+        "magnitude slower than any compiled path)", jax.default_backend(),
+    )
+
+
+def _require_upstream_shape(head_dim, seq_q: int, seq_k: int) -> None:
+    """A FORCED ``pallas_jax`` must be able to serve the shape: the upstream
+    kernel has no lane padding and asserts seq_len % block == 0. Only
+    ``auto`` may choose another backend; a forced one that cannot serve
+    raises instead of quietly giving way."""
+    if ((head_dim is not None and head_dim % 128 != 0)
+            or seq_q % _UPSTREAM_BLOCK != 0 or seq_k % _UPSTREAM_BLOCK != 0):
+        raise ValueError(
+            f"attention backend 'pallas_jax' cannot serve head_dim={head_dim} "
+            f"seq_q={seq_q} seq_k={seq_k}: it needs a 128-multiple head dim "
+            f"and {_UPSTREAM_BLOCK}-multiple sequence lengths; set the "
+            f"backend to 'auto' to let the dispatch choose"
+        )
 
 
 def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
@@ -315,18 +340,8 @@ def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
             backend = fused_backend(q.shape[1], q.shape[-1])
         else:
             backend = "xla"
-    if backend == "pallas_jax" and (
-        q.shape[-1] % 128 != 0
-        or q.shape[1] % _UPSTREAM_BLOCK != 0
-        or k.shape[1] % _UPSTREAM_BLOCK != 0
-    ):
-        # The upstream kernel has no lane padding and asserts seq_len %
-        # block == 0 (BlockSizes.get_default blocks are _UPSTREAM_BLOCK; no
-        # internal padding). A FORCED pallas_jax (the watchdog's
-        # probe-failure fallback) on a 40/64-dim head or a non-block-aligned
-        # sequence takes the safe XLA family rather than crashing at trace
-        # time on a shape the sweep never measured.
-        backend = "xla"
+    if backend == "pallas_jax":
+        _require_upstream_shape(q.shape[-1], q.shape[1], k.shape[1])
     if backend == "xla" and logit_elems > _chunk_threshold():
         # "xla" means the XLA family: shapes whose S×S logits would blow HBM
         # (pallas-ineligible 40/64-dim UNet heads at 1024², or a forced
@@ -338,8 +353,14 @@ def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
         from .pallas.tuning import best_blocks
 
         block_q, block_k = best_blocks(q.shape[1], q.shape[-1])
+        # Compiled on a TPU; a pallas backend FORCED elsewhere (tests) runs
+        # the interpreter, and says so once.
+        interpret = not _pallas_available()
+        if interpret:
+            _log_interpreted_once()
         return flash_attention(
-            q, k, v, scale=scale, block_q=block_q, block_k=block_k
+            q, k, v, scale=scale, block_q=block_q, block_k=block_k,
+            interpret=interpret,
         )
     if backend == "pallas_jax":
         return _pallas_jax_attention(q, k, v, scale)
@@ -354,8 +375,8 @@ def backend_plan(seq_q: int, seq_k: int | None = None,
     """The ``attention_local`` routing ladder as a side-effect-free,
     inspectable decision — what the auto-parallel planner's attention axis
     reads (parallel/planner.py): which backend WOULD serve this shape, the
-    chunk configuration it would run under, and the banked measurements
-    (``ops/attn_chunk.json`` threshold sweep + ``ops/pallas/tuning.json``
+    chunk configuration it would run under, and the measurements
+    (``$PA_ATTN_CHUNK_TUNING`` threshold sweep + ``$PA_TUNING_PATH``
     pallas-vs-xla wins) that decided it. Mirrors ``attention_local`` rule
     for rule so plan and execution agree by construction; a drift test pins
     the two against each other (tests/test_planner.py)."""
@@ -410,17 +431,14 @@ def backend_plan(seq_q: int, seq_k: int | None = None,
         measured_ms=None,
     )
     # The exact attention_local resolution order: configured pin first, the
-    # auto ladder only for "auto", then the pallas_jax shape guard and the
-    # xla→chunked size fallback — so a process-pinned backend plans the same
+    # auto ladder only for "auto", then the forced-pallas_jax shape check
+    # and the xla→chunked size fallback — so a process-pinned backend plans the same
     # way it executes.
     backend = _BACKEND
     if backend == "auto":
         backend = fused_backend(seq_q, head_dim) if fused_ok else "xla"
-    if backend == "pallas_jax" and (
-        (head_dim is not None and head_dim % 128 != 0)
-        or seq_q % _UPSTREAM_BLOCK != 0 or seq_k % _UPSTREAM_BLOCK != 0
-    ):
-        backend = "xla"
+    if backend == "pallas_jax":
+        _require_upstream_shape(head_dim, seq_q, seq_k)
     if backend == "xla" and logit_elems > threshold:
         backend = "xla_chunked"
     cfg = chunk_config()

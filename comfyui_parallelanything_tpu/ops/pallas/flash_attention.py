@@ -28,11 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax >= 0.6 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
+from jax.sharding import PartitionSpec as P
 
 # m/l scratch rows are stored broadcast across a full 128-wide lane dimension —
 # (block_q, 1) arrays lower poorly on the TPU vector unit.
@@ -79,6 +75,32 @@ def _flash_kernel(
         o_ref[...] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
 
 
+def over_data_axis(fn, q, k, v):
+    """Run ``fn(q, k, v) -> out`` (all (B, S, H, D)) so that it survives a
+    ``jit`` whose operands are sharded over the ``data`` mesh axis.
+
+    A Mosaic kernel in a partitioned program is refused outright ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call in a
+    shard_map") — the orchestrator's data-parallel step and a VAE decode of a
+    sharded latent both hit it on real chips. Attention is independent per
+    batch row, so under a context mesh (``jax.set_mesh`` — the callers that
+    shard a batch set it around their jitted call) with a ``data`` axis the
+    kernel runs inside a ``shard_map`` over that axis, each device on its own
+    rows. No context mesh, or a batch the axis does not divide: ``fn`` as is.
+    """
+    from ...parallel.mesh import AXIS_DATA
+
+    mesh = jax.sharding.get_abstract_mesh()
+    n = dict(mesh.shape).get(AXIS_DATA, 1) if not mesh.empty else 1
+    if n == 1 or AXIS_DATA in mesh.manual_axes or q.shape[0] % n:
+        return fn(q, k, v)
+    rows = P(AXIS_DATA)
+    return jax.shard_map(
+        fn, in_specs=(rows, rows, rows), out_specs=rows,
+        axis_names=frozenset({AXIS_DATA}), check_vma=False,
+    )(q, k, v)
+
+
 def _pad_to(x, axis: int, multiple: int):
     size = x.shape[axis]
     pad = (-size) % multiple
@@ -103,16 +125,35 @@ def flash_attention(
 ):
     """Flash attention on (B, S, H, D) q/k/v; returns (B, S_q, H, D).
 
-    ``interpret=None`` auto-selects interpreter mode off-TPU so the same kernel is
-    testable on the virtual CPU mesh.
-    """
-    from ...devices.discovery import is_tpu_device
+    ``interpret`` is the caller's decision and never a silent one: on a TPU
+    backend the kernel is always compiled (``interpret=True`` raises there);
+    off a TPU the caller must say which it wants — ``True`` to run the Pallas
+    interpreter (CPU tests), ``False`` to compile for a described TPU
+    topology — and leaving it ``None`` raises.
 
+    Batch-sharded operands are fine under a context mesh
+    (:func:`over_data_axis`).
+    """
     if scale is None:
         scale = float(q.shape[-1]) ** -0.5
+    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = not is_tpu_device(jax.devices()[0])
+        if not on_tpu:
+            raise ValueError(
+                "flash_attention: no TPU backend — pass interpret=True (Pallas "
+                "interpreter) or interpret=False (compile for a described "
+                "topology) explicitly"
+            )
+        interpret = False
+    elif interpret and on_tpu:
+        raise ValueError("flash_attention never interprets on a TPU backend")
+    kernel = functools.partial(_flash_attention, scale=scale, block_q=block_q,
+                               block_k=block_k, interpret=interpret)
+    return over_data_axis(kernel, q, k, v)
 
+
+def _flash_attention(q, k, v, *, scale: float, block_q: int, block_k: int,
+                     interpret: bool):
     # Lane alignment: the MXU wants the head dim in 128-lane multiples. For
     # the 40/64-dim UNet-family heads, zero-pad D — exact, not approximate:
     # padded K columns add zero to every q·k logit, and padded V columns
@@ -161,7 +202,7 @@ def flash_attention(
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

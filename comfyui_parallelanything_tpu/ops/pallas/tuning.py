@@ -1,17 +1,20 @@
-"""Data-driven flash-kernel tuning (VERDICT r2 item 7).
+"""Data-driven flash-kernel tuning.
 
 The pallas kernel's block sizes (256/256) started as guesses; real numbers come
 from ``scripts/bench_kernels.py``, which sweeps ``block_q``/``block_k`` over
 {128, 256, 512} at the shapes that matter (FLUX 4.6k joint attention, WAN
-16k/32k video) and — with ``--apply`` — writes the winners here as
-``tuning.json``. The ``auto`` attention backend (ops/attention.py) then:
+16k/32k video) and — with ``--apply`` — writes the winners to the JSON file
+``$PA_TUNING_PATH`` names. The ``auto`` attention backend (ops/attention.py)
+then:
 
 - picks the measured-best blocks for the nearest benchmarked sequence length,
 - falls back to XLA for sequence ranges where the measurement says the fused
   kernel LOSES (the reference's capability-gated backend disable, inverted:
   data-gated instead of SM-version-gated, any_device_parallel.py:126-164).
 
-Without a measured file everything behaves exactly as the defaults did.
+There is no default file: without ``$PA_TUNING_PATH`` everything runs on the
+defaults below, so a fresh clone and a checkout an earlier run wrote into
+behave the same.
 """
 
 from __future__ import annotations
@@ -20,11 +23,7 @@ import functools
 import json
 import os
 
-# PA_TUNING_PATH override exists for the watchdog dry-run (tests write a
-# throwaway measured table without touching the packaged one).
-_PATH = os.environ.get("PA_TUNING_PATH") or os.path.join(
-    os.path.dirname(os.path.abspath(__file__)), "tuning.json"
-)
+_PATH = os.environ.get("PA_TUNING_PATH")
 
 _DEFAULT = {
     "source": "default",       # "measured" once bench_kernels --apply ran
@@ -41,30 +40,26 @@ _DEFAULT = {
 
 @functools.lru_cache(maxsize=1)
 def kernel_tuning() -> dict:
-    """The active tuning table (defaults merged under any measured file).
+    """The active tuning table (defaults merged under ``$PA_TUNING_PATH``).
 
     A measured table is generation-specific: block winners and win/lose ranges
     from a v5e do not transfer to a v6e. When the file records a
-    ``device_kind`` that doesn't match the current first accelerator, fall back
-    to defaults rather than silently applying foreign measurements."""
-    try:
-        with open(_PATH) as f:
-            data = json.load(f)
-        if not isinstance(data, dict):
-            raise ValueError("tuning.json must hold an object")
-        measured_kind = data.get("device_kind")
-        if measured_kind:
-            try:
-                import jax
-
-                current = jax.devices()[0].device_kind
-            except Exception:
-                current = None
-            if current is not None and current != measured_kind:
-                return dict(_DEFAULT)
-        return {**_DEFAULT, **data}
-    except Exception:
+    ``device_kind`` that doesn't match the current first device, the defaults
+    apply rather than foreign measurements. A table that was asked for and
+    cannot be read is an error, not the defaults."""
+    if not _PATH:
         return dict(_DEFAULT)
+    with open(_PATH) as f:
+        data = json.load(f)
+    if not isinstance(data, dict):
+        raise ValueError(f"{_PATH} must hold a JSON object")
+    measured_kind = data.get("device_kind")
+    if measured_kind:
+        import jax
+
+        if jax.devices()[0].device_kind != measured_kind:
+            return dict(_DEFAULT)
+    return {**_DEFAULT, **data}
 
 
 def _nearest(entries: list, seq: int):
@@ -173,7 +168,10 @@ def pallas_wins(seq: int, head_dim: int | None = None) -> bool:
 
 
 def write_tuning(data: dict) -> str:
-    """Persist a measured tuning table (bench_kernels --apply) and reload."""
+    """Persist a measured tuning table (bench_kernels --apply) to
+    ``$PA_TUNING_PATH`` and reload."""
+    if not _PATH:
+        raise RuntimeError("set PA_TUNING_PATH to the file the table goes to")
     merged = {**_DEFAULT, **data, "source": "measured"}
     with open(_PATH, "w") as f:
         json.dump(merged, f, indent=2, sort_keys=True)

@@ -17,6 +17,7 @@ devices into any 2-D ``(data, seq)`` / ``(data, model)`` layout.
 
 from __future__ import annotations
 
+import contextlib
 from collections.abc import Sequence
 
 import numpy as np
@@ -27,6 +28,25 @@ AXIS_DATA = "data"
 AXIS_SEQ = "seq"
 AXIS_MODEL = "model"
 AXIS_STAGE = "stage"
+
+
+def mesh_context(mesh: Mesh | None):
+    """The context a program partitioned over ``mesh`` must be CALLED under
+    (``jax.set_mesh``; a no-op for ``None``). A fused attention kernel is a
+    Mosaic call, which the partitioner refuses to split on real chips; under a
+    context mesh ``ops/pallas/flash_attention.over_data_axis`` shard_maps it
+    over the data axis instead. The context mesh is part of jit's own cache
+    key, so callers need no key of their own."""
+    return jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
+
+
+def sharded_mesh_of(x) -> Mesh | None:
+    """The mesh ``x`` is laid out on when it is a jax array spread over more
+    than one device by a ``NamedSharding`` — else ``None``."""
+    sharding = getattr(x, "sharding", None)
+    if isinstance(sharding, NamedSharding) and sharding.mesh.size > 1:
+        return sharding.mesh
+    return None
 
 
 def mesh_axis_names() -> tuple[str, ...]:
@@ -73,8 +93,8 @@ def batch_sharded(mesh: Mesh, axis: str = AXIS_DATA, ndim: int | None = None) ->
 # Cap on transfer bytes in flight during big-pytree placement. A whole-pytree
 # jax.device_put dispatches every leaf's transfer at once; on a 16 GiB chip a
 # ~12 GiB model leaves no headroom for the staging the concurrent transfers
-# need (round-3 evidence: flux_16_int8 OOM'd while *placing* the int8 pytree,
-# BASELINE_measured.json fallback_stderr). Draining the queue every N bytes is
+# need (a flux_16_int8 run once OOM'd while *placing* the int8 pytree).
+# Draining the queue every N bytes is
 # the reference's incremental key-by-key state-dict copy trick
 # (any_device_parallel.py:639-665) applied to device_put.
 _MAX_INFLIGHT_BYTES = 1 << 30

@@ -69,7 +69,13 @@ from ..utils.logging import (
     log_setup_summary,
 )
 from .chain import DeviceChain, DeviceLink
-from .mesh import AXIS_DATA, build_mesh, place_params, place_params_fsdp
+from .mesh import (
+    AXIS_DATA,
+    build_mesh,
+    mesh_context,
+    place_params,
+    place_params_fsdp,
+)
 from .split import (
     batch_size_of,
     pad_leaf as _pad_leaf,
@@ -680,10 +686,11 @@ class ParallelModel:
                 )
 
             fn = self._jit_for(static)
-            return fn(
-                g.params, put_repl(x), put_repl(timesteps), put_repl(context),
-                put_repl(traced),
-            )
+            with mesh_context(g.mesh):
+                return fn(
+                    g.params, put_repl(x), put_repl(timesteps),
+                    put_repl(context), put_repl(traced),
+                )
         traced, static = partition_kwargs(kwargs)
 
         def put(v):
@@ -753,7 +760,9 @@ class ParallelModel:
 
         traced, static = partition_kwargs(kwargs)
         fn = self._jit_for(static)
-        out = fn(group.params, place(x), place(timesteps), place(context), place(traced))
+        with mesh_context(group.mesh):
+            out = fn(group.params, place(x), place(timesteps), place(context),
+                     place(traced))
         return _slice_padded(out, batch, padded)
 
     # -- whole-loop compilation handle (sampling/compiled.py) ----------------------
@@ -886,8 +895,7 @@ class ParallelModel:
         """
         if self._demoted and not self._cleaned:
             # An explicit rebalance signals intent to resume parallel execution
-            # after a step-OOM demotion (VERDICT r2: nothing ever reactivated
-            # automatically); failure to re-place keeps the single-device path.
+            # after a step-OOM demotion; failure to re-place keeps the single-device path.
             # Never resurrects an explicitly cleaned-up model.
             try:
                 self.reactivate()
@@ -909,8 +917,7 @@ class ParallelModel:
         if self.config.auto_speed_balance:
             # The SPEED half of the re-blend (round 17): same discipline as
             # memory — re-blended from the ORIGINAL user weights, platform
-            # specs read fresh (they are static, but the env-var fallback
-            # for tunneled device kinds is not).
+            # specs read fresh.
             new = blend_speed_weights(new, _device_step_times(devs))
         i = 0
         for g in self._groups:
@@ -1107,7 +1114,7 @@ def parallelize(
                 )
             )
 
-    # Weights-don't-fit routing rung (VERDICT r5 next-1): a replicate-mode
+    # Weights-don't-fit routing rung: a replicate-mode
     # model whose pytree exceeds the lead device's HBM budget cannot place —
     # on hardware the loop below would OOM deterministically, burn the
     # degradation ladder chip by chip, and still fail on the last one. When

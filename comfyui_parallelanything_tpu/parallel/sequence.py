@@ -27,19 +27,9 @@ from typing import Literal
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6: top-level shard_map with check_vma
-    from jax import shard_map
-except ImportError:  # older jax: experimental namespace, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_compat(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
 
 from .mesh import AXIS_SEQ
 

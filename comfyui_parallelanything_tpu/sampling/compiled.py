@@ -28,6 +28,7 @@ the full sampler menu.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import weakref
 from typing import Any, Callable
 
@@ -719,9 +720,20 @@ def _get_loop_jit(kind: str, spec: TraceSpec, static: dict, meta: tuple, build,
         # sampler name into the program label; the other kinds are
         # one-program-per-kind.
         prog = f"loop:{kind}:{meta[0]}" if kind == "k" else f"loop:{kind}"
-        fn = _loop_jits[key] = instrument_jit(
-            impl, prog, donate_argnums=donate
-        )
+        jitted = instrument_jit(impl, prog, donate_argnums=donate)
+        if spec.mesh is None:
+            fn = jitted
+        else:
+            from ..parallel.mesh import mesh_context
+
+            @functools.wraps(jitted)
+            def fn(*args, **kwargs):
+                # Partitioned over spec.mesh: must be called under it
+                # (parallel/mesh.mesh_context).
+                with mesh_context(spec.mesh):
+                    return jitted(*args, **kwargs)
+
+        _loop_jits[key] = fn
     return fn
 
 

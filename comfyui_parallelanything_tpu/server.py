@@ -1513,6 +1513,18 @@ def main() -> None:
                     help="base URL the ROUTER should reach this host at "
                          "(default http://<host>:<port>)")
     args = ap.parse_args()
+    if args.output_dir:
+        # The save nodes resolve their root from the environment; without
+        # this the images land outside the directory /view serves.
+        os.environ["PA_OUTPUT_DIR"] = args.output_dir
+    from .devices.discovery import default_device
+    from .utils.compile_cache import enable_compilation_cache
+    from .utils.logging import get_logger
+
+    cache_dir = enable_compilation_cache()
+    dev = default_device()
+    get_logger().info("compute device %s (%s); compile cache at %s",
+                      dev, dev.device_kind, cache_dir)
     srv, q = make_server(args.host, args.port, output_dir=args.output_dir,
                          workers=args.workers, max_pending=args.max_pending,
                          trace=args.trace, host_id=args.host_id,

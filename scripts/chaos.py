@@ -43,6 +43,10 @@ on stderr — the bench.py/loadgen contract):
 The REAL-model bitwise replay contract (fold_in RNG) is dryrun §18's job on
 the virtual mesh; this runner is the operational rehearsal CI can afford.
 
+CPU-only: the run pins ``JAX_PLATFORMS=cpu`` — its virtual fleet is several
+servers in one process rehearsing failover, which says nothing about a chip
+and must never take one (N real backends on one host need one chip each).
+
 Requires PA_EVIDENCE_DIR (the one arming rule — chaos artifacts must never
 land in the repo's real evidence); sets it to a temp dir when absent.
 
@@ -721,6 +725,7 @@ def main() -> int:
     ap.add_argument("--plan", default=None,
                     help="override the fleet phase's PA_FAULT_PLAN JSON")
     args = ap.parse_args()
+    os.environ["JAX_PLATFORMS"] = "cpu"  # before anything imports jax
     if not os.environ.get("PA_EVIDENCE_DIR"):
         # The one arming rule (utils/faults.py): chaos artifacts — ledgers,
         # postmortems, journals — must never land in the repo's evidence.

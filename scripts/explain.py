@@ -20,8 +20,7 @@ a workflow-node span is queue time, not compute), and ``host_gap`` is the
 residual against the wall — so the buckets are non-negative and sum to the
 wall BY CONSTRUCTION whenever the wall covers the trace window. The
 ``--check`` gate (CI: scripts/ci_tier1.sh) enforces the conservation rule:
-every bucket >= 0 and |sum - wall| <= 10% of wall (BASELINE.md forensics
-protocol).
+every bucket >= 0 and |sum - wall| <= 10% of wall.
 
 Stdlib-only and jax-free (the scripts/ standalone contract — same as
 trace_summary.py): runs anywhere the trace JSON can be carried.

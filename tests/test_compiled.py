@@ -183,12 +183,3 @@ class TestCompileCaching:
         _run("euler", True, model=_toy_model(c1))
         _run("euler", True, model=_toy_model(c2))
         assert len(c1) > 0 and len(c2) > 0
-
-
-class TestCompilationCacheUtil:
-    def test_enable_compilation_cache(self, tmp_path):
-        from comfyui_parallelanything_tpu.utils import enable_compilation_cache
-
-        d = enable_compilation_cache(str(tmp_path / "xla"))
-        assert (tmp_path / "xla").is_dir()
-        assert d == str(tmp_path / "xla")

@@ -113,65 +113,9 @@ def _inv_transformer(p, prefix, depth, sd):
             sd[f"{t}.{name}.to_out.0.bias"] = np.asarray(blk[f"{name}_o"]["bias"])
 
 
-def _ldm_sd(cfg: UNetConfig, params) -> dict:
-    sd: dict = {}
-    _inv_dense(params["time_embed_0"], "time_embed.0", sd)
-    _inv_dense(params["time_embed_2"], "time_embed.2", sd)
-    if cfg.adm_in_channels is not None:
-        _inv_dense(params["label_embed_0"], "label_emb.0.0", sd)
-        _inv_dense(params["label_embed_2"], "label_emb.0.2", sd)
-    _inv_conv(params["input_conv"], "input_blocks.0.0", sd)
-
-    def attn_at(level):
-        return level in cfg.attention_levels and cfg.transformer_depth[level] > 0
-
-    idx = 1
-    for level in range(len(cfg.channel_mult)):
-        for i in range(cfg.num_res_blocks):
-            _inv_res(params[f"in_{level}_{i}_res"], f"input_blocks.{idx}.0", sd)
-            if attn_at(level):
-                _inv_transformer(
-                    params[f"in_{level}_{i}_attn"], f"input_blocks.{idx}.1",
-                    cfg.transformer_depth[level], sd,
-                )
-            idx += 1
-        if level != len(cfg.channel_mult) - 1:
-            _inv_conv(params[f"down_{level}"]["Conv_0"], f"input_blocks.{idx}.0.op", sd)
-            idx += 1
-
-    from comfyui_parallelanything_tpu.models.unet import middle_depth
-
-    _inv_res(params["mid_res1"], "middle_block.0", sd)
-    if middle_depth(cfg) > 0:
-        _inv_transformer(
-            params["mid_attn"], "middle_block.1", middle_depth(cfg), sd
-        )
-        _inv_res(params["mid_res2"], "middle_block.2", sd)
-    else:
-        _inv_res(params["mid_res2"], "middle_block.1", sd)
-
-    idx = 0
-    for level in reversed(range(len(cfg.channel_mult))):
-        for i in range(cfg.num_res_blocks + 1):
-            _inv_res(params[f"out_{level}_{i}_res"], f"output_blocks.{idx}.0", sd)
-            sub = 1
-            if attn_at(level):
-                _inv_transformer(
-                    params[f"out_{level}_{i}_attn"], f"output_blocks.{idx}.{sub}",
-                    cfg.transformer_depth[level], sd,
-                )
-                sub += 1
-            if i == cfg.num_res_blocks and level != 0:
-                _inv_conv(
-                    params[f"up_{level}"]["Conv_0"],
-                    f"output_blocks.{idx}.{sub}.conv", sd,
-                )
-            idx += 1
-
-    _inv_norm(params["out_norm"], "out.0", sd)
-    _inv_conv(params["out_conv"], "out.2", sd)
-    return sd
-
+# The whole-UNet inverse is chip_smoke.py's (it writes the checkpoint the chip
+# smoke serves): the round-trip tests below pin it against the converter.
+from chip_smoke import ldm_unet_state_dict as _ldm_sd  # noqa: E402
 
 
 def _assert_trees_equal(got, want):

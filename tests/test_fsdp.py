@@ -49,7 +49,7 @@ class TestFsdpPlacement:
         assert len(placed["small"].sharding.device_set) == 8  # replicated
 
     def test_streamed_put_matches_direct_device_put(self, cpu_devices):
-        # streamed_tree_put (the int8-placement OOM fix, VERDICT r3 next-1)
+        # streamed_tree_put (the int8-placement OOM fix)
         # must be value- and sharding-identical to a whole-pytree device_put;
         # a tiny in-flight cap forces several drain cycles through the loop.
         import numpy as np
@@ -206,12 +206,11 @@ class TestFsdpEndToEnd:
 
 class TestStreamedPutPeakBound:
     def test_inflight_bytes_bounded_on_flux_dev_int8_shapes(self, monkeypatch):
-        """The round-3 flux_16_int8 placement OOM fix pinned without hardware
-        (VERDICT r4 next-5): over a FLUX-dev-shaped int8 pytree (exact leaf
+        """The flux_16_int8 placement OOM fix pinned without hardware: over a
+        FLUX-dev-shaped int8 pytree (exact leaf
         shapes via jax.eval_shape — no buffers materialize), the un-drained
         transfer queue must never exceed max_inflight_bytes + one leaf. Byte
-        math only; device_put/block_until_ready are instrumented stubs.
-        Referenced from BASELINE.md's flux_16_int8 paragraph."""
+        math only; device_put/block_until_ready are instrumented stubs."""
         from types import SimpleNamespace
 
         from comfyui_parallelanything_tpu.models.flux import (

@@ -129,7 +129,7 @@ class TestPipelinePath:
 
     def test_explicit_spec_hint_on_tuple(self, novel, cpu_devices):
         # The (apply, params) form cannot carry attributes; the explicit
-        # pipeline_spec argument is the segments hint (VERDICT r2 item 5).
+        # pipeline_spec argument is the segments hint.
         module, params = novel
         spec = derive_pipeline_spec(module, params)
 

@@ -576,7 +576,7 @@ class TestAuditGate:
         assert audit.check(recs, golden, ledger_dir=str(tmp_path)) == 0
         capsys.readouterr()
 
-    def test_cli_check_over_wedged_tunnel_env(self, tmp_path):
+    def test_cli_check_without_a_backend(self, tmp_path):
         """The gate is jax-free: runs (and passes) in a child whose env
         points at a temp ledger, never importing jax."""
         with open(tmp_path / "perf_ledger.jsonl", "w") as f:

@@ -1,6 +1,7 @@
 """Tests for shared ops: timestep embedding, attention backends, pallas flash kernel
 (interpreter mode on the CPU platform)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -52,7 +53,7 @@ class TestAttention:
 class TestBackendEnvOverride:
     """PA_TPU_ATTENTION_BACKEND seeds the startup backend (ops/attention.py
     _initial_backend) so a driving process can force the safe XLA path for
-    every child it spawns after a failed hardware probe (scripts/tpu_watchdog)."""
+    every child it spawns."""
 
     def test_env_forces_xla(self, monkeypatch):
         import importlib
@@ -167,8 +168,8 @@ class TestChunkedAttention:
         }
 
     def test_persisted_chunk_tuning_honored(self, tmp_path, monkeypatch):
-        # The watchdog's chunk sweep persists the measured winner; a fresh
-        # process (no env) must serve it.
+        # A chunk sweep persists the measured winner to the file
+        # $PA_ATTN_CHUNK_TUNING names; a process pointed at it must serve it.
         import json as _json
 
         att = self._mod()
@@ -205,49 +206,22 @@ class TestChunkedAttention:
         finally:
             att.set_attention_backend("auto")
 
-    def test_forced_pallas_jax_padded_dim_takes_xla_family(self, monkeypatch):
-        # The watchdog's probe-failure fallback forces pallas_jax globally;
-        # 40/64-dim heads (upstream kernel has no lane padding) must route to
-        # the safe XLA family — including the chunked path for big logits —
-        # not to the unprobed in-repo padded kernel.
+    @pytest.mark.parametrize("shape", [
+        dict(sq=16, sk=16, d=4),      # 4 % 128 != 0: upstream has no lane pad
+        dict(sq=40, sk=40, d=128),    # 40 % 128 != 0: upstream has no seq pad
+        dict(sq=128, sk=72, d=128),   # mixed alignment is equally unservable
+    ], ids=["padded-dim", "unaligned-seq", "unaligned-kv"])
+    def test_forced_pallas_jax_raises_on_shapes_it_cannot_serve(self, shape):
+        # A FORCED backend that cannot serve a shape raises; only "auto" may
+        # choose another one.
         att = self._mod()
         att.set_attention_backend("pallas_jax")
         try:
-            monkeypatch.setattr(att, "_RESOLVED", set())
-            q, k, v = _qkv(b=1, sq=16, sk=16, h=1, d=4)  # 4 % 128 != 0
-            out = att.attention_local(q, k, v)
-            ref = att._xla_attention(q, k, v, scale=4 ** -0.5)
-            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                       rtol=2e-2, atol=2e-2)
-            assert att.resolved_backends() == ("xla",)
-            monkeypatch.setattr(att, "_CHUNK_THRESHOLD", 64)
-            monkeypatch.setattr(att, "_RESOLVED", set())
-            att.attention_local(*_qkv(b=1, sq=32, sk=32, h=2, d=8))
-            assert att.resolved_backends() == ("xla_chunked",)
-        finally:
-            att.set_attention_backend("auto")
-
-    def test_forced_pallas_jax_unaligned_seq_takes_xla_family(self,
-                                                              monkeypatch):
-        # Upstream jax flash kernel asserts seq % block == 0 (no padding); a
-        # forced pallas_jax on a 128-lane head but non-block-aligned sequence
-        # (e.g. an unswept WAN-class latent length) must fall back to the XLA
-        # family instead of crashing at trace time.
-        att = self._mod()
-        att.set_attention_backend("pallas_jax")
-        try:
-            monkeypatch.setattr(att, "_RESOLVED", set())
-            q, k, v = _qkv(b=1, sq=40, sk=40, h=1, d=128)  # 40 % 128 != 0
-            out = att.attention_local(q, k, v)
-            ref = att._xla_attention(q, k, v, scale=128 ** -0.5)
-            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                       rtol=2e-2, atol=2e-2)
-            assert att.resolved_backends() == ("xla",)
-            # Mixed alignment (aligned q, unaligned kv) is equally unsafe.
-            monkeypatch.setattr(att, "_RESOLVED", set())
-            q2, k2, v2 = _qkv(b=1, sq=128, sk=72, h=1, d=128)
-            att.attention_local(q2, k2, v2)
-            assert att.resolved_backends() == ("xla",)
+            q, k, v = _qkv(b=1, h=1, **shape)
+            with pytest.raises(ValueError, match="pallas_jax.*cannot serve"):
+                att.attention_local(q, k, v)
+            with pytest.raises(ValueError, match="pallas_jax.*cannot serve"):
+                att.backend_plan(shape["sq"], shape["sk"], head_dim=shape["d"])
         finally:
             att.set_attention_backend("auto")
 
@@ -263,11 +237,18 @@ class TestKernelTuning:
     def test_defaults_without_file(self, monkeypatch):
         from comfyui_parallelanything_tpu.ops.pallas import tuning
 
-        monkeypatch.setattr(tuning, "_PATH", "/nonexistent/tuning.json")
+        # No $PA_TUNING_PATH: the defaults, whatever an earlier run left in
+        # the checkout.
+        monkeypatch.setattr(tuning, "_PATH", None)
         tuning.kernel_tuning.cache_clear()
         try:
             assert tuning.best_blocks(4608) == (256, 256)
             assert tuning.pallas_wins(4608) is True  # default guess
+            # A table that was asked for and cannot be read is an error.
+            monkeypatch.setattr(tuning, "_PATH", "/nonexistent/tuning.json")
+            tuning.kernel_tuning.cache_clear()
+            with pytest.raises(OSError):
+                tuning.kernel_tuning()
         finally:
             tuning.kernel_tuning.cache_clear()
 
@@ -473,6 +454,16 @@ class TestKernelTuning:
 
 
 class TestFlashAttention:
+    def test_interpret_is_never_a_silent_choice(self, monkeypatch):
+        q, k, v = _qkv(b=1, sq=64, sk=64, h=2, d=32)
+        # Off a TPU the caller must say what it wants.
+        with pytest.raises(ValueError, match="pass interpret="):
+            flash_attention(q, k, v)
+        # On a TPU backend the kernel is compiled, never interpreted.
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="never interprets on a TPU"):
+            flash_attention(q, k, v, block_q=64, interpret=True)
+
     @pytest.mark.parametrize("sq,sk", [(64, 64), (100, 80), (256, 256), (300, 513)])
     def test_matches_xla(self, sq, sk):
         q, k, v = _qkv(b=1, sq=sq, sk=sk, h=2, d=32)
@@ -481,6 +472,37 @@ class TestFlashAttention:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
         )
+
+    def test_batch_sharded_operands_run_under_the_callers_mesh(self, cpu_devices):
+        # A Mosaic kernel in a jit with sharded operands is refused by the
+        # partitioner on real chips ("cannot be automatically partitioned") —
+        # found by the 4-chip smoke. Under the caller's context mesh
+        # (parallel/mesh.mesh_context) the kernel is shard_mapped over the
+        # data axis: rows stay where they are, nothing is gathered.
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from comfyui_parallelanything_tpu.parallel.mesh import mesh_context
+
+        mesh = Mesh(np.array(cpu_devices[:4]), ("data",))
+        q, k, v = _qkv(b=4, sq=128, sk=128, h=4, d=32)
+        want = flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
+        sharded = [jax.device_put(a, NamedSharding(mesh, P("data")))
+                   for a in (q, k, v)]
+        fn = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, block_q=64, block_k=64, interpret=True))
+        with mesh_context(mesh):
+            got = fn(*sharded)
+            hlo = fn.lower(*sharded).compile().as_text()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+        assert got.sharding.spec == P("data")
+        assert "all-gather" not in hlo
+        # A batch the axis does not divide runs the kernel as is.
+        with mesh_context(mesh):
+            odd = flash_attention(q[:3], k[:3], v[:3], block_q=64, block_k=64,
+                                  interpret=True)
+        np.testing.assert_allclose(np.asarray(odd), np.asarray(want[:3]),
+                                   rtol=1e-6, atol=1e-6)
 
     def test_cross_attention_shape(self):
         q, k, v = _qkv(b=2, sq=32, sk=77, h=4, d=16)

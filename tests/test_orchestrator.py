@@ -417,7 +417,7 @@ class TestReviewRegressions:
 
 class TestHybridMultiGroup:
     def test_auto_reactivation_after_n_steps(self, toy):
-        # VERDICT r2 item 6: reactivate_after=N resumes parallel execution
+        # reactivate_after=N resumes parallel execution
         # after N single-device steps instead of serializing the rest of a run.
         apply_fn, params = toy
         pm = parallelize(

@@ -98,7 +98,7 @@ class TestQuantizedModel:
         assert np.abs(quant - full).mean() / scale < 0.05
 
     def test_int8_sampler_run_close_to_bf16(self, flux_model):
-        # VERDICT r2 item 3: bound int8-vs-full-precision error END-TO-END
+        # bound int8-vs-full-precision error END-TO-END
         # through a sampler run, not just one forward — quantization noise
         # compounds across steps, and this is the regime the flux_16_int8
         # bench rung runs in.

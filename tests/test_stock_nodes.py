@@ -349,7 +349,7 @@ class TestStockWorkflow:
 
     def test_stock_conditioning_and_image_shims_run(self, tmp_path,
                                                     monkeypatch):
-        # VERDICT r3 missing #4: regional prompting (SetArea → Combine),
+        # regional prompting (SetArea → Combine),
         # prompt blending (Average), stock image resize, and PreviewImage —
         # one exported-style graph exercising all of them.
         paths = _synthetic_stock_env(tmp_path, monkeypatch)
@@ -597,7 +597,7 @@ class TestStockWorkflow:
     def test_lora_loader_strength_clip_bakes_text_tower(self, tmp_path,
                                                         monkeypatch):
         # A LoRA with kohya lora_te_* keys must rebuild the CLIP wire with the
-        # deltas baked into the bundled tower (ADVICE/VERDICT r3: the
+        # deltas baked into the bundled tower (the
         # strength_clip divergence closed).
         from safetensors.numpy import save_file
 
@@ -2712,8 +2712,8 @@ class TestLatentTransforms:
         # stored scaled by 0.18215.
         legacy = tmp_path / "out" / "legacy.latent"
         save_file(
-            {"latent_tensor":
-             np.moveaxis(np.asarray(x, np.float32), -1, 1) * 0.18215},
+            {"latent_tensor": np.ascontiguousarray(
+                np.moveaxis(np.asarray(x, np.float32), -1, 1) * 0.18215)},
             str(legacy),
         )
         (lat2,) = LoadLatent().load("legacy.latent")

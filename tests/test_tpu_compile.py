@@ -1,0 +1,127 @@
+"""The flash kernels of the main path, compiled at real widths by the TPU's
+own compiler for a DESCRIBED ``v5e:2x2`` chip (nothing is attached, nothing
+runs): what interpret mode cannot show — a slice not aligned to the tiling,
+more VMEM than a kernel may use — fails here, at no chip time.
+
+The topology is described inside a module-scoped fixture and nowhere else:
+only one process at a time may load the TPU's library, so this must never
+happen while a module is imported, and every such test lives in THIS file
+(a second file could land on another xdist worker, whose fixture would skip).
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from comfyui_parallelanything_tpu.ops.pallas.flash_attention import (
+    flash_attention,
+)
+
+# (label, B, S, H, D): FLUX-dev 1024² joint attention, a WAN 480p 81-frame
+# clip, and an SD1.5 shape whose 40-wide heads are lane-padded to 128.
+SHAPES = [
+    ("flux-dev-1024", 1, 4608, 24, 128),
+    ("wan-32k", 1, 32768, 12, 128),
+    ("sd15-d40", 2, 4096, 8, 40),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no compiler here is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of it.
+    prev_cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    # conftest pins "highest" matmul precision for CPU equivalence tests; the
+    # chip path runs at the default, and that is the program compiled here
+    # (at "highest" the upstream kernel's bf16 dot is refused by Mosaic).
+    prev_precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    yield desc
+    jax.config.update("jax_default_matmul_precision", prev_precision)
+    jax.config.update("jax_enable_compilation_cache", prev_cache)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _qkv(one_chip, b, s, h, d, dtype=jnp.bfloat16):
+    return (jax.ShapeDtypeStruct((b, s, h, d), dtype, sharding=one_chip),) * 3
+
+
+@pytest.mark.parametrize("block", [128, 256, 512])
+@pytest.mark.parametrize("label,b,s,h,d", SHAPES, ids=[s[0] for s in SHAPES])
+def test_flash_attention_compiles_for_v5e(one_chip, label, b, s, h, d, block):
+    compiled = flash_attention.lower(
+        *_qkv(one_chip, b, s, h, d), block_q=block, block_k=block,
+        interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the kernel is in there
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_cross_attention_keys_of_length_77_compile(one_chip):
+    q = jax.ShapeDtypeStruct((2, 4096, 8, 40), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 77, 8, 40), jnp.bfloat16, sharding=one_chip)
+    compiled = flash_attention.lower(q, kv, kv, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("which", ["in-repo", "upstream"])
+def test_batch_sharded_kernels_compile_for_four_chips(topo, which):
+    """The data-parallel step and the VAE decode of a chain's latent hand the
+    kernel operands sharded over four chips. The partitioner refuses a bare
+    Mosaic call there (the first 4-chip run of chip_smoke.py failed on it);
+    under the caller's context mesh the kernel is shard_mapped and compiles,
+    with nothing gathered."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from comfyui_parallelanything_tpu.parallel.mesh import mesh_context
+
+    att = importlib.import_module("comfyui_parallelanything_tpu.ops.attention")
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    # The kl-f8 VAE's mid-block attention for 8 images of 512²: one 512-wide head.
+    q = jax.ShapeDtypeStruct((8, 4096, 1, 512), jnp.bfloat16, sharding=rows)
+    fn = {
+        "in-repo": lambda q, k, v: flash_attention(q, k, v, interpret=False),
+        "upstream": lambda q, k, v: att._pallas_jax_attention(q, k, v, 0.04),
+    }[which]
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        jax.jit(fn).lower(q, q, q).compile()
+    with mesh_context(mesh):
+        compiled = jax.jit(fn).lower(q, q, q).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "all-gather" not in hlo
+    assert compiled.output_shardings.spec == P("data")
+
+
+def test_upstream_flash_kernel_compiles_at_flux_shape(one_chip):
+    # ops/__init__ exports a function named ``attention`` that shadows the
+    # module attribute.
+    att = importlib.import_module("comfyui_parallelanything_tpu.ops.attention")
+    compiled = jax.jit(
+        lambda q, k, v: att._pallas_jax_attention(q, k, v, 128 ** -0.5)
+    ).lower(*_qkv(one_chip, 1, 4608, 24, 128)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -39,71 +39,10 @@ def tiny_vae():
     return build_vae(TINY, jax.random.key(0), sample_hw=16)
 
 
-def _inv_conv(p, key, sd):
-    sd[f"{key}.weight"] = np.asarray(p["kernel"]).transpose(3, 2, 0, 1)
-    if "bias" in p:
-        sd[f"{key}.bias"] = np.asarray(p["bias"])
-
-
-def _inv_norm(p, key, sd):
-    sd[f"{key}.weight"] = np.asarray(p["scale"])
-    sd[f"{key}.bias"] = np.asarray(p["bias"])
-
-
-def _inv_res(p, t, sd):
-    _inv_norm(p["norm1"], f"{t}.norm1", sd)
-    _inv_conv(p["conv1"], f"{t}.conv1", sd)
-    _inv_norm(p["norm2"], f"{t}.norm2", sd)
-    _inv_conv(p["conv2"], f"{t}.conv2", sd)
-    if "nin_shortcut" in p:
-        _inv_conv(p["nin_shortcut"], f"{t}.nin_shortcut", sd)
-
-
-def _inv_attn(p, t, sd):
-    _inv_norm(p["norm"], f"{t}.norm", sd)
-    for k in ("q", "k", "v", "proj_out"):
-        _inv_conv(p[k], f"{t}.{k}", sd)
-
-
-def _ldm_layout_sd(cfg: VAEConfig, params) -> dict:
-    """Params → ldm checkpoint layout (the converter's inverse)."""
-    sd: dict = {}
-    enc, dec = params["encoder"], params["decoder"]
-    _inv_conv(enc["conv_in"], "encoder.conv_in", sd)
-    _inv_res(enc["mid_block_1"], "encoder.mid.block_1", sd)
-    _inv_attn(enc["mid_attn_1"], "encoder.mid.attn_1", sd)
-    _inv_res(enc["mid_block_2"], "encoder.mid.block_2", sd)
-    _inv_norm(enc["norm_out"], "encoder.norm_out", sd)
-    _inv_conv(enc["conv_out"], "encoder.conv_out", sd)
-    for lvl in range(len(cfg.channel_mult)):
-        for i in range(cfg.num_res_blocks):
-            _inv_res(enc[f"down_{lvl}_block_{i}"], f"encoder.down.{lvl}.block.{i}", sd)
-        if lvl != len(cfg.channel_mult) - 1:
-            _inv_conv(
-                enc[f"down_{lvl}_downsample"]["conv"],
-                f"encoder.down.{lvl}.downsample.conv",
-                sd,
-            )
-    _inv_conv(dec["conv_in"], "decoder.conv_in", sd)
-    _inv_res(dec["mid_block_1"], "decoder.mid.block_1", sd)
-    _inv_attn(dec["mid_attn_1"], "decoder.mid.attn_1", sd)
-    _inv_res(dec["mid_block_2"], "decoder.mid.block_2", sd)
-    _inv_norm(dec["norm_out"], "decoder.norm_out", sd)
-    _inv_conv(dec["conv_out"], "decoder.conv_out", sd)
-    for lvl in range(len(cfg.channel_mult)):
-        for i in range(cfg.num_res_blocks + 1):
-            _inv_res(dec[f"up_{lvl}_block_{i}"], f"decoder.up.{lvl}.block.{i}", sd)
-        if lvl != 0:
-            _inv_conv(
-                dec[f"up_{lvl}_upsample"]["conv"],
-                f"decoder.up.{lvl}.upsample.conv",
-                sd,
-            )
-    if cfg.use_quant_conv:
-        _inv_conv(params["quant_conv"], "quant_conv", sd)
-        _inv_conv(params["post_quant_conv"], "post_quant_conv", sd)
-    return sd
-
+# Params → ldm checkpoint layout (the converter's inverse) is chip_smoke.py's
+# (it writes the checkpoint the chip smoke serves): the round-trip tests below
+# pin it against the converter.
+from chip_smoke import ldm_vae_state_dict as _ldm_layout_sd  # noqa: E402
 
 
 class TestShapes:
