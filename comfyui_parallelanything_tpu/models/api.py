@@ -14,6 +14,29 @@ from typing import Any, Callable
 
 import jax
 
+from ..utils import tracing
+from ..utils.metrics import registry
+
+
+def denoise_span(program: str, x):
+    """Count one denoiser forward of an eager sampler loop and bracket the
+    host's dispatch of it. Every such forward passes one of two sites:
+    ``DiffusionModel.__call__`` and, for a wrapped model,
+    ``ParallelModel.__call__`` (a ControlNet composition is one
+    ``DiffusionModel`` and passes once). The counter is always on: scraped
+    from ``/metrics`` its rate is forwards per second per program, and its
+    increase over a prompt holds a server without the tracer to its graph's
+    ``steps``; the ``denoise`` span nests under the sampler's ``step``. The
+    whole-loop program (``compile_loop=True``) and the serving lanes call
+    ``apply`` inside programs of their own and pass neither site."""
+    registry.counter(
+        "pa_denoiser_calls_total", labels={"program": program},
+        help="denoiser forwards dispatched by the eager sampler loops "
+             "(DiffusionModel / ParallelModel calls)",
+    )
+    return tracing.span("denoise", cat="sampling", program=program,
+                        rows=x.shape[0] if hasattr(x, "shape") else None)
+
 
 @dataclasses.dataclass(frozen=True)
 class PipelineSegment:
@@ -111,7 +134,8 @@ class DiffusionModel:
             fn = self._jit_cache[key] = instrument_jit(
                 self.apply, f"model-apply:{self.name}"
             )
-        return fn(self.params, x, timesteps, context, **kwargs)
+        with denoise_span(fn.name, x):
+            return fn(self.params, x, timesteps, context, **kwargs)
 
     def n_params(self) -> int:
         import jax

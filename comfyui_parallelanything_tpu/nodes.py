@@ -24,6 +24,7 @@ from typing import Any
 from .devices.discovery import available_devices
 from .parallel.chain import DeviceChain
 from .parallel.orchestrator import ParallelConfig, parallelize
+from .utils import tracing
 
 CATEGORY = "parallel/tpu"
 
@@ -1780,14 +1781,22 @@ class TPUSaveImage:
         target_dir, name, start = resolve_save_target(
             filename_prefix, output_dir, "png"
         )
-        arr = np.asarray(images)
+        # The node's time in three parts: the wait for the device to finish
+        # what the graph enqueued (the sync the fetch performs anyway, split
+        # out), the device-to-host copy, and per image the quantise + encode
+        # + file write — the chip is idle under the last two.
+        with tracing.span("device-wait", cat="graph"):
+            if hasattr(images, "block_until_ready"):
+                images.block_until_ready()
+        with tracing.span("image-fetch", cat="graph") as sp:
+            arr = np.asarray(images)
+            sp.set(bytes=arr.nbytes)
         if arr.ndim == 3:
             arr = arr[None]
         elif arr.ndim == 5:
             # Video floats (B, F, H, W, 3) — the WAN decode shape: write every
             # frame of every clip as its own numbered PNG, in order.
             arr = arr.reshape((-1,) + arr.shape[2:])
-        arr = (np.clip(arr, 0.0, 1.0) * 255.0 + 0.5).astype(np.uint8)
         pnginfo = None
         if metadata or prompt is not None:
             import json as _json
@@ -1805,7 +1814,11 @@ class TPUSaveImage:
         paths = []
         for i, img in enumerate(arr):
             path = os.path.join(target_dir, f"{name}_{start + i:05d}.png")
-            Image.fromarray(img).save(path, pnginfo=pnginfo)
+            with tracing.span("png-encode", cat="graph", index=i) as sp:
+                img = (np.clip(img, 0.0, 1.0) * 255.0 + 0.5).astype(np.uint8)
+                Image.fromarray(img).save(path, pnginfo=pnginfo)
+                if tracing.on():
+                    sp.set(bytes=os.path.getsize(path))
             paths.append(path)
         return (tuple(paths),)
 

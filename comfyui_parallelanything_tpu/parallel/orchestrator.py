@@ -61,6 +61,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..devices.discovery import device_platform
 from ..devices.memory import free_memory_bytes
+from ..models.api import denoise_span
 from ..utils.cleanup import aggressive_cleanup
 from ..utils.logging import (
     get_logger,
@@ -439,6 +440,10 @@ class ParallelModel:
         )
 
     def __call__(self, x, timesteps, context=None, **kwargs):
+        with denoise_span("parallel-apply", x):
+            return self._route(x, timesteps, context, kwargs)
+
+    def _route(self, x, timesteps, context, kwargs):
         from ..ops.attention import sequence_ctx_key
 
         if self._stream:

@@ -269,29 +269,35 @@ def run_sampler(
         compiled path is one XLA program — no step boundaries to report or
         stop at, which run_sampler's docstring lists among its trade-offs.
 
-        Tracing: each boundary-to-boundary interval is recorded as a ``step``
-        span — the host-side dispatch window of one denoise step (the eager
-        loops do not sync per step, and tracing must not add a sync; the
-        serving bucket's step spans, which do block, carry the
-        device-inclusive durations)."""
+        Tracing: each boundary-to-boundary interval is a live ``step`` span —
+        opened at one boundary, closed at the next, on this thread — so the
+        ``denoise`` span of the step's forward nests under it and its
+        profiler annotation carries the step number. It is the host-side
+        dispatch window of one denoise step (the eager loops do not sync per
+        step, and tracing must not add a sync; the serving bucket's step
+        spans, which do block, carry the device-inclusive durations). One
+        span is recorded per callback, as before; a step that never reaches
+        its boundary (an interrupt, a raising model, a sampler that skips an
+        iteration) is dropped when ``sampler-run`` closes over it."""
         from ..utils.progress import report_progress
 
-        t_last = [tracing.now_us()] if tracing.on() else None
+        def open_step(k):
+            sp = tracing.span("step", cat="sampling", step=k, of=n_steps)
+            sp.__enter__()
+            return sp
+
+        live = [open_step(1)] if tracing.on() else None
 
         def cb2(i, x):
-            if t_last is not None and tracing.on():
-                now = tracing.now_us()
-                tracing.record(
-                    "step", t_last[0], now - t_last[0], cat="sampling",
-                    step=i + 1, of=n_steps,
-                )
-                t_last[0] = now
+            if live is not None:
+                live[0].__exit__(None, None, None)
             # Raises Interrupted if requested; x feeds the WS latent-preview
             # hook (utils/progress.set_preview_hook) when one is installed.
             report_progress(i + 1, n_steps, latent=x)
-            if cb is not None:
-                return cb(i, x)
-            return None
+            out = cb(i, x) if cb is not None else None
+            if live is not None and i + 2 <= n_steps:
+                live[0] = open_step(i + 2)
+            return out
 
         return cb2
 

@@ -159,6 +159,9 @@ class ServeRequest:
     # fleet traceparent's trace_id) — the dispatcher stamps it onto this
     # request's lane-wait/step/lane spans, same rule as trace_tid.
     trace_id: Optional[str] = None
+    # The span open on the submitting thread at submit (its ``sampler-run``):
+    # the parent of every span the dispatcher records for this request.
+    trace_parent: Optional[int] = None
     rid: str = dataclasses.field(default_factory=lambda: uuid.uuid4().hex)
     submit_ts: float = dataclasses.field(default_factory=time.monotonic)
 
@@ -1031,6 +1034,7 @@ class StepBucket:
                         "lane-wait", req.trace_submit_us,
                         self.lanes[i].seat_us - req.trace_submit_us,
                         cat="serving", tid=req.trace_tid,
+                        parent_span_id=req.trace_parent,
                         prompt_id=req.prompt_id, bucket=self.label, lane=i,
                         rid=req.rid, queue_depth=len(self.queue),
                         **({"trace_id": req.trace_id}
@@ -1057,6 +1061,7 @@ class StepBucket:
             tracing.record(
                 "lane", lane.seat_us, tracing.now_us() - lane.seat_us,
                 cat="serving", tid=lane.req.trace_tid,
+                parent_span_id=lane.req.trace_parent,
                 prompt_id=lane.req.prompt_id, bucket=self.label, lane=i,
                 rid=lane.req.rid, steps_run=lane.idx,
                 outcome="error" if error is not None else "completed",
@@ -1406,6 +1411,7 @@ class StepBucket:
                 tracing.record(
                     "step", t0_us, dur_us, cat="serving",
                     tid=lane.req.trace_tid, prompt_id=lane.req.prompt_id,
+                    parent_span_id=lane.req.trace_parent,
                     bucket=self.label, lane=i, step=lane.idx + 1,
                     of=lane.req.n_steps, occupancy=len(active),
                     **({"trace_id": lane.req.trace_id}

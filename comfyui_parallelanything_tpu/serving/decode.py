@@ -98,6 +98,7 @@ class DecodeTicket:
     prompt_id: Any = None
     trace_tid: Any = None
     trace_id: Any = None  # distributed trace identity (see ServeRequest)
+    trace_parent: Any = None  # span open on the submitter (see ServeRequest)
     rid: str = dataclasses.field(default_factory=lambda: uuid.uuid4().hex)
 
     def __post_init__(self):
@@ -199,6 +200,7 @@ class DecodeQueue:
             prompt_id=tracing.current_prompt_id() if tracing.on() else None,
             trace_tid=threading.get_ident() if tracing.on() else None,
             trace_id=tracing.current_trace_id() if tracing.on() else None,
+            trace_parent=tracing.current_span_id() if tracing.on() else None,
         )
         with self._lock:
             if self._stop:
@@ -310,6 +312,7 @@ class DecodeQueue:
                 tracing.record(
                     "decode", t0_us, dur_us, cat="serving",
                     tid=t.trace_tid, prompt_id=t.prompt_id, rid=t.rid,
+                    parent_span_id=t.trace_parent,
                     occupancy=k,
                     **({"trace_id": t.trace_id} if t.trace_id else {}),
                 )

@@ -380,6 +380,7 @@ class ContinuousBatchingScheduler:
             trace_tid=threading.get_ident() if tracing.on() else None,
             trace_submit_us=tracing.now_us() if tracing.on() else None,
             trace_id=tracing.current_trace_id() if tracing.on() else None,
+            trace_parent=tracing.current_span_id() if tracing.on() else None,
             **_current_hints(),
         )
         with self._lock:

@@ -103,6 +103,18 @@ chaos site's watched latency signal; ``target`` is ``journal`` or
 existing ``pa_fleet_*`` family (fleet/scoreboard.py — seconds since each
 backend's last successful health poll, the sentinel's
 heartbeat-staleness signal).
+
+Denoiser forwards (PR 24): ``pa_denoiser_calls_total{program=}``
+(models/api.py ``denoise_span`` — one per model forward the eager sampler
+loops dispatch, through ``DiffusionModel`` (``program`` =
+``model-apply:<name>``; a ControlNet composition is ONE program,
+``<base>+control``, and counts once) or ``ParallelModel``
+(``parallel-apply``); always on. Operator's use: its rate is forwards per
+second per program, and its increase over a prompt against the graph's
+``steps`` (2n−1 for heun) shows a skipped or repeated forward on a server
+running without the span tracer; the ``denoise`` span is its traced twin).
+``pa_trace_dropped_total`` gains ``reason="abandoned"``: spans the traced
+code left open, closed by their parent and not recorded.
 """
 
 from __future__ import annotations

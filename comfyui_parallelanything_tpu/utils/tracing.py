@@ -8,7 +8,7 @@ lane lifecycles, per-thread progress scopes — and every open ROADMAP item
 is blocked on being able to *see* where time goes. This module is that layer:
 a process-wide :class:`Tracer` producing per-prompt traces of nested spans
 
-    prompt → workflow-node → sampler-run → lane-wait → step
+    prompt → workflow-node → sampler-run → lane-wait → step → denoise
                                               → stream-stage-{prefetch,compute}
 
 exported in Chrome/Perfetto trace-event JSON (``GET /trace?prompt_id=...`` on
@@ -39,11 +39,27 @@ Design rules (the near-zero-overhead contract):
   duration into ``MetricsRegistry`` (``pa_trace_span_seconds{name=...}``
   histogram), so ``/metrics`` aggregates and ``/trace`` timelines are two
   views of the same measurements.
+- **one tree**: every span carries ``parent_span_id`` — the innermost span
+  open on its thread when it opened, or, for a span recorded on behalf of
+  another thread (``record(..., tid=)``), the id the caller captured at
+  submission (:func:`current_span_id`). Root spans have none; the prompt
+  span keeps the router's id from ``traceparent`` under the same key.
+- **one clock with the profiler**: while tracing is on, every live span
+  (:func:`span`) also holds a ``jax.profiler.TraceAnnotation`` of its name
+  (the sampler's ``step`` a ``StepTraceAnnotation`` of its ``step``) carrying
+  ``span_id`` and ``prompt_id``, entered and left on the span's own thread.
+  Under any profiler session the span tree then lies on the xplane's host
+  plane, on the trace's clock, beside the PJRT events. Spans written after
+  the fact with :func:`record` have no annotation (their interval is over,
+  often on another thread). ``jax`` is imported on the first live span,
+  never at module import.
 
 ``block_until_ready`` discipline: instrumentation only ever *reads the clock*
 at boundaries that already synchronize (the serving bucket's post-dispatch
-block, the streaming runner's backpressure block, the eager loops' progress
-callbacks) — tracing never adds a device sync of its own.
+block, the streaming runner's backpressure block, the save node's wait before
+its fetch) or brackets the host's side of a dispatch (the eager loops' step
+boundaries, ``denoise``) — tracing never adds a device sync
+of its own.
 """
 
 from __future__ import annotations
@@ -125,6 +141,32 @@ def now_us() -> float:
     return time.perf_counter_ns() / 1e3
 
 
+_profiler = None
+
+
+def _annotate(name: str, attrs: dict, span_id: int):
+    """Enter the span's twin on the profiler's host plane (a no-op object
+    unless a profiler session is open). Without jax there is no profiler to
+    annotate for."""
+    global _profiler
+    if _profiler is None:
+        try:
+            from jax import profiler as _profiler
+        except ImportError:
+            _profiler = False
+    if not _profiler:
+        return None
+    kw = {"span_id": span_id}
+    if attrs.get("prompt_id") is not None:
+        kw["prompt_id"] = attrs["prompt_id"]
+    if name == "step" and "step" in attrs:
+        ann = _profiler.StepTraceAnnotation(name, step_num=attrs["step"], **kw)
+    else:
+        ann = _profiler.TraceAnnotation(name, **kw)
+    ann.__enter__()
+    return ann
+
+
 class _NullSpan:
     """The disabled-path singleton: a context manager that does nothing and
     allocates nothing. ``set()`` (attribute attach) is a no-op too, so call
@@ -149,7 +191,8 @@ class _OpenSpan:
     """One live span on the opening thread's stack; closing (context exit)
     records a completed ``X`` event into that thread's ring buffer."""
 
-    __slots__ = ("_tracer", "_local", "name", "cat", "ts", "attrs", "span_id")
+    __slots__ = ("_tracer", "_local", "name", "cat", "ts", "attrs", "span_id",
+                 "_ann", "_closed")
 
     def __init__(self, tracer, local, name, cat, attrs):
         self._tracer = tracer
@@ -159,25 +202,47 @@ class _OpenSpan:
         self.attrs = attrs
         self.span_id = next(_span_ids)
         self.ts = 0.0
+        self._ann = None
+        self._closed = False
 
     def set(self, **attrs):
         self.attrs.update(attrs)
         return self
 
     def __enter__(self):
-        self._local.stack.append(self)
+        stack = self._local.stack
+        if stack:
+            self.attrs.setdefault("parent_span_id", stack[-1].span_id)
+        stack.append(self)
+        self._ann = _annotate(self.name, self.attrs, self.span_id)
         self.ts = now_us()
         return self
 
+    def _close(self):
+        self._closed = True
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
     def __exit__(self, *exc):
+        if self._closed:
+            # Left once already, or abandoned below: one event per span.
+            return False
         dur = now_us() - self.ts
         stack = self._local.stack
-        # LIFO by construction (context managers); tolerate a corrupted stack
-        # rather than poisoning the traced code path.
-        if stack and stack[-1] is self:
+        if self in stack:
+            # A span still open above this one was abandoned by the code it
+            # traced (a sampler that raised between two step boundaries):
+            # its annotation is closed and it is counted as dropped, not
+            # recorded.
+            abandoned = 0
+            while stack[-1] is not self:
+                stack.pop()._close()
+                abandoned += 1
             stack.pop()
-        elif self in stack:
-            stack.remove(self)
+            if abandoned:
+                self._tracer._drop("abandoned", abandoned)
+        self._close()
         self._tracer._emit(
             self._local, self.name, self.ts, dur, self.cat,
             threading.get_ident(), self.attrs, self.span_id,
@@ -224,13 +289,8 @@ class Tracer:
         # Eviction accounting per reason — the local mirror of the
         # pa_trace_dropped_total counter (readable without a metrics scrape).
         self.dropped: dict[str, int] = {}  # guarded-by: _lock
-        self._epoch_us = now_us()
-        # Wall-clock anchor taken at the SAME moment as the monotonic epoch:
-        # the cross-host stitcher aligns each process's trace-event clock
-        # (perf_counter-based, per-process origin) onto a shared timeline via
-        # these anchors. NTP-level skew (ms) is the accepted error bar.
-        # palint: allow[observability] clock-alignment epoch STAMP
-        self._epoch_wall_s = time.time()
+        # perf_counter_ns of ts == 0: the trace-event clock's origin.
+        self._epoch_ns = time.perf_counter_ns()
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -245,9 +305,7 @@ class Tracer:
             self._retired.clear()
             self._retained.clear()
             self.dropped = {}
-            self._epoch_us = now_us()
-            # palint: allow[observability] clock-alignment epoch STAMP
-            self._epoch_wall_s = time.time()
+            self._epoch_ns = time.perf_counter_ns()
         self._local = _Local()
         self.enabled = True
 
@@ -279,19 +337,18 @@ class Tracer:
                     # record real spans — fleet dispatch hops among them).
                     if len(self._retired) == self._retired.maxlen:
                         evicted = len(self._retired[0][1])
-                        self.dropped["retired-ring"] = (
-                            self.dropped.get("retired-ring", 0) + evicted
-                        )
                     self._retired.append(prev)
                 self._buffers[threading.get_ident()] = (t.name, ev)
             if evicted:
-                # Counter emitted OUTSIDE the tracer lock (metrics registry
-                # has its own lock; keep the order acyclic).
-                self._count_dropped("retired-ring", evicted)
+                self._drop("retired-ring", evicted)
         return ev
 
-    @staticmethod
-    def _count_dropped(reason: str, n: int) -> None:
+    def _drop(self, reason: str, n: int) -> None:
+        """Count ``n`` spans lost for ``reason``. Called outside ``_lock``:
+        the counter is emitted with the tracer lock released (the metrics
+        registry has its own lock; keep the order acyclic)."""
+        with self._lock:
+            self.dropped[reason] = self.dropped.get(reason, 0) + n
         # Same lazy-import/never-raise contract as _feed_metrics.
         try:
             from .metrics import registry
@@ -300,8 +357,9 @@ class Tracer:
                 "pa_trace_dropped_total", float(n),
                 labels={"reason": reason},
                 help="spans evicted from tracer retention tiers "
-                     "(retired-thread ring, completed-prompt retention) — "
-                     "nonzero means the stitched-timeline view is incomplete",
+                     "(retired-thread ring, completed-prompt retention) or "
+                     "abandoned open by the code they traced — nonzero "
+                     "means the stitched-timeline view is incomplete",
             )
         except Exception:
             pass
@@ -348,10 +406,16 @@ class Tracer:
         """Record an already-measured span (explicit interval). ``tid``
         attributes the span to another thread's timeline (the serving
         dispatcher recording on behalf of a blocked submitter); the write
-        still goes to the *calling* thread's lock-free buffer."""
+        still goes to the *calling* thread's lock-free buffer. Its parent is
+        the innermost span open on the calling thread; with ``tid`` the
+        caller passes ``parent_span_id=`` as captured on the submitter."""
         if not self.enabled:
             return
         local = self._local
+        if attrs.get("parent_span_id") is None:
+            attrs.pop("parent_span_id", None)
+            if tid is None and local.stack:
+                attrs["parent_span_id"] = local.stack[-1].span_id
         if prompt_id is None:
             prompt_id = self._current_prompt_id(local)
         if prompt_id is not None:
@@ -452,12 +516,8 @@ class Tracer:
             while len(self._retained) > PROMPT_RETENTION:
                 _pid, old = self._retained.popitem(last=False)
                 evicted += len(old)
-            if evicted:
-                self.dropped["prompt-retention"] = (
-                    self.dropped.get("prompt-retention", 0) + evicted
-                )
         if evicted:
-            self._count_dropped("prompt-retention", evicted)
+            self._drop("prompt-retention", evicted)
         return len(rows)
 
     # -- export -------------------------------------------------------------
@@ -484,7 +544,7 @@ class Tracer:
             else:
                 retained = [r for rows in self._retained.values()
                             for r in rows]
-            epoch_wall = self._epoch_wall_s
+        epoch_us = self._epoch_ns / 1e3
         snap.append((0, "retained", retained))
         events: list[dict] = []
         tids_seen: set[int] = set()
@@ -500,7 +560,7 @@ class Tracer:
                 args["span_id"] = span_id
                 events.append({
                     "ph": "X", "name": name, "cat": cat,
-                    "ts": round(ts - self._epoch_us, 3),
+                    "ts": round(ts - epoch_us, 3),
                     "dur": round(dur, 3),
                     "pid": pid, "tid": tid, "args": args,
                 })
@@ -518,9 +578,16 @@ class Tracer:
         return {
             "traceEvents": meta + events,
             "displayTimeUnit": "ms",
-            # Wall-clock anchor of ts==0 (taken with the monotonic epoch):
-            # the cross-host stitcher's clock-domain alignment key.
-            "epoch_wall_s": epoch_wall,
+            # Wall-clock anchor of ts == 0, taken NOW (the wall clock less
+            # the monotonic time since the epoch): whoever aligns this trace
+            # with another clock domain — the cross-host stitcher, a profiler
+            # bracket's own wall stamp — pairs two wall readings taken moments
+            # apart, so wall/monotonic drift since enable() stays out of the
+            # alignment. NTP-level skew (ms) between hosts is the accepted
+            # error bar.
+            # palint: allow[observability] clock-alignment epoch STAMP
+            "epoch_wall_s": time.time()
+            - (time.perf_counter_ns() - self._epoch_ns) / 1e9,
         }
 
 
@@ -574,27 +641,6 @@ def current_trace_id() -> Optional[str]:
 
 def retain_prompt(prompt_id: str | None) -> int:
     return tracer.retain_prompt(prompt_id)
-
-
-def epoch_wall_s() -> float:
-    """Wall-clock instant of the tracer's ts==0 origin (stitcher anchor)."""
-    return tracer._epoch_wall_s
-
-
-@contextlib.contextmanager
-def hardware_trace(log_dir: str = "/tmp/parallelanything-trace"):
-    """Bracket a span subtree with ``jax.profiler.trace`` so the XProf device
-    timeline lines up with the host spans recorded inside the block: open the
-    trace in Perfetto alongside the ``GET /trace`` export and the
-    ``hardware-trace`` host span marks the profiled window."""
-    import jax
-
-    with span("hardware-trace", cat="profiler", log_dir=log_dir):
-        jax.profiler.start_trace(log_dir)
-        try:
-            yield log_dir
-        finally:
-            jax.profiler.stop_trace()
 
 
 # -- trace-derived aggregates ------------------------------------------------

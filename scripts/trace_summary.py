@@ -31,8 +31,8 @@ from collections import defaultdict
 SPAN_CATEGORIES = (
     "host",       # utils/tracing.py default — uncategorized host work
     "server",     # server.py prompt / admission-wait spans
-    "graph",      # host.py workflow-node spans
-    "sampling",   # sampling/runner.py sampler-run + eager step spans
+    "graph",      # host.py workflow-node + nodes.py save-stage spans
+    "sampling",   # sampling/runner.py sampler-run + eager step + denoise spans
     "serving",    # serving/bucket.py dispatch/lane/step spans
     "stream",     # parallel/streaming.py run/prefetch/wait/compute spans
     "bench",      # bench.py timed-iteration step spans
@@ -42,7 +42,6 @@ SPAN_CATEGORIES = (
     "faults",     # utils/faults.py fault-injected instants
     "anomaly",    # utils/anomaly.py sentinel-firing instants
     "degrade",    # utils/degrade.py degradation-rung instants
-    "profiler",   # utils/tracing.hardware_trace jax.profiler bracket
 )
 
 

@@ -613,3 +613,394 @@ class TestServerTraceEndpoint:
         )
         assert [e for e in get("/trace?prompt_id=nope")["traceEvents"]
                 if e.get("ph") == "X"] == []
+
+
+# -- PR 24: parents, the profiler bridge, denoise + save stage spans ---------
+
+
+def _counting_model():
+    """A ``DiffusionModel`` that counts the calls made INTO it, so spans and
+    the counter can be held to the forwards the sampler really asked for."""
+    from comfyui_parallelanything_tpu.models.api import DiffusionModel
+
+    class Counting(DiffusionModel):
+        calls = 0
+
+        def __call__(self, *a, **kw):
+            type(self).calls += 1
+            return super().__call__(*a, **kw)
+
+    return Counting(apply=lambda p, x, t, c=None, **kw: _tiny_model(x, t, c),
+                    params={}, name="stub")
+
+
+def _sample(model, sampler, steps, cfg=1.0, **kw):
+    from comfyui_parallelanything_tpu.sampling.runner import run_sampler
+
+    r = np.random.default_rng(0)
+    noise = jnp.asarray(r.normal(size=(2, 8, 8, 4)).astype(np.float32))
+    ctx = jnp.asarray(r.normal(size=(2, 6, 16)).astype(np.float32))
+    return run_sampler(model, noise, ctx, sampler=sampler, steps=steps,
+                       cfg_scale=cfg,
+                       uncond_context=jnp.zeros_like(ctx) if cfg != 1.0 else None,
+                       **kw)
+
+
+def _denoiser_calls(program="model-apply:stub") -> float:
+    return registry.get("pa_denoiser_calls_total",
+                        {"program": program}) or 0.0
+
+
+class _SampleNode:
+    @classmethod
+    def INPUT_TYPES(cls):
+        return {"required": {"steps": ("INT", {"default": 3})}}
+
+    RETURN_TYPES = ("LATENT",)
+    FUNCTION = "run"
+
+    def run(self, steps):
+        return (_sample(_counting_model(), "euler", steps),)
+
+
+class TestSpanTree:
+    def test_graph_exports_the_chain_by_parent_span_id(self):
+        from comfyui_parallelanything_tpu.host import run_workflow
+
+        tracing.enable()
+        with tracing.span("prompt", prompt_id="p"):
+            run_workflow({"1": {"class_type": "Sample", "inputs": {"steps": 3}}},
+                         class_mappings={"Sample": _SampleNode})
+        xs = _x_events()
+        by_id = {e["args"]["span_id"]: e for e in xs}
+        denoise = [e for e in xs if e["name"] == "denoise"]
+        assert len(denoise) == 3
+        for d in denoise:
+            chain, e = [], d
+            while e is not None:
+                chain.append(e["name"])
+                e = by_id.get(e["args"].get("parent_span_id"))
+            assert chain == ["denoise", "step", "sampler-run", "workflow-node",
+                             "prompt"], chain
+        # one denoise per step, each under a step of its own
+        assert len({d["args"]["parent_span_id"] for d in denoise}) == 3
+        roots = [e for e in xs if "parent_span_id" not in e["args"]]
+        assert [e["name"] for e in roots] == ["prompt"]
+        _assert_nested_per_tid(xs)
+
+    def test_record_parents(self):
+        """A span recorded on the calling thread hangs under the span open
+        there; one recorded for another thread (``tid=``) names the span its
+        caller captured at submission, and never the recorder's own."""
+        tracing.enable()
+        box = {}
+        with tracing.span("submitter", prompt_id="p") as sub:
+            box["parent"], box["tid"] = tracing.current_span_id(), threading.get_ident()
+            tracing.record("inline", tracing.now_us(), 1.0)
+
+            def dispatcher():
+                with tracing.span("dispatcher-loop"):
+                    tracing.record("lane", tracing.now_us(), 5.0, tid=box["tid"],
+                                   prompt_id="p", parent_span_id=box["parent"])
+                    tracing.record("orphan", tracing.now_us(), 5.0,
+                                   tid=box["tid"], prompt_id="p")
+
+            t = threading.Thread(target=dispatcher)
+            t.start()
+            t.join(10)
+        args = {e["name"]: e["args"] for e in _x_events()}
+        assert args["inline"]["parent_span_id"] == sub.span_id
+        assert args["lane"]["parent_span_id"] == sub.span_id
+        assert "parent_span_id" not in args["orphan"]
+
+    def test_serving_spans_name_the_submitters_sampler_run(self):
+        from comfyui_parallelanything_tpu.sampling.runner import run_sampler
+        from comfyui_parallelanything_tpu.serving import (
+            ContinuousBatchingScheduler,
+        )
+
+        tracing.enable()
+        sched = ContinuousBatchingScheduler(max_width=2, auto=False).install()
+        try:
+            def worker():
+                with tracing.span("prompt", prompt_id="p"):
+                    run_sampler(_tiny_model, jnp.ones((1, 8, 8, 4)),
+                                jnp.ones((1, 6, 16)), sampler="euler", steps=2)
+
+            t = threading.Thread(target=worker, daemon=True)
+            t.start()
+            t0 = time.time()
+            while time.time() - t0 < 20 and not any(
+                    len(b.queue) + len(b.active_lanes())
+                    for b in list(sched.buckets.values())):
+                time.sleep(0.005)
+            sched.drain()
+            t.join(20)
+        finally:
+            sched.uninstall()
+            sched.shutdown()
+        xs = _x_events()
+        run = next(e for e in xs if e["name"] == "sampler-run")
+        lane = [e for e in xs if e["name"] in ("lane-wait", "lane", "step")]
+        assert len(lane) == 4
+        assert {e["args"]["parent_span_id"] for e in lane} == {run["args"]["span_id"]}
+
+
+class TestDenoiseSite:
+    @pytest.mark.parametrize("tracer_on", [True, False])
+    @pytest.mark.parametrize("sampler,cfg,steps,forwards", [
+        ("euler", 1.0, 4, 4), ("euler", 5.0, 4, 4),
+        ("heun", 1.0, 4, 7), ("heun", 5.0, 3, 5),
+        ("dpmpp_2m", 5.0, 5, 5),
+    ])
+    def test_spans_and_counter_equal_the_models_calls(
+            self, sampler, cfg, steps, forwards, tracer_on):
+        if tracer_on:
+            tracing.enable()
+        model = _counting_model()
+        before = _denoiser_calls()
+        _sample(model, sampler, steps, cfg)
+        assert type(model).calls == forwards
+        # the counter moves the same whether or not the tracer is on
+        assert _denoiser_calls() - before == forwards
+        xs = _x_events()
+        if not tracer_on:
+            assert xs == []
+            return
+        denoise = [e for e in xs if e["name"] == "denoise"]
+        assert len(denoise) == forwards
+        assert sum(e["name"] == "step" for e in xs) == steps
+        assert all(e["args"]["program"] == "model-apply:stub"
+                   and e["args"]["rows"] == (4 if cfg != 1.0 else 2)
+                   and e["cat"] == "sampling" for e in denoise)
+
+    def test_metrics_text_serves_the_counter_with_the_tracer_off(self):
+        _sample(_counting_model(), "euler", 2)
+        assert re.search(
+            r'^pa_denoiser_calls_total\{program="model-apply:stub"\} \d',
+            registry.render(), re.M)
+
+    def test_parallel_model_is_one_site_of_its_own(self):
+        import comfyui_parallelanything_tpu as pa
+
+        tracing.enable()
+        pm = pa.parallelize(
+            (lambda p, x, t, context=None, **kw: _tiny_model(x, t, context), {}),
+            pa.DeviceChain.even([f"cpu:{i}" for i in range(2)]))
+        before = _denoiser_calls("parallel-apply")
+        _sample(pm, "euler", 3)
+        assert _denoiser_calls("parallel-apply") - before == 3
+        denoise = [e for e in _x_events() if e["name"] == "denoise"]
+        assert [e["args"]["program"] for e in denoise] == ["parallel-apply"] * 3
+
+    def test_a_skipped_forward_shows_where_a_step_count_cannot(self, monkeypatch):
+        """The guard the count of ``step`` spans could not give: a sampler
+        that reuses the last x0 for one iteration and still fires every
+        callback reads ``step`` = n and ``denoise`` = n - 1."""
+        from comfyui_parallelanything_tpu.sampling import runner
+        from comfyui_parallelanything_tpu.sampling.cfg import apply_callback
+
+        def lazy_euler(denoise, x, sigmas, callback=None):
+            x0 = None
+            for i in range(len(sigmas) - 1):
+                if i != 2:
+                    x0 = denoise(x, sigmas[i])
+                x = x + (x - x0) / sigmas[i] * (sigmas[i + 1] - sigmas[i])
+                x = apply_callback(callback, i, x)
+            return x
+
+        monkeypatch.setitem(runner.K_SAMPLERS, "euler", lazy_euler)
+        tracing.enable()
+        _sample(_counting_model(), "euler", 5)
+        names = [e["name"] for e in _x_events()]
+        assert (names.count("step"), names.count("denoise")) == (5, 4)
+
+    @pytest.mark.parametrize("fault", ["interrupt", "model-raises"])
+    def test_a_broken_run_leaves_no_span_open(self, fault):
+        """A step that never reaches its boundary is dropped with its
+        annotation when ``sampler-run`` closes; the thread's stack is clean
+        and only whole steps were recorded."""
+        from comfyui_parallelanything_tpu.utils.progress import Interrupted
+
+        tracing.enable()
+        model = _counting_model()
+        if fault == "interrupt":
+            def hook(value, max_value):
+                if value == 2:
+                    raise Interrupted("stop")
+
+            with progress_scope(hook=hook), pytest.raises(Interrupted):
+                _sample(model, "euler", 4)
+        else:
+            real = type(model).__call__
+
+            def failing(self, *a, **kw):
+                if type(self).calls == 2:
+                    raise RuntimeError("boom")
+                return real(self, *a, **kw)
+
+            type(model).__call__ = failing
+            with pytest.raises(RuntimeError):
+                _sample(model, "euler", 4)
+        assert tracing.tracer._local.stack == []
+        names = [e["name"] for e in _x_events()]
+        # both faults strike after the second boundary: two whole steps were
+        # recorded. The raising model leaves the third step open, and it is
+        # counted as dropped; the interrupt strikes before the third opens.
+        assert names.count("step") == 2 and names.count("sampler-run") == 1
+        assert tracing.tracer.dropped.get("abandoned", 0) == (
+            1 if fault == "model-raises" else 0)
+
+    def test_a_span_left_twice_is_recorded_once(self):
+        """``step`` is entered by hand and left from the callback: a sampler
+        that fires a callback too many must not emit the same span again."""
+        tracing.enable()
+        with tracing.span("outer"):
+            sp = tracing.span("step", step=1)
+            sp.__enter__()
+            sp.__exit__(None, None, None)
+            sp.__exit__(None, None, None)
+        names = [e["name"] for e in _x_events()]
+        assert names.count("step") == 1 and names.count("outer") == 1
+        assert tracing.tracer.dropped == {}
+
+    def test_abandoned_spans_are_counted_not_recorded(self):
+        before = registry.get("pa_trace_dropped_total",
+                              {"reason": "abandoned"}) or 0.0
+        tracing.enable()
+        with tracing.span("outer"):
+            tracing.span("left-open-1").__enter__()
+            late = tracing.span("left-open-2")
+            late.__enter__()
+        # leaving it after its parent closed over it records nothing
+        late.__exit__(None, None, None)
+        assert [e["name"] for e in _x_events()] == ["outer"]
+        assert tracing.tracer.dropped == {"abandoned": 2}
+        assert registry.get("pa_trace_dropped_total",
+                            {"reason": "abandoned"}) - before == 2
+
+    def test_a_controlnet_composition_is_one_forward(self):
+        """``apply_control`` merges the control trunk and the base into one
+        program: one ``denoise`` and one count per step, under the
+        composition's own program label."""
+        from comfyui_parallelanything_tpu.models.api import DiffusionModel
+        from comfyui_parallelanything_tpu.models.controlnet import apply_control
+
+        base = DiffusionModel(
+            apply=lambda p, x, t, c=None, control=None, **kw: _tiny_model(x, t, c),
+            params={}, name="stub")
+        ctrl = DiffusionModel(
+            apply=lambda p, x, t, c=None, hint=None, y=None: {},
+            params={}, name="ctrl")
+        composed = apply_control(base, ctrl, jnp.zeros((64, 64, 3)))
+        program = "model-apply:stub+control"
+        tracing.enable()
+        before = (_denoiser_calls(program), _denoiser_calls(),
+                  _denoiser_calls("model-apply:ctrl"))
+        _sample(composed, "euler", 3)
+        assert (_denoiser_calls(program), _denoiser_calls(),
+                _denoiser_calls("model-apply:ctrl")) == (
+            before[0] + 3, before[1], before[2])
+        denoise = [e for e in _x_events() if e["name"] == "denoise"]
+        assert [e["args"]["program"] for e in denoise] == [program] * 3
+
+
+class TestSaveStages:
+    @pytest.mark.parametrize("k", [1, 3])
+    def test_save_splits_into_wait_fetch_and_k_encodes(self, k, tmp_path):
+        from comfyui_parallelanything_tpu.nodes import TPUSaveImage
+
+        images = jnp.asarray(np.random.default_rng(1).uniform(
+            size=(k, 16, 16, 3)).astype(np.float32))
+        tracing.enable()
+        with tracing.span("workflow-node", cat="graph", class_type="SaveImage",
+                          prompt_id="p") as node:
+            (paths,) = TPUSaveImage().save(images, output_dir=str(tmp_path))
+        xs = _x_events()
+        parent = next(e for e in xs if e["name"] == "workflow-node")
+        parts = [e for e in xs if e["name"] != "workflow-node"]
+        assert sorted(e["name"] for e in parts) == sorted(
+            ["device-wait", "image-fetch"] + ["png-encode"] * k)
+        assert all(e["args"]["parent_span_id"] == node.span_id
+                   and e["cat"] == "graph" for e in parts)
+        assert sum(e["dur"] for e in parts) <= parent["dur"]
+        fetch = next(e for e in parts if e["name"] == "image-fetch")
+        assert fetch["args"]["bytes"] == k * 16 * 16 * 3 * 4
+        enc = sorted((e for e in parts if e["name"] == "png-encode"),
+                     key=lambda e: e["args"]["index"])
+        assert [e["args"]["index"] for e in enc] == list(range(k))
+        assert [e["args"]["bytes"] for e in enc] == [
+            Path(p).stat().st_size for p in paths]
+        # what is written is what the unsplit node wrote
+        from PIL import Image
+
+        want = (np.clip(np.asarray(images), 0, 1) * 255.0 + 0.5).astype(np.uint8)
+        for p, w in zip(paths, want):
+            np.testing.assert_array_equal(np.asarray(Image.open(p)), w)
+
+
+def _host_plane_events(log_dir) -> list:
+    from jax.profiler import ProfileData
+
+    (path,) = Path(log_dir).glob("plugins/profile/*/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return [(ev.name, dict(ev.stats)) for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+class TestProfilerBridge:
+    OURS = ("workflow-node", "sampler-run", "step", "denoise")
+
+    def _profiled_run(self, log_dir):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+        try:
+            with tracing.span("workflow-node", class_type="KSampler",
+                              prompt_id="p"):
+                _sample(_counting_model(), "euler", 3)
+        finally:
+            jax.profiler.stop_trace()
+        return _host_plane_events(log_dir)
+
+    def test_spans_lie_on_the_host_plane_under_a_profiler_session(self, tmp_path):
+        tracing.enable()
+        events = self._profiled_run(tmp_path)
+        names = [n for n, _ in events]
+        assert names.count("workflow-node") == 1 and names.count("step") == 3
+        assert names.count("denoise") == 3
+        # each carries the ids that tie it to the exported span
+        exported = {e["args"]["span_id"]: e["name"] for e in _x_events()}
+        for name, stats in events:
+            if name in self.OURS:
+                assert exported[int(stats["span_id"])] == name
+                assert stats["prompt_id"] == "p"
+        assert sorted(int(s["step_num"]) for n, s in events if n == "step") == [1, 2, 3]
+        # beside the runtime's own events, on the same plane
+        assert any(n.startswith("PjitFunction") for n in names)
+
+    def test_tracer_off_builds_no_annotation(self, tmp_path, monkeypatch):
+        def refuse(*a, **kw):
+            raise AssertionError("an annotation was built with the tracer off")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+        monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", refuse)
+        events = self._profiled_run(tmp_path)
+        assert not [n for n, _ in events if n in self.OURS]
+        assert _x_events() == []
+
+
+class TestEpochAnchor:
+    def test_export_retakes_the_wall_anchor(self, monkeypatch):
+        tracing.enable()
+        with tracing.span("a"):
+            pass
+        first = tracing.export()
+        assert abs(first["epoch_wall_s"] - time.time()) < 60
+        # the wall clock steps (NTP) between enable() and export(): the anchor
+        # follows the clock as it reads now, the stamp from enable() is gone
+        real = time.time
+        monkeypatch.setattr(tracing.time, "time", lambda: real() + 1000.0)
+        moved = tracing.export()["epoch_wall_s"] - first["epoch_wall_s"]
+        assert 999.0 < moved < 1001.0
