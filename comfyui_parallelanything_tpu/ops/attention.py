@@ -9,19 +9,22 @@ surface. The TPU equivalent is a backend registry:
   post-disable path). Shapes whose S×S logits would exceed ``_CHUNK_THRESHOLD``
   are automatically served by the chunked path below.
 - ``"xla_chunked"`` — memory-bounded attention in plain XLA ops (lax.scan over
-  query blocks; the S×S logits tensor never materializes). The only path that
-  fits SD-class 1024² workloads on one chip: 40/64-dim UNet heads can never
-  take the pallas kernel, and materializing logits there needs >100 GB.
-- ``"pallas"`` — fused flash-attention kernel for TPU (ops/pallas/), used for the long
-  sequences of the FLUX/video configs.
+  query blocks; the S×S logits tensor never materializes, but every block's
+  slice of it goes to HBM and back). What long sequences take off a TPU, and
+  on one when the sequence lengths are not multiples of 128.
+- ``"pallas"`` — fused flash-attention kernel for TPU (ops/pallas/): logits and
+  probabilities never leave VMEM. Serves the long sequences of the FLUX/video
+  configs and, chosen from the call's shape, the UNets' long self-attention at
+  40/64/80-wide heads (4096 and 1024 tokens; PERF.md §6, PR 25).
 - ``"pallas_jax"`` — jax's own battle-tested TPU flash kernel
   (jax.experimental.pallas.ops.tpu.flash_attention) as an alternative fused
   candidate: round-3's only hardware data point for the in-repo kernel was a
   30-minute hang at 4.6k tokens, so the kernel sweep measures BOTH fused
   implementations and the tuning table routes ``auto`` to whichever one
   actually won (128-aligned head dims only — no padding logic upstream).
-- ``"auto"``   — the measured-best fused kernel on TPU when available and the
-  shape qualifies, else the xla family (plain or chunked by size).
+- ``"auto"``   — on a TPU the fused kernel where the shape qualifies
+  (:func:`_auto_backend`: a measured table for lane-aligned head dims, a
+  shape rule for the others), else the xla family (plain or chunked by size).
 
 All functions take (B, S, H, D)-shaped q/k/v ("BSHD") and return (B, S, H, D).
 
@@ -125,9 +128,9 @@ def _xla_attention(q, k, v, scale, logits_dtype=jnp.float32):
 
 # Above this many f32 logits elements (B*H*S_q*S_k; 2**27 ≈ 512 MB) the
 # materializing XLA path is routed to the chunked one. SD-class UNets at 1024²
-# (16k tokens, batch 16) would need 137 GB of logits — far past any HBM — and
-# their 40/64-dim heads can never take the lane-aligned pallas kernel, so
-# chunking is the only way those workloads fit a chip at all.
+# (16k tokens, batch 16) would need 137 GB of logits — far past any HBM — so
+# where the fused kernel does not serve them (off a TPU, a forced "xla"),
+# chunking is the only way those workloads fit a device at all.
 _CHUNK_THRESHOLD = 2**27
 
 # Chunk tuning: a {threshold × softmax-dtype} sweep persists its winner to the
@@ -171,7 +174,11 @@ def shrink_chunk_threshold() -> int | None:
     "attn-chunk-shrink" rung); returns the new threshold, or None when
     already at the floor (the rung is spent — callers move to the next one).
     Programs traced before the shrink keep their old blocks — the caller
-    must rebuild (clear_compiled_loops) for the shrink to take effect."""
+    must rebuild (clear_compiled_loops) for the shrink to take effect.
+    Shapes ``auto`` sends to the fused kernel (on a TPU, the UNets' long
+    self-attention since PR 25) hold no logits slice to shrink — the kernel
+    keeps less in VMEM than any chunk keeps in HBM — so for them the rung
+    sheds nothing and is simply spent."""
     global _CHUNK_SHRINK
     if _chunk_threshold() <= _CHUNK_FLOOR:
         return None
@@ -310,6 +317,32 @@ def _require_upstream_shape(head_dim, seq_q: int, seq_k: int) -> None:
         )
 
 
+def _auto_backend(seq_q: int, seq_k: int, head_dim: int | None,
+                  batch_heads: int) -> str:
+    """What ``auto`` resolves to BEFORE the xla→chunked size fallback — read
+    from the call's shape and the backend, nothing else; ``attention_local``
+    and ``backend_plan`` both decide here.
+
+    The fused kernel needs a TPU and 128-multiple sequence lengths (what the
+    UNets' 64² and 32² token grids and FLUX's joint sequence are; a ragged
+    length stays with XLA rather than pay the kernel's padding). Then:
+    lane-aligned head dims (VAE 512, FLUX / WAN 128) go to it unless a
+    measured table (``$PA_TUNING_PATH``) says XLA won at the nearest length;
+    the others (UNet 40 / 64 / 80) by the shape rule of ops/pallas/tuning.py —
+    ``seq_k`` at or above 1024, which leaves cross-attention's 77 keys and
+    the short inner levels on XLA, and B·H·S_q·S_k at or above 2^27, the
+    smallest count of logits at which the kernel was measured to win."""
+    from .pallas.tuning import fused_backend, pallas_wins
+
+    if (_pallas_available() and seq_q % 128 == 0 and seq_k % 128 == 0
+            and pallas_wins(seq_q, head_dim, seq_k=seq_k,
+                            batch_heads=batch_heads)):
+        # Which fused implementation won the measurement at this shape
+        # class (in-repo streamed-KV kernel vs jax's upstream one).
+        return fused_backend(seq_q, head_dim)
+    return "xla"
+
+
 def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
     """Backend-dispatched attention WITHOUT sequence-parallel routing — the local
     compute kernel, also safe to call from inside a shard_map body (where re-entering
@@ -317,42 +350,35 @@ def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
     if scale is None:
         scale = q.shape[-1] ** -0.5
     backend = _BACKEND
-    logit_elems = q.shape[0] * q.shape[2] * q.shape[1] * k.shape[1]
+    batch_heads = q.shape[0] * q.shape[2]
+    logit_elems = batch_heads * q.shape[1] * k.shape[1]
     if backend == "auto":
-        from .pallas.tuning import pallas_wins
-
-        # The kernel itself pads any head dim to 128 lanes (exact; see
-        # flash_attention), so eligibility is just TPU + block-divisible
-        # sequence; the measured tuning table (ops/pallas/tuning.py) decides
-        # whether the fused kernel actually beats the XLA family at this
-        # (length, head-dim class) — non-aligned dims pay a padded FLOP tax
-        # and default to XLA until a measurement proves the kernel wins.
-        use_pallas = (
-            _pallas_available() and q.shape[1] % 128 == 0
-            and k.shape[1] % 128 == 0
-            and pallas_wins(q.shape[1], q.shape[-1])
-        )
-        if use_pallas:
-            from .pallas.tuning import fused_backend
-
-            # Which fused implementation won the measurement at this shape
-            # class (in-repo streamed-KV kernel vs jax's upstream one).
-            backend = fused_backend(q.shape[1], q.shape[-1])
-        else:
-            backend = "xla"
+        backend = _auto_backend(q.shape[1], k.shape[1], q.shape[-1],
+                                batch_heads)
     if backend == "pallas_jax":
         _require_upstream_shape(q.shape[-1], q.shape[1], k.shape[1])
     if backend == "xla" and logit_elems > _chunk_threshold():
         # "xla" means the XLA family: shapes whose S×S logits would blow HBM
-        # (pallas-ineligible 40/64-dim UNet heads at 1024², or a forced
+        # (long sequences off a TPU or at ragged lengths, or a forced
         # non-pallas run) go through the chunked path instead of OOMing.
         backend = "xla_chunked"
     _RESOLVED.add(backend)
+    # Once a trace, not once a forward: attention_local runs while a program
+    # is traced, so the count says which routes the compiled programs hold.
+    from ..utils.metrics import registry
+
+    registry.counter(
+        "pa_attention_route_total", labels={"backend": backend},
+        help="attention calls resolved to this backend while a program was "
+             "traced (ops/attention.attention_local)",
+    )
     if backend == "pallas":
         from .pallas.flash_attention import flash_attention
         from .pallas.tuning import best_blocks
 
-        block_q, block_k = best_blocks(q.shape[1], q.shape[-1])
+        block_q, block_k = best_blocks(
+            q.shape[1], q.shape[-1], seq_k=k.shape[1], batch_heads=batch_heads,
+        )
         # Compiled on a TPU; a pallas backend FORCED elsewhere (tests) runs
         # the interpreter, and says so once.
         interpret = not _pallas_available()
@@ -377,10 +403,11 @@ def backend_plan(seq_q: int, seq_k: int | None = None,
     reads (parallel/planner.py): which backend WOULD serve this shape, the
     chunk configuration it would run under, and the measurements
     (``$PA_ATTN_CHUNK_TUNING`` threshold sweep + ``$PA_TUNING_PATH``
-    pallas-vs-xla wins) that decided it. Mirrors ``attention_local`` rule
-    for rule so plan and execution agree by construction; a drift test pins
-    the two against each other (tests/test_planner.py)."""
-    from .pallas.tuning import fused_backend, kernel_tuning, pallas_wins
+    pallas-vs-xla wins) that decided it. ``auto`` is resolved by the same
+    ``_auto_backend`` that ``attention_local`` calls and the steps around it
+    mirror ``attention_local`` rule for rule; a drift test pins the two
+    against each other (tests/test_planner.py)."""
+    from .pallas.tuning import kernel_tuning
 
     seq_k = seq_q if seq_k is None else int(seq_k)
     logit_elems = int(batch) * int(heads) * int(seq_q) * int(seq_k)
@@ -401,19 +428,17 @@ def backend_plan(seq_q: int, seq_k: int | None = None,
         nearest = min(
             measured, key=lambda e: abs(int(e.get("seq", 0)) - int(seq_q))
         )
-    fused_ok = (
-        _pallas_available() and seq_q % 128 == 0 and seq_k % 128 == 0
-        and pallas_wins(seq_q, head_dim)
-    )
+    auto = _auto_backend(seq_q, seq_k, head_dim, int(batch) * int(heads))
+    fused_ok = auto != "xla"
     cand(
-        "pallas", fused_ok and fused_backend(seq_q, head_dim) == "pallas",
-        "fused in-repo kernel (tuning table winner)" if fused_ok
-        else "ineligible: not TPU / non-128-aligned seq / tuning says XLA",
+        "pallas", auto == "pallas",
+        "fused in-repo kernel (shape rule / tuning table winner)" if fused_ok
+        else "ineligible: not TPU / non-128-aligned seq / rule or tuning "
+             "says XLA",
         measured_ms=(nearest or {}).get("pallas_ms"),
     )
     cand(
-        "pallas_jax",
-        fused_ok and fused_backend(seq_q, head_dim) == "pallas_jax",
+        "pallas_jax", auto == "pallas_jax",
         "jax upstream fused kernel (tuning table winner)" if fused_ok
         else "ineligible: not TPU / non-aligned / tuning says XLA",
         measured_ms=(nearest or {}).get("pallas_jax_ms"),
@@ -436,7 +461,7 @@ def backend_plan(seq_q: int, seq_k: int | None = None,
     # way it executes.
     backend = _BACKEND
     if backend == "auto":
-        backend = fused_backend(seq_q, head_dim) if fused_ok else "xla"
+        backend = auto
     if backend == "pallas_jax":
         _require_upstream_shape(head_dim, seq_q, seq_k)
     if backend == "xla" and logit_elems > threshold:

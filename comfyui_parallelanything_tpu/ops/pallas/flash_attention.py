@@ -1,20 +1,36 @@
 """Fused flash attention for TPU (Pallas).
 
-The hot op of every model in scope: FLUX at 1024² is ~4.6k tokens of joint attention,
-video models far more. The reference rides torch's bundled flash/xformers kernels and
-merely toggles them off on old GPUs (any_device_parallel.py:126-164); here the fused
-path is a Pallas kernel tuned for the MXU/VMEM hierarchy:
+The hot op of every model in scope: an SD-class UNet's self-attention is 4096
+tokens at 512² (16,384 at 1024²), FLUX at 1024² is ~4.6k tokens of joint
+attention, video models far more. The reference rides torch's bundled
+flash/xformers kernels and merely toggles them off on old GPUs
+(any_device_parallel.py:126-164); here the fused path is a Pallas kernel tuned
+for the MXU/VMEM hierarchy:
 
-- grid over (batch·heads, query blocks, key blocks) — K/V stream through VMEM one
-  ``block_k`` tile at a time, so VMEM holds O(block_q + block_k), NOT O(seq_k).
-  This is what lets the same kernel cover WAN-video sequence lengths (tens of
-  thousands of tokens): at 32k keys the old whole-row layout needed ~16 MB of
-  VMEM per program just for K/V; streamed tiles stay ~1-2 MB at any length.
-- online-softmax state (f32 running max/sum/acc) lives in VMEM scratch and is
-  carried across the key-block grid dimension (the innermost, sequential one);
-  the output tile is written once, on the last key block. No S×S
-  materialization — HBM traffic stays O(S·D).
-- bf16 in, f32 accumulate, caller dtype out.
+- operands are read as the models hand them over, (B, S, H·D): no transpose,
+  no lane padding in HBM. One grid step holds a lane-aligned GROUP of heads —
+  the fewest whose widths add up to a multiple of 128 lanes (one 128-wide
+  head, two 64-wide), else all of them (eight 40-wide: the block is then the
+  array's full width) — and walks the group's heads over static lane slices.
+- grid over (batch, head groups, query blocks, key blocks) — K/V stream
+  through VMEM one ``block_k`` tile at a time, so VMEM holds
+  O(block_q + block_k), NOT O(seq_k), and the same kernel covers WAN-video
+  lengths. A UNet's 4096 keys are ONE block (a head group's whole K and V,
+  3 MB each at SD1.5's width): the key axis of the grid is then a single step
+  and the softmax state never leaves the loop's values.
+- inside a key block the softmax walks ``_CHUNK_K`` keys at a time: the live
+  logits tile is (block_q, chunk) however long the block, and what is paid per
+  tile and not per logit (the state's rescale, the accumulator's
+  read-modify-write) is amortised over 2048 keys, not 256 (measured, v5e,
+  256 queries a block at SD1.5's (16, 4096, 8, 40): 41.5 ms with 256-key
+  blocks, 6.7 ms with the whole row; PERF.md §6, PR 25).
+- online-softmax state (f32 running max/sum/acc) is carried across key blocks
+  in VMEM scratch (the key axis is the innermost, sequential one); the output
+  tile is written once, on the last key block. No S×S materialization — HBM
+  traffic stays O(S·D).
+- operands go to the MXU in their own dtype (bf16 on the chip: one pass) with
+  the softmax scale folded into q; logits, max, sum and accumulator are f32;
+  the probabilities are cast to the operand dtype for the second dot only.
 
 Non-TPU backends run the same kernel in interpreter mode (tests) or should prefer the
 plain XLA path (ops/attention.py handles the dispatch).
@@ -34,45 +50,75 @@ from jax.sharding import PartitionSpec as P
 # (block_q, 1) arrays lower poorly on the TPU vector unit.
 _LANES = 128
 
+# Keys per softmax tile inside a key block. Measured on the v5e at
+# (16, 4096, 8, 40), ms a call (my chip runs, PR 25; PERF.md §6): tiles of 256
+# keys 41.5, of 1024 keys 13.4, of 2048 keys 6.7; one 4096-key tile is no
+# faster and doubles the live logits.
+_CHUNK_K = 2048
+
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *, scale: float,
-    block_k: int, seq_k: int,
+    q_ref, k_ref, v_ref, o_ref, *state, scale: float, heads: int,
+    head_dim: int, block_k: int, chunk_k: int, seq_k: int, mask_keys: bool,
 ):
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+    """One (batch row, head group, query block, key block) step. ``state`` is
+    the (acc, m, l) scratch that carries the online softmax across key blocks,
+    or nothing when the key axis is a single block."""
+    j = pl.program_id(3)
+    nk = pl.num_programs(3)
+    block_q = q_ref.shape[0]
+    if state:
+        acc_ref, m_ref, l_ref = state
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        @pl.when(j == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[...].astype(jnp.float32) * scale
-    k_blk = k_ref[...].astype(jnp.float32)
-    v_blk = v_ref[...].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (block_q, block_k)
-    # Mask out-of-range key columns (host pads seq_k up to a block_k multiple).
-    col = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(col < seq_k, s, -jnp.inf)
+    for h in range(heads):
+        lanes = slice(h * head_dim, (h + 1) * head_dim)
+        # The scale is folded into q once a key block: one multiply a q
+        # element, not one a logit. Operands stay in their own dtype.
+        q = (q_ref[:, lanes].astype(jnp.float32) * scale).astype(q_ref.dtype)
+        if state:
+            m, l, acc = m_ref[h, :, :1], l_ref[h, :, :1], acc_ref[h]
+        else:
+            m = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
+            l = jnp.zeros((block_q, 1), jnp.float32)
+            acc = jnp.zeros((block_q, head_dim), jnp.float32)
+        # Unrolled: the next tile's first dot overlaps this tile's softmax.
+        for start in range(0, block_k, chunk_k):
+            keys = slice(start, start + chunk_k)
+            v_blk = v_ref[keys, lanes]
+            s = jax.lax.dot_general(
+                q, k_ref[keys, lanes], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (block_q, chunk_k)
+            if mask_keys:
+                # Out-of-range key columns (host pads seq_k up to a block).
+                col = (j * block_k + start
+                       + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+                s = jnp.where(col < seq_k, s, -jnp.inf)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = l * alpha + p.sum(axis=-1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m = m_new
+        if not state:
+            o_ref[:, lanes] = (acc / l).astype(o_ref.dtype)
+            continue
+        acc_ref[h] = acc
+        m_ref[h] = jnp.broadcast_to(m, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l, l_ref.shape[1:])
 
-    m_prev = m_ref[:, :1]
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(j == nk - 1)
-    def _finish():
-        o_ref[...] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+        @pl.when(j == nk - 1)
+        def _finish():
+            o_ref[:, lanes] = (acc / l).astype(o_ref.dtype)
 
 
 def over_data_axis(fn, q, k, v):
@@ -152,64 +198,78 @@ def flash_attention(
     return over_data_axis(kernel, q, k, v)
 
 
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
+
+def _head_group(heads: int, head_dim: int) -> int:
+    """Heads one grid step holds: the fewest whose widths fill whole 128-lane
+    tiles (a block narrower than the array must be lane-aligned), else all —
+    a block as wide as the array may have any width. Any head dim runs, with
+    no padding in HBM: 40-wide heads ride eight to a 320-lane block."""
+    for n in range(1, heads):
+        if heads % n == 0 and (n * head_dim) % _LANES == 0:
+            return n
+    return heads
+
+
 def _flash_attention(q, k, v, *, scale: float, block_q: int, block_k: int,
                      interpret: bool):
-    # Lane alignment: the MXU wants the head dim in 128-lane multiples. For
-    # the 40/64-dim UNet-family heads, zero-pad D — exact, not approximate:
-    # padded K columns add zero to every q·k logit, and padded V columns
-    # produce zeros that are sliced away below. (Scale was already fixed from
-    # the ORIGINAL head dim above.) Whether the padded FLOP tax beats chunked
-    # XLA at a given shape is a tuning-table question (ops/pallas/tuning.py);
-    # this function just makes any head dim runnable.
-    orig_head_dim = q.shape[-1]
-    lane_pad = (-orig_head_dim) % 128
-    if lane_pad:
-        pad_spec = ((0, 0), (0, 0), (0, 0), (0, lane_pad))
-        q = jnp.pad(q, pad_spec)
-        k = jnp.pad(k, pad_spec)
-        v = jnp.pad(v, pad_spec)
-
     batch, seq_q, heads, head_dim = q.shape
     seq_k = k.shape[1]
+    group = _head_group(heads, head_dim)
+    width = group * head_dim
 
-    # (B, S, H, D) -> (B·H, S, D)
-    def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(batch * heads, x.shape[1], head_dim)
-
-    q3, k3, v3 = fold(q), fold(k), fold(v)
-    bq = min(block_q, max(seq_q, 8))
-    bk = min(block_k, max(seq_k, 8))
-    q3 = _pad_to(q3, 1, bq)
-    k3 = _pad_to(k3, 1, bk)
-    v3 = _pad_to(v3, 1, bk)
+    bq = min(block_q, _round_up(seq_q, 8))
+    bk = min(block_k, _round_up(seq_k, 8))
+    ck = min(_CHUNK_K, bk)
+    bk = _round_up(bk, ck)
+    # (B, S, H, D) -> (B, S, H·D): the layout the projections wrote, so XLA
+    # folds this reshape into theirs and nothing is copied.
+    q3 = _pad_to(q.reshape(batch, seq_q, heads * head_dim), 1, bq)
+    k3 = _pad_to(k.reshape(batch, seq_k, heads * head_dim), 1, bk)
+    v3 = _pad_to(v.reshape(batch, seq_k, heads * head_dim), 1, bk)
     padded_q, padded_k = q3.shape[1], k3.shape[1]
+    nk = padded_k // bk
+
+    lanes = _round_up(width, _LANES)
+    item = q.dtype.itemsize
+    state = [
+        pltpu.VMEM((group, bq, head_dim), jnp.float32),
+        pltpu.VMEM((group, bq, _LANES), jnp.float32),
+        pltpu.VMEM((group, bq, _LANES), jnp.float32),
+    ] if nk > 1 else []
+    # Double-buffered q / out / k / v blocks, the carried state, and per live
+    # logits tile its float32 logits and exp and the cast for the second dot;
+    # two tiles are live (the unrolled loop overlaps them).
+    state_bytes = 4 * group * bq * (_round_up(head_dim, _LANES) + 2 * _LANES)
+    vmem = (2 * (2 * bq + 2 * bk) * lanes * item
+            + (state_bytes if state else 0) + 2 * bq * ck * (8 + item))
 
     out = pl.pallas_call(
-        functools.partial(_flash_kernel, scale=scale, block_k=bk, seq_k=seq_k),
+        functools.partial(
+            _flash_kernel, scale=scale, heads=group, head_dim=head_dim,
+            block_k=bk, chunk_k=ck, seq_k=seq_k, mask_keys=padded_k != seq_k,
+        ),
         # Key blocks are the innermost (sequential) grid dim: scratch carries the
         # online-softmax state across them, and the output tile (whose index map
         # ignores j) stays resident in VMEM until its last visit.
-        grid=(batch * heads, padded_q // bq, padded_k // bk),
+        grid=(batch, heads // group, padded_q // bq, nk),
         in_specs=[
-            pl.BlockSpec((None, bq, head_dim), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bk, head_dim), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, bk, head_dim), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((None, bq, width), lambda b, g, i, j: (b, i, g)),
+            pl.BlockSpec((None, bk, width), lambda b, g, i, j: (b, j, g)),
+            pl.BlockSpec((None, bk, width), lambda b, g, i, j: (b, j, g)),
         ],
-        out_specs=pl.BlockSpec((None, bq, head_dim), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch * heads, padded_q, head_dim), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, head_dim), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((None, bq, width), lambda b, g, i, j: (b, i, g)),
+        out_shape=jax.ShapeDtypeStruct(q3.shape, q.dtype),
+        scratch_shapes=state,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            # The default scoped limit (16 MB) is less than a UNet block's
+            # tiles; twice the reckoned need leaves the compiler its slack.
+            vmem_limit_bytes=min(max(2 * vmem, 32 << 20), 100 << 20),
         ),
         interpret=interpret,
     )(q3, k3, v3)
 
-    out = out[:, :seq_q, :]
-    out = out.reshape(batch, heads, seq_q, head_dim).transpose(0, 2, 1, 3)
-    if lane_pad:
-        out = out[..., :orig_head_dim]
-    return out
+    return out[:, :seq_q].reshape(batch, seq_q, heads, head_dim)

@@ -1,20 +1,25 @@
-"""Data-driven flash-kernel tuning.
+"""Flash-kernel routing and block sizes.
 
-The pallas kernel's block sizes (256/256) started as guesses; real numbers come
-from ``scripts/bench_kernels.py``, which sweeps ``block_q``/``block_k`` over
-{128, 256, 512} at the shapes that matter (FLUX 4.6k joint attention, WAN
-16k/32k video) and — with ``--apply`` — writes the winners to the JSON file
-``$PA_TUNING_PATH`` names. The ``auto`` attention backend (ops/attention.py)
-then:
+Two sources, in this order:
 
-- picks the measured-best blocks for the nearest benchmarked sequence length,
-- falls back to XLA for sequence ranges where the measurement says the fused
-  kernel LOSES (the reference's capability-gated backend disable, inverted:
-  data-gated instead of SM-version-gated, any_device_parallel.py:126-164).
+- **The shape rule** for head dims that are not a multiple of 128 (the UNets'
+  40 / 64 / 80-wide heads): thresholds on the key length and on B·H·S_q·S_k,
+  in code, with the v5e measurements they were set from beside them
+  (:func:`padded_dim_route`). No file, no environment variable.
+- **A measured table** for everything else: ``scripts/bench_kernels.py``
+  sweeps ``block_q``/``block_k`` at the shapes that matter (FLUX 4.6k joint
+  attention, WAN 16k/32k video) and — with ``--apply`` — writes the winners to
+  the JSON file ``$PA_TUNING_PATH`` names. The ``auto`` attention backend
+  (ops/attention.py) then picks the measured-best blocks for the nearest
+  benchmarked sequence length and falls back to XLA for sequence ranges where
+  the measurement says the fused kernel LOSES (the reference's
+  capability-gated backend disable, inverted: data-gated instead of
+  SM-version-gated, any_device_parallel.py:126-164). A same-dim entry of such
+  a table overrides the shape rule for its dim class.
 
 There is no default file: without ``$PA_TUNING_PATH`` everything runs on the
-defaults below, so a fresh clone and a checkout an earlier run wrote into
-behave the same.
+rule and the defaults below, so a fresh clone and a checkout an earlier run
+wrote into behave the same.
 """
 
 from __future__ import annotations
@@ -36,6 +41,50 @@ _DEFAULT = {
     # the kernel zero-padded and must win their own measurements).
     "entries": [],
 }
+
+
+# -- Head dims that are not a multiple of 128 ----------------------------------
+# The kernel reads (B, S, H·D) as it is and walks a group of heads over lane
+# slices, so such a dim pays no padding in HBM, only idle MXU lanes. Measured
+# on the v5e, bfloat16, ms a call (scripts/bench_kernels.py, my chip runs,
+# PR 25; PERF.md §6): the XLA path ``auto`` would otherwise take against this
+# kernel at PADDED_DIM_BLOCKS.
+#
+#   (batch, tokens, heads, head dim)        B·H·S_q·S_k    xla      fused
+#   sd15-b8-512   (16,  4096,  8, 40)       2^31          18.485    6.739
+#   sd15-b8-512   (16,  1024,  8, 80)       2^27           1.429    0.553
+#   sdxl-b1-1024  ( 2,  4096, 10, 64)       2^28.3         3.658    1.077
+#   sdxl-b1-1024  ( 2,  1024, 20, 64)       2^25.3         0.301    0.333  (loses)
+#   sd15-b1-512   ( 2,  4096,  8, 40)       2^28           2.385    0.897
+#   sd15-b2-1024  ( 4, 16384,  8, 40)       2^33          70.817   31.057
+#
+# What separates the five wins from the loss is how many logits XLA would
+# write to HBM and read back: from 2^27 up (B·H of 128 at 1024 tokens, of 8 at
+# 4096) the fused kernel is 2.3–3.4x faster; at 2^25.3 (B·H 40 at 1024 tokens)
+# its fixed cost a call is not paid back. Between the two nothing was
+# measured, so the threshold stands at the smallest measured win. Below
+# PADDED_DIM_MIN_KEYS nothing was measured at all: cross-attention's 77 keys
+# and the UNets' 256- and 64-token levels stay on XLA.
+PADDED_DIM_MIN_KEYS = 1024
+PADDED_DIM_MIN_LOGITS = 2**27
+# 256 queries against a head group's whole K and V, up to 4096 keys a block
+# (3 MB each at SD1.5's width). 512 queries are 3% faster at SD1.5's class
+# (6.506) and take 30 s to compile instead of 14; longer rows (SD1.5 at 1024²:
+# 16,384 keys) stream 4096 keys a block, where 256 queries are also fastest.
+PADDED_DIM_BLOCKS = (256, 4096)
+
+
+def padded_dim_route(seq_q: int, seq_k: int,
+                     batch_heads: int | None = None) -> tuple[int, int] | None:
+    """``(block_q, block_k)`` if the fused kernel serves a head dim that is
+    not a multiple of 128 at these lengths and this B·H (``None``: not
+    known, not tested), else ``None``: the call stays with the XLA family."""
+    if seq_k < PADDED_DIM_MIN_KEYS:
+        return None
+    if (batch_heads is not None
+            and batch_heads * seq_q * seq_k < PADDED_DIM_MIN_LOGITS):
+        return None
+    return PADDED_DIM_BLOCKS
 
 
 @functools.lru_cache(maxsize=1)
@@ -66,23 +115,26 @@ def _nearest(entries: list, seq: int):
     return min(entries, key=lambda e: abs(int(e.get("seq", 0)) - seq))
 
 
-def best_blocks(seq: int, head_dim: int | None = None) -> tuple[int, int]:
+def best_blocks(seq: int, head_dim: int | None = None,
+                seq_k: int | None = None,
+                batch_heads: int | None = None) -> tuple[int, int]:
     """(block_q, block_k) for a sequence length: the measured winner at the
     nearest benchmarked length (preferring measurements of the same head-dim
-    class), else the defaults."""
+    class), else the shape rule's blocks for a head dim that is not a multiple
+    of 128, else the defaults."""
     t = kernel_tuning()
+    seq_k = seq if seq_k is None else seq_k
     entries = [e for e in t["entries"] if e.get("block_q") and e.get("block_k")]
     if head_dim is not None:
         same_dim = [e for e in entries if e.get("head_dim") == head_dim]
         if same_dim:
             entries = same_dim
         elif head_dim % 128 != 0:
-            # Padded dim with no same-dim measurement: return the defaults
-            # rather than inheriting blocks tuned for a different dim class —
-            # mirrors pallas_wins' filtering, which matters when a forced
-            # (non-auto) pallas backend runs a padded shape the sweep never
-            # measured.
-            entries = []
+            # Never blocks tuned for another dim class: the rule's, or — a
+            # forced (non-auto) pallas backend at a shape the rule leaves to
+            # XLA — the defaults.
+            return (padded_dim_route(seq, seq_k, batch_heads)
+                    or (int(t["block_q"]), int(t["block_k"])))
         else:
             # Aligned dims must not inherit blocks tuned under the padded-FLOP
             # regime of a different dim class (mirrors pallas_wins).
@@ -128,26 +180,31 @@ def fused_backend(seq: int, head_dim: int | None = None) -> str:
     return "pallas"
 
 
-def pallas_wins(seq: int, head_dim: int | None = None) -> bool:
-    """Whether the fused kernel beat XLA at the nearest measured length. With
-    no measurement, True for lane-aligned head dims — the default guess (XLA's
-    S×S logits materialization loses at the long lengths this path serves) —
-    but False for non-aligned dims (40/64 UNet heads): those run the kernel
-    zero-PADDED to 128 lanes, a 2-3.2× FLOP tax that must *prove* it beats the
-    chunked-XLA path before auto picks it. Entries measured at a specific
-    ``head_dim`` (bench_kernels records it) gate their own dim class; an entry
-    whose XLA measurement FAILED (``xla_ms`` None — S×S logits OOM) counts as
-    a pallas win: that is a length where the fused kernel is mandatory, not
-    absent data."""
+def pallas_wins(seq: int, head_dim: int | None = None,
+                seq_k: int | None = None,
+                batch_heads: int | None = None) -> bool:
+    """Whether the fused kernel serves this shape. Lane-aligned head dims:
+    whether it beat XLA at the nearest measured length, and with no
+    measurement True — the default guess (XLA's S×S logits materialization
+    loses at the long lengths this path serves). Head dims that are not a
+    multiple of 128 (40/64/80 UNet heads): the shape rule
+    (:func:`padded_dim_route`, on the key length ``seq_k`` — ``seq`` if not
+    given — and B·H), unless the table holds entries measured at that very
+    ``head_dim`` (bench_kernels records it), which then gate their own dim
+    class. An entry whose XLA measurement FAILED (``xla_ms`` None — S×S logits
+    OOM) counts as a pallas win: that is a length where the fused kernel is
+    mandatory, not absent data."""
     t = kernel_tuning()
     entries = [e for e in t["entries"] if _fused_ms(e) is not None]
     padded_dim = head_dim is not None and head_dim % 128 != 0
+    by_rule = padded_dim and padded_dim_route(
+        seq, seq if seq_k is None else seq_k, batch_heads) is not None
     if head_dim is not None:
         same_dim = [e for e in entries if e.get("head_dim") == head_dim]
         if same_dim:
             entries = same_dim
         elif padded_dim:
-            return False
+            return by_rule
         else:
             # Aligned dim: generic (dim-less or aligned-dim) entries apply.
             entries = [
@@ -158,10 +215,9 @@ def pallas_wins(seq: int, head_dim: int | None = None) -> bool:
         return True
     e = _nearest(entries, seq)
     if padded_dim and not (seq / 2 <= int(e.get("seq", 0)) <= seq * 2):
-        # A padded-dim win extrapolates at most 2x in sequence length: the
-        # padded FLOP tax that wins at 16k against chunked XLA was never
-        # measured against the cheap plain-XLA competitor at short lengths.
-        return False
+        # A measured padded-dim entry speaks for at most 2x in sequence
+        # length either way; beyond that the rule decides.
+        return by_rule
     if e.get("xla_ms") is None:
         return True
     return float(_fused_ms(e)) <= float(e["xla_ms"])

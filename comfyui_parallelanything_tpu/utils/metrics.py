@@ -115,6 +115,13 @@ second per program, and its increase over a prompt against the graph's
 running without the span tracer; the ``denoise`` span is its traced twin).
 ``pa_trace_dropped_total`` gains ``reason="abandoned"``: spans the traced
 code left open, closed by their parent and not recorded.
+
+Attention routing (PR 25): ``pa_attention_route_total{backend=}``
+(ops/attention.py — one count per ``attention_local`` resolution, made
+while a program is traced and not per forward: ``pallas`` moving while a
+UNet's step program compiles is the evidence that its long self-attention
+took the fused flash kernel; ``resolved_backends()`` is the same fact as a
+set).
 """
 
 from __future__ import annotations

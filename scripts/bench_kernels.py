@@ -1,10 +1,11 @@
-"""Flash-attention kernel benchmark: pallas (streamed K/V) vs plain XLA,
-with a block-size sweep.
+"""Flash-attention kernel benchmark: the fused pallas kernel vs the XLA
+family ``auto`` would otherwise pick, with a block-size sweep.
 
 Needs the chip and runs in ONE process (a chip belongs to one process at a
 time); without a TPU it exits non-zero and measures nothing:
 
     python scripts/bench_kernels.py            # measure, append KERNEL_BENCH.json
+    python scripts/bench_kernels.py --shape sd15-b8-512.self4096,sdxl-b1-1024.self4096
     PA_TUNING_PATH=tuning.json python scripts/bench_kernels.py --apply
                                                # ALSO write the winners to
                                                # $PA_TUNING_PATH; a process
@@ -13,13 +14,17 @@ time); without a TPU it exits non-zero and measures nothing:
                                                # xla-fallback ranges
     KERNEL_SWEEP=0 python scripts/bench_kernels.py   # default blocks only
 
-Shapes cover the rungs that matter: FLUX joint attention at 1024² (4.6k tokens,
-24 heads × 128) and WAN-video lengths (16k/32k tokens) where the streamed-K/V
-layout is what keeps VMEM bounded. The sweep tries block_q × block_k over
-{128, 256, 512}² per shape; each cell is the mean of 5 chained timed calls
-after compile+warmup (see ``_time_fn`` for why chained). Appends JSON lines to
-``<evidence dir>/KERNEL_BENCH.json`` (not tracked; no figure from it has been
-banked: not measured).
+Shapes cover the rungs that matter: the benchmark cells' UNet self-attention
+classes (named ``<cell>.self<tokens>``: what ops/pallas/tuning.py's shape rule
+was set from), FLUX joint attention at 1024² (4.6k tokens, 24 heads × 128) and
+WAN-video lengths (16k/32k tokens) where the streamed-K/V layout is what keeps
+VMEM bounded. Operands are made as (B, S, H·D) and split into heads inside the
+timed program, as a model's projections hand them over. The sweep tries
+block_q over {128, 256, 512} × block_k over {256, 1024, 4096} per shape; each
+cell is the mean of 10 chained timed calls after compile+warmup (see
+``_time_fn`` for why chained). Appends JSON lines to
+``<evidence dir>/KERNEL_BENCH.json`` (not tracked); the lines the shape rule
+rests on are quoted in PERF.md §6 (PR 25).
 """
 
 from __future__ import annotations
@@ -44,9 +49,22 @@ SHAPES = [
     # loss keeps chunked XLA.
     ("sd15_1024_d40", 16, 16384, 8, 40),
     ("sdxl_1024_d64", 8, 4096, 10, 64),
+    # The benchmark cells' self-attention classes (BENCHMARK.json; CFG doubles
+    # the batch): the shapes the rule in ops/pallas/tuning.py was set from.
+    ("sd15-b8-512.self4096", 16, 4096, 8, 40),
+    ("sd15-b8-512.self1024", 16, 1024, 8, 80),
+    ("sdxl-b1-1024.self4096", 2, 4096, 10, 64),
+    ("sdxl-b1-1024.self1024", 2, 1024, 20, 64),
+    # Their neighbours: one image of SD1.5 (the least B·H a UNet sends) and
+    # the 16,384 tokens of SD1.5 at 2 x 1024² (a cell for a later issue).
+    ("sd15-b1-512.self4096", 2, 4096, 8, 40),
+    ("sd15-b2-1024.self16384", 4, 16384, 8, 40),
 ]
 
-def _time_fn(fn, *args, iters=5):
+BLOCKS_Q = (128, 256, 512)
+BLOCKS_K = (256, 1024, 4096)
+
+def _time_fn(fn, *args, iters=10):
     """Mean time per call, closed by a host readback (attention maps q-shaped
     to q-shaped, so the output chains back as the first argument; see
     utils/metrics.chained_time)."""
@@ -85,26 +103,33 @@ def _run_shapes(shapes, dev):
 
     out_path = os.path.join(evidence_dir(), "KERNEL_BENCH.json")
     sweep = os.environ.get("KERNEL_SWEEP", "1") != "0"
-    blocks = (128, 256, 512)
     entries = []
     for label, b, s, h, d in shapes:
-        k1, k2, k3 = jax.random.split(jax.random.key(0), 3)
-        q = jax.random.normal(k1, (b, s, h, d), jnp.bfloat16)
-        k = jax.random.normal(k2, (b, s, h, d), jnp.bfloat16)
-        v = jax.random.normal(k3, (b, s, h, d), jnp.bfloat16)
+        # (B, S, H·D), split into heads inside the timed program: what a
+        # model's projections hand over, so no backend is charged (or spared)
+        # a relayout of a 40-wide minor dimension that no model pays.
+        q, k, v = (jax.random.normal(key, (b, s, h * d), jnp.bfloat16)
+                   for key in jax.random.split(jax.random.key(0), 3))
+
+        def projected(fn, _h=h, _d=d):
+            def run(a, b_, c):
+                split = lambda x: x.reshape(*x.shape[:2], _h, _d)  # noqa: E731
+                return fn(split(a), split(b_), split(c)).reshape(a.shape)
+            return jax.jit(run)
+
         rec = {"shape": label, "b": b, "seq": s, "heads": h, "head_dim": d,
                "platform": dev.platform, "device_kind": dev.device_kind,
                "ts": time.time()}
-        combos = (
-            [(bq, bk) for bq in blocks for bk in blocks] if sweep else [(256, 256)]
-        )
+        combos = sorted(
+            {(min(bq, s), min(bk, s)) for bq in BLOCKS_Q for bk in BLOCKS_K}
+        ) if sweep else [(256, 256)]
         best = None  # (ms, bq, bk)
         for bq, bk in combos:
             try:
                 ms = _time_fn(
-                    lambda a, b_, c, _bq=bq, _bk=bk: flash_attention(
+                    projected(lambda a, b_, c, _bq=bq, _bk=bk: flash_attention(
                         a, b_, c, block_q=_bq, block_k=_bk, interpret=False
-                    ),
+                    )),
                     q, k, v,
                 ) * 1e3
             except Exception as e:  # noqa: BLE001 — record, keep sweeping
@@ -126,14 +151,16 @@ def _run_shapes(shapes, dev):
 
             try:
                 rec["pallas_jax_ms"] = round(_time_fn(
-                    lambda a, b_, c: _pallas_jax_attention(a, b_, c, d**-0.5),
+                    projected(
+                        lambda a, b_, c: _pallas_jax_attention(a, b_, c, d**-0.5)
+                    ),
                     q, k, v,
                 ) * 1e3, 3)
             except Exception as e:  # noqa: BLE001 — record, keep measuring
                 rec["pallas_jax_error"] = str(e)[:120]
         try:
             rec["xla_ms"] = round(
-                _time_fn(lambda a, b_, c: xla_family(a, b_, c, d**-0.5),
+                _time_fn(projected(lambda a, b_, c: xla_family(a, b_, c, d**-0.5)),
                          q, k, v) * 1e3, 3
             )
         except Exception as e:  # noqa: BLE001 — S×S logits OOM at video lengths
@@ -170,10 +197,10 @@ def main() -> None:
 
     shapes = SHAPES
     if "--shape" in sys.argv:
-        label = sys.argv[sys.argv.index("--shape") + 1]
-        shapes = [sh for sh in SHAPES if sh[0] == label]
-        if not shapes:
-            raise SystemExit(f"unknown shape {label!r}")
+        labels = sys.argv[sys.argv.index("--shape") + 1].split(",")
+        shapes = [sh for sh in SHAPES if sh[0] in labels]
+        if len(shapes) != len(labels):
+            raise SystemExit(f"unknown shape among {labels!r}")
     entries = _run_shapes(shapes, dev)
 
     if "--apply" in sys.argv:

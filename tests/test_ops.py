@@ -326,17 +326,25 @@ class TestKernelTuning:
             tuning.kernel_tuning.cache_clear()
 
     def test_padded_head_dim_gate(self, monkeypatch):
-        # Non-128-aligned head dims (40/64 UNet heads) run the kernel
-        # zero-padded — a FLOP tax that must PROVE itself: without a measured
-        # entry for that dim class auto says no; with a measured win it says
-        # yes; aligned dims keep the default-True guess.
+        # Non-128-aligned head dims (40/64/80 UNet heads) are routed by the
+        # shape rule (tuning.padded_dim_route: key length and B·H·S_q·S_k,
+        # set from the v5e measurements beside it), not by a default "no":
+        # aligned dims keep the default-True guess; a table measured at that
+        # very dim overrides the rule for its class.
         from comfyui_parallelanything_tpu.ops.pallas import tuning
 
         monkeypatch.setattr(
             tuning, "kernel_tuning", lambda: {**tuning._DEFAULT, "entries": []}
         )
         assert tuning.pallas_wins(16384, 128) is True   # aligned: default guess
-        assert tuning.pallas_wins(16384, 40) is False   # padded: needs proof
+        assert tuning.pallas_wins(16384, 40) is True    # rule: long keys
+        assert tuning.pallas_wins(4096, 40, seq_k=77) is False  # cross-attn
+        assert tuning.pallas_wins(256, 160) is False    # short inner level
+        # B·H·S_q·S_k under 2^27 (SDXL's 1024-token class) lost on the chip.
+        assert tuning.pallas_wins(1024, 64, batch_heads=40) is False
+        assert tuning.pallas_wins(1024, 80, batch_heads=128) is True
+        assert tuning.best_blocks(4096, 40) == tuning.PADDED_DIM_BLOCKS
+        assert tuning.best_blocks(4096, 40, seq_k=77) == (256, 256)
 
         table = self._table([
             {"seq": 16384, "head_dim": 40, "block_q": 512, "block_k": 256,
@@ -350,23 +358,22 @@ class TestKernelTuning:
             tuning, "kernel_tuning", lambda: {**tuning._DEFAULT, **table}
         )
         assert tuning.pallas_wins(16384, 40) is True
-        assert tuning.pallas_wins(4096, 64) is False
+        assert tuning.pallas_wins(4096, 64) is False    # measured loss wins
         # Aligned queries must not be judged by padded-dim entries.
         assert tuning.pallas_wins(4608, 128) is True
         # Same-dim measurements drive block choice for that class.
         assert tuning.best_blocks(16384, 40) == (512, 256)
         assert tuning.best_blocks(4608, 128) == (256, 256)
-        # A padded-dim win extrapolates at most 2x in seq: the 16k dim-40 win
-        # must NOT route a 256-token dim-40 attention (never measured against
-        # the cheap plain-XLA competitor there) through the padded kernel.
+        # A measured padded-dim entry speaks for at most 2x in seq either
+        # way; beyond that the rule decides: 256 tokens stay on XLA.
         assert tuning.pallas_wins(256, 40) is False
         assert tuning.pallas_wins(8192, 40) is True  # within 2x of 16384
 
     def test_padded_dim_blocks_never_inherit_aligned_winners(self, monkeypatch):
         # ADVICE r3: best_blocks for a padded dim with NO same-dim entry must
-        # return the defaults, mirroring pallas_wins' filtering — under a
-        # forced pallas backend the kernel would otherwise run blocks tuned
-        # for the wrong dim class.
+        # never return blocks tuned for another dim class: the shape rule's
+        # where it routes, the defaults where a forced pallas backend runs a
+        # shape the rule leaves to XLA.
         from comfyui_parallelanything_tpu.ops.pallas import tuning
 
         table = self._table([
@@ -376,7 +383,8 @@ class TestKernelTuning:
         monkeypatch.setattr(
             tuning, "kernel_tuning", lambda: {**tuning._DEFAULT, **table}
         )
-        assert tuning.best_blocks(4608, head_dim=40) == (256, 256)
+        assert tuning.best_blocks(4608, head_dim=40) == tuning.PADDED_DIM_BLOCKS
+        assert tuning.best_blocks(512, head_dim=40) == (256, 256)
         assert tuning.best_blocks(4608, head_dim=128) == (512, 512)
 
     def test_fused_backend_picks_measured_winner(self, monkeypatch):
@@ -582,3 +590,85 @@ class TestFlashAttention:
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=5e-2, atol=5e-2
         )
+
+    # The UNet classes with the shipped blocks (256 queries, a head group's
+    # whole K and V up to 4096 keys, the softmax walking 2048 keys a tile)
+    # scaled down 16x: 16 queries a block, 256 keys a block, 128 a tile.
+    # (label, b, sq, sk, heads, head_dim)
+    UNET_CLASSES = [
+        ("sd15-4096x40", 2, 256, 256, 8, 40),      # 8 heads a group: 320 lanes
+        ("sd15-1024x80", 2, 64, 64, 8, 80),        # one block, one tile
+        ("sdxl-4096x64", 1, 256, 256, 10, 64),     # 2 heads a group, 5 groups
+        ("sdxl-1024x64", 1, 64, 64, 20, 64),
+        ("ragged-keys-40", 1, 48, 300, 4, 40),     # S_k not a multiple of block_k
+        ("streamed-keys-64", 1, 32, 1000, 2, 64),  # 4 key blocks, masked tail
+    ]
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("label,b,sq,sk,h,d", UNET_CLASSES,
+                             ids=[c[0] for c in UNET_CLASSES])
+    def test_unet_head_dims_match_xla(self, monkeypatch, label, b, sq, sk, h,
+                                      d, dtype):
+        import importlib
+
+        fa = importlib.import_module(
+            "comfyui_parallelanything_tpu.ops.pallas.flash_attention"
+        )
+        monkeypatch.setattr(fa, "_CHUNK_K", 128)
+        q, k, v = (a.astype(dtype) for a in _qkv(b=b, sq=sq, sk=sk, h=h, d=d, seed=5))
+        got = fa._flash_attention(q, k, v, scale=d ** -0.5, block_q=16,
+                                  block_k=256, interpret=True)
+        assert got.shape == q.shape and got.dtype == dtype
+        want = _xla_attention(q, k, v, scale=d ** -0.5)
+        tol = 2e-4 if dtype == jnp.float32 else 3e-2
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("heads,head_dim,group", [
+        (8, 40, 8), (10, 64, 2), (20, 64, 2), (8, 80, 8), (8, 160, 4),
+        (1, 512, 1), (24, 128, 1), (12, 128, 1), (5, 64, 5), (2, 32, 2),
+    ])
+    def test_head_groups_fill_whole_lane_tiles(self, heads, head_dim, group):
+        # A block narrower than the (B, S, H·D) array must be a multiple of
+        # 128 lanes wide; one as wide as the array may be anything.
+        from comfyui_parallelanything_tpu.ops.pallas.flash_attention import (
+            _head_group,
+        )
+
+        assert _head_group(heads, head_dim) == group
+        assert group == heads or (group * head_dim) % 128 == 0
+
+    def test_softmax_state_is_float32_and_operands_keep_their_dtype(self):
+        # The stated precision: bfloat16 operands to both dots, float32
+        # logits / max / sum / accumulator, probabilities cast to the operand
+        # dtype for the second dot only — read off the kernel's jaxpr.
+        import importlib
+
+        fa = importlib.import_module(
+            "comfyui_parallelanything_tpu.ops.pallas.flash_attention"
+        )
+        q, k, v = (a.astype(jnp.bfloat16) for a in _qkv(b=1, sq=64, sk=512, h=2, d=40))
+        jaxpr = jax.make_jaxpr(lambda q, k, v: fa._flash_attention(
+            q, k, v, scale=0.1, block_q=32, block_k=256, interpret=False))(q, k, v)
+        (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+
+        def eqns(jp):
+            for e in jp.eqns:
+                yield e
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    yield from eqns(sub)
+
+        seen = set()
+        for e in eqns(call.params["jaxpr"]):
+            name = e.primitive.name
+            if name == "dot_general":
+                assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+                assert e.outvars[0].aval.dtype == jnp.float32
+            elif name in ("exp", "reduce_max", "reduce_sum", "max", "div"):
+                assert e.outvars[0].aval.dtype == jnp.float32, name
+            else:
+                continue
+            seen.add(name)
+        assert seen >= {"dot_general", "exp", "reduce_max", "reduce_sum", "div"}

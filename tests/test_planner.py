@@ -200,6 +200,76 @@ class TestAttentionAxis:
             resolved = set(att.resolved_backends()) - before or {expect}
             assert plan["backend"] in resolved | {expect}
 
+    # The shape rule (PR 25), case by case: the benchmark cells' four UNet
+    # self-attention classes, the cross-attention that rides with them, the
+    # VAE's and FLUX's lane-aligned heads, and the same shapes off a TPU.
+    # (label, on a TPU, batch, seq_q, seq_k, heads, head_dim, backend, blocks)
+    ROUTES = [
+        ("sd15-self4096", True, 16, 4096, 4096, 8, 40, "pallas", (256, 4096)),
+        ("sd15-self1024", True, 16, 1024, 1024, 8, 80, "pallas", (256, 4096)),
+        ("sdxl-self4096", True, 2, 4096, 4096, 10, 64, "pallas", (256, 4096)),
+        # 2^25.3 logits: measured 0.333 ms fused against 0.301 — stays on XLA.
+        ("sdxl-self1024", True, 2, 1024, 1024, 20, 64, "xla", None),
+        ("sd15-cross77", True, 16, 4096, 77, 8, 40, "xla", None),
+        ("sd15-self256", True, 16, 256, 256, 8, 160, "xla", None),
+        ("sd15-1024sq-self16384", True, 4, 16384, 16384, 8, 40, "pallas", (256, 4096)),
+        ("vae-mid-512wide", True, 8, 4096, 4096, 1, 512, "pallas", (256, 256)),
+        ("flux-joint-128wide", True, 1, 4608, 4608, 24, 128, "pallas", (256, 256)),
+        ("sd15-self4096-cpu", False, 16, 4096, 4096, 8, 40, "xla_chunked", None),
+        ("sd15-self1024-cpu", False, 16, 1024, 1024, 8, 80, "xla", None),
+        ("flux-joint-cpu", False, 1, 4608, 4608, 24, 128, "xla_chunked", None),
+    ]
+
+    @pytest.mark.parametrize(
+        "label,tpu,b,sq,sk,h,d,backend,blocks", ROUTES,
+        ids=[r[0] for r in ROUTES],
+    )
+    def test_route_is_read_from_the_shape(self, monkeypatch, label, tpu, b,
+                                          sq, sk, h, d, backend, blocks):
+        """``backend_plan`` and ``attention_local`` resolve every class the
+        same way, from the call's shape and the backend alone, and the
+        resolution is counted once a trace."""
+        import importlib
+
+        import jax
+        import jax.numpy as jnp
+
+        from comfyui_parallelanything_tpu.utils.metrics import registry
+
+        att = importlib.import_module(
+            "comfyui_parallelanything_tpu.ops.attention"
+        )
+        fa = importlib.import_module(
+            "comfyui_parallelanything_tpu.ops.pallas.flash_attention"
+        )
+        for var in ("PA_ATTN_CHUNK_ELEMS", "PA_ATTN_BF16_SOFTMAX"):
+            monkeypatch.delenv(var, raising=False)
+        monkeypatch.setattr(att, "_pallas_available", lambda: tpu)
+        calls = []
+        monkeypatch.setattr(
+            fa, "flash_attention",
+            lambda q, k, v, **kw: calls.append(
+                (kw["block_q"], kw["block_k"])) or q,
+        )
+        plan = att.backend_plan(sq, sk, head_dim=d, batch=b, heads=h)
+        assert plan["backend"] == backend, plan
+
+        def count():
+            return registry.get("pa_attention_route_total",
+                                {"backend": backend}) or 0.0
+
+        before = count()
+        q = jax.ShapeDtypeStruct((b, sq, h, d), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((b, sk, h, d), jnp.bfloat16)
+        # A fresh function each case: a trace is cached on (function, shapes),
+        # and a cached trace resolves — and counts — nothing.
+        fn = jax.jit(lambda q, k, v: att.attention_local(q, k, v))
+        assert fn.eval_shape(q, kv, kv).shape == q.shape
+        assert count() == before + 1
+        fn.eval_shape(q, kv, kv)
+        assert count() == before + 1  # once a trace, not once a call
+        assert calls == ([blocks] if blocks else [])
+
     def test_backend_plan_carries_the_banked_tables(self, monkeypatch):
         import importlib
 

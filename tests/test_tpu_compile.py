@@ -80,6 +80,40 @@ def test_flash_attention_compiles_for_v5e(one_chip, label, b, s, h, d, block):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
 
 
+# The benchmark cells' UNet self-attention classes (CFG doubles the batch) and
+# SD1.5 at 1024², as ops/pallas/tuning.py's shape rule routes them.
+# (label, B, S, H, D)
+UNET_CLASSES = [
+    ("sd15-b8-512.self4096", 16, 4096, 8, 40),
+    ("sd15-b8-512.self1024", 16, 1024, 8, 80),
+    ("sdxl-b1-1024.self4096", 2, 4096, 10, 64),
+    ("sdxl-b1-1024.self1024", 2, 1024, 20, 64),
+    ("sd15-b2-1024.self16384", 4, 16384, 8, 40),
+]
+
+
+@pytest.mark.parametrize("label,b,s,h,d", UNET_CLASSES,
+                         ids=[c[0] for c in UNET_CLASSES])
+def test_unet_classes_compile_with_the_shipped_blocks(one_chip, label, b, s, h, d):
+    """A head group's whole K and V in VMEM, 256 queries a block, 2048-key
+    softmax tiles over static lane slices of a (B, S, H·D) block: what
+    interpret mode cannot show is whether Mosaic takes the unaligned slices
+    and whether the tiles fit the raised VMEM limit."""
+    from comfyui_parallelanything_tpu.ops.pallas.tuning import PADDED_DIM_BLOCKS
+
+    block_q, block_k = PADDED_DIM_BLOCKS
+    compiled = flash_attention.lower(
+        *_qkv(one_chip, b, s, h, d), block_q=block_q, block_k=block_k,
+        interpret=False,
+    ).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # The kernel reads the projections' own layout: no transpose, no pad.
+    assert " transpose(" not in hlo and " pad(" not in hlo
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
 def test_cross_attention_keys_of_length_77_compile(one_chip):
     q = jax.ShapeDtypeStruct((2, 4096, 8, 40), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((2, 77, 8, 40), jnp.bfloat16, sharding=one_chip)
@@ -87,7 +121,7 @@ def test_cross_attention_keys_of_length_77_compile(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("which", ["in-repo", "upstream"])
+@pytest.mark.parametrize("which", ["in-repo", "upstream", "in-repo-unet"])
 def test_batch_sharded_kernels_compile_for_four_chips(topo, which):
     """The data-parallel step and the VAE decode of a chain's latent hand the
     kernel operands sharded over four chips. The partitioner refuses a bare
@@ -104,9 +138,15 @@ def test_batch_sharded_kernels_compile_for_four_chips(topo, which):
     rows = NamedSharding(mesh, P("data"))
     # The kl-f8 VAE's mid-block attention for 8 images of 512²: one 512-wide head.
     q = jax.ShapeDtypeStruct((8, 4096, 1, 512), jnp.bfloat16, sharding=rows)
+    if which == "in-repo-unet":
+        # The chain's UNet step: SD1.5's 4096-token self-attention at CFG
+        # batch 16, four rows a chip, with the shape rule's blocks.
+        q = jax.ShapeDtypeStruct((16, 4096, 8, 40), jnp.bfloat16, sharding=rows)
     fn = {
         "in-repo": lambda q, k, v: flash_attention(q, k, v, interpret=False),
         "upstream": lambda q, k, v: att._pallas_jax_attention(q, k, v, 0.04),
+        "in-repo-unet": lambda q, k, v: flash_attention(
+            q, k, v, block_q=256, block_k=4096, interpret=False),
     }[which]
     with pytest.raises(NotImplementedError, match="automatically partitioned"):
         jax.jit(fn).lower(q, q, q).compile()
