@@ -400,4 +400,7 @@ def build_mmdit(
         config=cfg,
         block_lists={"joint_blocks": cfg.depth},
         pipeline_spec=_mmdit_pipeline_spec(module, cfg),
+        # The family's trained timestep shift (the host's SD3 sampling
+        # default): a graph without a ModelSamplingSD3 node samples with it.
+        sampler_prefs={"shift": 3.0},
     )

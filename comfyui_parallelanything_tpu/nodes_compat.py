@@ -81,11 +81,21 @@ _TOKENIZER_HELP = (
 )
 
 
+# Key prefixes of the two CLIP towers an SD3-family ``*_incl_clips`` single
+# file bundles (CLIP-L, OpenCLIP-bigG), in that order.
+SD3_BUNDLED_TOWERS = (
+    "text_encoders.clip_l.transformer.",
+    "text_encoders.clip_g.transformer.",
+)
+
+
 class CheckpointLoaderSimple:
     """Stock loader: (ckpt_name) → (MODEL, CLIP, VAE). Family is sniffed off
     the checkpoint keys (stock has no family widget); CLIP comes from the
     bundled ``cond_stage_model``/``conditioner`` towers for the SD families
-    (SDXL gets the dual L+G wire TPUTextEncode combines)."""
+    (SDXL gets the dual L+G wire TPUTextEncode combines) and the
+    ``text_encoders.clip_l``/``clip_g`` towers of an SD3-family
+    ``*_incl_clips`` file (the ``sd3-triple`` wire, no T5)."""
 
     DESCRIPTION = "Stock-name checkpoint loader (family sniffed, bundled CLIP)."
     RETURN_TYPES = ("MODEL", "CLIP", "VAE")
@@ -160,6 +170,49 @@ class CheckpointLoaderSimple:
                 repr((file_stamp(ckpt_path),) + parts).encode()
             ).hexdigest()
 
+        def lg_pair(prefix_l, prefix_g, tag_l, tag_g):
+            """The CLIP-L + bigG pair SDXL and the SD3 family bundle, as the
+            ``l`` / ``g`` sub-wires of a dual wire; ``None`` when the file
+            lacks either tower. bigG is read in whichever layout it is in
+            (OpenCLIP resblocks or HF)."""
+            from .models import open_clip_g_config
+
+            towers = load_safetensors_subset(path, prefix_l, prefix_g)
+            sub_l, sub_g = (
+                {k: v for k, v in towers.items() if k.startswith(pfx)}
+                for pfx in (prefix_l, prefix_g)
+            )
+            if not sub_l or not sub_g:
+                return None
+            if te_loras:
+                from .models.convert import bake_lora
+
+                # kohya dual-tower convention: te1 = CLIP-L, te2 = G.
+                for sub, s in self._te_filtered(
+                    te_loras, "lora_te1_", "lora_te_"
+                ):
+                    sub_l = bake_lora(sub_l, sub, s)
+                for sub, s in self._te_filtered(te_loras, "lora_te2_"):
+                    sub_g = bake_lora(sub_g, sub, s)
+            enc_l = load_clip_text_checkpoint(sub_l)
+            enc_g = load_clip_text_checkpoint(
+                sub_g, cfg=open_clip_g_config(),
+                open_clip=any(
+                    k.endswith("positional_embedding") for k in sub_g
+                ),
+            )
+            tok_l = _clip_tokenizer(max_len=enc_l.cfg.max_len)
+            tok_g = _clip_tokenizer(max_len=enc_g.cfg.max_len, pad_id=0)
+            err = None if (tok_l and tok_g) else _TOKENIZER_HELP
+
+            def wire(enc, tok, tag):
+                return {"encoder": enc, "tokenizer": tok, "type": "clip",
+                        "model_key": stamp(path, family, tag),
+                        "tokenizer_error": err}
+
+            return {"l": wire(enc_l, tok_l, tag_l),
+                    "g": wire(enc_g, tok_g, tag_g), "tokenizer_error": err}
+
         try:
             if family in ("sd15", "sd21", "sd21-v", "sd21-unclip"):
                 open_clip = family.startswith("sd21")
@@ -221,49 +274,25 @@ class CheckpointLoaderSimple:
                     "tokenizer_error": None if tok_g else _TOKENIZER_HELP,
                 }
             if family == "sdxl":
-                from .models import open_clip_g_config
-
                 # conditioner.embedders.0 = CLIP-L (HF layout),
                 # conditioner.embedders.1 = OpenCLIP-G (resblocks layout).
-                towers = load_safetensors_subset(
-                    path, "conditioner.embedders.0.", "conditioner.embedders.1."
-                )
-                sub_l = {k: v for k, v in towers.items()
-                         if k.startswith("conditioner.embedders.0.")}
-                sub_g = {k: v for k, v in towers.items()
-                         if k.startswith("conditioner.embedders.1.")}
-                if not sub_l or not sub_g:
+                pair = lg_pair("conditioner.embedders.0.",
+                               "conditioner.embedders.1.",
+                               "embedders.0", "embedders.1")
+                if pair is None:
                     return error_wire(
                         "sdxl checkpoint has no bundled conditioner towers; "
                         "wire TPUCLIPLoader nodes instead"
                     )
-                if te_loras:
-                    from .models.convert import bake_lora
-
-                    # kohya dual-tower convention: te1 = CLIP-L, te2 = G.
-                    for sub, s in self._te_filtered(
-                        te_loras, "lora_te1_", "lora_te_"
-                    ):
-                        sub_l = bake_lora(sub_l, sub, s)
-                    for sub, s in self._te_filtered(te_loras, "lora_te2_"):
-                        sub_g = bake_lora(sub_g, sub, s)
-                enc_l = load_clip_text_checkpoint(sub_l)
-                enc_g = load_clip_text_checkpoint(
-                    sub_g, cfg=open_clip_g_config(), open_clip=True
-                )
-                tok_l = _clip_tokenizer(max_len=enc_l.cfg.max_len)
-                tok_g = _clip_tokenizer(max_len=enc_g.cfg.max_len, pad_id=0)
-                err = None if (tok_l and tok_g) else _TOKENIZER_HELP
-                return {
-                    "type": "sdxl-dual",
-                    "l": {"encoder": enc_l, "tokenizer": tok_l, "type": "clip",
-                          "model_key": stamp(path, family, "embedders.0"),
-                          "tokenizer_error": err},
-                    "g": {"encoder": enc_g, "tokenizer": tok_g, "type": "clip",
-                          "model_key": stamp(path, family, "embedders.1"),
-                          "tokenizer_error": err},
-                    "tokenizer_error": err,
-                }
+                return {"type": "sdxl-dual", **pair}
+            if family in ("sd3-medium", "sd35-medium", "sd35-large"):
+                # The ``*_incl_clips`` single files: CLIP-L and bigG under
+                # text_encoders.clip_l / clip_g (bigG in the HF layout), no
+                # T5 — the documented low-memory deployment; the encode path
+                # then conditions on the 77 CLIP tokens alone.
+                pair = lg_pair(*SD3_BUNDLED_TOWERS, "clip_l", "clip_g")
+                if pair is not None:
+                    return {"type": "sd3-triple", **pair, "t5": None}
             return error_wire(
                 f"{family} checkpoints do not bundle text encoders; wire "
                 "TPUCLIPLoader (or the DualCLIPLoader shim) instead"

@@ -11,11 +11,12 @@ surface. The TPU equivalent is a backend registry:
 - ``"xla_chunked"`` — memory-bounded attention in plain XLA ops (lax.scan over
   query blocks; the S×S logits tensor never materializes, but every block's
   slice of it goes to HBM and back). What long sequences take off a TPU, and
-  on one when the sequence lengths are not multiples of 128.
+  on one at a ragged length too short to pay back the kernel's padding.
 - ``"pallas"`` — fused flash-attention kernel for TPU (ops/pallas/): logits and
   probabilities never leave VMEM. Serves the long sequences of the FLUX/video
   configs and, chosen from the call's shape, the UNets' long self-attention at
-  40/64/80-wide heads (4096 and 1024 tokens; PERF.md §6, PR 25).
+  40/64/80-wide heads (4096 and 1024 tokens; PERF.md §6, PR 25) and SD3's
+  joint attention over 77 + 4096 tokens, padded and masked (PR 26).
 - ``"pallas_jax"`` — jax's own battle-tested TPU flash kernel
   (jax.experimental.pallas.ops.tpu.flash_attention) as an alternative fused
   candidate: round-3's only hardware data point for the in-repo kernel was a
@@ -323,20 +324,31 @@ def _auto_backend(seq_q: int, seq_k: int, head_dim: int | None,
     from the call's shape and the backend, nothing else; ``attention_local``
     and ``backend_plan`` both decide here.
 
-    The fused kernel needs a TPU and 128-multiple sequence lengths (what the
-    UNets' 64² and 32² token grids and FLUX's joint sequence are; a ragged
-    length stays with XLA rather than pay the kernel's padding). Then:
+    The fused kernel needs a TPU. A sequence length that is not a multiple
+    of 128 (SD3's joint 77 + 4096 tokens) goes to it padded and masked where
+    ``ragged_route`` of ops/pallas/tuning.py says the padding is paid back,
+    else stays with XLA. At 128-multiple lengths (the UNets' 64² and 32²
+    token grids, FLUX's joint sequence):
     lane-aligned head dims (VAE 512, FLUX / WAN 128) go to it unless a
     measured table (``$PA_TUNING_PATH``) says XLA won at the nearest length;
     the others (UNet 40 / 64 / 80) by the shape rule of ops/pallas/tuning.py —
     ``seq_k`` at or above 1024, which leaves cross-attention's 77 keys and
     the short inner levels on XLA, and B·H·S_q·S_k at or above 2^27, the
     smallest count of logits at which the kernel was measured to win."""
-    from .pallas.tuning import fused_backend, pallas_wins
+    from .pallas.tuning import (
+        fused_backend,
+        is_ragged,
+        pallas_wins,
+        ragged_route,
+    )
 
-    if (_pallas_available() and seq_q % 128 == 0 and seq_k % 128 == 0
-            and pallas_wins(seq_q, head_dim, seq_k=seq_k,
-                            batch_heads=batch_heads)):
+    if not _pallas_available():
+        return "xla"
+    if is_ragged(seq_q, seq_k):
+        # The in-repo kernel pads the row to its blocks and masks the padded
+        # keys; the upstream one cannot.
+        return "pallas" if ragged_route(seq_q, seq_k, batch_heads) else "xla"
+    if pallas_wins(seq_q, head_dim, seq_k=seq_k, batch_heads=batch_heads):
         # Which fused implementation won the measurement at this shape
         # class (in-repo streamed-KV kernel vs jax's upstream one).
         return fused_backend(seq_q, head_dim)
@@ -374,7 +386,14 @@ def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
     )
     if backend == "pallas":
         from .pallas.flash_attention import flash_attention
-        from .pallas.tuning import best_blocks
+        from .pallas.tuning import best_blocks, is_ragged
+
+        if is_ragged(q.shape[1], k.shape[1]):
+            registry.counter(
+                "pa_attention_padded_total", labels={"backend": backend},
+                help="attention calls, counted like pa_attention_route_total, "
+                     "whose sequence lengths were padded to reach the kernel",
+            )
 
         block_q, block_k = best_blocks(
             q.shape[1], q.shape[-1], seq_k=k.shape[1], batch_heads=batch_heads,
@@ -433,8 +452,8 @@ def backend_plan(seq_q: int, seq_k: int | None = None,
     cand(
         "pallas", auto == "pallas",
         "fused in-repo kernel (shape rule / tuning table winner)" if fused_ok
-        else "ineligible: not TPU / non-128-aligned seq / rule or tuning "
-             "says XLA",
+        else "ineligible: not TPU / ragged length the rule leaves to XLA / "
+             "rule or tuning says XLA",
         measured_ms=(nearest or {}).get("pallas_ms"),
     )
     cand(

@@ -222,8 +222,11 @@ def _flash_attention(q, k, v, *, scale: float, block_q: int, block_k: int,
 
     bq = min(block_q, _round_up(seq_q, 8))
     bk = min(block_k, _round_up(seq_k, 8))
-    ck = min(_CHUNK_K, bk)
-    bk = _round_up(bk, ck)
+    # A key block is walked in equal softmax tiles of at most _CHUNK_K keys:
+    # 4096 keys in two of 2048, a ragged row's 4224 in three of 1408.
+    tiles = -(-bk // _CHUNK_K)
+    ck = bk if tiles == 1 else _round_up(-(-bk // tiles), _LANES)
+    bk = tiles * ck
     # (B, S, H, D) -> (B, S, H·D): the layout the projections wrote, so XLA
     # folds this reshape into theirs and nothing is copied.
     q3 = _pad_to(q.reshape(batch, seq_q, heads * head_dim), 1, bq)

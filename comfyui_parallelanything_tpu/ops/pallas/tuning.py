@@ -17,8 +17,12 @@ Two sources, in this order:
   SM-version-gated, any_device_parallel.py:126-164). A same-dim entry of such
   a table overrides the shape rule for its dim class.
 
+Before either, a sequence length that is not a multiple of 128 (SD3's joint
+text + image tokens) is decided by :func:`ragged_route`, a rule of the same
+kind on the call's lengths and B·H, whatever the head dim.
+
 There is no default file: without ``$PA_TUNING_PATH`` everything runs on the
-rule and the defaults below, so a fresh clone and a checkout an earlier run
+rules and the defaults below, so a fresh clone and a checkout an earlier run
 wrote into behave the same.
 """
 
@@ -87,6 +91,55 @@ def padded_dim_route(seq_q: int, seq_k: int,
     return PADDED_DIM_BLOCKS
 
 
+# -- Sequence lengths that are not a multiple of 128 -----------------------------
+# SD3's joint attention runs over text and image tokens together: 77 + 4096 =
+# 4173 at 1024², 77 + 1024 = 1101 at 512². The kernel pads such a row to its
+# blocks itself and masks the padded keys (flash_attention.py), so the question
+# is only whether the padded work is paid back. Measured on the v5e, bfloat16,
+# ms a call (scripts/bench_kernels.py, my chip runs, PR 26; PERF.md §6): the
+# XLA path ``auto`` would otherwise take (chunked over 2^27 logits; the whole
+# logits tensor in HBM reads 7.279 at the joint class) against this kernel
+# with the padded row as ONE key block, by queries a block:
+#
+#   (batch, tokens, heads, head dim)           B·H·S_q·S_k   xla    128    256    384
+#   sd35m-b1-1024.joint4173 (2, 4173, 24, 64)  2^29.6       8.443  3.733  3.294  3.085
+#   sd35m-b1-512.joint1101  (2, 1101, 24, 64)  2^25.8       0.696  0.507  0.463  0.413
+#   sd35m-b1-1024.self4096  (2, 4096, 24, 64)  2^29.6       8.398  2.824  2.463  (not ragged)
+#
+# Both ragged classes win, the short one too (1.7x), where sdxl's 1024-token
+# class at 2^25.3 logits lost by 10%: XLA pays for a ragged length as well.
+# Below the short class nothing was measured, so the threshold stands there,
+# at the smallest measured win (and, as above, at PADDED_DIM_MIN_KEYS keys). Streaming the joint class as two 4096-key
+# blocks (the second nearly all mask) takes 7.119: a row of up to
+# RAGGED_ONE_BLOCK keys is one key block, padded to the next 128-multiple
+# (4224: 1.2% more keys, walked in three 1408-key softmax tiles); a longer one
+# streams PADDED_DIM_BLOCKS' 4096 keys a block and masks the last. 384 queries
+# a block divide both padded rows (4224 = 11 x 384, 1152 = 3 x 384), so no
+# padded query rows are computed; where they do not divide the row, 256.
+RAGGED_MIN_LOGITS = 2 * 24 * 1101 * 1101  # 2^25.8, the 512² joint class
+RAGGED_ONE_BLOCK = 4352
+
+
+def is_ragged(seq_q: int, seq_k: int) -> bool:
+    return seq_q % 128 != 0 or seq_k % 128 != 0
+
+
+def ragged_route(seq_q: int, seq_k: int,
+                 batch_heads: int | None = None) -> tuple[int, int] | None:
+    """``(block_q, block_k)`` if the fused kernel serves these lengths, of
+    which one is not a multiple of 128, padded and masked; else ``None``: the
+    call stays with the XLA family. Read from the call's shape alone, whatever
+    the head dim."""
+    if seq_k < PADDED_DIM_MIN_KEYS:
+        return None
+    if (batch_heads is not None
+            and batch_heads * seq_q * seq_k < RAGGED_MIN_LOGITS):
+        return None
+    rows_q, row_k = (-(-n // 128) * 128 for n in (seq_q, seq_k))
+    block_k = row_k if row_k <= RAGGED_ONE_BLOCK else PADDED_DIM_BLOCKS[1]
+    return (384 if rows_q % 384 == 0 else 256), block_k
+
+
 @functools.lru_cache(maxsize=1)
 def kernel_tuning() -> dict:
     """The active tuning table (defaults merged under ``$PA_TUNING_PATH``).
@@ -125,6 +178,12 @@ def best_blocks(seq: int, head_dim: int | None = None,
     t = kernel_tuning()
     seq_k = seq if seq_k is None else seq_k
     entries = [e for e in t["entries"] if e.get("block_q") and e.get("block_k")]
+    if not entries and is_ragged(seq, seq_k):
+        # No measured table: a ragged length the rule routes takes the rule's
+        # blocks (the padded row as one key block), whatever the head dim.
+        ragged = ragged_route(seq, seq_k, batch_heads)
+        if ragged is not None:
+            return ragged
     if head_dim is not None:
         same_dim = [e for e in entries if e.get("head_dim") == head_dim]
         if same_dim:

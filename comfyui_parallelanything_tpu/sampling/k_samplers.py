@@ -112,7 +112,11 @@ def sgm_uniform_sigmas(
     n+1 uniform timesteps, last dropped, so the final nonzero sigma sits one
     uniform stride above 0 instead of at sigma_min."""
     table = _sigma_table(alphas_cumprod, sigma_table)
-    idx = jnp.linspace(len(table) - 1, 0, n_steps + 1, dtype=jnp.float32)[:-1]
+    # The dropped end point is the host's ``timestep(sigma_min)``: index 0 of
+    # an eps table; for a CONST (flow) table, whose timestep is sigma·n, the
+    # index sigma_min·n − 1 (2 at SD3's shift 3, not 0).
+    last = 0.0 if sigma_table is None else float(table[0]) * len(table) - 1.0
+    idx = jnp.linspace(len(table) - 1, last, n_steps + 1, dtype=jnp.float32)[:-1]
     sig = jnp.interp(idx, jnp.arange(len(table), dtype=jnp.float32), table)
     return jnp.concatenate([sig, jnp.zeros((1,), jnp.float32)])
 

@@ -121,7 +121,9 @@ Attention routing (PR 25): ``pa_attention_route_total{backend=}``
 while a program is traced and not per forward: ``pallas`` moving while a
 UNet's step program compiles is the evidence that its long self-attention
 took the fused flash kernel; ``resolved_backends()`` is the same fact as a
-set).
+set). ``pa_attention_padded_total{backend=}`` (PR 26) counts, the same way,
+the calls among them whose sequence length was not a multiple of 128 and was
+padded and masked to reach the kernel (SD3's joint text + image tokens).
 """
 
 from __future__ import annotations

@@ -16,11 +16,13 @@ time); without a TPU it exits non-zero and measures nothing:
 
 Shapes cover the rungs that matter: the benchmark cells' UNet self-attention
 classes (named ``<cell>.self<tokens>``: what ops/pallas/tuning.py's shape rule
-was set from), FLUX joint attention at 1024² (4.6k tokens, 24 heads × 128) and
+was set from) and SD3.5-medium's joint and image-only classes, FLUX joint
+attention at 1024² (4.6k tokens, 24 heads × 128) and
 WAN-video lengths (16k/32k tokens) where the streamed-K/V layout is what keeps
 VMEM bounded. Operands are made as (B, S, H·D) and split into heads inside the
 timed program, as a model's projections hand them over. The sweep tries
-block_q over {128, 256, 512} × block_k over {256, 1024, 4096} per shape; each
+block_q over {128, 256, 512} × block_k over {256, 1024, 4096} per shape (at a
+ragged length the padded row as one key block, ``_combos``); each
 cell is the mean of 10 chained timed calls after compile+warmup (see
 ``_time_fn`` for why chained). Appends JSON lines to
 ``<evidence dir>/KERNEL_BENCH.json`` (not tracked); the lines the shape rule
@@ -59,10 +61,27 @@ SHAPES = [
     # the 16,384 tokens of SD1.5 at 2 x 1024² (a cell for a later issue).
     ("sd15-b1-512.self4096", 2, 4096, 8, 40),
     ("sd15-b2-1024.self16384", 4, 16384, 8, 40),
+    # SD3.5-medium's two attention classes at 1 x 1024² (24 joint attentions
+    # over 77 text + 4096 image tokens and 13 over the image tokens alone a
+    # step) and the joint class of 512²: what the ragged-length rule in
+    # ops/pallas/tuning.py was set from.
+    ("sd35m-b1-1024.joint4173", 2, 4173, 24, 64),
+    ("sd35m-b1-1024.self4096", 2, 4096, 24, 64),
+    ("sd35m-b1-512.joint1101", 2, 1101, 24, 64),
 ]
 
 BLOCKS_Q = (128, 256, 512)
 BLOCKS_K = (256, 1024, 4096)
+
+
+def _combos(s: int) -> list[tuple[int, int]]:
+    """The (block_q, block_k) pairs tried at ``s`` tokens. A ragged length
+    is padded and masked by the kernel: its row as one key block (the next
+    128-multiple) under three query blocks, and streamed 4096 keys a block."""
+    if s % 128:
+        row = -(-s // 128) * 128
+        return [(bq, row) for bq in (128, 256, 384)] + [(256, min(4096, row))]
+    return sorted({(min(bq, s), min(bk, s)) for bq in BLOCKS_Q for bk in BLOCKS_K})
 
 def _time_fn(fn, *args, iters=10):
     """Mean time per call, closed by a host readback (attention maps q-shaped
@@ -120,9 +139,7 @@ def _run_shapes(shapes, dev):
         rec = {"shape": label, "b": b, "seq": s, "heads": h, "head_dim": d,
                "platform": dev.platform, "device_kind": dev.device_kind,
                "ts": time.time()}
-        combos = sorted(
-            {(min(bq, s), min(bk, s)) for bq in BLOCKS_Q for bk in BLOCKS_K}
-        ) if sweep else [(256, 256)]
+        combos = _combos(s) if sweep else [(256, 256)]
         best = None  # (ms, bq, bk)
         for bq, bk in combos:
             try:
@@ -165,6 +182,15 @@ def _run_shapes(shapes, dev):
             )
         except Exception as e:  # noqa: BLE001 — S×S logits OOM at video lengths
             rec["xla_error"] = str(e)[:200]
+        if s % 128 and b * h * s * s > _chunk_threshold():
+            # The third route of a ragged length: XLA with the whole logits
+            # tensor in HBM, where it fits.
+            try:
+                rec["xla_plain_ms"] = round(_time_fn(
+                    projected(lambda a, b_, c: _xla_attention(a, b_, c, d**-0.5)),
+                    q, k, v) * 1e3, 3)
+            except Exception as e:  # noqa: BLE001 — the logits do not fit
+                rec["xla_plain_error"] = str(e)[:200]
         if "pallas_ms" in rec and "xla_ms" in rec:
             rec["pallas_speedup"] = round(rec["xla_ms"] / rec["pallas_ms"], 2)
         print(json.dumps(rec))

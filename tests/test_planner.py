@@ -215,6 +215,20 @@ class TestAttentionAxis:
         ("sd15-1024sq-self16384", True, 4, 16384, 16384, 8, 40, "pallas", (256, 4096)),
         ("vae-mid-512wide", True, 8, 4096, 4096, 1, 512, "pallas", (256, 256)),
         ("flux-joint-128wide", True, 1, 4608, 4608, 24, 128, "pallas", (256, 256)),
+        # SD3.5-medium's joint attention (77 text + image tokens): a ragged
+        # length goes to the kernel padded and masked from 2^25.8 logits up
+        # (the row as one key block), else stays with XLA (PR 26).
+        ("sd35m-joint4173", True, 2, 4173, 4173, 24, 64, "pallas", (384, 4224)),
+        ("sd35m-joint1101", True, 2, 1101, 1101, 24, 64, "pallas", (384, 1152)),
+        # under the smallest measured win, and under 1024 keys: XLA
+        ("sd35m-joint1101-b1", True, 1, 1101, 1101, 24, 64, "xla", None),
+        ("sd35m-joint333", True, 2, 333, 333, 24, 64, "xla", None),
+        # a padded row that 384 does not divide keeps 256-query blocks
+        ("ragged-1300", True, 4, 1300, 1300, 24, 64, "pallas", (256, 1408)),
+        # past one block's keys the row streams 4096 keys a block
+        ("ragged-8269", True, 1, 8269, 8269, 24, 64, "pallas", (256, 4096)),
+        ("sd35m-self4096", True, 2, 4096, 4096, 24, 64, "pallas", (256, 4096)),
+        ("sd35m-joint4173-cpu", False, 2, 4173, 4173, 24, 64, "xla_chunked", None),
         ("sd15-self4096-cpu", False, 16, 4096, 4096, 8, 40, "xla_chunked", None),
         ("sd15-self1024-cpu", False, 16, 1024, 1024, 8, 80, "xla", None),
         ("flux-joint-cpu", False, 1, 4608, 4608, 24, 128, "xla_chunked", None),

@@ -114,6 +114,33 @@ def test_unet_classes_compile_with_the_shipped_blocks(one_chip, label, b, s, h, 
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
 
 
+# SD3.5-medium's two attention classes at 1 x 1024² (CFG doubles the batch):
+# the joint one is 77 text + 4096 image tokens, a ragged length.
+MMDIT_CLASSES = [
+    ("sd35m-b1-1024.joint4173", 2, 4173, 24, 64),
+    ("sd35m-b1-1024.self4096", 2, 4096, 24, 64),
+]
+
+
+@pytest.mark.parametrize("label,b,s,h,d", MMDIT_CLASSES,
+                         ids=[c[0] for c in MMDIT_CLASSES])
+def test_mmdit_classes_compile_with_the_routed_blocks(one_chip, label, b, s, h, d):
+    """The joint class goes to the kernel padded and masked: one key block of
+    4224 keys walked in three 1408-key softmax tiles, 11 query blocks of 384
+    — slices at offsets that are multiples of 128 but not of 2048."""
+    from comfyui_parallelanything_tpu.ops.pallas.tuning import best_blocks
+
+    block_q, block_k = best_blocks(s, d, seq_k=s, batch_heads=b * h)
+    assert block_k == -(-s // 128) * 128 <= 4352
+    compiled = flash_attention.lower(
+        *_qkv(one_chip, b, s, h, d), block_q=block_q, block_k=block_k,
+        interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
 def test_cross_attention_keys_of_length_77_compile(one_chip):
     q = jax.ShapeDtypeStruct((2, 4096, 8, 40), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((2, 77, 8, 40), jnp.bfloat16, sharding=one_chip)
