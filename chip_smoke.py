@@ -411,6 +411,10 @@ def numerics_phase(ckpt: str, seed: int) -> None:
     import jax.numpy as jnp
 
     from comfyui_parallelanything_tpu import models
+    from comfyui_parallelanything_tpu.ops.attention import (
+        get_attention_backend,
+        set_attention_backend,
+    )
 
     cfg = models.sd15_config()
     model = models.load_sd_unet_checkpoint(ckpt, cfg)
@@ -424,7 +428,18 @@ def numerics_phase(ckpt: str, seed: int) -> None:
     cpu = jax.devices("cpu")[0]
     on_cpu = jax.device_put((model.params, x, t, ctx), cpu)
     t0 = time.monotonic()
-    want = np.asarray(jax.jit(model.apply)(*on_cpu), np.float32)
+    # The shape rule sends this forward's 4096-token self-attention to the
+    # fused kernel because the process holds a TPU; the reference runs on the
+    # host, where Mosaic cannot lower, and is the plain XLA path by intent.
+    # The route is taken while tracing, and jit would hand back the trace the
+    # chip forward made of ``model.apply``: a function of its own is traced anew.
+    routed = get_attention_backend()
+    set_attention_backend("xla")
+    try:
+        reference = jax.jit(lambda *args: model.apply(*args))
+        want = np.asarray(reference(*on_cpu), np.float32)
+    finally:
+        set_attention_backend(routed)
     err = _rel_err(got, want)
     emit("numerics", model="sd15-unet", params=model.n_params(),
          out_shape=got.shape, rel_err=err,

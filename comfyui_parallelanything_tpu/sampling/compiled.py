@@ -19,10 +19,11 @@ chains, replicated or FSDP). It intentionally does NOT cover:
 - step-level OOM demotion (parity 1435-1448): one program means one
   allocation decision at compile time. Elasticity stays with the eager path.
 
-Each scan sampler mirrors its eager twin in ``k_samplers.py``/``ddim.py``/
-``flow.py`` op-for-op (Python schedule branches become ``jnp.where`` on the
-step index); ``tests/test_compiled.py`` pins eager/compiled equivalence for
-the full sampler menu.
+Each scan sampler is its eager twin (``ddim.py``, ``flow.py``, and for the
+k-family the plans of ``lane_specs.py`` that ``k_samplers.sample_planned``
+walks) with the Python schedule branches as ``jnp.where`` on the step index;
+``tests/test_compiled.py`` pins eager/compiled equivalence for the full
+sampler menu.
 """
 
 from __future__ import annotations

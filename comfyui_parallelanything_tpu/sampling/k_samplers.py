@@ -5,7 +5,10 @@ The reference is driven by its host's KSampler — every sampler in that menu ca
 (any_device_parallel.py:1287). To stand alone, this framework carries the standard
 sigma-space sampler set itself. Host-side step loops like ddim.py/flow.py: each model
 call routes through the (possibly parallelized) forward, so the DP/pipeline scheduler
-sees exactly the per-step batched calls it is designed for.
+sees exactly the per-step batched calls it is designed for. The loops keep the
+schedule and every scalar derived from it on the host and never read the device
+between steps; the samplers that have a plan in lane_specs.py are walked from it
+(``sample_planned``), ``lms`` and ``uni_pc*`` keep a loop of their own.
 
 Conventions (eps-prediction SD family, k-diffusion/EDM parameterization):
 ``sigma_t = sqrt((1-ᾱ_t)/ᾱ_t)``; model input is ``x/sqrt(sigma²+1)`` at the discrete
@@ -14,11 +17,14 @@ timestep nearest in log-sigma; denoised prediction ``x0 = x - sigma·eps``.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .cfg import apply_callback, double_kwargs, rescale_guidance
+from .lane_specs import LANE_SPECS
 from .schedules import scaled_linear_schedule
 
 
@@ -345,8 +351,11 @@ class EpsDenoiser:
         self.cond_strength = cond_strength
         self.cond_mask_strength = cond_mask_strength
         self.kwargs = model_kwargs
+        self.alphas_cumprod = alphas_cumprod
         self.sigma_table = model_sigmas(alphas_cumprod)
         self.log_sigmas = jnp.log(self.sigma_table)
+        self._host_log_sigmas = None  # float64 twin, read once on first use
+        self._cfg_inputs = None       # context ‖ uncond and doubled kwargs
 
     def _area_mask(self, area, strength: float, shape, mask=None,
                    mask_strength: float = 1.0, area_pct=None):
@@ -401,9 +410,80 @@ class EpsDenoiser:
             jnp.arange(len(self.log_sigmas), dtype=jnp.float32),
         )
 
-    def __call__(self, x: jnp.ndarray, sigma: jnp.ndarray) -> jnp.ndarray:
-        batch = x.shape[0]
+    @property
+    def use_cfg(self) -> bool:
+        return self.cfg_scale != 1.0 and self.uncond_context is not None
+
+    @property
+    def plain(self) -> bool:
+        """One model call an eval and no Python between it and x0: such an
+        eval can be two compiled programs around the model (``fused_eval``).
+        Multi-cond (extra conds, an area or a mask on the primary cond) calls
+        the model again per cond and blends in Python."""
+        return not (self.extra_conds or self.cond_area is not None
+                    or self.cond_area_pct is not None
+                    or self.cond_mask is not None)
+
+    def eval_scalars(self, sigma: float) -> np.ndarray:
+        """Host arithmetic of one eval at ``sigma``, float64 rounded once:
+        ``(c_in, t, c_skip, c_out, cfg_scale)`` float32 — the
+        model sees ``c_in·x`` at timestep ``t`` and
+        ``x0 = c_skip·x + c_out·prediction``."""
         if self.prediction == "flow":
+            c_in, t, c_skip, c_out = 1.0, sigma, 1.0, -sigma
+        else:
+            if self._host_log_sigmas is None:
+                acp = np.asarray(self.alphas_cumprod, np.float64)
+                self._host_log_sigmas = 0.5 * np.log((1.0 - acp) / acp)
+            table = self._host_log_sigmas
+            c_in = 1.0 / np.sqrt(sigma * sigma + 1.0)
+            t = np.interp(np.log(sigma), table, np.arange(len(table)))
+            c_skip, c_out = 1.0, -sigma
+            if self.prediction == "v":
+                c_skip, c_out = c_in * c_in, -sigma * c_in
+        return np.array([c_in, t, c_skip, c_out, self.cfg_scale], np.float32)
+
+    def _model_inputs(self, batch: int):
+        """(context, kwargs) of the model call. Under CFG every per-batch
+        kwarg doubles with the batch; uncond variants (e.g. SDXL's negative
+        pooled y) ride the second half (sampling/cfg.py). Neither changes
+        across steps, so outside a trace both are built once a run."""
+        if not self.use_cfg:
+            return self.context, self.kwargs
+        if self._cfg_inputs is not None and self._cfg_inputs[0] == batch:
+            return self._cfg_inputs[1:]
+        ctx = jnp.concatenate([self.context, self.uncond_context], axis=0)
+        kw = double_kwargs(self.kwargs, self.uncond_kwargs, batch)
+        if not isinstance(ctx, jax.core.Tracer):
+            self._cfg_inputs = (batch, ctx, kw)
+        return ctx, kw
+
+    def fused_eval(self, state, plan, keys, draw):
+        """One StepPlan as prepare → model → finish (``plain`` only): new
+        ``(x, xe, h1, h2)``. The model is called as in ``__call__``, once,
+        from Python, so its own dispatch, span and counter are untouched."""
+        x, xe, h1, h2 = state
+        cfg = self.use_cfg
+        scal = self.eval_scalars(plan.sigma_eval)
+        x_in, t_vec = sampler_prepare(xe, scal, cfg=cfg)
+        ctx, kw = self._model_inputs(xe.shape[0])
+        pred = self.model(x_in, t_vec, ctx, **kw)
+        return sampler_finish(
+            pred, x, xe, h1, h2, scal, plan.coef, keys, draw,
+            cfg=cfg, rescale=float(self.cfg_rescale),
+        )
+
+    def __call__(self, x: jnp.ndarray, sigma) -> jnp.ndarray:
+        """x0 at ``sigma``. A host float (the eager loops pass nothing else)
+        keeps the scalar arithmetic on the host; a traced or device ``sigma``
+        (the whole-loop programs, the serving lanes) computes it in place."""
+        batch = x.shape[0]
+        host = isinstance(sigma, (float, np.floating))
+        if host:
+            scale, t, c_skip, c_out = self.eval_scalars(float(sigma))[:4]
+            t_vec = jnp.full((batch,), t, jnp.float32)
+            x_in = x if self.prediction == "flow" else x * scale
+        elif self.prediction == "flow":
             # Flow time is the sigma: the model takes x raw and t = σ directly.
             scale = 1.0
             t_vec = jnp.full((batch,), sigma, jnp.float32)
@@ -412,56 +492,172 @@ class EpsDenoiser:
             scale = 1.0 / jnp.sqrt(sigma**2 + 1.0)
             t_vec = jnp.full((batch,), self._timestep(sigma), jnp.float32)
             x_in = x * scale
-        use_cfg = self.cfg_scale != 1.0 and self.uncond_context is not None
-        if use_cfg:
-            # Every per-batch kwarg doubles with the batch; uncond variants (e.g.
-            # SDXL's negative pooled y) ride the second half (sampling/cfg.py).
-            kw = double_kwargs(self.kwargs, self.uncond_kwargs, batch)
+        ctx, kw = self._model_inputs(batch)
+        if self.use_cfg:
             eps_both = self.model(
                 jnp.concatenate([x_in, x_in], axis=0),
                 jnp.concatenate([t_vec, t_vec], axis=0),
-                jnp.concatenate([self.context, self.uncond_context], axis=0),
-                **kw,
+                ctx, **kw,
             )
             eps_c, eps_u = jnp.split(eps_both, 2, axis=0)
-            if (self.extra_conds or self.cond_area is not None
-                    or self.cond_area_pct is not None
-                    or self.cond_mask is not None):
+            if not self.plain:
                 eps_c = self._combine_conds(eps_c, x_in, t_vec, batch)
             eps = eps_u + self.cfg_scale * (eps_c - eps_u)
             eps = rescale_guidance(eps, eps_c, self.cfg_rescale)
         else:
-            eps = self.model(x_in, t_vec, self.context, **self.kwargs)
-            if (self.extra_conds or self.cond_area is not None
-                    or self.cond_area_pct is not None
-                    or self.cond_mask is not None):
+            eps = self.model(x_in, t_vec, ctx, **kw)
+            if not self.plain:
                 eps = self._combine_conds(eps, x_in, t_vec, batch)
+        if host:
+            return c_skip * x + c_out * eps
         if self.prediction == "v":
             return x / (sigma**2 + 1.0) - eps * sigma * scale
         # eps: x0 = x − σ·eps. flow: x0 = x − σ·v — the same expression.
         return x - sigma * eps
 
 
-def sample_euler(denoise, x, sigmas, callback=None):
-    """Deterministic Euler over the sigma schedule."""
-    for i in range(len(sigmas) - 1):
-        x0 = denoise(x, sigmas[i])
-        d = (x - x0) / sigmas[i]
-        x = x + d * (sigmas[i + 1] - sigmas[i])
-        x = apply_callback(callback, i, x)
-    return x
+def host_sigmas(sigmas) -> np.ndarray:
+    """The schedule as host float64: the ONE device read of an eager sampler
+    run, made before its first step. Every scalar a step derives from the
+    schedule is host arithmetic on this copy, so no step waits on the device
+    for a number (a ``float(sigmas[i])`` sits in the queue behind the previous
+    step's latent update and stalls the host until the device has drained)."""
+    return np.asarray(sigmas, np.float64)
 
 
 def ancestral_steps(s, s_next, eta: float = 1.0):
     """(sigma_down, sigma_up) for an ancestral step from ``s`` to ``s_next``
     (k-diffusion's get_ancestral_step): deterministic integration runs to
-    sigma_down, then sigma_up of fresh noise restores the s_next level."""
+    sigma_down, then sigma_up of fresh noise restores the s_next level.
+    Traced form, for the whole-loop programs (sampling/compiled.py); the eager
+    loop's is ``lane_specs._ancestral`` on the host."""
     sigma_up = jnp.minimum(
         s_next,
         eta * jnp.sqrt(jnp.maximum(s_next**2 * (s**2 - s_next**2) / s**2, 0.0)),
     )
     sigma_down = jnp.sqrt(jnp.maximum(s_next**2 - sigma_up**2, 0.0))
     return sigma_down, sigma_up
+
+
+# ---------------------------------------------------------------------------
+# The planned step. A k-sampler step is one model eval plus a linear update of
+# the state (x, xe, h1, h2) whose weights depend only on the schedule
+# (sampling/lane_specs.py: float64 on the host, one [4, 6] float32 matrix an
+# eval). The eager loop walks those plans and hands the device two compiled
+# programs around the denoiser's own, with every coefficient an ARRAY argument
+# so that first, middle and last steps run the same executables. The programs
+# are named functions: the benchmark finds the denoiser and the decode by their
+# XLA module names (jit_apply, jit__lambda), and these must match neither.
+# ---------------------------------------------------------------------------
+
+
+def _linear_update(x, xe, x0, h1, h2, coef, keys, draw):
+    """New (x, xe, h1, h2) from one plan's ``coef`` rows over the basis
+    (x, xe, x0, h1, h2[, noise]); the draw is ``keys[draw[0], draw[1]]``."""
+    basis = [x, xe, x0, h1, h2]
+    if keys is not None:
+        basis.append(jax.random.normal(keys[draw[0], draw[1]], x.shape, x.dtype))
+    return tuple(
+        sum(coef[row, k] * term for k, term in enumerate(basis)).astype(x.dtype)
+        for row in range(4)
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def sampler_prepare(xe, scal, *, cfg: bool):
+    """Model input of one eval: ``c_in·xe`` and the timestep vector, doubled
+    along the batch under CFG. ``scal`` = (c_in, t, ...)."""
+    x_in = xe * scal[0]
+    t_vec = jnp.full((xe.shape[0],), scal[1], jnp.float32)
+    if cfg:
+        x_in = jnp.concatenate([x_in, x_in], axis=0)
+        t_vec = jnp.concatenate([t_vec, t_vec], axis=0)
+    return x_in, t_vec
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "rescale"))
+def sampler_finish(pred, x, xe, h1, h2, scal, coef, keys, draw, *,
+                   cfg: bool, rescale: float):
+    """CFG split and combination (``rescale`` is the model's guidance-rescale
+    phi, fixed for a run), x0 from the prediction (``c_skip·xe + c_out·pred``:
+    eps, v and flow differ only in the two scalars) and the plan's linear
+    update, in one program. ``scal`` = (c_in, t, c_skip, c_out, cfg_scale)."""
+    if cfg:
+        pred_c, pred_u = jnp.split(pred, 2, axis=0)
+        pred = pred_u + scal[4] * (pred_c - pred_u)
+        pred = rescale_guidance(pred, pred_c, rescale)
+    x0 = scal[2] * xe + scal[3] * pred
+    return _linear_update(x, xe, x0, h1, h2, coef, keys, draw)
+
+
+@jax.jit
+def sampler_update(x, xe, x0, h1, h2, coef, keys, draw):
+    """The plan's linear update alone, for a denoiser that is called whole
+    (multi-cond, or any ``denoise(x, sigma) -> x0`` callable)."""
+    return _linear_update(x, xe, x0, h1, h2, coef, keys, draw)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "split"))
+def sampler_step_keys(rng, *, n: int, split: bool):
+    """[n, 2] per-step keys under the fold_in discipline (see
+    ``sample_euler_ancestral``): row i is ``fold_in(rng, i)`` twice, or its
+    ``split`` halves for dpmpp_sde's two draws a step. One program a run."""
+    if not jnp.issubdtype(rng.dtype, jax.dtypes.prng_key):
+        rng = jax.random.wrap_key_data(rng)
+    ks = jax.vmap(lambda i: jax.random.fold_in(rng, i))(jnp.arange(n))
+    if split:
+        return jax.vmap(jax.random.split)(ks)
+    return jnp.stack([ks, ks], axis=1)
+
+
+def takes_fused_step(denoise) -> bool:
+    """Whether ``sample_planned`` gives ``denoise`` the two-program step: it
+    has one (``EpsDenoiser.fused_eval``) and is one model call an eval."""
+    return getattr(denoise, "fused_eval", None) is not None and denoise.plain
+
+
+def sample_planned(name, denoise, x, sigmas, rng=None, callback=None, *,
+                   prediction: str = "eps", **plan_kwargs):
+    """Walk sampler ``name``'s StepPlans over ``sigmas``: the eager loop of
+    every sampler in ``LANE_SPECS``. ``prediction="flow"`` picks the
+    rectified-flow form of the samplers that have one; ``plan_kwargs`` (eta)
+    go to the plan compiler. The callback fires once a completed σ-interval.
+
+    A plain ``EpsDenoiser`` (one model call an eval, no Python of its own
+    between the model and x0) takes the two-program step; anything else is
+    called whole with a host-float sigma and followed by one update program.
+    Nothing in the loop reads the device."""
+    spec = LANE_SPECS[name]
+    sig = host_sigmas(sigmas)
+    plans = spec.compile_plans(sig, prediction, **plan_kwargs)
+    # The eager loops computed in the float32 their device scalars carried.
+    x = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    xe, h1 = x, jnp.zeros_like(x)
+    h2 = h1
+    keys = None
+    if any(p.noise for p in plans):
+        keys = sampler_step_keys(rng, n=len(sig) - 1, split=spec.split_keys)
+    fused = takes_fused_step(denoise)
+    for plan in plans:
+        draw = None
+        if keys is not None:
+            draw = np.array([plan.step, plan.noise == "sde_end"], np.int32)
+        if fused:
+            x, xe, h1, h2 = denoise.fused_eval((x, xe, h1, h2), plan, keys, draw)
+        else:
+            x0 = denoise(xe, plan.sigma_eval)
+            x, xe, h1, h2 = sampler_update(
+                x, xe, x0, h1, h2, plan.coef, keys, draw)
+        if plan.completes:
+            # A completed step's next eval input IS its output latent
+            # (lane_specs._mk), so a callback's replacement feeds both.
+            x = xe = apply_callback(callback, plan.step, x)
+    return x
+
+
+def sample_euler(denoise, x, sigmas, callback=None):
+    """Deterministic Euler over the sigma schedule."""
+    return sample_planned("euler", denoise, x, sigmas, callback=callback)
 
 
 def sample_euler_ancestral(denoise, x, sigmas, rng, eta: float = 1.0, callback=None):
@@ -472,17 +668,8 @@ def sample_euler_ancestral(denoise, x, sigmas, rng, eta: float = 1.0, callback=N
     — a pure function of (request rng, step index), never of how many draws
     preceded it — so output is bit-identical whether the run executes alone,
     inside a compiled loop, or co-batched in a serving lane (round 10)."""
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        sigma_down, sigma_up = ancestral_steps(s, s_next, eta)
-        d = (x - x0) / s
-        x = x + d * (sigma_down - s)
-        if float(s_next) > 0:
-            sub = jax.random.fold_in(rng, i)
-            x = x + sigma_up * jax.random.normal(sub, x.shape, x.dtype)
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("euler_ancestral", denoise, x, sigmas, rng,
+                          callback, eta=eta)
 
 
 def sample_euler_ancestral_rf(denoise, x, sigmas, rng, eta: float = 1.0,
@@ -492,27 +679,8 @@ def sample_euler_ancestral_rf(denoise, x, sigmas, rng, eta: float = 1.0,
     ``x += σ_up·n`` would leave the (1−t)·x0 component unscaled, so the RF form
     rescales by the interpolant's alpha ratio and injects the variance that
     exactly restores the t_next marginal."""
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        if float(s_next) == 0.0:
-            x = x0
-        else:
-            downstep = 1.0 + (s_next / s - 1.0) * eta
-            sd = s_next * downstep
-            alpha_ip1 = 1.0 - s_next
-            alpha_down = 1.0 - sd
-            renoise = jnp.sqrt(jnp.maximum(
-                s_next**2 - sd**2 * alpha_ip1**2 / alpha_down**2, 0.0
-            ))
-            ratio = sd / s
-            x = ratio * x + (1.0 - ratio) * x0
-            sub = jax.random.fold_in(rng, i)
-            x = (alpha_ip1 / alpha_down) * x + renoise * jax.random.normal(
-                sub, x.shape, x.dtype
-            )
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("euler_ancestral", denoise, x, sigmas, rng,
+                          callback, prediction="flow", eta=eta)
 
 
 def sample_dpmpp_2s_ancestral_rf(denoise, x, sigmas, rng, eta: float = 1.0,
@@ -522,111 +690,34 @@ def sample_dpmpp_2s_ancestral_rf(denoise, x, sigmas, rng, eta: float = 1.0,
     flow log-SNR λ = log((1−σ)/σ), the midpoint sits at λ + h/2 (pinned to
     σ = 0.9999 when σ = 1, where λ diverges), and the renoise rescales by the
     interpolant's alpha ratio like the RF Euler-ancestral form."""
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        downstep = 1.0 + (s_next / s - 1.0) * eta
-        sd = s_next * downstep
-        alpha_ip1 = 1.0 - s_next
-        alpha_down = 1.0 - sd
-        renoise = jnp.sqrt(jnp.maximum(
-            s_next**2 - sd**2 * alpha_ip1**2 / alpha_down**2, 0.0
-        ))
-        if float(s_next) == 0.0:
-            d = (x - x0) / s
-            x = x + d * (sd - s)
-        else:
-            if float(s) >= 1.0:
-                sigma_mid = jnp.float32(0.9999)
-            else:
-                t_i = jnp.log((1.0 - s) / s)
-                t_down = jnp.log((1.0 - sd) / sd)
-                h = t_down - t_i
-                sigma_mid = 1.0 / (jnp.exp(t_i + 0.5 * h) + 1.0)
-            u = (sigma_mid / s) * x + (1.0 - sigma_mid / s) * x0
-            x0_2 = denoise(u, sigma_mid)
-            x = (sd / s) * x + (1.0 - sd / s) * x0_2
-        if float(s_next) > 0:
-            sub = jax.random.fold_in(rng, i)
-            x = (alpha_ip1 / alpha_down) * x + renoise * jax.random.normal(
-                sub, x.shape, x.dtype
-            )
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("dpmpp_2s_ancestral", denoise, x, sigmas, rng,
+                          callback, prediction="flow", eta=eta)
 
 
 def sample_lcm_rf(denoise, x, sigmas, rng, callback=None):
     """LCM on rectified-flow schedules: re-noising uses the flow interpolant
     ``x = t·n + (1−t)·x0`` (the host's CONST ``noise_scaling``) instead of the
     VE ``x0 + σ·n``."""
-    for i in range(len(sigmas) - 1):
-        x0 = denoise(x, sigmas[i])
-        x = x0
-        if float(sigmas[i + 1]) > 0:
-            sub = jax.random.fold_in(rng, i)
-            t = sigmas[i + 1]
-            x = t * jax.random.normal(sub, x.shape, x.dtype) + (1.0 - t) * x0
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("lcm", denoise, x, sigmas, rng, callback,
+                          prediction="flow")
 
 
 def sample_heun(denoise, x, sigmas, callback=None):
     """Heun's 2nd-order method (two model calls per step except the last)."""
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        d = (x - x0) / s
-        x_pred = x + d * (s_next - s)
-        if float(s_next) == 0.0:
-            x = x_pred
-        else:
-            x0_2 = denoise(x_pred, s_next)
-            d2 = (x_pred - x0_2) / s_next
-            x = x + 0.5 * (d + d2) * (s_next - s)
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("heun", denoise, x, sigmas, callback=callback)
 
 
 def sample_dpm_2(denoise, x, sigmas, callback=None):
     """DPM2 (k-diffusion ``sample_dpm_2``): explicit midpoint method — the
     second model call sits at the geometric mean of the step's sigmas."""
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        d = (x - x0) / s
-        if float(s_next) == 0.0:
-            x = x + d * (s_next - s)
-        else:
-            sigma_mid = jnp.exp(0.5 * (jnp.log(s) + jnp.log(s_next)))
-            x_2 = x + d * (sigma_mid - s)
-            x0_2 = denoise(x_2, sigma_mid)
-            d_2 = (x_2 - x0_2) / sigma_mid
-            x = x + d_2 * (s_next - s)
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("dpm_2", denoise, x, sigmas, callback=callback)
 
 
 def sample_dpm_2_ancestral(denoise, x, sigmas, rng, eta: float = 1.0, callback=None):
     """DPM2 ancestral (k-diffusion ``sample_dpm_2_ancestral``): the midpoint
     step runs to sigma_down, then sigma_up of fresh noise is injected."""
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        sigma_down, sigma_up = ancestral_steps(s, s_next, eta)
-        d = (x - x0) / s
-        if float(sigma_down) == 0.0:
-            x = x + d * (sigma_down - s)
-        else:
-            sigma_mid = jnp.exp(0.5 * (jnp.log(s) + jnp.log(sigma_down)))
-            x_2 = x + d * (sigma_mid - s)
-            x0_2 = denoise(x_2, sigma_mid)
-            d_2 = (x_2 - x0_2) / sigma_mid
-            x = x + d_2 * (sigma_down - s)
-        if float(s_next) > 0:
-            sub = jax.random.fold_in(rng, i)
-            x = x + sigma_up * jax.random.normal(sub, x.shape, x.dtype)
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("dpm_2_ancestral", denoise, x, sigmas, rng,
+                          callback, eta=eta)
 
 
 def sample_dpmpp_2s_ancestral(denoise, x, sigmas, rng, eta: float = 1.0,
@@ -634,25 +725,8 @@ def sample_dpmpp_2s_ancestral(denoise, x, sigmas, rng, eta: float = 1.0,
     """DPM-Solver++ (2S) ancestral (k-diffusion ``sample_dpmpp_2s_ancestral``):
     single-step 2nd order in exponential-integrator form (midpoint at
     r = 1/2 in log-sigma time), ancestral noise on every non-final step."""
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        sigma_down, sigma_up = ancestral_steps(s, s_next, eta)
-        if float(sigma_down) == 0.0:
-            d = (x - x0) / s
-            x = x + d * (sigma_down - s)
-        else:
-            t, t_next = -jnp.log(s), -jnp.log(sigma_down)
-            h = t_next - t
-            sigma_mid = jnp.exp(-(t + 0.5 * h))
-            x_2 = (sigma_mid / s) * x - jnp.expm1(-0.5 * h) * x0
-            x0_2 = denoise(x_2, sigma_mid)
-            x = (sigma_down / s) * x - jnp.expm1(-h) * x0_2
-        if float(s_next) > 0:
-            sub = jax.random.fold_in(rng, i)
-            x = x + sigma_up * jax.random.normal(sub, x.shape, x.dtype)
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("dpmpp_2s_ancestral", denoise, x, sigmas, rng,
+                          callback, eta=eta)
 
 
 def sample_dpmpp_sde(denoise, x, sigmas, rng, eta: float = 1.0, callback=None):
@@ -662,88 +736,21 @@ def sample_dpmpp_sde(denoise, x, sigmas, rng, eta: float = 1.0, callback=None):
     Per-step keys: ``k_mid, k_end = split(fold_in(rng, i))`` — the fold_in
     discipline (see sample_euler_ancestral), with the two draws split from the
     step key (the compiled twin and the serving lanes consume the same)."""
-    r = 0.5
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        if float(s_next) == 0.0:
-            d = (x - x0) / s
-            x = x + d * (s_next - s)
-        else:
-            sub = jax.random.fold_in(rng, i)
-            k_mid, k_end = jax.random.split(sub)
-            t, t_next = -jnp.log(s), -jnp.log(s_next)
-            h = t_next - t
-            sigma_mid = jnp.exp(-(t + r * h))
-            fac = 1.0 / (2.0 * r)
-            # Step 1: to the midpoint's sigma_down, + its sigma_up of noise.
-            sd1, su1 = ancestral_steps(s, sigma_mid, eta)
-            t_down1 = -jnp.log(jnp.maximum(sd1, 1e-10))
-            x_2 = (sd1 / s) * x - jnp.expm1(t - t_down1) * x0
-            x_2 = x_2 + su1 * jax.random.normal(k_mid, x.shape, x.dtype)
-            x0_2 = denoise(x_2, sigma_mid)
-            # Step 2: full step from the blended denoised estimate.
-            sd2, su2 = ancestral_steps(s, s_next, eta)
-            t_down2 = -jnp.log(jnp.maximum(sd2, 1e-10))
-            x0_blend = (1.0 - fac) * x0 + fac * x0_2
-            x = (sd2 / s) * x - jnp.expm1(t - t_down2) * x0_blend
-            x = x + su2 * jax.random.normal(k_end, x.shape, x.dtype)
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("dpmpp_sde", denoise, x, sigmas, rng, callback,
+                          eta=eta)
 
 
 def sample_dpmpp_2m(denoise, x, sigmas, callback=None):
     """DPM-Solver++ (2M): multistep 2nd order, one model call per step."""
-    old_x0 = None
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        t, t_next = -jnp.log(s), -jnp.log(jnp.maximum(s_next, 1e-10))
-        h = t_next - t
-        if old_x0 is None or float(s_next) == 0.0:
-            x = (s_next / s) * x - jnp.expm1(-h) * x0
-        else:
-            h_last = t - (-jnp.log(sigmas[i - 1]))
-            r = h_last / h
-            x0_prime = (1 + 1 / (2 * r)) * x0 - (1 / (2 * r)) * old_x0
-            x = (s_next / s) * x - jnp.expm1(-h) * x0_prime
-        old_x0 = x0
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("dpmpp_2m", denoise, x, sigmas, callback=callback)
 
 
 def sample_dpmpp_2m_sde(denoise, x, sigmas, rng, eta: float = 1.0, callback=None):
     """DPM-Solver++ (2M) SDE: the stochastic 2M variant (k-diffusion's
     'dpmpp_2m_sde' with the default midpoint solver) — one model call per step,
     per-step noise injection scaled by the SDE's decay."""
-    old_x0 = None
-    h_last = None
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        if float(s_next) == 0.0:
-            x = x0
-        else:
-            t, t_next = -jnp.log(s), -jnp.log(s_next)
-            h = t_next - t
-            eta_h = eta * h
-            x = (
-                (s_next / s) * jnp.exp(-eta_h) * x
-                + (-jnp.expm1(-h - eta_h)) * x0
-            )
-            if old_x0 is not None:
-                r = h_last / h
-                # midpoint correction
-                x = x + 0.5 * (-jnp.expm1(-h - eta_h)) * (1 / r) * (x0 - old_x0)
-            if eta > 0:
-                sub = jax.random.fold_in(rng, i)
-                x = x + s_next * jnp.sqrt(
-                    jnp.maximum(-jnp.expm1(-2 * eta_h), 0.0)
-                ) * jax.random.normal(sub, x.shape, x.dtype)
-            h_last = h
-        old_x0 = x0
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("dpmpp_2m_sde", denoise, x, sigmas, rng, callback,
+                          eta=eta)
 
 
 def sample_dpmpp_3m_sde(denoise, x, sigmas, rng, eta: float = 1.0, callback=None):
@@ -751,45 +758,8 @@ def sample_dpmpp_3m_sde(denoise, x, sigmas, rng, eta: float = 1.0, callback=None
     multistep in exponential-integrator form — one model call per step, the two
     previous x0 estimates building 1st/2nd difference corrections, per-step
     noise injection scaled by the SDE decay."""
-    x0_1 = x0_2 = None  # previous two denoised estimates
-    h_1 = h_2 = None    # previous two log-sigma step sizes
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        if float(s_next) == 0.0:
-            # Final (or interior-zero) step: no history update — a None h must
-            # never enter the multistep state (k-diffusion updates history only
-            # on non-zero steps).
-            x = apply_callback(callback, i, x0)
-            continue
-        else:
-            t, t_next = -jnp.log(s), -jnp.log(s_next)
-            h = t_next - t
-            h_eta = h * (eta + 1.0)
-            x = jnp.exp(-h_eta) * x + (-jnp.expm1(-h_eta)) * x0
-            if h_2 is not None:
-                r0, r1 = h_1 / h, h_2 / h
-                d1_0 = (x0 - x0_1) / r0
-                d1_1 = (x0_1 - x0_2) / r1
-                d1 = d1_0 + (d1_0 - d1_1) * r0 / (r0 + r1)
-                d2 = (d1_0 - d1_1) / (r0 + r1)
-                phi_2 = jnp.expm1(-h_eta) / h_eta + 1.0
-                phi_3 = phi_2 / h_eta - 0.5
-                x = x + phi_2 * d1 - phi_3 * d2
-            elif h_1 is not None:
-                r = h_1 / h
-                d = (x0 - x0_1) / r
-                phi_2 = jnp.expm1(-h_eta) / h_eta + 1.0
-                x = x + phi_2 * d
-            if eta > 0:
-                sub = jax.random.fold_in(rng, i)
-                x = x + s_next * jnp.sqrt(
-                    jnp.maximum(-jnp.expm1(-2.0 * eta * h), 0.0)
-                ) * jax.random.normal(sub, x.shape, x.dtype)
-        x0_1, x0_2 = x0, x0_1
-        h_1, h_2 = h, h_1
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("dpmpp_3m_sde", denoise, x, sigmas, rng, callback,
+                          eta=eta)
 
 
 def lms_coefficient_matrix(sigmas, order: int = 4):
@@ -829,11 +799,12 @@ def lms_coefficient_matrix(sigmas, order: int = 4):
 def sample_lms(denoise, x, sigmas, order: int = 4, callback=None):
     """Linear multistep (Katherine Crowson's LMS): Adams-Bashforth over the
     sigma schedule with numerically integrated coefficients."""
-    C = lms_coefficient_matrix(sigmas, order)
+    sig = host_sigmas(sigmas)
+    C = lms_coefficient_matrix(sig, order).astype(np.float32)
     ds = []
-    for i in range(len(sigmas) - 1):
-        x0 = denoise(x, sigmas[i])
-        d = (x - x0) / sigmas[i]
+    for i in range(len(sig) - 1):
+        x0 = denoise(x, sig[i])
+        d = (x - x0) / np.float32(sig[i])
         ds.append(d)
         if len(ds) > order:
             ds.pop(0)
@@ -847,14 +818,7 @@ def sample_lcm(denoise, x, sigmas, rng, callback=None):
     """Latent Consistency Model sampling (the host KSampler's ``lcm`` entry):
     each step takes the model's x0 prediction directly and re-noises it to the
     next sigma with FRESH noise — one jump per step, no ODE integration."""
-    for i in range(len(sigmas) - 1):
-        x0 = denoise(x, sigmas[i])
-        x = x0
-        if float(sigmas[i + 1]) > 0:
-            sub = jax.random.fold_in(rng, i)
-            x = x + sigmas[i + 1] * jax.random.normal(sub, x.shape, x.dtype)
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("lcm", denoise, x, sigmas, rng, callback)
 
 
 def sample_ddpm(denoise, x, sigmas, rng, callback=None):
@@ -863,26 +827,7 @@ def sample_ddpm(denoise, x, sigmas, rng, callback=None):
     estimate drives the exact DDPM posterior mean in ᾱ-space, with posterior
     variance noise on every non-final step. x rides in k-diffusion's sigma
     scaling (x = √(1+σ²)·x_ᾱ) between steps."""
-    for i in range(len(sigmas) - 1):
-        s, s_next = sigmas[i], sigmas[i + 1]
-        x0 = denoise(x, s)
-        eps = (x - x0) / s
-        acp = 1.0 / (s**2 + 1.0)          # ᾱ_t from sigma
-        acp_prev = 1.0 / (s_next**2 + 1.0)
-        alpha = acp / acp_prev
-        x_a = x / jnp.sqrt(1.0 + s**2)     # ᾱ-space sample
-        mu = jnp.sqrt(1.0 / alpha) * (
-            x_a - (1.0 - alpha) * eps / jnp.sqrt(1.0 - acp)
-        )
-        if float(s_next) > 0:
-            sub = jax.random.fold_in(rng, i)
-            var = (1.0 - alpha) * (1.0 - acp_prev) / (1.0 - acp)
-            mu = mu + jnp.sqrt(var) * jax.random.normal(sub, x.shape, x.dtype)
-            x = mu * jnp.sqrt(1.0 + s_next**2)  # back to sigma scaling
-        else:
-            x = mu
-        x = apply_callback(callback, i, x)
-    return x
+    return sample_planned("ddpm", denoise, x, sigmas, rng, callback)
 
 
 def unipc_coeff_table(sigmas, order: int = 3, variant: str = "bh1"):
@@ -949,21 +894,21 @@ def _sample_unipc(denoise, x, sigmas, callback=None, variant="bh1", order=3):
     call per step: the corrector reuses the evaluation at the predictor's
     point, which then becomes the next step's history entry — the official
     multistep flow. Final (σ→0) step returns m0 directly."""
-    C = unipc_coeff_table(sigmas, order, variant)
-    n = len(sigmas) - 1
-    hist = [denoise(x, sigmas[0])]
+    sig = host_sigmas(sigmas)
+    C = unipc_coeff_table(sig, order, variant).astype(np.float32)
+    n = len(sig) - 1
+    hist = [denoise(x, sig[0])]
     for i in range(n):
-        s, s_next = sigmas[i], sigmas[i + 1]
         m0 = hist[-1]
-        if float(s_next) == 0.0:
+        if sig[i + 1] == 0.0:
             x = apply_callback(callback, i, m0)
             continue
-        hphi1, Bh, rp0, rp1, rc0, rc1, rct, rki0, rki1 = (float(v) for v in C[i])
+        hphi1, Bh, rp0, rp1, rc0, rc1, rct, rki0, rki1 = C[i]
         D1_1 = (hist[-2] - m0) * rki0 if len(hist) >= 2 else 0.0
         D1_2 = (hist[-3] - m0) * rki1 if len(hist) >= 3 else 0.0
-        base = (s_next / s) * x - hphi1 * m0
+        base = np.float32(sig[i + 1] / sig[i]) * x - hphi1 * m0
         x_pred = base - Bh * (rp0 * D1_1 + rp1 * D1_2)
-        m_t = denoise(x_pred, s_next)
+        m_t = denoise(x_pred, sig[i + 1])
         x = base - Bh * (rc0 * D1_1 + rc1 * D1_2 + rct * (m_t - m0))
         hist.append(m_t)
         if len(hist) > order:

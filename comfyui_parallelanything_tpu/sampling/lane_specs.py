@@ -32,9 +32,13 @@ the build). Excluded by design: ``lms``/``uni_pc*`` (order-4 latent history /
 predictor-corrector eval-at-next-sigma structure — a different dispatch
 shape), and ``ddpm`` on flow schedules (``k_samplers.FLOW_REJECT``).
 
-Reference behavior: each plan compiler transcribes its eager twin in
-``k_samplers.py`` (which mirrors any_device_parallel.py:1287's host sampler
-menu) op-for-op, with the sigma-dependent scalars lifted to the host.
+Reference behavior: each plan compiler is the host sampler menu's sampler
+(any_device_parallel.py:1287; k-diffusion) with the sigma-dependent scalars
+lifted to the host. The eager loop walks the same plans
+(``k_samplers.sample_planned``: two compiled programs around the denoiser, no
+device read in the loop), so a sampler is defined here once for solo and lane
+use; ``tests/k_sampler_numpy.py`` writes each out in float64 numpy, and
+``tests/test_k_samplers.py`` holds the loop to it.
 """
 
 from __future__ import annotations
@@ -110,8 +114,8 @@ def _ancestral(s: float, s_next: float, eta: float = 1.0):
 
 # ---------------------------------------------------------------------------
 # plan compilers — one per sampler; (sigmas float64, prediction) -> [StepPlan].
-# Each transcribes its eager twin's branch structure; eta is the eager default
-# (1.0) because run_sampler never overrides it.
+# eta defaults to 1.0, which is all run_sampler asks for; the ``sample_*``
+# entry points of k_samplers.py pass their own.
 # ---------------------------------------------------------------------------
 
 

@@ -10,9 +10,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..utils import tracing
 from ..utils.degrade import DegradedToInline
+from ..utils.metrics import registry
 
 from .ddim import ddim_sample
 from .flow import flow_euler_sample, flow_timesteps
@@ -23,8 +25,11 @@ from .k_samplers import (
     SAMPLERS as K_SAMPLERS,
     EpsDenoiser,
     flow_sigma_table,
+    host_sigmas,
     make_sigmas,
+    takes_fused_step,
 )
+from .lane_specs import LANE_SPECS
 
 SAMPLER_NAMES = ("ddim", *K_SAMPLERS, "flow_euler")
 
@@ -114,6 +119,12 @@ def _traced_sampler_run(fn):
             return fn(model, noise, context, **kwargs)
 
     return wrapped
+
+
+@jax.jit
+def sampler_mask_blend(x, mask, keep):
+    """Inpainting's re-pin of the keep region after a step, as one program."""
+    return x * mask + keep * (1.0 - mask)
 
 
 @_traced_sampler_run
@@ -255,7 +266,7 @@ def run_sampler(
         user = callback
 
         def cb(i, x):
-            x = x * m + keep_at(i) * (1.0 - m)
+            x = sampler_mask_blend(x, m, keep_at(i))
             if user is not None:
                 out = user(i, x)
                 x = x if out is None else out
@@ -278,7 +289,12 @@ def run_sampler(
         spans, which do block, carry the device-inclusive durations). One
         span is recorded per callback, as before; a step that never reaches
         its boundary (an interrupt, a raising model, a sampler that skips an
-        iteration) is dropped when ``sampler-run`` closes over it."""
+        iteration) is dropped when ``sampler-run`` closes over it.
+
+        Run-ahead: before step *i*'s event the loop waits for the LATENT of
+        step *i − 1*. Step *i*'s programs are queued by then, so the device
+        never idles under the wait, the event fires while the device runs
+        step *i*, and an interrupt costs at most one step of device work."""
         from ..utils.progress import report_progress
 
         def open_step(k):
@@ -287,8 +303,12 @@ def run_sampler(
             return sp
 
         live = [open_step(1)] if tracing.on() else None
+        behind = [None]
 
         def cb2(i, x):
+            if behind[0] is not None:
+                jax.block_until_ready(behind[0])
+            behind[0] = x
             if live is not None:
                 live[0].__exit__(None, None, None)
             # Raises Interrupted if requested; x feeds the WS latent-preview
@@ -470,13 +490,16 @@ def run_sampler(
     # zero EmptyLatent base degenerates to pure noise); otherwise only
     # img2img mixes the init.
     mix_init = img2img or (explicit_sigmas and init_latent is not None)
+    # The run's one read of the schedule; the loops below take every scalar
+    # from this copy (k_samplers.host_sigmas).
+    sig = host_sigmas(sigmas).astype(np.float32)
     if is_flow:
         # Flow forward process: x_t = t·noise + (1−t)·x0.
-        x = sigmas[0] * noise
+        x = sig[0] * noise
         if mix_init:
-            x = x + (1.0 - sigmas[0]) * init_latent
+            x = x + (1.0 - sig[0]) * init_latent
     else:
-        x = noise * sigmas[0]
+        x = noise * sig[0]
         if mix_init:
             x = init_latent + x
     if sampler in RNG_SAMPLERS and rng is None:
@@ -498,8 +521,6 @@ def run_sampler(
 
         _sched = get_scheduler()
         if _sched is not None:
-            from ..utils.metrics import registry as _registry
-
             ticket = _sched.maybe_submit(
                 model=model,  # still the LoRA base — the merge is deferred
                 x=x, sigmas=sigmas, context=context,
@@ -531,7 +552,7 @@ def run_sampler(
 
                     record_rung("inline-fallback",
                                 f"{sampler}: {e}", sampler=sampler)
-                    _registry.counter(
+                    registry.counter(
                         "pa_serving_inline_fallback_total",
                         labels={"reason": "degraded", "sampler": sampler},
                         help="sampler runs that fell back to the inline "
@@ -542,7 +563,7 @@ def run_sampler(
                 # (capability/shape/queue ineligibility): it runs inline.
                 # Round 16's loadgen mixed-workload summary watches this
                 # counter — eligible mixed traffic must NOT tick it.
-                _registry.counter(
+                registry.counter(
                     "pa_serving_inline_fallback_total",
                     labels={"reason": "ineligible", "sampler": sampler},
                     help="sampler runs that fell back to the inline eager "
@@ -596,11 +617,22 @@ def run_sampler(
         # Host CONST-dispatch parity: samplers with an RF renoise form swap in.
         step_fn = FLOW_VARIANTS.get(sampler, step_fn)
         cb = masked_callback(
-            lambda i: (1.0 - sigmas[i + 1]) * init_latent + sigmas[i + 1] * noise
+            lambda i: (1.0 - sig[i + 1]) * init_latent + sig[i + 1] * noise
         )
     else:
-        cb = masked_callback(lambda i: init_latent + noise * sigmas[i + 1])
-    cb = with_progress(cb, len(sigmas) - 1)
+        cb = masked_callback(lambda i: init_latent + noise * sig[i + 1])
+    cb = with_progress(cb, len(sig) - 1)
+    registry.counter(
+        "pa_sampler_loop_total",
+        labels={
+            "path": ("planned" if sampler in LANE_SPECS
+                     and takes_fused_step(denoiser) else "eager"),
+            "sampler": sampler,
+        },
+        help="inline k-sampler runs by step form: planned = two compiled "
+             "programs around the denoiser, eager = the denoiser called "
+             "whole with host scalars",
+    )
     if sampler in RNG_SAMPLERS:
-        return step_fn(denoiser, x, sigmas, jax.random.fold_in(rng, 1), callback=cb)
-    return step_fn(denoiser, x, sigmas, callback=cb)
+        return step_fn(denoiser, x, sig, jax.random.fold_in(rng, 1), callback=cb)
+    return step_fn(denoiser, x, sig, callback=cb)

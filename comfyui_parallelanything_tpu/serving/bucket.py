@@ -51,8 +51,9 @@ the SAME StepPlans against their own denoiser, gaining step-boundary
 scheduling, the full sampler family, cancel, and metrics, just not
 co-batching.
 
-Bitwise discipline: the update math here IS each sampler's ``k_samplers``
-twin with the schedule-derived scalars host-lifted per lane;
+Bitwise discipline: the update math here is the same plan walk as the solo
+loop's (``k_samplers.sample_planned``), the schedule-derived scalars
+host-lifted per lane;
 ``tests/test_serving.py`` pins the full registry's lane-vs-solo equivalence
 at bf16 tolerances on CPU and the 8-device mesh.
 """

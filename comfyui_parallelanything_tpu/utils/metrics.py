@@ -116,6 +116,14 @@ running without the span tracer; the ``denoise`` span is its traced twin).
 ``pa_trace_dropped_total`` gains ``reason="abandoned"``: spans the traced
 code left open, closed by their parent and not recorded.
 
+Sampler loop form (PR 27): ``pa_sampler_loop_total{path=,sampler=}``
+(sampling/runner.py — once an inline k-sampler run, beside
+``pa_serving_inline_fallback_total``; always on): ``path="planned"`` is the
+step as two compiled programs around the denoiser's own (the sampler has a
+plan in ``sampling/lane_specs.py`` and the denoiser is one model call an
+eval), ``path="eager"`` the denoiser called whole with host scalars
+(multi-cond conditioning; ``lms`` / ``uni_pc*``, which have no plan).
+
 Attention routing (PR 25): ``pa_attention_route_total{backend=}``
 (ops/attention.py — one count per ``attention_local`` resolution, made
 while a program is traced and not per forward: ``pallas`` moving while a

@@ -43,6 +43,13 @@ HOT_PATHS = (
     ("comfyui_parallelanything_tpu/parallel/streaming.py",
      "StreamingRunner.__call__"),
     ("bench.py", "step"),
+    # The eager k-sampler loops: schedule scalars live on the host, and a
+    # read between steps stalls the host behind the previous step's latent.
+    ("comfyui_parallelanything_tpu/sampling/k_samplers.py", "sample_planned"),
+    ("comfyui_parallelanything_tpu/sampling/k_samplers.py", "sample_lms"),
+    ("comfyui_parallelanything_tpu/sampling/k_samplers.py", "_sample_unipc"),
+    ("comfyui_parallelanything_tpu/sampling/k_samplers.py",
+     "EpsDenoiser.fused_eval"),
 )
 
 _SYNC_ATTRS = {"block_until_ready", "device_get", "item"}

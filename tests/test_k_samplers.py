@@ -829,3 +829,345 @@ class TestAreaPercentage:
                         cond_area_pct=(0.5, 1.0, 0.0, 0.0))
         out = d(x, jnp.float32(1.0))
         assert np.isfinite(np.asarray(out)).all()
+
+
+# ---------------------------------------------------------------------------
+# The eager loop: schedule scalars on the host, two programs around the
+# denoiser, no read inside the loop.
+# ---------------------------------------------------------------------------
+
+
+def _toy_apply(params, x, t, context=None, **kw):
+    """A smooth stand-in denoiser whose output depends on the input, the
+    timestep and the conditioning (so CFG's two halves differ)."""
+    shape = (-1,) + (1,) * (x.ndim - 1)
+    out = params["w"] * x + 0.1 * jnp.sin(t * params["f"]).reshape(shape)
+    if context is not None:
+        out = out + 0.05 * jnp.mean(context, axis=(1, 2)).reshape(shape)
+    return out
+
+
+def _toy_numpy(x, t, context, w, f):
+    shape = (-1,) + (1,) * (x.ndim - 1)
+    out = w * x + 0.1 * np.sin(np.asarray(t, np.float64) * f).reshape(shape)
+    if context is not None:
+        out = out + 0.05 * np.mean(context, axis=(1, 2)).reshape(shape)
+    return out
+
+
+def _toy_problem(prediction, cfg, ends_at_zero):
+    """(model fn, run_sampler kwargs, float64 ``denoise(x, sigma)``, sigmas)."""
+    from comfyui_parallelanything_tpu.sampling.k_samplers import flow_sigma_table
+
+    r = np.random.default_rng(5)
+    w, f = 0.3, (6.0 if prediction == "flow" else 0.01)
+    params = {"w": jnp.float32(w), "f": jnp.float32(f)}
+    ctx = r.normal(size=(2, 5, 8)).astype(np.float32)
+    unc = r.normal(size=(2, 5, 8)).astype(np.float32)
+    acp = np.asarray(scaled_linear_schedule(), np.float64)
+    if prediction == "flow":
+        sigmas = make_sigmas("normal", 7, sigma_table=flow_sigma_table(1.3))
+    else:
+        sigmas = make_sigmas("karras", 7, scaled_linear_schedule())
+    sigmas = np.asarray(sigmas)
+    if not ends_at_zero:
+        sigmas = sigmas[:6]
+    table = 0.5 * np.log((1.0 - acp) / acp)
+
+    def denoise(x, sigma):
+        if prediction == "flow":
+            x_in, t = x, sigma
+        else:
+            x_in = x / np.sqrt(sigma**2 + 1.0)
+            t = np.interp(np.log(sigma), table, np.arange(len(table)))
+        t = np.full((x.shape[0],), t)
+        pred = _toy_numpy(x_in, t, ctx.astype(np.float64), w, f)
+        if cfg != 1.0:
+            pred_u = _toy_numpy(x_in, t, unc.astype(np.float64), w, f)
+            pred = pred_u + cfg * (pred - pred_u)
+        return x - sigma * pred
+
+    kwargs = dict(prediction=prediction, cfg_scale=cfg, sigmas=sigmas)
+    if cfg != 1.0:
+        kwargs["uncond_context"] = jnp.asarray(unc)
+    model = lambda x, t, c=None, **kw: _toy_apply(params, x, t, c, **kw)  # noqa: E731
+    return model, jnp.asarray(ctx), kwargs, denoise, sigmas.astype(np.float64)
+
+
+def _reference_noise(rng, split, shape):
+    """Step i's draws as the program makes them: fold_in(rng, i), its split
+    halves for dpmpp_sde."""
+    def noise(i, col):
+        key = jax.random.fold_in(rng, i)
+        if split:
+            key = jax.random.split(key)[col]
+        return np.asarray(jax.random.normal(key, shape, jnp.float32), np.float64)
+
+    return noise
+
+
+_LOOP_CASES = [(name, "eps") for name in SAMPLERS] + [
+    (name, "flow") for name in SAMPLERS if name != "ddpm"  # FLOW_REJECT
+]
+
+
+class TestHostScheduleLoop:
+    @pytest.mark.parametrize("name,prediction", _LOOP_CASES)
+    def test_loop_matches_float64_transcription(self, name, prediction):
+        """run_sampler's eager loop against k-diffusion written out in float64
+        numpy: with and without CFG, on a schedule that ends at 0 and one that
+        does not."""
+        from k_sampler_numpy import REFERENCE, REFERENCE_FLOW
+
+        from comfyui_parallelanything_tpu.sampling import RNG_SAMPLERS
+        from comfyui_parallelanything_tpu.sampling.runner import run_sampler
+
+        ref = REFERENCE[name]
+        if prediction == "flow":
+            ref = REFERENCE_FLOW.get(name, ref)
+        rng = jax.random.key(11)
+        noise = jax.random.normal(jax.random.key(3), (2, 4, 4, 3), jnp.float32)
+        for cfg in (1.0, 3.0):
+            for ends_at_zero in (True, False):
+                model, ctx, kwargs, denoise, sig = _toy_problem(
+                    prediction, cfg, ends_at_zero)
+                out = run_sampler(model, noise, ctx, sampler=name,
+                                  steps=len(sig) - 1, rng=rng, **kwargs)
+                x = np.asarray(noise, np.float64) * sig[0]
+                # run_sampler hands the stochastic samplers fold_in(rng, 1).
+                draws = _reference_noise(jax.random.fold_in(rng, 1),
+                                         name == "dpmpp_sde", noise.shape)
+                want = ref(denoise, x, sig, draws if name in RNG_SAMPLERS else None)
+                err = np.abs(np.asarray(out, np.float64) - want).max()
+                # VE ancestral noise on a flow ladder's last step (0.2 → 0.0013)
+                # leaves sigma_down = 8e-6 and a midpoint derivative weighted
+                # 160-fold: float32 cancellation, in any form of the step.
+                tol = 4e-5 if (name, prediction) == ("dpm_2_ancestral", "flow") else 1e-5
+                assert err <= tol * np.abs(want).max(), (cfg, ends_at_zero, err)
+
+    @pytest.mark.parametrize("name", list(SAMPLERS))
+    def test_no_device_to_host_read_inside_the_loop(self, name, monkeypatch):
+        """Between the first and the last step nothing reads a device value.
+        The CPU backend does not honour transfer_guard_device_to_host, so the
+        array's own conversions are armed instead."""
+        import jax._src.array as jarray
+
+        from comfyui_parallelanything_tpu.sampling.runner import run_sampler
+
+        armed = [False]
+
+        def guard(real):
+            def method(self, *a, **kw):
+                assert not armed[0], f"device value read inside the loop: {real.__name__}"
+                return real(self, *a, **kw)
+            return method
+
+        for attr in ("__float__", "__bool__", "__int__", "__index__",
+                     "__array__", "item", "tolist"):
+            monkeypatch.setattr(jarray.ArrayImpl, attr,
+                                guard(getattr(jarray.ArrayImpl, attr)))
+        noise = jax.random.normal(jax.random.key(3), (2, 4, 4, 3), jnp.float32)
+        for prediction in ("eps", "flow"):
+            if (name, prediction) not in _LOOP_CASES:
+                continue
+            model, ctx, kwargs, _, sig = _toy_problem(prediction, 3.0, True)
+            n = len(sig) - 1
+
+            def cb(i, x):
+                armed[0] = i < n - 1
+
+            with jax.transfer_guard_device_to_host("disallow"):
+                out = run_sampler(model, noise, ctx, sampler=name, steps=n,
+                                  rng=jax.random.key(2), callback=cb, **kwargs)
+            assert not armed[0]
+            assert np.isfinite(np.asarray(out)).all()
+
+
+def _host_line_events(trace_dir):
+    """Names on the python thread's line of a CPU profile, in time order."""
+    import glob
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(path)
+    (plane,) = [p for p in data.planes if p.name == "/host:CPU"]
+    events = [e for line in plane.lines for e in line.events
+              if e.name.startswith(("PjitFunction(", "boundary:"))
+              or e.name == "PjRtCpuExecutable::Execute"]
+    return [e.name for e in sorted(events, key=lambda e: e.start_ns)]
+
+
+class TestProgramsAStep:
+    @pytest.mark.parametrize("sampler,prediction", [("dpmpp_2m", "eps"),
+                                                    ("euler", "flow")])
+    def test_a_step_is_two_named_programs_around_the_model(
+            self, sampler, prediction, tmp_path):
+        """The benchmark cells' two paths (CFG on): a step executes at most 4
+        XLA programs besides the model's own, every step after the second runs
+        executables that exist, and no new module's name matches the patterns
+        by which the benchmark finds the denoiser and the decode."""
+        import json
+        import re
+        from pathlib import Path
+
+        from comfyui_parallelanything_tpu.models.api import DiffusionModel
+        from comfyui_parallelanything_tpu.sampling.runner import run_sampler
+        from comfyui_parallelanything_tpu.utils.telemetry import (
+            compile_snapshot,
+            watch_compiles,
+        )
+
+        cfg = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                          / "configs" / "sd15.json").read_text())
+        patterns = [p for group in ("denoiser", "decode")
+                    for p in cfg["trace_modules"][group]]
+        assert patterns
+
+        def apply(params, x, t, context=None, **kw):  # the program's own name
+            return _toy_apply(params, x, t, context, **kw)
+
+        model = DiffusionModel(
+            apply=apply, name="toy",
+            params={"w": jnp.float32(0.3), "f": jnp.float32(0.01)})
+        r = np.random.default_rng(0)
+        noise = jnp.asarray(r.normal(size=(2, 8, 8, 4)).astype(np.float32))
+        ctx = jnp.asarray(r.normal(size=(2, 6, 16)).astype(np.float32))
+        watch_compiles()
+        compiles = []
+
+        def cb(i, x):
+            compiles.append(compile_snapshot()["compiles"])
+            with jax.profiler.TraceAnnotation(f"boundary:{i}"):
+                pass
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            run_sampler(model, noise, ctx, sampler=sampler, steps=6,
+                        cfg_scale=7.0, uncond_context=jnp.zeros_like(ctx),
+                        prediction=prediction, callback=cb).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        assert len(compiles) == 6 and compiles[1] == compiles[-1], compiles
+        # Programs by step: the PjitFunction that each Execute ran, between
+        # one boundary and the next.
+        steps, current, last = [], [], None
+        for name in _host_line_events(tmp_path):
+            if name.startswith("boundary:"):
+                steps.append(current)
+                current = []
+            elif name.startswith("PjitFunction("):
+                last = name[len("PjitFunction("):-1]
+            else:
+                current.append(last)
+        assert len(steps) == 6
+        for programs in steps[1:]:
+            assert programs.count("apply") == 1, programs
+            own = [p for p in programs if p != "apply"]
+            assert 2 <= len(own) <= 4, programs
+            assert {"sampler_prepare", "sampler_finish"} <= set(own), programs
+            for p in own:
+                for pattern in patterns:
+                    assert not re.search(pattern, f"jit_{p}("), (p, pattern)
+        assert any(re.search(p, "jit_apply(") for p in patterns)
+
+
+class TestRunAhead:
+    def test_host_stays_one_step_ahead(self):
+        """With a forward slow enough to see: when step i's callback fires the
+        latent of step i − 1 is ready (the loop waited on it, behind the
+        queued forward of step i), and step i's own is still being computed —
+        one step ahead, no more. The flow path read nothing back and ran every
+        step ahead of the device before. (The CPU backend dispatches a program
+        that calls back to the host synchronously, so the forward cannot be
+        made to block on an event here; readiness is asked of the arrays.)"""
+        from comfyui_parallelanything_tpu.models.api import DiffusionModel
+        from comfyui_parallelanything_tpu.sampling.runner import run_sampler
+
+        def apply(params, x, t, context=None, **kw):
+            w = params["w"]
+            for _ in range(6):  # some tens of milliseconds on the CPU
+                w = jnp.tanh(w @ params["w"])
+            return 0.3 * x + 1e-9 * jnp.mean(w)
+
+        model = DiffusionModel(
+            apply=apply, name="slow",
+            params={"w": jnp.full((1024, 1024), 1e-3, jnp.float32)})
+        noise = jax.random.normal(jax.random.key(0), (2, 8, 8, 4))
+        steps = 6
+        for measured in (False, True):  # the first round compiles
+            latents, behind_ready, own_ready = [], [], []
+
+            def cb(i, x):
+                if latents:
+                    behind_ready.append(latents[-1].is_ready())
+                own_ready.append(x.is_ready())
+                latents.append(x)
+
+            run_sampler(model, noise, None, sampler="euler", steps=steps,
+                        prediction="flow", callback=cb).block_until_ready()
+            if measured:
+                assert behind_ready == [True] * (steps - 1)
+                assert not all(own_ready), own_ready
+
+    def test_spans_counters_and_interrupt(self):
+        from comfyui_parallelanything_tpu.models.api import DiffusionModel
+        from comfyui_parallelanything_tpu.sampling.runner import run_sampler
+        from comfyui_parallelanything_tpu.utils import progress, tracing
+        from comfyui_parallelanything_tpu.utils.metrics import registry
+
+        model = DiffusionModel(
+            apply=_toy_apply, name="toy-spans",
+            params={"w": jnp.float32(0.3), "f": jnp.float32(0.01)})
+        r = np.random.default_rng(0)
+        noise = jnp.asarray(r.normal(size=(2, 8, 8, 4)).astype(np.float32))
+        ctx = jnp.asarray(r.normal(size=(2, 6, 16)).astype(np.float32))
+        cfg = dict(cfg_scale=7.0, uncond_context=jnp.zeros_like(ctx))
+
+        def calls():
+            return registry.get("pa_denoiser_calls_total",
+                                {"program": "model-apply:toy-spans"}) or 0.0
+
+        def loops(path, sampler="dpmpp_2m"):
+            return registry.get("pa_sampler_loop_total",
+                                {"path": path, "sampler": sampler}) or 0.0
+
+        tracing.disable()
+        tracing.tracer.clear()
+        tracing.enable()
+        try:
+            c0, p0, e0 = calls(), loops("planned"), loops("eager")
+            run_sampler(model, noise, ctx, sampler="dpmpp_2m", steps=20, **cfg)
+            names = [e["name"] for e in tracing.export()["traceEvents"]
+                     if e.get("ph") == "X"]
+            assert names.count("step") == 20 and names.count("denoise") == 20
+            assert calls() - c0 == 20
+            assert (loops("planned") - p0, loops("eager") - e0) == (1, 0)
+        finally:
+            tracing.disable()
+            tracing.tracer.clear()
+        # Multi-cond calls the model again from Python: the denoiser is called
+        # whole, and the run counts as eager. So does a sampler with no plan.
+        run_sampler(model, noise, ctx, sampler="dpmpp_2m", steps=3,
+                    extra_conds=[{"context": ctx * 0.5}], **cfg)
+        assert (loops("planned") - p0, loops("eager") - e0) == (1, 1)
+        u0 = loops("eager", "uni_pc")
+        run_sampler(model, noise, ctx, sampler="uni_pc", steps=3, **cfg)
+        assert loops("eager", "uni_pc") - u0 == 1
+        # An interrupt raised at step 3 stops the run there: at most one more
+        # forward is dispatched than steps were reported.
+        c1 = calls()
+
+        def hook(value, max_value):
+            if value == 3:
+                progress.request_interrupt()
+
+        prev = progress.set_progress_hook(hook)
+        try:
+            with pytest.raises(progress.Interrupted):
+                run_sampler(model, noise, ctx, sampler="dpmpp_2m", steps=20, **cfg)
+        finally:
+            progress.set_progress_hook(prev)
+            progress.clear_interrupt()
+        assert 3 <= calls() - c1 <= 4
