@@ -886,8 +886,8 @@ def _run_inner() -> None:
         # "auto" setting. Falls back to the configured setting if the model
         # has no attention at all.
         "attention_backend": "+".join(resolved_backends()) or get_attention_backend(),
-        # Which chunked-attention configuration served the run (the sd15_16
-        # MFU-budget sweep dimension): threshold elems + softmax dtype.
+        # The XLA family's chunk threshold this run was served under, and
+        # whether the degradation ladder had shrunk it.
         "attn_chunk": chunk_config(),
         # Roofline attribution (utils/roofline.py): the calibrated analytic
         # step prediction, its ratio against the measured step (sane band

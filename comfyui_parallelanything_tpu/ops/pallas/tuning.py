@@ -1,50 +1,25 @@
-"""Flash-kernel routing and block sizes.
+"""Which attention backend a call takes, and with which blocks: one rule.
 
-Two sources, in this order:
-
-- **The shape rule** for head dims that are not a multiple of 128 (the UNets'
-  40 / 64 / 80-wide heads): thresholds on the key length and on B·H·S_q·S_k,
-  in code, with the v5e measurements they were set from beside them
-  (:func:`padded_dim_route`). No file, no environment variable.
-- **A measured table** for everything else: ``scripts/bench_kernels.py``
-  sweeps ``block_q``/``block_k`` at the shapes that matter (FLUX 4.6k joint
-  attention, WAN 16k/32k video) and — with ``--apply`` — writes the winners to
-  the JSON file ``$PA_TUNING_PATH`` names. The ``auto`` attention backend
-  (ops/attention.py) then picks the measured-best blocks for the nearest
-  benchmarked sequence length and falls back to XLA for sequence ranges where
-  the measurement says the fused kernel LOSES (the reference's
-  capability-gated backend disable, inverted: data-gated instead of
-  SM-version-gated, any_device_parallel.py:126-164). A same-dim entry of such
-  a table overrides the shape rule for its dim class.
-
-Before either, a sequence length that is not a multiple of 128 (SD3's joint
-text + image tokens) is decided by :func:`ragged_route`, a rule of the same
-kind on the call's lengths and B·H, whatever the head dim.
-
-There is no default file: without ``$PA_TUNING_PATH`` everything runs on the
-rules and the defaults below, so a fresh clone and a checkout an earlier run
-wrote into behave the same.
+:func:`route` reads the call's shape (both sequence lengths, head dim, B·H),
+whether the backend is a TPU, the process's pin and the XLA family's logits
+threshold, and names the backend and the fused kernel's blocks.
+``ops/attention.attention_local`` executes what it says and the planner
+(parallel/planner.py) records the same answer; nothing else decides. The
+reference gates its fused backends on the GPU generation
+(disable_flash_xformers, any_device_parallel.py:126-164); here the gate is the
+shape, with the v5e measurements each threshold and block size was set from
+beside the constant (``scripts/bench_kernels.py``; PERF.md §6, PRs 25 and 26).
+A new threshold or block size is one more row of :func:`route`.
 """
 
 from __future__ import annotations
 
-import functools
-import json
-import os
+from typing import NamedTuple
 
-_PATH = os.environ.get("PA_TUNING_PATH")
-
-_DEFAULT = {
-    "source": "default",       # "measured" once bench_kernels --apply ran
-    "device_kind": None,
-    "block_q": 256,
-    "block_k": 256,
-    # [{"seq": int, "head_dim": int|None, "block_q": int, "block_k": int,
-    #   "pallas_ms": float, "xla_ms": float|None}, ...]
-    # head_dim tags a measurement to its dim class (non-128-aligned dims run
-    # the kernel zero-padded and must win their own measurements).
-    "entries": [],
-}
+# What the kernel takes where no row below names blocks: lane-aligned head
+# dims (the VAE's one 512-wide head, FLUX / WAN's 128-wide), and a pinned
+# ``pallas`` at a shape the rule leaves to XLA.
+DEFAULT_BLOCKS = (256, 256)
 
 
 # -- Head dims that are not a multiple of 128 ----------------------------------
@@ -79,14 +54,13 @@ PADDED_DIM_BLOCKS = (256, 4096)
 
 
 def padded_dim_route(seq_q: int, seq_k: int,
-                     batch_heads: int | None = None) -> tuple[int, int] | None:
+                     batch_heads: int) -> tuple[int, int] | None:
     """``(block_q, block_k)`` if the fused kernel serves a head dim that is
-    not a multiple of 128 at these lengths and this B·H (``None``: not
-    known, not tested), else ``None``: the call stays with the XLA family."""
+    not a multiple of 128 at these lengths and this B·H, else ``None``: the
+    call stays with the XLA family."""
     if seq_k < PADDED_DIM_MIN_KEYS:
         return None
-    if (batch_heads is not None
-            and batch_heads * seq_q * seq_k < PADDED_DIM_MIN_LOGITS):
+    if batch_heads * seq_q * seq_k < PADDED_DIM_MIN_LOGITS:
         return None
     return PADDED_DIM_BLOCKS
 
@@ -125,171 +99,57 @@ def is_ragged(seq_q: int, seq_k: int) -> bool:
 
 
 def ragged_route(seq_q: int, seq_k: int,
-                 batch_heads: int | None = None) -> tuple[int, int] | None:
+                 batch_heads: int) -> tuple[int, int] | None:
     """``(block_q, block_k)`` if the fused kernel serves these lengths, of
     which one is not a multiple of 128, padded and masked; else ``None``: the
     call stays with the XLA family. Read from the call's shape alone, whatever
     the head dim."""
     if seq_k < PADDED_DIM_MIN_KEYS:
         return None
-    if (batch_heads is not None
-            and batch_heads * seq_q * seq_k < RAGGED_MIN_LOGITS):
+    if batch_heads * seq_q * seq_k < RAGGED_MIN_LOGITS:
         return None
     rows_q, row_k = (-(-n // 128) * 128 for n in (seq_q, seq_k))
     block_k = row_k if row_k <= RAGGED_ONE_BLOCK else PADDED_DIM_BLOCKS[1]
     return (384 if rows_q % 384 == 0 else 256), block_k
 
 
-@functools.lru_cache(maxsize=1)
-def kernel_tuning() -> dict:
-    """The active tuning table (defaults merged under ``$PA_TUNING_PATH``).
-
-    A measured table is generation-specific: block winners and win/lose ranges
-    from a v5e do not transfer to a v6e. When the file records a
-    ``device_kind`` that doesn't match the current first device, the defaults
-    apply rather than foreign measurements. A table that was asked for and
-    cannot be read is an error, not the defaults."""
-    if not _PATH:
-        return dict(_DEFAULT)
-    with open(_PATH) as f:
-        data = json.load(f)
-    if not isinstance(data, dict):
-        raise ValueError(f"{_PATH} must hold a JSON object")
-    measured_kind = data.get("device_kind")
-    if measured_kind:
-        import jax
-
-        if jax.devices()[0].device_kind != measured_kind:
-            return dict(_DEFAULT)
-    return {**_DEFAULT, **data}
+class Route(NamedTuple):
+    backend: str            # "xla" | "xla_chunked" | "pallas"
+    block_q: int | None     # the fused kernel's blocks; None in the XLA family
+    block_k: int | None
+    rule: str               # the row of route() that decided
 
 
-def _nearest(entries: list, seq: int):
-    return min(entries, key=lambda e: abs(int(e.get("seq", 0)) - seq))
+def route(seq_q: int, seq_k: int, head_dim: int, batch_heads: int, *,
+          on_tpu: bool, pinned: str = "auto", chunk_threshold: int) -> Route:
+    """The backend and blocks of one attention call. A pin other than
+    ``auto`` is served as pinned; off a TPU the XLA family; on one the fused
+    kernel where the call's row of the shape rule has blocks for it. Inside
+    the XLA family the logits are written out whole up to ``chunk_threshold``
+    elements (B·H·S_q·S_k) and in query chunks above."""
+    # The call's row of the shape rule, and the blocks the fused kernel takes
+    # there (None: the row leaves the call to the XLA family).
+    if is_ragged(seq_q, seq_k):
+        row, blocks = "ragged", ragged_route(seq_q, seq_k, batch_heads)
+    elif head_dim % 128 != 0:
+        row, blocks = "padded-dim", padded_dim_route(seq_q, seq_k, batch_heads)
+    else:
+        row, blocks = "lane-aligned", DEFAULT_BLOCKS
 
+    def xla_family(rule: str) -> Route:
+        chunked = batch_heads * seq_q * seq_k > chunk_threshold
+        return Route("xla_chunked" if chunked else "xla", None, None, rule)
 
-def best_blocks(seq: int, head_dim: int | None = None,
-                seq_k: int | None = None,
-                batch_heads: int | None = None) -> tuple[int, int]:
-    """(block_q, block_k) for a sequence length: the measured winner at the
-    nearest benchmarked length (preferring measurements of the same head-dim
-    class), else the shape rule's blocks for a head dim that is not a multiple
-    of 128, else the defaults."""
-    t = kernel_tuning()
-    seq_k = seq if seq_k is None else seq_k
-    entries = [e for e in t["entries"] if e.get("block_q") and e.get("block_k")]
-    if not entries and is_ragged(seq, seq_k):
-        # No measured table: a ragged length the rule routes takes the rule's
-        # blocks (the padded row as one key block), whatever the head dim.
-        ragged = ragged_route(seq, seq_k, batch_heads)
-        if ragged is not None:
-            return ragged
-    if head_dim is not None:
-        same_dim = [e for e in entries if e.get("head_dim") == head_dim]
-        if same_dim:
-            entries = same_dim
-        elif head_dim % 128 != 0:
-            # Never blocks tuned for another dim class: the rule's, or — a
-            # forced (non-auto) pallas backend at a shape the rule leaves to
-            # XLA — the defaults.
-            return (padded_dim_route(seq, seq_k, batch_heads)
-                    or (int(t["block_q"]), int(t["block_k"])))
-        else:
-            # Aligned dims must not inherit blocks tuned under the padded-FLOP
-            # regime of a different dim class (mirrors pallas_wins).
-            entries = [
-                e for e in entries
-                if e.get("head_dim") is None or e["head_dim"] % 128 == 0
-            ]
-    if not entries:
-        return int(t["block_q"]), int(t["block_k"])
-    e = _nearest(entries, seq)
-    return int(e["block_q"]), int(e["block_k"])
-
-
-def _fused_ms(e: dict):
-    """Best measured fused-kernel time for an entry: min over the in-repo
-    kernel (``pallas_ms``) and jax's upstream one (``pallas_jax_ms``)."""
-    times = [e.get("pallas_ms"), e.get("pallas_jax_ms")]
-    times = [t for t in times if t is not None]
-    return min(times) if times else None
-
-
-def fused_backend(seq: int, head_dim: int | None = None) -> str:
-    """Which fused implementation serves this shape class: "pallas_jax" when
-    jax's upstream kernel measured faster at the nearest benchmarked length
-    (and the dim is lane-aligned — upstream has no padding logic), else the
-    in-repo "pallas"."""
-    if head_dim is not None and head_dim % 128 != 0:
-        return "pallas"
-    t = kernel_tuning()
-    entries = [e for e in t["entries"] if _fused_ms(e) is not None]
-    if head_dim is not None:
-        same_dim = [e for e in entries if e.get("head_dim") == head_dim]
-        entries = same_dim or [
-            e for e in entries
-            if e.get("head_dim") is None or e.get("head_dim", 0) % 128 == 0
-        ]
-    if not entries:
-        return "pallas"
-    e = _nearest(entries, seq)
-    pj, pm = e.get("pallas_jax_ms"), e.get("pallas_ms")
-    if pj is not None and (pm is None or pj < pm):
-        return "pallas_jax"
-    return "pallas"
-
-
-def pallas_wins(seq: int, head_dim: int | None = None,
-                seq_k: int | None = None,
-                batch_heads: int | None = None) -> bool:
-    """Whether the fused kernel serves this shape. Lane-aligned head dims:
-    whether it beat XLA at the nearest measured length, and with no
-    measurement True — the default guess (XLA's S×S logits materialization
-    loses at the long lengths this path serves). Head dims that are not a
-    multiple of 128 (40/64/80 UNet heads): the shape rule
-    (:func:`padded_dim_route`, on the key length ``seq_k`` — ``seq`` if not
-    given — and B·H), unless the table holds entries measured at that very
-    ``head_dim`` (bench_kernels records it), which then gate their own dim
-    class. An entry whose XLA measurement FAILED (``xla_ms`` None — S×S logits
-    OOM) counts as a pallas win: that is a length where the fused kernel is
-    mandatory, not absent data."""
-    t = kernel_tuning()
-    entries = [e for e in t["entries"] if _fused_ms(e) is not None]
-    padded_dim = head_dim is not None and head_dim % 128 != 0
-    by_rule = padded_dim and padded_dim_route(
-        seq, seq if seq_k is None else seq_k, batch_heads) is not None
-    if head_dim is not None:
-        same_dim = [e for e in entries if e.get("head_dim") == head_dim]
-        if same_dim:
-            entries = same_dim
-        elif padded_dim:
-            return by_rule
-        else:
-            # Aligned dim: generic (dim-less or aligned-dim) entries apply.
-            entries = [
-                e for e in entries
-                if e.get("head_dim") is None or e["head_dim"] % 128 == 0
-            ]
-    if not entries:
-        return True
-    e = _nearest(entries, seq)
-    if padded_dim and not (seq / 2 <= int(e.get("seq", 0)) <= seq * 2):
-        # A measured padded-dim entry speaks for at most 2x in sequence
-        # length either way; beyond that the rule decides.
-        return by_rule
-    if e.get("xla_ms") is None:
-        return True
-    return float(_fused_ms(e)) <= float(e["xla_ms"])
-
-
-def write_tuning(data: dict) -> str:
-    """Persist a measured tuning table (bench_kernels --apply) to
-    ``$PA_TUNING_PATH`` and reload."""
-    if not _PATH:
-        raise RuntimeError("set PA_TUNING_PATH to the file the table goes to")
-    merged = {**_DEFAULT, **data, "source": "measured"}
-    with open(_PATH, "w") as f:
-        json.dump(merged, f, indent=2, sort_keys=True)
-        f.write("\n")
-    kernel_tuning.cache_clear()
-    return _PATH
+    if pinned == "pallas":
+        return Route("pallas", *(blocks or DEFAULT_BLOCKS), "pinned")
+    if pinned == "xla_chunked":
+        return Route("xla_chunked", None, None, "pinned")
+    if pinned == "xla":
+        return xla_family("pinned")
+    if pinned != "auto":
+        raise ValueError(f"unknown attention backend {pinned!r}")
+    if not on_tpu:
+        return xla_family("off-tpu")
+    if blocks is None:
+        return xla_family(row)
+    return Route("pallas", *blocks, row)

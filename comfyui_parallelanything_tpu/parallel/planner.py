@@ -13,8 +13,8 @@ stream, fixed mesh factorization) with a cost-model search:
   candidates from ``models/loader.carve_ranges`` — the same arithmetic the
   streaming executor carves with), pipeline stage carves for the batch==1
   block-placement path, and the attention axis
-  (``ops.attention.backend_plan`` — the banked chunk-sweep and
-  pallas-vs-xla tuning tables become a planner input);
+  (``ops.attention.resolve_route`` — the backend and blocks the rung's
+  attention class will take, recorded with the decision);
 - **prune** HBM-infeasible plans against the residency budget
   (``devices.memory.usable_hbm_bytes`` / ``ParallelConfig.hbm_budget_bytes``
   — infeasible candidates stay in the score table, marked, and are never
@@ -367,7 +367,7 @@ def plan(inp: PlanInputs, pinned_mode: str | None = None) -> dict:
     as the recorded shadow. ``pinned_mode="stream"`` restricts the space to
     the stream-carve axis (an explicit ``weight_sharding="stream"`` pins
     the mode; the carve is still searched). Pure in ``inp`` + the banked
-    tables (calibration store, attention tuning files)."""
+    calibration store."""
     spec = platform_spec(inp.device_kind, inp.platform)
     calib = load_calibration()
     n = max(1, int(inp.n_devices))
@@ -410,16 +410,13 @@ def plan(inp: PlanInputs, pinned_mode: str | None = None) -> dict:
         fallback = "no-feasible-candidate"
 
     attn = None
-    if inp.seq_len:
-        try:
-            from ..ops.attention import backend_plan
+    if inp.seq_len and inp.head_dim:
+        from ..ops.attention import resolve_route
 
-            attn = backend_plan(
-                int(inp.seq_len), head_dim=inp.head_dim,
-                batch=int(inp.batch or 1), heads=int(inp.heads or 1),
-            )
-        except Exception:
-            attn = None
+        attn = resolve_route(
+            int(inp.seq_len), int(inp.seq_len), int(inp.head_dim),
+            int(inp.batch or 1) * int(inp.heads or 1),
+        )._asdict()
 
     pipeline = (
         _pipeline_plan(inp, spec)

@@ -6,12 +6,6 @@ time); without a TPU it exits non-zero and measures nothing:
 
     python scripts/bench_kernels.py            # measure, append KERNEL_BENCH.json
     python scripts/bench_kernels.py --shape sd15-b8-512.self4096,sdxl-b1-1024.self4096
-    PA_TUNING_PATH=tuning.json python scripts/bench_kernels.py --apply
-                                               # ALSO write the winners to
-                                               # $PA_TUNING_PATH; a process
-                                               # started with that variable
-                                               # then uses measured blocks +
-                                               # xla-fallback ranges
     KERNEL_SWEEP=0 python scripts/bench_kernels.py   # default blocks only
 
 Shapes cover the rungs that matter: the benchmark cells' UNet self-attention
@@ -26,7 +20,9 @@ ragged length the padded row as one key block, ``_combos``); each
 cell is the mean of 10 chained timed calls after compile+warmup (see
 ``_time_fn`` for why chained). Appends JSON lines to
 ``<evidence dir>/KERNEL_BENCH.json`` (not tracked); the lines the shape rule
-rests on are quoted in PERF.md §6 (PR 25).
+rests on are quoted beside its constants in ops/pallas/tuning.py and in
+PERF.md §6 (PRs 25, 26). A new threshold or block size goes into ``route()``
+there, with its lines.
 """
 
 from __future__ import annotations
@@ -95,7 +91,7 @@ def _time_fn(fn, *args, iters=10):
 
 def _run_shapes(shapes, dev):
     """Measure the given shapes inline, appending one JSON line each to
-    KERNEL_BENCH.json. Returns the per-shape tuning entries."""
+    KERNEL_BENCH.json."""
     import jax
     import jax.numpy as jnp
 
@@ -110,9 +106,8 @@ def _run_shapes(shapes, dev):
 
     def xla_family(a, b_, c, scale):
         # The real competitor the auto backend would pick: chunked when the
-        # S×S logits would blow HBM, plain otherwise — routed on the LIVE
-        # threshold (env + persisted chunk tuning), same as attention_local,
-        # so pallas_wins decisions compare against production routing.
+        # S×S logits would blow HBM, plain otherwise, on attention_local's
+        # own threshold.
         elems = a.shape[0] * a.shape[2] * a.shape[1] * b_.shape[1]
         if elems > _chunk_threshold():
             return _xla_chunked_attention(a, b_, c, scale)
@@ -122,7 +117,6 @@ def _run_shapes(shapes, dev):
 
     out_path = os.path.join(evidence_dir(), "KERNEL_BENCH.json")
     sweep = os.environ.get("KERNEL_SWEEP", "1") != "0"
-    entries = []
     for label, b, s, h, d in shapes:
         # (B, S, H·D), split into heads inside the timed program: what a
         # model's projections hand over, so no backend is charged (or spared)
@@ -158,23 +152,6 @@ def _run_shapes(shapes, dev):
         if best is not None:
             rec["pallas_ms"] = round(best[0], 3)
             rec["block_q"], rec["block_k"] = best[1], best[2]
-        if d % 128 == 0:
-            # jax's upstream fused kernel: the second fused candidate the
-            # tuning table can route auto to (ops/attention.py "pallas_jax").
-            # Lane-aligned dims only; upstream block heuristics, no sweep.
-            from comfyui_parallelanything_tpu.ops.attention import (
-                _pallas_jax_attention,
-            )
-
-            try:
-                rec["pallas_jax_ms"] = round(_time_fn(
-                    projected(
-                        lambda a, b_, c: _pallas_jax_attention(a, b_, c, d**-0.5)
-                    ),
-                    q, k, v,
-                ) * 1e3, 3)
-            except Exception as e:  # noqa: BLE001 — record, keep measuring
-                rec["pallas_jax_error"] = str(e)[:120]
         try:
             rec["xla_ms"] = round(
                 _time_fn(projected(lambda a, b_, c: xla_family(a, b_, c, d**-0.5)),
@@ -196,17 +173,6 @@ def _run_shapes(shapes, dev):
         print(json.dumps(rec))
         with open(out_path, "a") as f:
             f.write(json.dumps(rec) + "\n")
-        if "pallas_ms" in rec or "pallas_jax_ms" in rec:
-            entries.append({
-                "seq": s,
-                "head_dim": d,
-                "block_q": rec.get("block_q", 256),
-                "block_k": rec.get("block_k", 256),
-                "pallas_ms": rec.get("pallas_ms"),
-                "pallas_jax_ms": rec.get("pallas_jax_ms"),
-                "xla_ms": rec.get("xla_ms"),
-            })
-    return entries
 
 
 def main() -> None:
@@ -227,21 +193,7 @@ def main() -> None:
         shapes = [sh for sh in SHAPES if sh[0] in labels]
         if len(shapes) != len(labels):
             raise SystemExit(f"unknown shape among {labels!r}")
-    entries = _run_shapes(shapes, dev)
-
-    if "--apply" in sys.argv:
-        if not entries:
-            raise SystemExit("--apply: no measurement succeeded")
-        from comfyui_parallelanything_tpu.ops.pallas.tuning import write_tuning
-
-        # Per-shape winners live in `entries` (best_blocks picks the nearest);
-        # the table-level block fields stay the neutral 256/256 default — a
-        # cross-shape "fastest absolute ms" would just crown the cheapest shape.
-        path = write_tuning({
-            "device_kind": dev.device_kind,
-            "entries": entries,
-        })
-        print(f"# tuning table written: {path}", file=sys.stderr)
+    _run_shapes(shapes, dev)
 
 
 if __name__ == "__main__":

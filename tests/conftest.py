@@ -11,12 +11,6 @@ import os
 # Force the CPU platform: the test suite is defined over the virtual 8-device
 # CPU mesh, whatever the machine has attached.
 os.environ["JAX_PLATFORMS"] = "cpu"
-# Hermetic kernel/chunk tuning: the suite asserts against the built-in
-# defaults (tests that exercise a table monkeypatch the module's path).
-os.environ.pop("PA_ATTN_CHUNK_TUNING", None)
-os.environ.pop("PA_TUNING_PATH", None)
-os.environ.pop("PA_ATTN_CHUNK_ELEMS", None)
-os.environ.pop("PA_ATTN_BF16_SOFTMAX", None)
 # Everything a test appends to — the perf ledger, postmortem bundles, bench
 # evidence files — goes to a per-process temp directory, so a run leaves the
 # checkout clean. Tests that assert on those files set their own. Only the

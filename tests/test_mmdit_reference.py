@@ -255,19 +255,23 @@ UNET_CELL_CLASSES = [
 def test_auto_backend_is_unchanged_at_every_class_of_the_unet_cells(
         monkeypatch, label, b, sq, sk, h, d):
     """The rule before this configuration, restated: the fused kernel at
-    128-multiple lengths where ``pallas_wins``, XLA otherwise. The ragged
+    128-multiple lengths where the head dim is lane-aligned or the keys and
+    logits reach the padded-dim thresholds, XLA otherwise. The ragged
     rule leaves cross-attention's 77 keys where they were, so ``sd15`` and
     ``sdxl`` trace the programs they did."""
     from comfyui_parallelanything_tpu.ops.pallas import tuning
 
     att = _att()
     monkeypatch.setattr(att, "_pallas_available", lambda: True)
-    before = ("pallas" if sq % 128 == 0 and sk % 128 == 0
-              and tuning.pallas_wins(sq, d, seq_k=sk, batch_heads=b * h) else "xla")
-    assert att._auto_backend(sq, sk, d, b * h) == before
-    if before == "pallas":
-        assert tuning.best_blocks(sq, d, seq_k=sk, batch_heads=b * h) == (
-            tuning.PADDED_DIM_BLOCKS if d % 128 else (256, 256))
+    fused = sq % 128 == 0 and sk % 128 == 0 and (d % 128 == 0 or (
+        sk >= tuning.PADDED_DIM_MIN_KEYS
+        and b * h * sq * sk >= tuning.PADDED_DIM_MIN_LOGITS))
+    got = att.resolve_route(sq, sk, d, b * h)
+    if fused:
+        assert got[:3] == ("pallas", *(
+            tuning.PADDED_DIM_BLOCKS if d % 128 else (256, 256)))
+    else:
+        assert got[:3] == ("xla", None, None)
 
 
 def test_padded_calls_are_counted_once_a_trace():

@@ -139,61 +139,6 @@ class TestChunkedAttention:
         att.attention_local(q, k, v)  # 1*2*32*32 = 2048 > 64 -> chunked
         assert att.resolved_backends() == ("xla_chunked",)
 
-    def test_bf16_softmax_env_matches_f32_at_bf16_tolerance(self, monkeypatch):
-        # The sd15_16 MFU-budget lever: bf16 logits+softmax halves the chunked
-        # path's HBM traffic; numerics must stay within bf16 tolerances.
-        att = self._mod()
-        q, k, v = _qkv(b=2, sq=96, sk=64, h=2, d=16, seed=7)
-        monkeypatch.setattr(att, "_CHUNK_THRESHOLD", 2 * 2 * 64 * 16)
-        ref = att._xla_chunked_attention(q, k, v, scale=16 ** -0.5)
-        monkeypatch.setenv("PA_ATTN_BF16_SOFTMAX", "1")
-        out = att._xla_chunked_attention(q, k, v, scale=16 ** -0.5)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-2, atol=2e-2)
-
-    def test_chunk_elems_env_overrides_threshold(self, monkeypatch):
-        att = self._mod()
-        monkeypatch.setattr(att, "_RESOLVED", set())
-        monkeypatch.setenv("PA_ATTN_CHUNK_ELEMS", "64")
-        q, k, v = _qkv(b=1, sq=32, sk=32, h=2, d=8)
-        att.attention_local(q, k, v)  # 2048 > 64 -> chunked
-        assert att.resolved_backends() == ("xla_chunked",)
-        assert att.chunk_config() == {
-            "chunk_elems": 64, "bf16_softmax": False,
-            # No degradation-ladder shrink in effect (round 14 evidence
-            # labeling — a degraded process must not bank as configured).
-            "degraded": False,
-            # Per-field provenance: only the threshold came from the env.
-            "sources": {"chunk_elems": "env", "bf16_softmax": "default"},
-        }
-
-    def test_persisted_chunk_tuning_honored(self, tmp_path, monkeypatch):
-        # A chunk sweep persists the measured winner to the file
-        # $PA_ATTN_CHUNK_TUNING names; a process pointed at it must serve it.
-        import json as _json
-
-        att = self._mod()
-        path = tmp_path / "attn_chunk.json"
-        path.write_text(_json.dumps(
-            {"source": "measured", "chunk_elems": 128, "bf16_softmax": True}
-        ))
-        monkeypatch.setattr(att, "_CHUNK_TUNING_PATH", str(path))
-        att._chunk_tuning.cache_clear()
-        try:
-            assert att._chunk_threshold() == 128
-            assert att._softmax_dtype() == jnp.bfloat16
-            cfg = att.chunk_config()
-            assert cfg["sources"] == {"chunk_elems": "measured",
-                                      "bf16_softmax": "measured"}
-            assert cfg["chunk_elems"] == 128
-            # Env still wins over the persisted table (the sweep itself).
-            monkeypatch.setenv("PA_ATTN_CHUNK_ELEMS", "256")
-            monkeypatch.setenv("PA_ATTN_BF16_SOFTMAX", "0")
-            assert att._chunk_threshold() == 256
-            assert att._softmax_dtype() == jnp.float32
-        finally:
-            att._chunk_tuning.cache_clear()
-
     def test_explicit_backend_name(self, monkeypatch):
         att = self._mod()
         att.set_attention_backend("xla_chunked")
@@ -206,259 +151,155 @@ class TestChunkedAttention:
         finally:
             att.set_attention_backend("auto")
 
-    @pytest.mark.parametrize("shape", [
-        dict(sq=16, sk=16, d=4),      # 4 % 128 != 0: upstream has no lane pad
-        dict(sq=40, sk=40, d=128),    # 40 % 128 != 0: upstream has no seq pad
-        dict(sq=128, sk=72, d=128),   # mixed alignment is equally unservable
-    ], ids=["padded-dim", "unaligned-seq", "unaligned-kv"])
-    def test_forced_pallas_jax_raises_on_shapes_it_cannot_serve(self, shape):
-        # A FORCED backend that cannot serve a shape raises; only "auto" may
-        # choose another one.
-        att = self._mod()
-        att.set_attention_backend("pallas_jax")
-        try:
-            q, k, v = _qkv(b=1, h=1, **shape)
-            with pytest.raises(ValueError, match="pallas_jax.*cannot serve"):
-                att.attention_local(q, k, v)
-            with pytest.raises(ValueError, match="pallas_jax.*cannot serve"):
-                att.backend_plan(shape["sq"], shape["sk"], head_dim=shape["d"])
-        finally:
-            att.set_attention_backend("auto")
+
+# What ``auto`` resolves to on a TPU at the XLA family's default threshold.
+def _route(sq, sk, d, bh, **kw):
+    from comfyui_parallelanything_tpu.ops.pallas.tuning import route
+
+    kw = {"on_tpu": True, "chunk_threshold": 2**27, **kw}
+    return route(sq, sk, d, bh, **kw)
 
 
-class TestKernelTuning:
-    """Data-driven block sizes / backend choice (ops/pallas/tuning.py): the
-    mechanism bench_kernels.py --apply feeds on real hardware."""
+class TestRoute:
+    """``ops/pallas/tuning.route``: the one place that names an attention
+    call's backend and blocks. Pure Python: nothing here compiles."""
 
-    def _table(self, entries):
-        return {"source": "measured", "block_q": 256, "block_k": 256,
-                "entries": entries}
-
-    def test_defaults_without_file(self, monkeypatch):
-        from comfyui_parallelanything_tpu.ops.pallas import tuning
-
-        # No $PA_TUNING_PATH: the defaults, whatever an earlier run left in
-        # the checkout.
-        monkeypatch.setattr(tuning, "_PATH", None)
-        tuning.kernel_tuning.cache_clear()
-        try:
-            assert tuning.best_blocks(4608) == (256, 256)
-            assert tuning.pallas_wins(4608) is True  # default guess
-            # A table that was asked for and cannot be read is an error.
-            monkeypatch.setattr(tuning, "_PATH", "/nonexistent/tuning.json")
-            tuning.kernel_tuning.cache_clear()
-            with pytest.raises(OSError):
-                tuning.kernel_tuning()
-        finally:
-            tuning.kernel_tuning.cache_clear()
-
-    def test_measured_entries_drive_choice(self, monkeypatch):
-        from comfyui_parallelanything_tpu.ops.pallas import tuning
-
-        table = self._table([
-            {"seq": 4608, "block_q": 512, "block_k": 256,
-             "pallas_ms": 1.0, "xla_ms": 2.0},
-            {"seq": 512, "block_q": 128, "block_k": 128,
-             "pallas_ms": 3.0, "xla_ms": 1.0},  # kernel LOSES at short seq
-        ])
-        monkeypatch.setattr(tuning, "kernel_tuning", lambda: {**tuning._DEFAULT, **table})
-        assert tuning.best_blocks(4000) == (512, 256)  # nearest: 4608
-        assert tuning.best_blocks(600) == (128, 128)
-        assert tuning.pallas_wins(4608) is True
-        assert tuning.pallas_wins(384) is False  # nearest entry says xla
-
-    def test_xla_oom_entry_counts_as_pallas_win(self, monkeypatch):
-        # An entry whose XLA measurement failed (S×S logits OOM at video
-        # lengths) marks a length where the fused kernel is MANDATORY.
-        from comfyui_parallelanything_tpu.ops.pallas import tuning
-
-        table = self._table([
-            {"seq": 4608, "block_q": 256, "block_k": 256,
-             "pallas_ms": 2.0, "xla_ms": 1.5},        # xla narrowly wins
-            {"seq": 32768, "block_q": 256, "block_k": 512,
-             "pallas_ms": 40.0, "xla_ms": None},      # xla OOMed
-        ])
-        monkeypatch.setattr(
-            tuning, "kernel_tuning", lambda: {**tuning._DEFAULT, **table}
-        )
-        assert tuning.pallas_wins(32768) is True   # never route 32k to xla
-        assert tuning.pallas_wins(4608) is False
-
-    def test_foreign_device_table_ignored(self, monkeypatch, tmp_path):
-        # A v5e-measured table must not apply on a different TPU generation.
-        import json as _json
-
-        from comfyui_parallelanything_tpu.ops.pallas import tuning
-
-        p = tmp_path / "tuning.json"
-        p.write_text(_json.dumps({
-            "device_kind": "TPU v99", "block_q": 512, "block_k": 512,
-            "entries": [{"seq": 128, "block_q": 512, "block_k": 512,
-                         "pallas_ms": 9.0, "xla_ms": 1.0}],
-        }))
-        monkeypatch.setattr(tuning, "_PATH", str(p))
-        tuning.kernel_tuning.cache_clear()
-        try:
-            assert tuning.kernel_tuning()["source"] == "default"
-            assert tuning.best_blocks(128) == (256, 256)
-        finally:
-            tuning.kernel_tuning.cache_clear()
-
-    def test_write_and_reload_roundtrip(self, monkeypatch, tmp_path):
-        from comfyui_parallelanything_tpu.ops.pallas import tuning
-
-        monkeypatch.setattr(tuning, "_PATH", str(tmp_path / "tuning.json"))
-        tuning.kernel_tuning.cache_clear()
-        try:
-            import jax
-
-            kind = jax.devices()[0].device_kind  # must match to be applied
-            tuning.write_tuning({
-                "device_kind": kind,
-                "block_q": 512, "block_k": 128,
-                "entries": [{"seq": 16384, "block_q": 512, "block_k": 128,
-                             "pallas_ms": 5.0, "xla_ms": 50.0}],
-            })
-            t = tuning.kernel_tuning()
-            assert t["source"] == "measured" and t["device_kind"] == kind
-            assert tuning.best_blocks(20000) == (512, 128)
-        finally:
-            tuning.kernel_tuning.cache_clear()
-
-    def test_padded_head_dim_gate(self, monkeypatch):
-        # Non-128-aligned head dims (40/64/80 UNet heads) are routed by the
-        # shape rule (tuning.padded_dim_route: key length and B·H·S_q·S_k,
-        # set from the v5e measurements beside it), not by a default "no":
-        # aligned dims keep the default-True guess; a table measured at that
-        # very dim overrides the rule for its class.
-        from comfyui_parallelanything_tpu.ops.pallas import tuning
-
-        monkeypatch.setattr(
-            tuning, "kernel_tuning", lambda: {**tuning._DEFAULT, "entries": []}
-        )
-        assert tuning.pallas_wins(16384, 128) is True   # aligned: default guess
-        assert tuning.pallas_wins(16384, 40) is True    # rule: long keys
-        assert tuning.pallas_wins(4096, 40, seq_k=77) is False  # cross-attn
-        assert tuning.pallas_wins(256, 160) is False    # short inner level
-        # B·H·S_q·S_k under 2^27 (SDXL's 1024-token class) lost on the chip.
-        assert tuning.pallas_wins(1024, 64, batch_heads=40) is False
-        assert tuning.pallas_wins(1024, 80, batch_heads=128) is True
-        assert tuning.best_blocks(4096, 40) == tuning.PADDED_DIM_BLOCKS
-        assert tuning.best_blocks(4096, 40, seq_k=77) == (256, 256)
-
-        table = self._table([
-            {"seq": 16384, "head_dim": 40, "block_q": 512, "block_k": 256,
-             "pallas_ms": 100.0, "xla_ms": 180.0},      # padded kernel wins
-            {"seq": 4096, "head_dim": 64, "block_q": 256, "block_k": 256,
-             "pallas_ms": 9.0, "xla_ms": 4.0},          # padded kernel loses
-            {"seq": 4608, "block_q": 256, "block_k": 256,
-             "pallas_ms": 1.0, "xla_ms": 2.0},          # aligned (no dim tag)
-        ])
-        monkeypatch.setattr(
-            tuning, "kernel_tuning", lambda: {**tuning._DEFAULT, **table}
-        )
-        assert tuning.pallas_wins(16384, 40) is True
-        assert tuning.pallas_wins(4096, 64) is False    # measured loss wins
-        # Aligned queries must not be judged by padded-dim entries.
-        assert tuning.pallas_wins(4608, 128) is True
-        # Same-dim measurements drive block choice for that class.
-        assert tuning.best_blocks(16384, 40) == (512, 256)
-        assert tuning.best_blocks(4608, 128) == (256, 256)
-        # A measured padded-dim entry speaks for at most 2x in seq either
-        # way; beyond that the rule decides: 256 tokens stay on XLA.
-        assert tuning.pallas_wins(256, 40) is False
-        assert tuning.pallas_wins(8192, 40) is True  # within 2x of 16384
-
-    def test_padded_dim_blocks_never_inherit_aligned_winners(self, monkeypatch):
-        # ADVICE r3: best_blocks for a padded dim with NO same-dim entry must
-        # never return blocks tuned for another dim class: the shape rule's
-        # where it routes, the defaults where a forced pallas backend runs a
-        # shape the rule leaves to XLA.
-        from comfyui_parallelanything_tpu.ops.pallas import tuning
-
-        table = self._table([
-            {"seq": 4608, "head_dim": 128, "block_q": 512, "block_k": 512,
-             "pallas_ms": 1.0, "xla_ms": 2.0},
-        ])
-        monkeypatch.setattr(
-            tuning, "kernel_tuning", lambda: {**tuning._DEFAULT, **table}
-        )
-        assert tuning.best_blocks(4608, head_dim=40) == tuning.PADDED_DIM_BLOCKS
-        assert tuning.best_blocks(512, head_dim=40) == (256, 256)
-        assert tuning.best_blocks(4608, head_dim=128) == (512, 512)
-
-    def test_fused_backend_picks_measured_winner(self, monkeypatch):
-        # Two fused candidates (in-repo kernel vs jax's upstream one): auto
-        # routes to whichever measured faster; padded dims always take the
-        # in-repo kernel (upstream has no lane padding); a shape where ONLY
-        # the upstream kernel produced a number (round-3's hang scenario)
-        # still counts as a fused win over XLA.
-        from comfyui_parallelanything_tpu.ops.pallas import tuning
-
-        table = self._table([
-            {"seq": 4608, "head_dim": 128, "block_q": 256, "block_k": 256,
-             "pallas_ms": None, "pallas_jax_ms": 3.0, "xla_ms": 9.0},
-            {"seq": 16384, "head_dim": 128, "block_q": 256, "block_k": 256,
-             "pallas_ms": 2.0, "pallas_jax_ms": 4.0, "xla_ms": 9.0},
-        ])
-        monkeypatch.setattr(
-            tuning, "kernel_tuning", lambda: {**tuning._DEFAULT, **table}
-        )
-        assert tuning.fused_backend(4608, 128) == "pallas_jax"
-        assert tuning.fused_backend(16384, 128) == "pallas"
-        assert tuning.fused_backend(4608, 40) == "pallas"  # padded dim
-        assert tuning.pallas_wins(4608, 128) is True  # jax-kernel-only entry
-        # No measurements at all: default to the in-repo kernel.
-        monkeypatch.setattr(tuning, "kernel_tuning", lambda: dict(tuning._DEFAULT))
-        assert tuning.fused_backend(4608, 128) == "pallas"
-
-    def test_aligned_blocks_ignore_padded_dim_entries(self, monkeypatch):
-        # A partial sweep can leave ONLY padded-dim entries (per-shape
-        # subprocess timeouts); aligned dims must then fall back to defaults,
-        # not adopt blocks tuned under the padded-FLOP regime.
-        from comfyui_parallelanything_tpu.ops.pallas import tuning
-
-        table = self._table([
-            {"seq": 16384, "head_dim": 40, "block_q": 512, "block_k": 512,
-             "pallas_ms": 100.0, "xla_ms": 180.0},
-        ])
-        monkeypatch.setattr(
-            tuning, "kernel_tuning", lambda: {**tuning._DEFAULT, **table}
-        )
-        assert tuning.best_blocks(4608, 128) == (256, 256)  # defaults
-        assert tuning.pallas_wins(4608, 128) is True        # default guess
-
-    def test_auto_backend_respects_measured_loss(self, monkeypatch):
-        # Auto mode must fall back to XLA for lengths where measurement says
-        # the fused kernel loses — even on TPU with aligned shapes.
+    def _att(self):
         import importlib
 
-        # ops/__init__ rebinds the name `attention` to the function, shadowing
-        # the submodule on attribute access — resolve the module explicitly.
-        att = importlib.import_module("comfyui_parallelanything_tpu.ops.attention")
-        from comfyui_parallelanything_tpu.ops.pallas import tuning
+        return importlib.import_module("comfyui_parallelanything_tpu.ops.attention")
 
-        calls = []
+    # Both sides of every threshold of the rule.
+    # (label, route arguments, on a TPU, backend, blocks, rule)
+    THRESHOLDS = [
+        # PADDED_DIM_MIN_KEYS, at a 40-wide head and B·H 128
+        ("keys-896", (4096, 896, 40, 128), True, "xla_chunked", None, "padded-dim"),
+        ("keys-1024", (4096, 1024, 40, 128), True, "pallas", (256, 4096), "padded-dim"),
+        # PADDED_DIM_MIN_LOGITS: 2^27 is B·H 128 at 1024 x 1024
+        ("bh-127", (1024, 1024, 80, 127), True, "xla", None, "padded-dim"),
+        ("bh-128", (1024, 1024, 80, 128), True, "pallas", (256, 4096), "padded-dim"),
+        # RAGGED_MIN_LOGITS: B·H 48 at 1101 x 1101
+        ("ragged-bh-47", (1101, 1101, 64, 47), True, "xla", None, "ragged"),
+        ("ragged-bh-48", (1101, 1101, 64, 48), True, "pallas", (384, 1152), "ragged"),
+        # RAGGED_ONE_BLOCK: a row padded to 4352 keys is one key block, a
+        # longer one streams 4096 keys a block
+        ("ragged-pads-to-4352", (4300, 4300, 64, 48), True, "pallas", (256, 4352), "ragged"),
+        ("ragged-pads-to-4480", (4400, 4400, 64, 48), True, "pallas", (256, 4096), "ragged"),
+        # 384 queries a block where they divide the padded row, else 256
+        ("ragged-2304-by-384", (2250, 2250, 64, 48), True, "pallas", (384, 2304), "ragged"),
+        ("ragged-2432-by-256", (2400, 2400, 64, 48), True, "pallas", (256, 2432), "ragged"),
+        # the XLA family off a TPU: whole up to 2^27 logits, chunked above
+        # (2^27 + 1 = 513 x 261633)
+        ("cpu-2^27", (1024, 1024, 80, 128), False, "xla", None, "off-tpu"),
+        ("cpu-2^27+1", (513, 261633, 80, 1), False, "xla_chunked", None, "off-tpu"),
+    ]
+
+    @pytest.mark.parametrize("label,args,tpu,backend,blocks,rule", THRESHOLDS,
+                             ids=[c[0] for c in THRESHOLDS])
+    def test_both_sides_of_every_threshold(self, label, args, tpu, backend,
+                                           blocks, rule):
+        got = _route(*args, on_tpu=tpu)
+        assert got == (backend, *(blocks or (None, None)), rule)
+
+    # A pin is served as pinned, on a TPU or off one, with the rule's blocks
+    # where the rule has them at that shape.
+    PINS = [
+        ("pallas-where-the-rule-says-xla", "pallas", (256, 256, 160, 128), "pallas", (256, 256)),
+        ("pallas-at-a-padded-dim-class", "pallas", (4096, 4096, 40, 128), "pallas", (256, 4096)),
+        ("pallas-at-a-ragged-class", "pallas", (4173, 4173, 64, 48), "pallas", (384, 4224)),
+        ("xla-over-the-threshold", "xla", (4096, 4096, 40, 128), "xla_chunked", None),
+        ("xla_chunked-at-a-small-shape", "xla_chunked", (16, 16, 4, 1), "xla_chunked", None),
+        ("unknown", "pallas_mosaic", (16, 16, 4, 1), None, None),
+    ]
+
+    @pytest.mark.parametrize("label,pin,args,backend,blocks", PINS,
+                             ids=[c[0] for c in PINS])
+    @pytest.mark.parametrize("tpu", [True, False], ids=["tpu", "cpu"])
+    def test_a_pin_is_served_as_pinned(self, tpu, label, pin, args, backend,
+                                       blocks):
+        if backend is None:
+            with pytest.raises(ValueError, match="unknown attention backend"):
+                _route(*args, on_tpu=tpu, pinned=pin)
+            return
+        got = _route(*args, on_tpu=tpu, pinned=pin)
+        assert got == (backend, *(blocks or (None, None)), "pinned")
+
+    @pytest.mark.parametrize("shrinks,threshold", [(1, 2**26), (7, 2**20),
+                                                    (9, 2**20)],
+                             ids=["one", "all", "past-the-floor"])
+    def test_ladder_shrinks_move_the_xla_boundary_only(self, monkeypatch,
+                                                       shrinks, threshold):
+        """The ladder's attn-chunk-shrink rung halves where the XLA family
+        starts chunking and stops at the floor; no fused route moves."""
+        from test_planner import TestAttentionAxis
+
+        att = self._att()
         monkeypatch.setattr(att, "_pallas_available", lambda: True)
-        monkeypatch.setattr(
-            tuning, "kernel_tuning",
-            lambda: {**tuning._DEFAULT, "entries": [
-                {"seq": 128, "block_q": 128, "block_k": 128,
-                 "pallas_ms": 9.0, "xla_ms": 1.0},
-            ]},
-        )
-        fa = importlib.import_module(
-            "comfyui_parallelanything_tpu.ops.pallas.flash_attention"
-        )
-        real = fa.flash_attention
-        monkeypatch.setattr(
-            fa, "flash_attention",
-            lambda *a, **kw: calls.append(kw) or real(*a, interpret=True, **kw),
-        )
-        q = jnp.ones((1, 128, 2, 128), jnp.float32)
-        out = att.attention_local(q, q, q)
-        assert out.shape == q.shape
-        assert calls == []  # measured loss -> xla path, kernel never invoked
+        fused = [(sq, sk, d, b * h)
+                 for _, tpu, b, sq, sk, h, d, backend, _ in TestAttentionAxis.ROUTES
+                 if tpu and backend == "pallas"]
+        before = [att.resolve_route(*c) for c in fused]
+        try:
+            said = [att.shrink_chunk_threshold() for _ in range(shrinks)]
+            assert said[-1] == (threshold if shrinks <= 7 else None)
+            assert att.chunk_config() == {"chunk_elems": threshold,
+                                          "degraded": True}
+            # 1024 x 1024 at a 64-wide head stays in the XLA family on a TPU
+            # (under 2^27 logits): B·H rows of 2^20 logits each.
+            rows = threshold // 2**20
+            assert att.resolve_route(1024, 1024, 64, rows).backend == "xla"
+            assert att.resolve_route(
+                1024, 1024, 64, rows + 1).backend == "xla_chunked"
+            assert [att.resolve_route(*c) for c in fused] == before
+        finally:
+            att.reset_chunk_shrink()
+        assert att.chunk_config() == {"chunk_elems": 2**27, "degraded": False}
+
+    @pytest.mark.parametrize("label", ["sd15-self4096", "sdxl-self1024",
+                                       "sd35m-joint4173"])
+    def test_the_planner_records_the_same_route(self, monkeypatch, label):
+        from test_planner import TestAttentionAxis
+
+        from comfyui_parallelanything_tpu.parallel import planner
+
+        _, _, b, sq, sk, h, d, backend, blocks = next(
+            r for r in TestAttentionAxis.ROUTES if r[0] == label)
+        monkeypatch.setattr(self._att(), "_pallas_available", lambda: True)
+        decision = planner.plan(planner.PlanInputs(
+            n_devices=1, platform="tpu", device_kind="TPU v5e",
+            weights_bytes=10**9, batch=b, seq_len=sq, head_dim=d, heads=h,
+        ))
+        assert decision["attn"] == _route(sq, sk, d, b * h)._asdict()
+        assert decision["attn"]["backend"] == backend
+        assert (decision["attn"]["block_q"], decision["attn"]["block_k"]) == (
+            blocks or (None, None))
+
+    # The four PA_* variables that went with the measured table and the chunk
+    # tuning, by suffix (so a grep for the full names finds no code).
+    @pytest.mark.parametrize("suffix,value", [
+        ("TUNING_PATH", "/nonexistent/tuning.json"),
+        ("ATTN_CHUNK_TUNING", "/nonexistent/chunk.json"),
+        ("ATTN_CHUNK_ELEMS", "1"),
+        ("ATTN_BF16_SOFTMAX", "1"),
+    ])
+    def test_the_deleted_variables_are_read_by_nothing(self, monkeypatch,
+                                                       suffix, value):
+        att = self._att()
+        shapes = [(4096, 4096, 40, 128), (1024, 1024, 64, 40),
+                  (4173, 4173, 64, 48), (4608, 4608, 128, 24)]
+        q, k, v = _qkv(b=2, sq=96, sk=64, h=2, d=16, seed=7)
+
+        def chunked():
+            with monkeypatch.context() as m:
+                # several scan blocks: the threshold under the logits' size
+                m.setattr(att, "_CHUNK_THRESHOLD", 2 * 2 * 64 * 16)
+                return np.asarray(att._xla_chunked_attention(q, k, v, 0.25))
+
+        routes = [att.resolve_route(*c) for c in shapes]
+        out = chunked()
+        monkeypatch.setenv(f"PA_{suffix}", value)
+        assert [att.resolve_route(*c) for c in shapes] == routes
+        np.testing.assert_array_equal(chunked(), out)
 
 
 class TestFlashAttention:

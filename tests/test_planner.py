@@ -177,9 +177,10 @@ class TestCalibrationFeedback:
 
 
 class TestAttentionAxis:
-    def test_backend_plan_matches_trace_time_resolution(self, monkeypatch):
-        """Drift gate: the planner's attention decision and the actual
-        ``attention_local`` trace-time resolution are the same ladder."""
+    def test_planner_attn_matches_trace_time_resolution(self, monkeypatch):
+        """The planner's attention decision and the ``attention_local``
+        trace-time resolution are one function's answer, on both sides of
+        the XLA family's chunk threshold."""
         import importlib
 
         import jax.numpy as jnp
@@ -188,17 +189,16 @@ class TestAttentionAxis:
             "comfyui_parallelanything_tpu.ops.attention"
         )
         q = jnp.zeros((1, 8, 2, 4), jnp.float32)
-        for env, expect in ((None, "xla"), ("64", "xla_chunked")):
-            if env is None:
-                monkeypatch.delenv("PA_ATTN_CHUNK_ELEMS", raising=False)
-            else:
-                monkeypatch.setenv("PA_ATTN_CHUNK_ELEMS", env)
-            plan = att.backend_plan(8, head_dim=4, batch=1, heads=2)
-            assert plan["backend"] == expect, plan
-            before = set(att.resolved_backends())
+        for threshold, expect in ((2**27, "xla"), (64, "xla_chunked")):
+            monkeypatch.setattr(att, "_CHUNK_THRESHOLD", threshold)
+            monkeypatch.setattr(att, "_RESOLVED", set())
+            d = planner.plan(planner.PlanInputs(
+                n_devices=1, platform="cpu", weights_bytes=10**6, batch=1,
+                seq_len=8, head_dim=4, heads=2,
+            ))
+            assert d["attn"]["backend"] == expect, d["attn"]
             att.attention_local(q, q, q)
-            resolved = set(att.resolved_backends()) - before or {expect}
-            assert plan["backend"] in resolved | {expect}
+            assert att.resolved_backends() == (expect,)
 
     # The shape rule (PR 25), case by case: the benchmark cells' four UNet
     # self-attention classes, the cross-attention that rides with them, the
@@ -240,9 +240,9 @@ class TestAttentionAxis:
     )
     def test_route_is_read_from_the_shape(self, monkeypatch, label, tpu, b,
                                           sq, sk, h, d, backend, blocks):
-        """``backend_plan`` and ``attention_local`` resolve every class the
-        same way, from the call's shape and the backend alone, and the
-        resolution is counted once a trace."""
+        """``route`` names the backend and blocks of every class from the
+        call's shape and the backend alone; ``attention_local`` executes
+        that answer, counted once a trace."""
         import importlib
 
         import jax
@@ -256,8 +256,8 @@ class TestAttentionAxis:
         fa = importlib.import_module(
             "comfyui_parallelanything_tpu.ops.pallas.flash_attention"
         )
-        for var in ("PA_ATTN_CHUNK_ELEMS", "PA_ATTN_BF16_SOFTMAX"):
-            monkeypatch.delenv(var, raising=False)
+        from comfyui_parallelanything_tpu.ops.pallas.tuning import route
+
         monkeypatch.setattr(att, "_pallas_available", lambda: tpu)
         calls = []
         monkeypatch.setattr(
@@ -265,8 +265,9 @@ class TestAttentionAxis:
             lambda q, k, v, **kw: calls.append(
                 (kw["block_q"], kw["block_k"])) or q,
         )
-        plan = att.backend_plan(sq, sk, head_dim=d, batch=b, heads=h)
-        assert plan["backend"] == backend, plan
+        chosen = route(sq, sk, d, b * h, on_tpu=tpu, chunk_threshold=2**27)
+        assert chosen[:3] == (backend, *(blocks or (None, None))), chosen
+        assert att.resolve_route(sq, sk, d, b * h) == chosen
 
         def count():
             return registry.get("pa_attention_route_total",
@@ -283,19 +284,6 @@ class TestAttentionAxis:
         fn.eval_shape(q, kv, kv)
         assert count() == before + 1  # once a trace, not once a call
         assert calls == ([blocks] if blocks else [])
-
-    def test_backend_plan_carries_the_banked_tables(self, monkeypatch):
-        import importlib
-
-        att = importlib.import_module(
-            "comfyui_parallelanything_tpu.ops.attention"
-        )
-        plan = att.backend_plan(4608, head_dim=128, batch=4, heads=24)
-        assert plan["backend"] == "xla_chunked"  # no TPU: fused ineligible
-        assert plan["chunk_elems"] > 0
-        names = {c["backend"] for c in plan["candidates"]}
-        assert names == {"pallas", "pallas_jax", "xla", "xla_chunked"}
-        assert plan["sources"]["chunk_elems"] in ("env", "default", "measured")
 
 
 # ---------------------------------------------------------------------------

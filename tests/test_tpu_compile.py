@@ -9,8 +9,6 @@ happen while a module is imported, and every such test lives in THIS file
 (a second file could land on another xdist worker, whose fixture would skip).
 """
 
-import importlib
-
 import jax
 import jax.numpy as jnp
 import pytest
@@ -128,9 +126,10 @@ def test_mmdit_classes_compile_with_the_routed_blocks(one_chip, label, b, s, h, 
     """The joint class goes to the kernel padded and masked: one key block of
     4224 keys walked in three 1408-key softmax tiles, 11 query blocks of 384
     — slices at offsets that are multiples of 128 but not of 2048."""
-    from comfyui_parallelanything_tpu.ops.pallas.tuning import best_blocks
+    from comfyui_parallelanything_tpu.ops.pallas.tuning import route
 
-    block_q, block_k = best_blocks(s, d, seq_k=s, batch_heads=b * h)
+    _, block_q, block_k, _ = route(s, s, d, b * h, on_tpu=True,
+                                   chunk_threshold=2**27)
     assert block_k == -(-s // 128) * 128 <= 4352
     compiled = flash_attention.lower(
         *_qkv(one_chip, b, s, h, d), block_q=block_q, block_k=block_k,
@@ -148,7 +147,7 @@ def test_cross_attention_keys_of_length_77_compile(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("which", ["in-repo", "upstream", "in-repo-unet"])
+@pytest.mark.parametrize("which", ["in-repo", "in-repo-unet"])
 def test_batch_sharded_kernels_compile_for_four_chips(topo, which):
     """The data-parallel step and the VAE decode of a chain's latent hand the
     kernel operands sharded over four chips. The partitioner refuses a bare
@@ -160,7 +159,6 @@ def test_batch_sharded_kernels_compile_for_four_chips(topo, which):
 
     from comfyui_parallelanything_tpu.parallel.mesh import mesh_context
 
-    att = importlib.import_module("comfyui_parallelanything_tpu.ops.attention")
     mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
     rows = NamedSharding(mesh, P("data"))
     # The kl-f8 VAE's mid-block attention for 8 images of 512²: one 512-wide head.
@@ -171,7 +169,6 @@ def test_batch_sharded_kernels_compile_for_four_chips(topo, which):
         q = jax.ShapeDtypeStruct((16, 4096, 8, 40), jnp.bfloat16, sharding=rows)
     fn = {
         "in-repo": lambda q, k, v: flash_attention(q, k, v, interpret=False),
-        "upstream": lambda q, k, v: att._pallas_jax_attention(q, k, v, 0.04),
         "in-repo-unet": lambda q, k, v: flash_attention(
             q, k, v, block_q=256, block_k=4096, interpret=False),
     }[which]
@@ -182,13 +179,3 @@ def test_batch_sharded_kernels_compile_for_four_chips(topo, which):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and "all-gather" not in hlo
     assert compiled.output_shardings.spec == P("data")
-
-
-def test_upstream_flash_kernel_compiles_at_flux_shape(one_chip):
-    # ops/__init__ exports a function named ``attention`` that shadows the
-    # module attribute.
-    att = importlib.import_module("comfyui_parallelanything_tpu.ops.attention")
-    compiled = jax.jit(
-        lambda q, k, v: att._pallas_jax_attention(q, k, v, 128 ** -0.5)
-    ).lower(*_qkv(one_chip, 1, 4608, 24, 128)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
