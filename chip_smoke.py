@@ -574,6 +574,25 @@ def serve_phase(graph: dict, seeds=(42, 7, 42), want_device: str | None = "tpu:0
     return summary
 
 
+def _saved_files(images, paths) -> dict:
+    """Where the save node's filter program ran (it takes the images where
+    they are: a sharded batch is not gathered first), and whether the files
+    decode to numpy's quantise of the fetched floats, to the bit."""
+    from PIL import Image
+
+    from comfyui_parallelanything_tpu.utils.png_encode import filter_program
+
+    want = (np.clip(np.asarray(images), 0.0, 1.0) * 255.0 + 0.5).astype(np.uint8)
+    return {
+        "image_devices": sorted(d.id for d in images.sharding.device_set),
+        "filtered_devices": sorted(
+            d.id for d in filter_program(images)[1].sharding.device_set),
+        "files_exact": len(paths) == len(want) and all(
+            np.array_equal(np.asarray(Image.open(p)), w)
+            for p, w in zip(paths, want)),
+    }
+
+
 def chain_phase(devs, graph: dict | None = None) -> None:
     """The cross-chip path and what it is compared with: the stock graph with
     a ParallelDeviceList + ParallelAnything chain over four chips against the
@@ -601,11 +620,13 @@ def chain_phase(devs, graph: dict | None = None) -> None:
         t0 = time.monotonic()
         out = pa.run_workflow(wf)
         latent = out["3"][0]["samples"]
+        images = out["8"][0]
         results[name] = {
             "latent": np.asarray(latent, np.float32),
-            "images": np.asarray(out["8"][0], np.float32),
+            "images": np.asarray(images, np.float32),
             "devices": sorted(d.id for d in latent.sharding.device_set),
             "wall_s": round(time.monotonic() - t0, 2),
+            **_saved_files(images, out["9"][0]),
         }
         if name == "chain":
             in_use = [_bytes_in_use(d) for d in devs]
@@ -617,8 +638,13 @@ def chain_phase(devs, graph: dict | None = None) -> None:
          single_devices=one["devices"], bytes_in_use_before=before,
          bytes_in_use_after=in_use, latent_rel_err=lat_err,
          image_max_abs_err=img_err, chain_wall_s=chain["wall_s"],
-         single_wall_s=one["wall_s"])
+         single_wall_s=one["wall_s"],
+         **{f"{name}_{k}": results[name][k] for name in results
+            for k in ("image_devices", "filtered_devices", "files_exact")})
     assert chain["devices"] == sorted(d.id for d in devs), chain["devices"]
+    for r in results.values():
+        assert r["filtered_devices"] == r["image_devices"], r
+        assert r["files_exact"], "a saved PNG is not the quantised floats"
     assert all(a > b for a, b in zip(in_use, before)), (before, in_use)
     assert np.isfinite(chain["latent"]).all()
     assert lat_err < 5e-2, f"chain off the single chip: rel err {lat_err}"

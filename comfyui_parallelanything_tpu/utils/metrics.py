@@ -132,6 +132,12 @@ took the fused flash kernel; ``resolved_backends()`` is the same fact as a
 set). ``pa_attention_padded_total{backend=}`` (PR 26) counts, the same way,
 the calls among them whose sequence length was not a multiple of 128 and was
 padded and masked to reach the kernel (SD3's joint text + image tokens).
+
+PNG encoder (PR 29): ``pa_png_images_total`` / ``pa_png_strips_total``
+(utils/png_encode.py ``write_pngs`` — once a save node's call, always on:
+the files written and the row strips deflated for them on the pool's
+threads; their ratio is strips a file, 1 where images are too small or too
+many for strips to engage).
 """
 
 from __future__ import annotations

@@ -906,8 +906,7 @@ class TestDenoiseSite:
 
 
 class TestSaveStages:
-    @pytest.mark.parametrize("k", [1, 3])
-    def test_save_splits_into_wait_fetch_and_k_encodes(self, k, tmp_path):
+    def _save(self, k, tmp_path):
         from comfyui_parallelanything_tpu.nodes import TPUSaveImage
 
         images = jnp.asarray(np.random.default_rng(1).uniform(
@@ -916,27 +915,50 @@ class TestSaveStages:
         with tracing.span("workflow-node", cat="graph", class_type="SaveImage",
                           prompt_id="p") as node:
             (paths,) = TPUSaveImage().save(images, output_dir=str(tmp_path))
+        return images, paths, node
+
+    @pytest.mark.parametrize("k", [1, 3])
+    def test_save_splits_into_wait_fetch_and_one_encode(self, k, tmp_path):
+        images, paths, node = self._save(k, tmp_path)
         xs = _x_events()
         parent = next(e for e in xs if e["name"] == "workflow-node")
-        parts = [e for e in xs if e["name"] != "workflow-node"]
-        assert sorted(e["name"] for e in parts) == sorted(
-            ["device-wait", "image-fetch"] + ["png-encode"] * k)
-        assert all(e["args"]["parent_span_id"] == node.span_id
-                   and e["cat"] == "graph" for e in parts)
+        # the node's own spans (a first call's `compile` of the filter
+        # program lies under `image-fetch`, in its own category)
+        parts = [e for e in xs if e["cat"] == "graph" and e is not parent]
+        assert sorted(e["name"] for e in parts) == [
+            "device-wait", "image-fetch", "png-encode"]
+        assert all(e["args"]["parent_span_id"] == node.span_id for e in parts)
         assert sum(e["dur"] for e in parts) <= parent["dur"]
         fetch = next(e for e in parts if e["name"] == "image-fetch")
-        assert fetch["args"]["bytes"] == k * 16 * 16 * 3 * 4
-        enc = sorted((e for e in parts if e["name"] == "png-encode"),
-                     key=lambda e: e["args"]["index"])
-        assert [e["args"]["index"] for e in enc] == list(range(k))
-        assert [e["args"]["bytes"] for e in enc] == [
-            Path(p).stat().st_size for p in paths]
+        # the filtered bytes, not the floats: a filter byte and 16 RGB pixels a row
+        assert fetch["args"]["bytes"] == k * 16 * (1 + 48)
+        enc = next(e for e in parts if e["name"] == "png-encode")["args"]
+        assert enc["images"] == k and enc["strips"] >= k and enc["threads"] >= 1
+        assert enc["bytes"] == sum(Path(p).stat().st_size for p in paths)
         # what is written is what the unsplit node wrote
         from PIL import Image
 
         want = (np.clip(np.asarray(images), 0, 1) * 255.0 + 0.5).astype(np.uint8)
         for p, w in zip(paths, want):
             np.testing.assert_array_equal(np.asarray(Image.open(p)), w)
+
+    def test_no_span_is_written_from_a_pool_thread(self, tmp_path, monkeypatch):
+        """The strips deflate on the pool's threads; every span of the node is
+        the prompt thread's, so summed spans read wall time, not thread time."""
+        from comfyui_parallelanything_tpu.utils import png_encode
+
+        ran_on = set()
+        deflate = png_encode._deflate
+
+        def spy(strip, last):
+            ran_on.add(threading.get_ident())
+            return deflate(strip, last)
+
+        monkeypatch.setattr(png_encode, "_deflate", spy)
+        self._save(3, tmp_path)
+        assert ran_on and threading.get_ident() not in ran_on
+        assert {e["tid"] for e in _x_events()} == {threading.get_ident()}
+        assert not ran_on & set(tracing.tracer._buffers)
 
 
 def _host_plane_events(log_dir) -> list:
