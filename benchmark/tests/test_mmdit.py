@@ -3,7 +3,6 @@ the layout's parameter counts against the published ones, the shape
 functions against a hand count, what ``describe`` reads off the graph, the
 reference against the program, and the whole command walked on the twin."""
 
-import importlib
 import json
 import os
 
@@ -176,17 +175,6 @@ def test_reference_agrees_with_the_program_in_float32(twin):
     assert not ok and nums[0]["value"] > 1.3 * nums[0]["limit"], nums
 
 
-@pytest.fixture
-def restorable(monkeypatch, tmp_path):
-    for target in run.load_json("configs", "sd35m-tiny")["program_presets"]:
-        mod_name, name = target.split(":")
-        mod = importlib.import_module(mod_name)
-        monkeypatch.setattr(mod, name, getattr(mod, name))
-    for var in ("PA_MODELS_DIR", "PA_OUTPUT_DIR", "PA_CLIP_VOCAB", "PA_CLIP_MERGES",
-                "PA_COMPILE_CACHE_MIN_S"):
-        monkeypatch.setenv(var, os.environ.get(var, ""))
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
-    monkeypatch.setattr(run, "WORK", str(tmp_path / "work"))
 
 
 @pytest.mark.parametrize("trace", [0, 1])

@@ -8,7 +8,7 @@ import json
 import os
 
 import run
-from test_run import _last_line, restorable  # noqa: F401 — the fixture
+from test_run import _last_line
 
 CELL = "sd15-b8-512.closed"  # the cell the tiny twin stands for
 NEW = {"sampler.denoiser_calls_per_request": "calls/request",

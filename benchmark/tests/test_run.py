@@ -1,13 +1,11 @@
 """The whole command walked on the CPU at tiny widths — and with the timed
 path broken underneath, where ``correct`` has to come out false."""
 
-import importlib
 import json
 import os
 import subprocess
 import sys
 
-import pytest
 
 import run
 
@@ -17,20 +15,6 @@ def _last_line(capsys):
     return json.loads(out[-1]), [json.loads(ln) for ln in out[:-1] if ln.startswith("{")]
 
 
-@pytest.fixture
-def restorable(monkeypatch, tmp_path):
-    """run.main() swaps the program's preset factories for the tiny twin's
-    and sets PA_* variables: register the originals so they come back."""
-    for cfg in ("sd15-tiny", "sdxl-tiny"):
-        for target in run.load_json("configs", cfg)["program_presets"]:
-            mod_name, name = target.split(":")
-            mod = importlib.import_module(mod_name)
-            monkeypatch.setattr(mod, name, getattr(mod, name))
-    for var in ("PA_MODELS_DIR", "PA_OUTPUT_DIR", "PA_CLIP_VOCAB", "PA_CLIP_MERGES",
-                "PA_COMPILE_CACHE_MIN_S"):
-        monkeypatch.setenv(var, os.environ.get(var, ""))
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
-    monkeypatch.setattr(run, "WORK", str(tmp_path / "work"))
 
 
 def test_rehearsal_prints_a_result_line_that_names_the_cpu(restorable, capsys):

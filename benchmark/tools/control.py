@@ -68,16 +68,12 @@ def main() -> None:
 
     import importlib
 
-    from yardstick import synth, traffic
-    from yardstick.tokenizer import BPE
+    from yardstick import traffic
 
     reference = importlib.import_module(f"yardstick.{config['reference']}")
     work = os.path.join(run.WORK, config["name"] + "-control")
     for seed in (int(s) for s in args.seeds.split(",")):
-        ckpt = os.path.join(work, config["checkpoint"]["file"])
-        synth.write_checkpoint(ckpt, seed, config)
-        vocab, merges = synth.write_tokenizer(
-            os.path.join(work, "tokenizer"), seed, config["text"]["vocab_size"])
+        ref_args, ref_kw, _, _ = run.synthesize(config, work, seed)
         schedule = traffic.Schedule(mix, seed, args.seconds)
         reqs, rows = run.pick_checked(mix, schedule, seed)
         for i in reqs:
@@ -89,7 +85,7 @@ def main() -> None:
             for p in ["float32", stated, *wanted]:
                 t = time.perf_counter()
                 images[p] = reference.Reference(
-                    config, ckpt, BPE(vocab, merges), p).images(req, rows)
+                    config, *ref_args, p, **ref_kw).images(req, rows)
                 secs[p] = round(time.perf_counter() - t, 2)
             for p in wanted:
                 print(json.dumps({

@@ -212,15 +212,15 @@ def open_clip_layout(c: dict) -> list[tuple]:
     return out
 
 
-def checkpoint_layout(config: dict) -> list[tuple]:
-    """Every tensor of the configuration's single file: the ``checkpoint``
-    list of the configuration names each part, its key prefix, which layout
+def checkpoint_layout(config: dict, parts: list | None = None) -> list[tuple]:
+    """Every tensor of one weight file: its ``parts`` (the configuration's
+    single file's unless given) name each part, its key prefix, which layout
     function describes it (``<name>_layout`` of the module
     ``yardstick.<layouts>`` the configuration names, so a new family brings
     its own module) and which group of sizes it reads."""
     mod = importlib.import_module(f"yardstick.{config['checkpoint']['layouts']}")
     out = []
-    for part in config["checkpoint"]["parts"]:
+    for part in config["checkpoint"]["parts"] if parts is None else parts:
         sizes = config[part["sizes"]]
         out += [(part["prefix"] + k, s, kind)
                 for k, s, kind in getattr(mod, part["layout"] + "_layout")(sizes)]

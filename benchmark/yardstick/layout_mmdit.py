@@ -10,10 +10,11 @@ both in the HF ``CLIPTextModel`` layout (bigG with its ``text_projection`` as a
 
 from __future__ import annotations
 
-from .layout import _lin, _norm, clip_hf_layout, vae_layout
+from .layout import _lin, _norm, clip_hf_layout, open_clip_layout, vae_layout
 
+# open_clip_layout: bigG as open_clip writes it, in a tower file of its own
 __all__ = ["mmdit_layout", "vae16_layout", "clip_hf_layout", "clip_g_hf_layout",
-           "hidden_size", "dual_layers"]
+           "open_clip_layout", "t5_layout", "hidden_size", "dual_layers"]
 
 
 def hidden_size(m: dict) -> int:
@@ -85,3 +86,35 @@ def clip_g_hf_layout(c: dict) -> list[tuple]:
     h = c["hidden_size"]
     return clip_hf_layout(c) + [
         ("text_projection.weight", (c["projection_dim"], h), f"w:{h}")]
+
+
+def t5_layout(t: dict) -> list[tuple]:
+    """The T5 v1.1 encoder as the public ``t5xxl`` files hold it (HF
+    ``T5EncoderModel`` keys, ``config.json`` size names): ``shared.weight``,
+    per block an RMS norm, bias-free q / k / v / o of inner width
+    ``num_heads * d_kv``, a second RMS norm and the gated feed-forward
+    ``wi_0`` / ``wi_1`` / ``wo``; the relative-position table in block 0 only
+    (every block with ``per_layer_bias``, UMT5); ``encoder.final_layer_norm``.
+    The embedding and the position table are drawn at unit variance (kernels of
+    fan-in 1): T5 adds the bias to unscaled logits and norms the embedding
+    before any product, so the small ``emb`` kind would switch both off."""
+    out: list[tuple] = []
+    d, ff, heads = t["d_model"], t["d_ff"], t["num_heads"]
+    inner = heads * t["d_kv"]
+    out.append(("shared.weight", (t["vocab_size"], d), "w:1"))
+    for i in range(t["num_layers"]):
+        a = f"encoder.block.{i}.layer.0"
+        for n in "qkv":
+            _lin(out, f"{a}.SelfAttention.{n}", d, inner, bias=False)
+        _lin(out, f"{a}.SelfAttention.o", inner, d, bias=False)
+        if i == 0 or t.get("per_layer_bias"):
+            out.append((f"{a}.SelfAttention.relative_attention_bias.weight",
+                        (t["relative_attention_num_buckets"], heads), "w:1"))
+        out.append((f"{a}.layer_norm.weight", (d,), "norm"))
+        f = f"encoder.block.{i}.layer.1"
+        _lin(out, f"{f}.DenseReluDense.wi_0", d, ff, bias=False)
+        _lin(out, f"{f}.DenseReluDense.wi_1", d, ff, bias=False)
+        _lin(out, f"{f}.DenseReluDense.wo", ff, d, bias=False)
+        out.append((f"{f}.layer_norm.weight", (d,), "norm"))
+    out.append(("encoder.final_layer_norm.weight", (d,), "norm"))
+    return out

@@ -176,11 +176,12 @@ def _span_count(a, ctx):
 
 def _module_events(ctx, group):
     """Durations (ns) of the group's module runs wholly inside the traced
-    window, on the chip that spent most time in them."""
+    window (the chip's last run, which the profiler's stop cut, is not), on
+    the chip that spent most time in them."""
     pat = ctx.patterns(group)
     if ctx.trace is None or not ctx.trace.chips or pat is None:
         return []
-    per_chip = [xplane.whole_events(c.modules, ctx.win(), pat)
+    per_chip = [xplane.whole_events(c.modules, ctx.win(), pat, xplane.last_start_ns(c))
                 for c in ctx.trace.chips.values()]
     return max(per_chip, key=sum)
 
@@ -212,9 +213,8 @@ def _trace_op_ms(a, ctx):
             pat = ctx.patterns(group)
             if pat is None:
                 return None
-            runs = [(s0, e0) for name, s0, e0 in chip.modules
-                    if re.search(pat, name) and ctx.win()
-                    and ctx.win()[0] <= s0 and e0 <= ctx.win()[1]]
+            runs = xplane.whole_runs(chip.modules, ctx.win(), pat,
+                                     xplane.last_start_ns(chip)) if ctx.win() else []
             if not runs:
                 continue
             total = sum(sum(xplane.time_by_name(chip.ops, w, a["pattern"]).values())
@@ -248,6 +248,13 @@ def peaks(ctx) -> dict:
     return table[ctx.device_kind]
 
 
+def _cost(a, ctx) -> dict:
+    """``flops`` and ``bytes`` of one run of what ``cost`` names, at the cell's
+    shapes, by the configuration's shape functions."""
+    mod = importlib.import_module(f"yardstick.{ctx.config['shape_functions']}")
+    return getattr(mod, a["cost"])(ctx.config, ctx.cell["mix"], ctx.chips)
+
+
 def _roofline_share(a, ctx):
     """Least time for one forward of the group's module at the cell's shapes
     (operations over peak FLOP/s against bytes over peak B/s, the larger) over
@@ -255,12 +262,37 @@ def _roofline_share(a, ctx):
     durs = _module_events(ctx, a["group"])
     if not durs:
         return None
-    mod = importlib.import_module(f"yardstick.{ctx.config['shape_functions']}")
-    cost = getattr(mod, a["cost"])(ctx.config, ctx.cell["mix"], ctx.chips)
-    pk = peaks(ctx)
+    cost, pk = _cost(a, ctx), peaks(ctx)
     least = max(cost["flops"] / pk["flops_per_s_bf16"],
                 cost["bytes"] / pk["hbm_bytes_per_s"])
     return 100.0 * least / (sum(durs) / len(durs) / 1e9)
+
+
+def _window_mfu(a, ctx):
+    """The traced window's share of the chip's peak FLOP/s: ``cost`` operations
+    for every run of the group's module in the window (a run cut by an edge
+    counts by the share of it that lies inside), over the window's length —
+    up to where the device trace stops — times the peak; the worst chip. All
+    else the window holds (the decode, the sampler's own programs, the gaps
+    between programs and between prompts) is time and no operations, so it
+    bounds every kernel's roofline from the whole step's side. No clamp."""
+    pat = ctx.patterns(a["group"])
+    if ctx.trace is None or not ctx.trace.chips or pat is None or ctx.win() is None:
+        return None
+    shares = []
+    for chip in ctx.trace.chips.values():
+        end = xplane.last_ns(chip)
+        durs = xplane.whole_events(chip.modules, ctx.win(), pat,
+                                   xplane.last_start_ns(chip))
+        if not durs:
+            continue
+        win = (ctx.win()[0], min(ctx.win()[1], end))
+        inside = sum(xplane.time_by_name(chip.modules, win, pat).values())
+        runs = inside / (sum(durs) / len(durs))
+        shares.append(runs / ((win[1] - win[0]) / 1e9))
+    if not shares:
+        return None
+    return 100.0 * min(shares) * _cost(a, ctx)["flops"] / peaks(ctx)["flops_per_s_bf16"]
 
 
 READERS = {
@@ -272,6 +304,7 @@ READERS = {
     "trace_op_ms": _trace_op_ms,
     "trace_idle_share": _trace_idle_share,
     "roofline_share": _roofline_share,
+    "window_mfu": _window_mfu,
 }
 
 

@@ -23,11 +23,21 @@ float32 whatever the mode; attention one head at a time (the same sums; the
 T5-XXL tower the context is the 77 CLIP tokens, as ComfyUI conditions
 (``sd3_clip.py``: ``out = lg_out``) — Stability's ``sd3_infer.py`` appends 77
 zero rows in T5's place instead, which this graph's host does not.
+
+A configuration with a ``text_t5`` block (and a ``TripleCLIPLoader`` in its
+graph) has the third tower: the context is then the CLIP-L ⊕ bigG stream
+zero-padded to ``joint_attention_dim`` (2048 → 4096 at published widths),
+concatenated ALONG THE TOKENS with the T5 encoder's final states
+(``reference_t5.py``; ``sd3_clip.py``: ``torch.cat([lg_out, t5_out], dim=-2)``),
+the T5 ids from the configuration's named tokenizer ``t5`` at its
+``max_length`` (77: ComfyUI's SD3 T5 tokenizer pads to at least 77 and does
+not truncate; a prompt of the traffic has far fewer pieces). The towers then
+come from files of their own (``checkpoint.files``), each read in the layout
+its part names.
 """
 
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -36,6 +46,7 @@ import numpy as np
 from jax import lax
 
 from . import reference_sd as sd
+from . import reference_t5, synth
 from .layout_mmdit import dual_layers
 from .reference_sd import F32, PRECISIONS
 
@@ -268,7 +279,9 @@ def describe(graph: dict) -> dict:
     """What ComfyUI's SD3.5 txt2img graph asks for, read off the graph as
     sent: one KSampler fed by the checkpoint loader directly (the family's
     shift, no ModelSamplingSD3 node), an EmptySD3LatentImage, two text
-    prompts, one untiled VAEDecode."""
+    prompts encoded by the towers of one loader (``text_loader``: the
+    checkpoint's bundled pair, or a ``TripleCLIPLoader``'s three files), one
+    untiled VAEDecode."""
     ks = [(i, n) for i, n in graph.items() if n["class_type"] == "KSampler"]
     if len(ks) != 1:
         raise ValueError("the reference reads graphs with exactly one KSampler")
@@ -286,10 +299,15 @@ def describe(graph: dict) -> dict:
            if n["class_type"].startswith("VAEDecode") and n["inputs"]["samples"][0] == ks_id]
     if dec != ["VAEDecode"]:
         raise ValueError("the reference reads graphs with one untiled VAEDecode")
+    texts = [node(k["positive"]), node(k["negative"])]
+    loaders = {node(t["inputs"]["clip"])["class_type"] for t in texts}
+    if len(loaders) != 1 or not loaders <= {"CheckpointLoaderSimple", "TripleCLIPLoader"}:
+        raise ValueError(f"the reference does not know the text loaders {sorted(loaders)}")
     return {"seed": k["seed"], "steps": k["steps"], "cfg": k["cfg"],
             "sampler_name": k["sampler_name"], "scheduler": k["scheduler"],
-            "positive": node(k["positive"])["inputs"]["text"],
-            "negative": node(k["negative"])["inputs"]["text"], **latent["inputs"]}
+            "positive": texts[0]["inputs"]["text"],
+            "negative": texts[1]["inputs"]["text"],
+            "text_loader": loaders.pop(), **latent["inputs"]}
 
 
 class Reference:
@@ -297,24 +315,37 @@ class Reference:
     the device in the checkpoint's own type, once per part, and are dropped
     with the object."""
 
-    def __init__(self, config: dict, checkpoint: str, tokenizer, precision: str):
-        from . import safetensors_io
-
+    def __init__(self, config: dict, checkpoint: str, tokenizer, precision: str,
+                 tokenizers: dict | None = None, files: dict | None = None):
         if precision not in PRECISIONS:
             raise ValueError(f"precision {precision!r} not in {PRECISIONS}")
         self.c, self.p, self.tok = config, precision, tokenizer
-        self._read = functools.partial(safetensors_io.read, checkpoint)
+        self.toks = tokenizers or {}
+        # (file's path, part) by the group of sizes the part reads
+        self._parts = {
+            part["sizes"]: ((files or {}).get(spec["file"], checkpoint), part)
+            for spec in synth.checkpoint_files(config) for part in spec["parts"]}
 
     def _part(self, sizes: str) -> dict:
-        prefix = next(q["prefix"] for q in self.c["checkpoint"]["parts"]
-                      if q["sizes"] == sizes)
-        return sd.load_weights(self._read(prefix))
+        from . import safetensors_io
+
+        path, part = self._parts[sizes]
+        return sd.load_weights(safetensors_io.read(path, part["prefix"]))
+
+    def t5_states(self, ids, mask):
+        """The third tower's final states for ids (N, S); a method of its own
+        so that a test can put a broken tower in its place."""
+        t = self.c["text_t5"]
+        w = self._part("text_t5")
+        return reference_t5.encode(self.p, w, t, ids,
+                                   mask if t.get("attention_mask", True) else None)
 
     def encode(self, texts: list[str]):
-        """→ (context (N, 77, joint_attention_dim), y (N, pooled)): CLIP-L ⊕
-        bigG penultimate streams zero-padded to the context width; L pooled
-        (the final-normed state at the first EOS, not projected) ⊕ G pooled
-        (the same through ``text_projection``)."""
+        """→ (context (N, 77 or 77 + T5's length, joint_attention_dim), y (N,
+        pooled)): CLIP-L ⊕ bigG penultimate streams zero-padded to the context
+        width, then (with a ``text_t5`` block) the T5 states appended along
+        the tokens; L pooled (the final-normed state at the first EOS, not
+        projected) ⊕ G pooled (the same through ``text_projection``)."""
         c, p = self.c, self.p
         eos = c["text"]["vocab_size"] - 1
         out = []
@@ -324,16 +355,23 @@ class Reference:
             ids = jnp.asarray(np.stack(
                 [self.tok.ids(s, t["max_position_embeddings"], **kw) for s in texts]))
             w = self._part(sizes)
-            _, pen, pooled = sd.clip_hf_text(p, w, ids, t)
-            if "text_projection.weight" in w:
-                pooled = sd._ein(p, "bi,oi->bo", pooled,
-                                 w["text_projection.weight"].astype(F32))
+            if self._parts[sizes][1]["layout"] == "open_clip":
+                _, pen, pooled = sd.open_clip_text(p, w, ids, t)
+            else:
+                _, pen, pooled = sd.clip_hf_text(p, w, ids, t)
+                if "text_projection.weight" in w:
+                    pooled = sd._ein(p, "bi,oi->bo", pooled,
+                                     w["text_projection.weight"].astype(F32))
             out.append((pen, pooled))
             del w
         (pen_l, pool_l), (pen_g, pool_g) = out
         joint = jnp.concatenate([pen_l, pen_g], axis=-1)
         width = c["mmdit"]["joint_attention_dim"]
         context = jnp.pad(joint, ((0, 0), (0, 0), (0, width - joint.shape[-1])))
+        if "text_t5" in c:
+            ids = np.stack([self.toks["t5"].ids(s) for s in texts])
+            states = self.t5_states(jnp.asarray(ids), ids != 0)  # <pad> is id 0
+            context = jnp.concatenate([context, states.astype(F32)], axis=1)
         return context, jnp.concatenate([pool_l, pool_g], axis=-1)
 
     def images(self, req: dict, rows: list[int]) -> np.ndarray:
@@ -343,6 +381,9 @@ class Reference:
         if (req["sampler_name"], req["scheduler"]) != ("euler", "sgm_uniform"):
             raise NotImplementedError(
                 f"reference has no {req['sampler_name']}/{req['scheduler']}")
+        triple = req.get("text_loader") == "TripleCLIPLoader"
+        if triple != ("text_t5" in c):
+            raise ValueError("a text_t5 block and a TripleCLIPLoader go together")
         context, y = self.encode([req["positive"], req["negative"]])
         h8, w8 = req["height"] // 8, req["width"] // 8
         # The served path draws the whole batch's noise as one NHWC array

@@ -9,11 +9,12 @@ from __future__ import annotations
 import json
 import struct
 
+import ml_dtypes  # numpy's bfloat16; a dependency of jax
 import numpy as np
 
-_DTYPES = {"F16": np.float16, "F32": np.float32, "BF16": None, "I64": np.int64}
-_NAMES = {np.dtype(np.float16): "F16", np.dtype(np.float32): "F32",
-          np.dtype(np.int64): "I64"}
+_DTYPES = {"F16": np.float16, "F32": np.float32, "BF16": ml_dtypes.bfloat16,
+           "I64": np.int64}
+_NAMES = {np.dtype(v): k for k, v in _DTYPES.items()}
 
 
 def write(path: str, entries) -> int:
@@ -36,7 +37,8 @@ def write(path: str, entries) -> int:
         f.write(blob)
         for _, _, _, chunks in entries:
             for c in chunks:
-                f.write(memoryview(np.ascontiguousarray(c)).cast("B"))
+                # as bytes: bfloat16 has no buffer-protocol format of its own
+                f.write(memoryview(np.ascontiguousarray(c).reshape(-1).view(np.uint8)))
     return total
 
 
@@ -52,8 +54,6 @@ def read(path: str, prefix: str = "") -> dict[str, np.ndarray]:
         if name == "__metadata__" or not name.startswith(prefix):
             continue
         dtype = _DTYPES[meta["dtype"]]
-        if dtype is None:
-            raise ValueError(f"{name}: dtype {meta['dtype']} not supported here")
         a, b = meta["data_offsets"]
         out[name[len(prefix):]] = data[a:b].view(dtype).reshape(meta["shape"])
     return out
