@@ -26,6 +26,7 @@ from __future__ import annotations
 from collections.abc import Mapping
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -61,6 +62,24 @@ def to_numpy(t: Any) -> np.ndarray:
     return np.asarray(t, dtype=np.float32)
 
 
+def resident(t: Any, operand_dtype: Any = None):
+    """A matmul kernel or embedding table in the type it stays resident in —
+    the load policy of the families whose towers do not fit a chip in float32
+    (the FLUX denoiser, T5-XXL): bfloat16 where the file stores bfloat16
+    (widening it back is exact, whatever type the module computes in) or where
+    the module casts its operands to bfloat16 at every use (rounded once here:
+    the same values); float32 otherwise, as every other family's loader keeps
+    it. The cast runs on the device, one tensor at a time, so a 16-bit file
+    never exists whole in float32 on the host or the chip."""
+    if not isinstance(t, (np.ndarray, jax.Array)):
+        t = to_numpy(t)
+    if t.dtype == jnp.bfloat16:
+        return jnp.asarray(t)
+    if operand_dtype is not None and jnp.dtype(operand_dtype) == jnp.bfloat16:
+        return jnp.asarray(t).astype(jnp.bfloat16)
+    return to_numpy(t)
+
+
 # --------------------------------------------------------------------------------------
 # Layout transforms (torch → flax)
 # --------------------------------------------------------------------------------------
@@ -76,9 +95,10 @@ def conv_kernel(w: Any) -> np.ndarray:
     return to_numpy(w).transpose(2, 3, 1, 0)
 
 
-def qkv_kernel(w: Any, heads: int, head_dim: int) -> np.ndarray:
-    """Fused qkv (3·H·D, in) → DenseGeneral kernel (in, 3, H, D)."""
-    arr = to_numpy(w)
+def qkv_kernel(w: Any, heads: int, head_dim: int, operand_dtype: Any = None):
+    """Fused qkv (3·H·D, in) → DenseGeneral kernel (in, 3, H, D); in its
+    resident type where ``operand_dtype`` is given (``resident``)."""
+    arr = to_numpy(w) if operand_dtype is None else resident(w, operand_dtype)
     in_dim = arr.shape[1]
     return arr.reshape(3, heads, head_dim, in_dim).transpose(3, 0, 1, 2)
 
@@ -198,9 +218,13 @@ def bake_lora(
 # --------------------------------------------------------------------------------------
 
 
-def dense_params(sd: Mapping[str, Any], key: str) -> dict:
-    """torch ``{key}.weight``/``.bias`` → flax Dense ``kernel``/``bias``."""
-    out = {"kernel": linear_kernel(sd[f"{key}.weight"])}
+def dense_params(sd: Mapping[str, Any], key: str, operand_dtype: Any = None) -> dict:
+    """torch ``{key}.weight``/``.bias`` → flax Dense ``kernel``/``bias``. With
+    ``operand_dtype`` (the type the module computes in) the kernel stays in
+    its resident type (``resident``); the bias is float32 either way."""
+    w = sd[f"{key}.weight"]
+    out = {"kernel": linear_kernel(w) if operand_dtype is None
+           else resident(w, operand_dtype).T}
     if f"{key}.bias" in sd:
         out["bias"] = to_numpy(sd[f"{key}.bias"])
     return out
@@ -213,17 +237,17 @@ def tree_to_jnp(tree: Any) -> Any:
     return jnp.asarray(tree)
 
 
-def _mlp_embedder(sd: Mapping[str, Any], prefix: str) -> dict:
-    return {
-        "in_layer": {
-            "kernel": linear_kernel(sd[f"{prefix}.in_layer.weight"]),
-            "bias": to_numpy(sd[f"{prefix}.in_layer.bias"]),
-        },
-        "out_layer": {
-            "kernel": linear_kernel(sd[f"{prefix}.out_layer.weight"]),
-            "bias": to_numpy(sd[f"{prefix}.out_layer.bias"]),
-        },
-    }
+def flux_depths(keys) -> tuple[int, int]:
+    """(double blocks, single blocks) a FLUX-layout file holds, from its key
+    names (bare or under ``model.diffusion_model.``): a depth cut of a
+    published model — a contiguous block range, one pipeline stage's share —
+    loads at the depth it has."""
+    def count(kind):
+        idx = [int(k.split(f"{kind}.", 1)[1].split(".", 1)[0])
+               for k in keys if f"{kind}." in k]
+        return 1 + max(idx) if idx else 0
+
+    return count("double_blocks"), count("single_blocks")
 
 
 def convert_flux_checkpoint(
@@ -233,53 +257,80 @@ def convert_flux_checkpoint(
     lora_strength: float = 1.0,
 ) -> dict:
     """Official FLUX state dict (flux1-dev/schnell layout) → the param pytree of
-    ``models.flux.FluxModel``. LoRA, when given, is baked first (992-1004 parity)."""
+    ``models.flux.FluxModel``. LoRA, when given, is baked first (992-1004 parity).
+
+    Matmul kernels stay in their resident type (``resident``: bfloat16 from a
+    bfloat16 file or under bfloat16 compute); norm scales and biases are
+    float32. The file orders a token's 2×2-patch features (c, ph, pw) — BFL's
+    ``rearrange(img, "b c (h ph) (w pw) -> b (h w) (c ph pw)")`` — and the
+    model's patchify orders them (ph, pw, c): ``img_in``'s input rows and the
+    final projection's output columns are permuted here, once."""
     sd = dict(state_dict)
     if lora_sd:
         sd = bake_lora(sd, lora_sd, lora_strength)
     H, D = cfg.num_heads, cfg.head_dim
+    dt, f32 = cfg.dtype, jnp.float32
     p: dict[str, Any] = {}
 
-    p["img_in"] = dense_params(sd, "img_in")
-    p["txt_in"] = dense_params(sd, "txt_in")
-    p["time_in"] = _mlp_embedder(sd, "time_in")
-    p["vector_in"] = _mlp_embedder(sd, "vector_in")
+    def dense(key, operand=dt):
+        return dense_params(sd, key, operand)
+
+    def embedder(prefix):
+        return {"in_layer": dense(f"{prefix}.in_layer"),
+                "out_layer": dense(f"{prefix}.out_layer")}
+
+    pp = cfg.patch_size ** 2
+    ch = cfg.in_channels // pp
+
+    def patch_order(a, axis):
+        """Features (c, ph, pw) → (ph, pw, c) along ``axis``."""
+        a = jnp.asarray(a)
+        shape = a.shape
+        a = a.reshape(shape[:axis] + (ch, pp) + shape[axis + 1:])
+        return jnp.swapaxes(a, axis, axis + 1).reshape(shape)
+
+    p["img_in"] = dense("img_in")
+    p["img_in"]["kernel"] = patch_order(p["img_in"]["kernel"], 0)
+    p["txt_in"] = dense("txt_in")
+    p["time_in"] = embedder("time_in")
+    p["vector_in"] = embedder("vector_in")
     if cfg.guidance_embed:
-        p["guidance_in"] = _mlp_embedder(sd, "guidance_in")
+        p["guidance_in"] = embedder("guidance_in")
+
+    def qkv(key):
+        return {"kernel": qkv_kernel(sd[f"{key}.weight"], H, D, dt),
+                "bias": qkv_bias(sd[f"{key}.bias"], H, D)}
+
+    def qk_norm(key):
+        return {"query_norm": to_numpy(sd[f"{key}.query_norm.scale"]),
+                "key_norm": to_numpy(sd[f"{key}.key_norm.scale"])}
 
     for i in range(cfg.depth):
         t = f"double_blocks.{i}"
         blk: dict[str, Any] = {}
         for stream in ("img", "txt"):
-            blk[f"{stream}_mod"] = {"lin": dense_params(sd, f"{t}.{stream}_mod.lin")}
-            blk[f"{stream}_attn_qkv"] = {
-                "kernel": qkv_kernel(sd[f"{t}.{stream}_attn.qkv.weight"], H, D),
-                "bias": qkv_bias(sd[f"{t}.{stream}_attn.qkv.bias"], H, D),
-            }
-            blk[f"{stream}_attn_norm"] = {
-                "query_norm": to_numpy(sd[f"{t}.{stream}_attn.norm.query_norm.scale"]),
-                "key_norm": to_numpy(sd[f"{t}.{stream}_attn.norm.key_norm.scale"]),
-            }
-            blk[f"{stream}_attn_proj"] = dense_params(sd, f"{t}.{stream}_attn.proj")
-            blk[f"{stream}_mlp_in"] = dense_params(sd, f"{t}.{stream}_mlp.0")
-            blk[f"{stream}_mlp_out"] = dense_params(sd, f"{t}.{stream}_mlp.2")
+            # the modulation computes in float32 (flux.Modulation)
+            blk[f"{stream}_mod"] = {"lin": dense(f"{t}.{stream}_mod.lin", f32)}
+            blk[f"{stream}_attn_qkv"] = qkv(f"{t}.{stream}_attn.qkv")
+            blk[f"{stream}_attn_norm"] = qk_norm(f"{t}.{stream}_attn.norm")
+            blk[f"{stream}_attn_proj"] = dense(f"{t}.{stream}_attn.proj")
+            blk[f"{stream}_mlp_in"] = dense(f"{t}.{stream}_mlp.0")
+            blk[f"{stream}_mlp_out"] = dense(f"{t}.{stream}_mlp.2")
         p[f"double_blocks_{i}"] = blk
 
     for i in range(cfg.depth_single_blocks):
         t = f"single_blocks.{i}"
         p[f"single_blocks_{i}"] = {
-            "modulation": {"lin": dense_params(sd, f"{t}.modulation.lin")},
-            "linear1": dense_params(sd, f"{t}.linear1"),
-            "linear2": dense_params(sd, f"{t}.linear2"),
-            "norm": {
-                "query_norm": to_numpy(sd[f"{t}.norm.query_norm.scale"]),
-                "key_norm": to_numpy(sd[f"{t}.norm.key_norm.scale"]),
-            },
+            "modulation": {"lin": dense(f"{t}.modulation.lin", f32)},
+            "linear1": dense(f"{t}.linear1"),
+            "linear2": dense(f"{t}.linear2"),
+            "norm": qk_norm(f"{t}.norm"),
         }
 
     # final_layer.adaLN_modulation.1 emits (shift, scale); our final_mod emits the
-    # same two chunks in the same order.
-    p["final_mod"] = dense_params(sd, "final_layer.adaLN_modulation.1")
-    p["final_proj"] = dense_params(sd, "final_layer.linear")
+    # same two chunks in the same order. Both final layers compute in float32.
+    p["final_mod"] = dense("final_layer.adaLN_modulation.1", f32)
+    p["final_proj"] = {k: patch_order(v, v.ndim - 1)
+                       for k, v in dense("final_layer.linear", f32).items()}
 
     return tree_to_jnp(p)

@@ -22,15 +22,8 @@ from typing import Any
 
 import numpy as np
 
-from .convert import linear_kernel, to_numpy, tree_to_jnp
+from .convert import dense_params, resident, to_numpy, tree_to_jnp
 from .text_encoders import CLIPTextConfig, T5Config
-
-
-def _dense(sd: Mapping[str, Any], key: str, bias: bool = True) -> dict:
-    out = {"kernel": linear_kernel(sd[f"{key}.weight"])}
-    if bias and f"{key}.bias" in sd:
-        out["bias"] = to_numpy(sd[f"{key}.bias"])
-    return out
 
 
 def _ln(sd: Mapping[str, Any], key: str) -> dict:
@@ -73,13 +66,13 @@ def convert_clip_text_checkpoint(
         t = f"text_model.encoder.layers.{i}"
         p[f"layers_{i}"] = {
             "ln1": _ln(sd, f"{t}.layer_norm1"),
-            "q": _dense(sd, f"{t}.self_attn.q_proj"),
-            "k": _dense(sd, f"{t}.self_attn.k_proj"),
-            "v": _dense(sd, f"{t}.self_attn.v_proj"),
-            "out": _dense(sd, f"{t}.self_attn.out_proj"),
+            "q": dense_params(sd, f"{t}.self_attn.q_proj"),
+            "k": dense_params(sd, f"{t}.self_attn.k_proj"),
+            "v": dense_params(sd, f"{t}.self_attn.v_proj"),
+            "out": dense_params(sd, f"{t}.self_attn.out_proj"),
             "ln2": _ln(sd, f"{t}.layer_norm2"),
-            "fc1": _dense(sd, f"{t}.mlp.fc1"),
-            "fc2": _dense(sd, f"{t}.mlp.fc2"),
+            "fc1": dense_params(sd, f"{t}.mlp.fc1"),
+            "fc2": dense_params(sd, f"{t}.mlp.fc2"),
         }
     if cfg.projection_dim is not None:
         # HF stores text_projection as a Linear (out,in); some exports as a matrix.
@@ -113,9 +106,9 @@ def convert_open_clip_checkpoint(
         blk: dict[str, Any] = {
             "ln1": _ln(sd, f"{t}.ln_1"),
             "ln2": _ln(sd, f"{t}.ln_2"),
-            "out": _dense(sd, f"{t}.attn.out_proj"),
-            "fc1": _dense(sd, f"{t}.mlp.c_fc"),
-            "fc2": _dense(sd, f"{t}.mlp.c_proj"),
+            "out": dense_params(sd, f"{t}.attn.out_proj"),
+            "fc1": dense_params(sd, f"{t}.mlp.c_fc"),
+            "fc2": dense_params(sd, f"{t}.mlp.c_proj"),
         }
         for j, n in enumerate("qkv"):
             blk[n] = {"kernel": w[j * H : (j + 1) * H].T, "bias": b[j * H : (j + 1) * H]}
@@ -129,11 +122,14 @@ def convert_open_clip_checkpoint(
 
 def convert_t5_checkpoint(state_dict: Mapping[str, Any], cfg: T5Config) -> dict:
     """HF T5 v1.1 layout → T5Encoder params (encoder stack only; decoder/lm_head
-    keys in full-model checkpoints are ignored)."""
+    keys in full-model checkpoints are ignored). The bias-free matmul kernels
+    and the embedding stay in their resident type (``convert.resident``:
+    bfloat16 under the tower's bfloat16 compute, so T5-XXL is 9.5 GB and never
+    whole in float32); norm scales and the position table are float32."""
     sd = _strip(state_dict, "encoder.final_layer_norm.weight")
     emb_key = "shared.weight" if "shared.weight" in sd else "encoder.embed_tokens.weight"
     p: dict[str, Any] = {
-        "tok_emb": {"embedding": to_numpy(sd[emb_key])},
+        "tok_emb": {"embedding": resident(sd[emb_key], cfg.dtype)},
         "final_ln": {"scale": to_numpy(sd["encoder.final_layer_norm.weight"])},
     }
     rel = ".layer.0.SelfAttention.relative_attention_bias.weight"
@@ -143,17 +139,21 @@ def convert_t5_checkpoint(state_dict: Mapping[str, Any], cfg: T5Config) -> dict:
             p[f"rel_bias_{i}"] = to_numpy(sd[f"encoder.block.{i}{rel}"])
     else:
         p["rel_bias"] = to_numpy(sd[f"encoder.block.0{rel}"])
+
+    def dense(key):
+        return dense_params(sd, key, cfg.dtype)
+
     for i in range(cfg.num_layers):
         t = f"encoder.block.{i}"
         p[f"blocks_{i}"] = {
             "ln1": {"scale": to_numpy(sd[f"{t}.layer.0.layer_norm.weight"])},
-            "q": _dense(sd, f"{t}.layer.0.SelfAttention.q", bias=False),
-            "k": _dense(sd, f"{t}.layer.0.SelfAttention.k", bias=False),
-            "v": _dense(sd, f"{t}.layer.0.SelfAttention.v", bias=False),
-            "o": _dense(sd, f"{t}.layer.0.SelfAttention.o", bias=False),
+            "q": dense(f"{t}.layer.0.SelfAttention.q"),
+            "k": dense(f"{t}.layer.0.SelfAttention.k"),
+            "v": dense(f"{t}.layer.0.SelfAttention.v"),
+            "o": dense(f"{t}.layer.0.SelfAttention.o"),
             "ln2": {"scale": to_numpy(sd[f"{t}.layer.1.layer_norm.weight"])},
-            "wi_0": _dense(sd, f"{t}.layer.1.DenseReluDense.wi_0", bias=False),
-            "wi_1": _dense(sd, f"{t}.layer.1.DenseReluDense.wi_1", bias=False),
-            "wo": _dense(sd, f"{t}.layer.1.DenseReluDense.wo", bias=False),
+            "wi_0": dense(f"{t}.layer.1.DenseReluDense.wi_0"),
+            "wi_1": dense(f"{t}.layer.1.DenseReluDense.wi_1"),
+            "wo": dense(f"{t}.layer.1.DenseReluDense.wo"),
         }
     return tree_to_jnp(p)

@@ -28,7 +28,7 @@ import numpy as np
 
 from ..utils.logging import get_logger
 from .api import DiffusionModel
-from .convert import bake_lora, convert_flux_checkpoint, to_numpy
+from .convert import bake_lora, convert_flux_checkpoint, flux_depths, to_numpy
 from .convert_unet import convert_sd_unet_checkpoint, strip_prefix
 from .flux import FluxConfig, build_flux
 from .unet import UNetConfig, build_unet
@@ -43,6 +43,27 @@ def params_nbytes(params) -> int:
     return sum(
         int(l.size) * l.dtype.itemsize for l in jax.tree.leaves(params)
     )
+
+
+def record_resident(model: str, params) -> None:
+    """``pa_params_resident_bytes{model=,dtype=}``: what a loaded pytree keeps
+    resident, by stored type, set once where a loader hands the pytree over —
+    the load policy (``convert.resident``) readable on a server's ``/metrics``
+    without a trace."""
+    import jax
+
+    from ..utils.metrics import registry
+
+    by_dtype: dict[str, int] = {}
+    for leaf in jax.tree.leaves(params):
+        name = str(leaf.dtype)
+        by_dtype[name] = by_dtype.get(name, 0) + int(leaf.size) * leaf.dtype.itemsize
+    for name, nbytes in by_dtype.items():
+        registry.gauge(
+            "pa_params_resident_bytes", nbytes,
+            labels={"model": model, "dtype": name},
+            help="bytes of a loaded model's parameters by stored type",
+        )
 
 
 def pin_params_host(params, device=None):
@@ -145,10 +166,46 @@ def load_safetensors(path: str | os.PathLike) -> dict[str, np.ndarray]:
     return out
 
 
-def _resolve_state_dict(src: Any) -> dict[str, Any]:
-    """Accept a path to .safetensors or an in-memory {name: tensor} mapping."""
+_SAFETENSORS_DTYPES = {
+    "F64": "float64", "F32": "float32", "F16": "float16", "BF16": "bfloat16",
+    "F8_E4M3": "float8_e4m3fn", "F8_E5M2": "float8_e5m2",
+    "I64": "int64", "I32": "int32", "I16": "int16", "I8": "int8",
+    "U8": "uint8", "BOOL": "bool",
+}
+
+
+def open_safetensors(path: str | os.PathLike) -> dict[str, np.ndarray]:
+    """Every tensor of a .safetensors file as a read-only view over a memory
+    map, in its STORED type: nothing is read until a tensor is used, and
+    nothing is widened. The load path of the families too large to pass
+    through float32 (``load_flux_checkpoint``, ``load_t5_checkpoint``), whose
+    converters take each tensor to its resident type one at a time."""
+    import json
+    import struct
+
+    import ml_dtypes  # numpy's bfloat16 / fp8; a dependency of jax
+
+    with open(os.fspath(path), "rb") as f:
+        (n,) = struct.unpack("<Q", f.read(8))
+        header = json.loads(f.read(n))
+    data = np.memmap(os.fspath(path), dtype=np.uint8, mode="r", offset=8 + n)
+    out: dict[str, np.ndarray] = {}
+    for key, meta in header.items():
+        if key == "__metadata__":
+            continue
+        name = _SAFETENSORS_DTYPES[meta["dtype"]]
+        dtype = np.dtype(getattr(ml_dtypes, name, None) or name)
+        a, b = meta["data_offsets"]
+        out[key] = data[a:b].view(dtype).reshape(meta["shape"])
+    return out
+
+
+def _resolve_state_dict(src: Any, stored: bool = False) -> dict[str, Any]:
+    """Accept a path to .safetensors or an in-memory {name: tensor} mapping.
+    ``stored``: a file's tensors in their stored types (``open_safetensors``)
+    instead of upcast to float32."""
     if isinstance(src, (str, os.PathLike)):
-        return load_safetensors(src)
+        return open_safetensors(src) if stored else load_safetensors(src)
     if isinstance(src, Mapping):
         return dict(src)
     raise TypeError(f"expected a path or state dict, got {type(src).__name__}")
@@ -213,9 +270,29 @@ def load_flux_checkpoint(
     lora_strength: float = 1.0,
     name: str = "flux",
 ) -> DiffusionModel:
-    """FLUX checkpoint (path or state dict, official BFL layout) → DiffusionModel."""
-    sd = _maybe_bake(_resolve_state_dict(src), lora, lora_strength)
-    return build_flux(cfg, name=name, params=convert_flux_checkpoint(sd, cfg))
+    """FLUX checkpoint (path or state dict, official BFL layout) → DiffusionModel.
+
+    A file is read in its stored types and each kernel taken to its resident
+    type on its own (``convert.resident``): the 12 B-parameter family is never
+    whole in float32. (A LoRA still bakes in float32 on the host.) The block
+    counts are facts of the file: a depth cut of a published model loads at
+    the depth it has, whatever ``cfg`` says."""
+    sd = _resolve_state_dict(src, stored=lora is None)
+    depths = flux_depths(sd)
+    if 0 not in depths and depths != (cfg.depth, cfg.depth_single_blocks):
+        import dataclasses
+
+        get_logger().info(
+            "aligning FLUX config to checkpoint: %d double + %d single blocks",
+            *depths,
+        )
+        cfg = dataclasses.replace(
+            cfg, depth=depths[0], depth_single_blocks=depths[1]
+        )
+    sd = _maybe_bake(sd, lora, lora_strength)
+    model = build_flux(cfg, name=name, params=convert_flux_checkpoint(sd, cfg))
+    record_resident(name, model.params)
+    return model
 
 
 def load_sd_unet_checkpoint(
@@ -229,7 +306,9 @@ def load_sd_unet_checkpoint(
     (``model.diffusion_model.*`` subtree selected automatically) or bare UNet dicts."""
     sd = strip_prefix(_resolve_state_dict(src))
     sd = _maybe_bake(sd, lora, lora_strength)
-    return build_unet(cfg, name=name, params=convert_sd_unet_checkpoint(sd, cfg))
+    model = build_unet(cfg, name=name, params=convert_sd_unet_checkpoint(sd, cfg))
+    record_resident(name, model.params)
+    return model
 
 
 def load_controlnet_checkpoint(
@@ -301,13 +380,14 @@ def sniff_model_family(state_dict: Mapping[str, Any]) -> str:
     if has("double_blocks."):
         if has("guidance_in."):
             return "flux-dev"
-        depth = 1 + max(
-            int(n.split(".")[1]) for n in names if n.startswith("double_blocks.")
-        )
-        # No guidance embed: schnell runs the full 19-double-block stack; the
-        # z-image proxy (flux.py z_image_turbo_config, depth 6/26) is the
+        depth, single = flux_depths(names)
+        # No guidance embed: schnell runs the full 19-double-block stack, and
+        # a depth cut of it (a contiguous block range, one pipeline stage's
+        # share) keeps its published 1 : 2 ratio of double to single blocks;
+        # the z-image proxy (flux.py z_image_turbo_config, depth 6/26) is the
         # shallow single-stream-dominant point of the family.
-        return "flux-schnell" if depth >= 12 else "zimage-turbo"
+        schnell = depth >= 12 or single == 2 * depth
+        return "flux-schnell" if schnell else "zimage-turbo"
     if has("joint_blocks."):
         if any(".x_block.attn2." in n for n in names):
             return "sd35-medium"  # dual-attention mmdit-x
@@ -412,7 +492,9 @@ def load_vae_checkpoint(
     if cfg is None:
         cfg = sniff_vae_config(sd)
     # convert_vae_checkpoint owns the prefix strip — no pre-strip here.
-    return build_vae(cfg, params=convert_vae_checkpoint(sd, cfg))
+    vae = build_vae(cfg, params=convert_vae_checkpoint(sd, cfg))
+    record_resident("vae", vae.params)
+    return vae
 
 
 def load_clip_text_checkpoint(src: Any, cfg=None, open_clip: bool = False):
@@ -429,18 +511,24 @@ def load_clip_text_checkpoint(src: Any, cfg=None, open_clip: bool = False):
     if cfg is None:
         cfg = open_clip_g_config() if open_clip else clip_l_config()
     convert = convert_open_clip_checkpoint if open_clip else convert_clip_text_checkpoint
-    return build_clip_text(cfg, params=convert(sd, cfg))
+    enc = build_clip_text(cfg, params=convert(sd, cfg))
+    record_resident("open-clip" if open_clip else "clip-text", enc.params)
+    return enc
 
 
 def load_t5_checkpoint(src: Any, cfg=None):
-    """T5 encoder checkpoint (HF layout) → TextEncoder (FLUX/WAN t5xxl)."""
+    """T5 encoder checkpoint (HF layout) → TextEncoder (FLUX/WAN t5xxl). A
+    file is read in its stored types, kernel by kernel (``convert.resident``):
+    T5-XXL's 4.76 B parameters are 19 GB in float32 and never exist so."""
     from .convert_text import convert_t5_checkpoint
     from .text_encoders import build_t5_encoder, t5_xxl_config
 
-    sd = _resolve_state_dict(src)
+    sd = _resolve_state_dict(src, stored=True)
     if cfg is None:
         cfg = t5_xxl_config()
-    return build_t5_encoder(cfg, params=convert_t5_checkpoint(sd, cfg))
+    enc = build_t5_encoder(cfg, params=convert_t5_checkpoint(sd, cfg))
+    record_resident("t5", enc.params)
+    return enc
 
 
 def load_wan_checkpoint(
@@ -549,4 +637,6 @@ def load_mmdit_checkpoint(src: Any, cfg, lora: Any = None,
         cfg = dataclasses.replace(
             cfg, x_block_self_attn_layers=attn2_layers, qk_norm=has_qk_norm
         )
-    return build_mmdit(cfg, name=name, params=convert_mmdit_checkpoint(sd, cfg))
+    model = build_mmdit(cfg, name=name, params=convert_mmdit_checkpoint(sd, cfg))
+    record_resident(name, model.params)
+    return model

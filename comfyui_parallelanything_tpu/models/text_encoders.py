@@ -296,10 +296,16 @@ class TextEncoder:
 
     def _jitted(self):
         if not hasattr(self, "_jit_cache"):
-            fn = jax.jit(
-                lambda p, *a, **kw: self.module.apply({"params": p}, *a, **kw)
+            def encode(p, *a, **kw):
+                return self.module.apply({"params": p}, *a, **kw)
+
+            # The XLA module is named for the tower (``jit_text_encode_
+            # T5Encoder`` / ``..._CLIPTextModel``), so a device trace tells a
+            # text tower's runs from the VAE's ``jit__lambda``.
+            encode.__name__ = encode.__qualname__ = (
+                f"text_encode_{type(self.module).__name__}"
             )
-            object.__setattr__(self, "_jit_cache", fn)
+            object.__setattr__(self, "_jit_cache", jax.jit(encode))
         return self._jit_cache
 
     def __call__(self, tokens, **kw):

@@ -379,6 +379,7 @@ class TPUCheckpointLoader:
             load_safetensors,
             load_sd_unet_checkpoint,
             load_vae_checkpoint,
+            open_safetensors,
             sd15_config,
             sd21_config,
             sd_vae_config,
@@ -412,7 +413,10 @@ class TPUCheckpointLoader:
                 return quantize_model(m)
             return m
 
-        sd = load_safetensors(ckpt_path)
+        flux_family = family in ("flux-dev", "flux-schnell", "zimage-turbo")
+        # The FLUX families are read in the file's stored types and never pass
+        # through float32 whole (models/loader.load_flux_checkpoint).
+        sd = (open_safetensors if flux_family else load_safetensors)(ckpt_path)
         if family.startswith("wan"):
             # WAN family: video DiT + causal 3D VAE (its own checkpoint file —
             # WAN releases don't bundle the VAE with the DiT weights).
@@ -519,9 +523,15 @@ class TPUCheckpointLoader:
                     "flux-schnell": flux_schnell_config,
                     "zimage-turbo": z_image_turbo_config,
                 }[family]()
-                model = load_flux_checkpoint(sd, cfg, lora, lora_strength)
+                model = load_flux_checkpoint(
+                    sd, cfg, lora, lora_strength, name=family
+                )
                 vae_cfg = flux_vae_config()
             model = maybe_quant(model)
+            if family == "flux-schnell":
+                # The host's FluxSchnell sampling settings: a discrete flow
+                # table at shift 1.0 (dev keeps the widget's 1.15).
+                model.sampler_prefs = {"shift": 1.0}
         if not load_vae:
             return model, None
         vae_sd = load_safetensors(vae_path) if vae_path else sd
@@ -772,19 +782,42 @@ class TPUTextEncode:
         # cached-vs-fresh is bitwise-equal and same-prompt requests share
         # one cond object (the serving tier's sibling-seed broadcast seam).
         from .models import embed_cache
+        from .utils.metrics import registry
 
-        ids, mask = tok([text])
-        if clip["type"] in ("t5", "umt5"):
-            context = embed_cache.cached_encode(
-                enc, clip.get("model_key"), clip["type"], ids, mask,
-                lambda: enc(jnp.asarray(ids, jnp.int32),
-                            mask=jnp.asarray(mask)),
+        tower = clip["type"]
+        ran = []
+
+        def cached(mask, compute):
+            return embed_cache.cached_encode(
+                enc, clip.get("model_key"), tower, ids, mask,
+                lambda: (ran.append(1), compute())[1],
             )
-            return ({"context": context, "pooled": None},)
-        last, penultimate, pooled = embed_cache.cached_encode(
-            enc, clip.get("model_key"), clip["type"], ids, None,
-            lambda: enc(jnp.asarray(ids, jnp.int32)),
+
+        # One span a tower a call: the host's tokenizer walk and the lookup,
+        # and on a miss the dispatch of the tower's program (its device time
+        # is the trace's, not the span's).
+        with tracing.span("text-encode", cat="graph", tower=tower) as sp:
+            ids, mask = tok([text])
+            if tower in ("t5", "umt5"):
+                # ``attention_mask`` False (the flux-dual wire): the source
+                # hands the tower no mask, so padded keys take part.
+                masked = clip.get("attention_mask", True)
+                out = cached(
+                    mask if masked else None,
+                    lambda: enc(jnp.asarray(ids, jnp.int32),
+                                mask=jnp.asarray(mask) if masked else None),
+                )
+            else:
+                out = cached(None, lambda: enc(jnp.asarray(ids, jnp.int32)))
+            cache = "miss" if ran else "hit"
+            sp.set(tokens=len(ids[0]), cache=cache)
+        registry.counter(
+            "pa_text_encode_total", labels={"tower": tower, "cache": cache},
+            help="text-tower encodes by tower and embed-cache outcome",
         )
+        if tower in ("t5", "umt5"):
+            return ({"context": out, "pooled": None},)
+        last, penultimate, pooled = out
         if clip_skip == 1:
             context = last
         elif clip_skip == 2:

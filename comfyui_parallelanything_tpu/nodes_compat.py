@@ -326,9 +326,8 @@ class DualCLIPLoader:
 
         loader = TPUCLIPLoader()
 
-        def clip_wire(name: str, encoder_type: str):
+        def clip_wire(name: str, encoder_type: str, **kw):
             path = resolve_model_file(name, "clip", "text_encoders")
-            kw = {}
             if encoder_type in ("t5", "umt5"):
                 tok_json = os.environ.get("PA_T5_TOKENIZER_JSON", "")
                 if not tok_json:
@@ -357,10 +356,14 @@ class DualCLIPLoader:
             swapped = "t5" not in n1 and "t5" in n2
             t5_name = clip_name2 if swapped else clip_name1
             l_name = clip_name1 if swapped else clip_name2
+            # The source's conditioner (BFL ``HFEmbedder``, schnell): T5 at
+            # 256 tokens, padded with id 0 and handed NO attention mask, so
+            # the padded keys take part in every softmax.
             return (
                 {
                     "type": "flux-dual",
-                    "t5": clip_wire(t5_name, "t5"),
+                    "t5": {**clip_wire(t5_name, "t5", max_len=256),
+                           "attention_mask": False},
                     "l": clip_wire(l_name, "clip-l"),
                     "tokenizer_error": None,
                 },
@@ -654,9 +657,11 @@ class VAELoader:
 class UNETLoader:
     """Stock diffusion-model-only loader (FLUX/WAN templates): (unet_name,
     weight_dtype) → MODEL. Family is sniffed off the keys like
-    CheckpointLoaderSimple; ``weight_dtype`` is accepted for workflow
-    compatibility but ignored — the load path's dtype policy (bf16 compute,
-    fp8 upcast-on-load, mirroring the reference's fp8 handling at
+    CheckpointLoaderSimple, and a FLUX file's block counts with it (a depth
+    cut loads at the depth it has: ``load_flux_checkpoint``); ``weight_dtype``
+    is accepted for workflow compatibility but ignored — the load path's
+    dtype policy (bf16 compute, FLUX kernels resident in bf16, fp8
+    upcast-on-load, mirroring the reference's fp8 handling at
     any_device_parallel.py:93-124) already covers every menu entry."""
 
     DESCRIPTION = "Stock-name bare diffusion-model loader (family sniffed)."

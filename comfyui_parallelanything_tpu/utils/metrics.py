@@ -138,6 +138,15 @@ PNG encoder (PR 29): ``pa_png_images_total`` / ``pa_png_strips_total``
 the files written and the row strips deflated for them on the pool's
 threads; their ratio is strips a file, 1 where images are too small or too
 many for strips to engage).
+
+Text towers and what a loader keeps resident (PR 32):
+``pa_text_encode_total{tower=,cache=}`` (nodes.py ``TPUTextEncode.encode`` —
+once a tower a call, always on: the embed cache's outcome, ``hit`` or
+``miss``, beside the ``text-encode`` span) and
+``pa_params_resident_bytes{model=,dtype=}`` (models/loader.py
+``record_resident`` — a gauge set once where a loader hands its pytree
+over: the bytes it keeps resident by stored type, bfloat16 kernels for the
+FLUX and T5 load paths, float32 for the others).
 """
 
 from __future__ import annotations

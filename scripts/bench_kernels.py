@@ -64,7 +64,23 @@ SHAPES = [
     ("sd35m-b1-1024.joint4173", 2, 4173, 24, 64),
     ("sd35m-b1-1024.self4096", 2, 4096, 24, 64),
     ("sd35m-b1-512.joint1101", 2, 1101, 24, 64),
+    # FLUX.1-schnell's one attention class at 1 x 1024² and CFG 1.0: 256 T5
+    # tokens + 4096 image tokens = 34 x 128, 24 heads of 128 — the
+    # ``lane-aligned`` row of ``route`` serves all 36 calls of a 4-step prompt
+    # at the cut's 9 blocks.
+    ("flux-schnell-b1-1024.joint4352", 1, 4352, 24, 128),
 ]
+
+# Shapes whose sweep is not the grid below: 4352 = 17 x 256, so only 128- and
+# 256-wide blocks divide it; the wider query blocks (what PR 25 read as the
+# winners at FLUX's class) pad the last one, and the whole row as one key
+# block stands beside the shipped 256 keys.
+COMBOS = {
+    "flux-schnell-b1-1024.joint4352": [
+        (256, 256), (512, 256), (1024, 256),
+        (256, 4352), (512, 4352), (1024, 4352),
+    ],
+}
 
 BLOCKS_Q = (128, 256, 512)
 BLOCKS_K = (256, 1024, 4096)
@@ -133,7 +149,7 @@ def _run_shapes(shapes, dev):
         rec = {"shape": label, "b": b, "seq": s, "heads": h, "head_dim": d,
                "platform": dev.platform, "device_kind": dev.device_kind,
                "ts": time.time()}
-        combos = _combos(s) if sweep else [(256, 256)]
+        combos = (COMBOS.get(label) or _combos(s)) if sweep else [(256, 256)]
         best = None  # (ms, bq, bk)
         for bq, bk in combos:
             try:

@@ -48,6 +48,23 @@ def _inv_mlp_embedder(params, prefix, sd):
 def _torch_layout_sd(cfg: FluxConfig, params) -> dict:
     """Model params → official FLUX checkpoint layout (the converter's inverse)."""
     sd: dict = {}
+    # The file orders a token's patch features (c, ph, pw), the model
+    # (ph, pw, c): the converter permutes img_in's inputs and the final
+    # projection's outputs, and this is its inverse.
+    pp = cfg.patch_size ** 2
+    ch = cfg.in_channels // pp
+
+    def file_order(a, axis):
+        a = np.asarray(a)
+        shape = a.shape
+        a = a.reshape(shape[:axis] + (pp, ch) + shape[axis + 1:])
+        return np.swapaxes(a, axis, axis + 1).reshape(shape)
+
+    params = {**params,
+              "img_in": {**params["img_in"],
+                         "kernel": file_order(params["img_in"]["kernel"], 0)},
+              "final_proj": {k: file_order(v, np.ndim(v) - 1)
+                             for k, v in params["final_proj"].items()}}
     _inv_dense(params["img_in"], "img_in", sd)
     _inv_dense(params["txt_in"], "txt_in", sd)
     _inv_mlp_embedder(params["time_in"], "time_in", sd)

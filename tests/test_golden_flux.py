@@ -247,8 +247,10 @@ class TFlux(tnn.Module):
         p = cfg.patch_size
         hp, wp = Hh // p, Ww // p
 
-        img = x.reshape(B, hp, p, wp, p, C).permute(0, 1, 3, 2, 4, 5)
-        img = img.reshape(B, hp * wp, p * p * C)
+        # BFL: rearrange(img, "b c (h ph) (w pw) -> b (h w) (c ph pw)") — a
+        # token's features run channel-first, then the patch's rows, columns.
+        img = x.reshape(B, hp, p, wp, p, C).permute(0, 1, 3, 5, 2, 4)
+        img = img.reshape(B, hp * wp, C * p * p)
         img = self.img_in(img)
         txt = self.txt_in(context)
 
@@ -277,7 +279,8 @@ class TFlux(tnn.Module):
         )
         img = t_modulate(_ln(img, cfg.hidden_size), shift, scale)
         img = self.final_layer.linear(img)
-        img = img.reshape(B, hp, wp, p, p, C).permute(0, 1, 3, 2, 4, 5)
+        # "b (h w) (c ph pw) -> b c (h ph) (w pw)", then back to NHWC
+        img = img.reshape(B, hp, wp, C, p, p).permute(0, 1, 4, 2, 5, 3)
         return img.reshape(B, Hh, Ww, C)
 
 
