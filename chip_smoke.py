@@ -375,14 +375,16 @@ def _rel_err(got, want) -> float:
 
 
 def kernel_phase(seed: int) -> None:
-    """The in-repo flash kernel, compiled by the chip's compiler, against the
-    plain XLA attention at FLUX-dev's 1024² joint-attention shape."""
+    """The in-repo flash kernel, compiled by the chip's compiler with the
+    blocks ``route`` names, against the plain XLA attention at FLUX-dev's
+    1024² joint-attention shape."""
     import jax
     import jax.numpy as jnp
 
     from comfyui_parallelanything_tpu.ops.pallas.flash_attention import (
         flash_attention,
     )
+    from comfyui_parallelanything_tpu.ops.pallas.tuning import route
 
     # ops/__init__ exports a function named ``attention`` that shadows the
     # module attribute.
@@ -392,11 +394,16 @@ def kernel_phase(seed: int) -> None:
     q, k, v = (jax.random.normal(key, shape, jnp.bfloat16)
                for key in jax.random.split(jax.random.key(seed), 3))
     scale = shape[-1] ** -0.5
+    chosen = route(shape[1], shape[1], shape[3], shape[0] * shape[2],
+                   on_tpu=True, chunk_threshold=2**27)
     t0 = time.monotonic()
-    got = jax.block_until_ready(flash_attention(q, k, v, interpret=False))
+    got = jax.block_until_ready(flash_attention(
+        q, k, v, block_q=chosen.block_q, block_k=chosen.block_k,
+        interpret=False))
     want = jax.block_until_ready(jax.jit(xla, static_argnums=3)(q, k, v, scale))
     err = _rel_err(got, want)
-    emit("kernel", shape=shape, dtype="bfloat16", rel_err=err,
+    emit("kernel", shape=shape, dtype="bfloat16", rule=chosen.rule,
+         blocks=(chosen.block_q, chosen.block_k), rel_err=err,
          finite=bool(np.isfinite(np.asarray(got, np.float32)).all()),
          seconds=round(time.monotonic() - t0, 2))
     assert np.isfinite(np.asarray(got, np.float32)).all()

@@ -215,15 +215,17 @@ def _log_interpreted_once() -> None:
     )
 
 
-def resolve_route(seq_q: int, seq_k: int, head_dim: int, batch_heads: int):
+def resolve_route(seq_q: int, seq_k: int, head_dim: int, batch_heads: int,
+                  itemsize: int = 2):
     """``route()`` of ops/pallas/tuning.py for a call in this process: on the
     default backend, under the process's pin and its chunk threshold. What
-    :func:`attention_local` executes and the planner records."""
+    :func:`attention_local` executes and the planner records (the planner at
+    bfloat16's item size, what the models compute in on the chip)."""
     from .pallas.tuning import route
 
     return route(
         seq_q, seq_k, head_dim, batch_heads, on_tpu=_pallas_available(),
-        pinned=_BACKEND, chunk_threshold=_chunk_threshold(),
+        pinned=_BACKEND, chunk_threshold=_chunk_threshold(), itemsize=itemsize,
     )
 
 
@@ -234,7 +236,8 @@ def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
     if scale is None:
         scale = q.shape[-1] ** -0.5
     chosen = resolve_route(
-        q.shape[1], k.shape[1], q.shape[-1], q.shape[0] * q.shape[2]
+        q.shape[1], k.shape[1], q.shape[-1], q.shape[0] * q.shape[2],
+        k.dtype.itemsize,
     )
     _RESOLVED.add(chosen.backend)
     # Once a trace, not once a forward: attention_local runs while a program
@@ -247,9 +250,21 @@ def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
              "traced (ops/attention.attention_local)",
     )
     if chosen.backend == "pallas":
-        from .pallas.flash_attention import flash_attention
+        from .pallas.flash_attention import flash_attention, key_split
         from .pallas.tuning import is_ragged
 
+        # The row of route() that decided, and whether the key axis of the
+        # kernel's grid is a single step (a head group's whole K and V in
+        # VMEM, no carried softmax state) or streams.
+        keys_a_block, _ = key_split(k.shape[1], chosen.block_k)
+        registry.counter(
+            "pa_attention_key_blocks_total",
+            labels={"rule": chosen.rule,
+                    "keys": "one" if keys_a_block >= k.shape[1] else "streamed"},
+            help="fused-kernel attention calls, counted like "
+                 "pa_attention_route_total, by the row of route() that named "
+                 "their blocks and whether the row of keys is one key block",
+        )
         if is_ragged(q.shape[1], k.shape[1]):
             registry.counter(
                 "pa_attention_padded_total", labels={"backend": chosen.backend},

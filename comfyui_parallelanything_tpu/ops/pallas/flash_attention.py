@@ -16,14 +16,18 @@ for the MXU/VMEM hierarchy:
   through VMEM one ``block_k`` tile at a time, so VMEM holds
   O(block_q + block_k), NOT O(seq_k), and the same kernel covers WAN-video
   lengths. A UNet's 4096 keys are ONE block (a head group's whole K and V,
-  3 MB each at SD1.5's width): the key axis of the grid is then a single step
-  and the softmax state never leaves the loop's values.
-- inside a key block the softmax walks ``_CHUNK_K`` keys at a time: the live
-  logits tile is (block_q, chunk) however long the block, and what is paid per
-  tile and not per logit (the state's rescale, the accumulator's
-  read-modify-write) is amortised over 2048 keys, not 256 (measured, v5e,
-  256 queries a block at SD1.5's (16, 4096, 8, 40): 41.5 ms with 256-key
-  blocks, 6.7 ms with the whole row; PERF.md §6, PR 25).
+  3 MB each at SD1.5's width), and so is every row that ``tuning.route``
+  finds short enough in bytes — FLUX's 4352 keys under a 128-wide head, 1.1
+  MB each (PR 33): the key axis of the grid is then a single step and the
+  softmax state never leaves the loop's values.
+- inside a key block the softmax walks equal tiles of about ``_CHUNK_K`` keys
+  (:func:`key_split`): the live logits tile is (block_q, tile) however long
+  the block, and what is paid per tile and not per logit (the state's
+  rescale, the accumulator's read-modify-write) is amortised over 2048 keys,
+  not 256 (measured, v5e, 256 queries a block at SD1.5's (16, 4096, 8, 40):
+  41.5 ms with 256-key blocks, 6.7 ms with the whole row; PERF.md §6, PR 25).
+  Tiles that divide the block exactly are preferred, so a row that is a
+  multiple of 128 keys is neither padded in HBM nor masked.
 - online-softmax state (f32 running max/sum/acc) is carried across key blocks
   in VMEM scratch (the key axis is the innermost, sequential one); the output
   tile is written once, on the last key block. No S×S materialization — HBM
@@ -55,6 +59,11 @@ _LANES = 128
 # keys 41.5, of 1024 keys 13.4, of 2048 keys 6.7; one 4096-key tile is no
 # faster and doubles the live logits.
 _CHUNK_K = 2048
+# How far over _CHUNK_K a tile may go so that equal tiles divide a key block
+# exactly (:func:`key_split`): a sixteenth, which takes in FLUX.1's 256 text +
+# 4096 image keys, 4352 = 2 x 2176. Measurement beside the lane-aligned table
+# in tuning.py; nothing longer was measured.
+_CHUNK_STRETCH = 16
 
 
 def _flash_kernel(
@@ -202,6 +211,29 @@ def _round_up(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
 
 
+def key_split(seq_k: int, block_k: int) -> tuple[int, int]:
+    """``(keys a key block, keys a softmax tile)`` of a call with ``seq_k``
+    keys asked to take ``block_k`` a block. A block is walked in equal tiles,
+    each a multiple of 128 keys where there are several: as many as
+    ``_CHUNK_K`` keys a tile need if those divide the block exactly (4096 =
+    2 x 2048, a ragged row's 4224 = 3 x 1408, FLUX-dev's 4608 = 3 x 1536),
+    else one fewer if THOSE do and are at most a sixteenth longer (4352 =
+    2 x 2176: nothing padded, nothing masked), else that many rounded up to
+    128, the block growing with them (the caller pads K and V and the kernel
+    masks). ``ceil(seq_k / keys a block)`` is the key axis of the grid: 1 where
+    the row is one key block."""
+    bk = min(block_k, _round_up(seq_k, 8))
+    tiles = -(-bk // _CHUNK_K)
+    if tiles == 1:
+        return bk, bk
+    for n in (tiles, tiles - 1):
+        if (bk % (n * _LANES) == 0
+                and bk // n <= _CHUNK_K + _CHUNK_K // _CHUNK_STRETCH):
+            return bk, bk // n
+    ck = _round_up(-(-bk // tiles), _LANES)
+    return tiles * ck, ck
+
+
 def _head_group(heads: int, head_dim: int) -> int:
     """Heads one grid step holds: the fewest whose widths fill whole 128-lane
     tiles (a block narrower than the array must be lane-aligned), else all —
@@ -221,12 +253,7 @@ def _flash_attention(q, k, v, *, scale: float, block_q: int, block_k: int,
     width = group * head_dim
 
     bq = min(block_q, _round_up(seq_q, 8))
-    bk = min(block_k, _round_up(seq_k, 8))
-    # A key block is walked in equal softmax tiles of at most _CHUNK_K keys:
-    # 4096 keys in two of 2048, a ragged row's 4224 in three of 1408.
-    tiles = -(-bk // _CHUNK_K)
-    ck = bk if tiles == 1 else _round_up(-(-bk // tiles), _LANES)
-    bk = tiles * ck
+    bk, ck = key_split(seq_k, block_k)
     # (B, S, H, D) -> (B, S, H·D): the layout the projections wrote, so XLA
     # folds this reshape into theirs and nothing is copied.
     q3 = _pad_to(q.reshape(batch, seq_q, heads * head_dim), 1, bq)

@@ -1,14 +1,18 @@
 """Which attention backend a call takes, and with which blocks: one rule.
 
 :func:`route` reads the call's shape (both sequence lengths, head dim, B·H),
-whether the backend is a TPU, the process's pin and the XLA family's logits
-threshold, and names the backend and the fused kernel's blocks.
+the operands' item size, whether the backend is a TPU, the process's pin and
+the XLA family's logits threshold, and names the backend and the fused
+kernel's blocks. Three rows: a sequence length that is not a multiple of 128
+(``ragged_route``), a head dim that is not (``padded_dim_route``), and the
+rest (``lane_aligned_route``: a head's whole row of keys as one key block as
+far as VMEM carries it).
 ``ops/attention.attention_local`` executes what it says and the planner
 (parallel/planner.py) records the same answer; nothing else decides. The
 reference gates its fused backends on the GPU generation
 (disable_flash_xformers, any_device_parallel.py:126-164); here the gate is the
 shape, with the v5e measurements each threshold and block size was set from
-beside the constant (``scripts/bench_kernels.py``; PERF.md §6, PRs 25 and 26).
+beside the constant (``scripts/bench_kernels.py``; PERF.md §6, PRs 25, 26, 33).
 A new threshold or block size is one more row of :func:`route`.
 """
 
@@ -16,9 +20,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
-# What the kernel takes where no row below names blocks: lane-aligned head
-# dims (the VAE's one 512-wide head, FLUX / WAN's 128-wide), and a pinned
-# ``pallas`` at a shape the rule leaves to XLA.
+# What a pinned ``pallas`` takes at a shape the rule leaves to XLA.
 DEFAULT_BLOCKS = (256, 256)
 
 
@@ -113,6 +115,66 @@ def ragged_route(seq_q: int, seq_k: int,
     return (384 if rows_q % 384 == 0 else 256), block_k
 
 
+# -- Head dims that are a multiple of 128 ----------------------------------------
+# One head is a grid step's whole lane group (FLUX / WAN's 128-wide heads, the
+# VAE decoder's one 512-wide head), so the bytes of a row of keys for one head
+# — keys x head dim x item size, what K takes in VMEM and V again — are what
+# the call shows of its cost there. Measured on the v5e, bfloat16, ms a call
+# (scripts/bench_kernels.py, my chip run, PR 33; PERF.md §6): the XLA path
+# ``auto`` would otherwise take, this kernel at the 256 x 256 blocks the row
+# had until then, streamed in key blocks of 2048 / 4096 / 8192 keys, and with
+# the row as ONE key block — each at 256 queries a block; last, the row as one
+# block again at 512 queries a block (other forms as noted):
+#
+#   (batch, queries, heads, head dim) keys  K a head   xla    256²   2048   4096   8192    one   512 queries
+#   flux-schnell-b1-1024 (1,  4352, 24, 128)  1.1 MB   5.175   8.174    —      —      —    1.620   1.720 (pads 4352 to 4608)
+#   flux-dev-1024        (1,  4608, 24, 128)  1.2 MB   4.910   9.144  2.492*   —      —    1.629   1.576
+#   flux-dev-1024, b 4   (4,  4608, 24, 128)  1.2 MB  21.732  36.502    —      —      —    6.196   6.009
+#   flux-schnell-b1-512  (1,  1280, 24, 128)  0.3 MB   0.326   0.809    —      —      —    0.307   0.355 (640 queries; 128: 0.301)
+#   wan cross, 512 keys  (1, 16384, 12, 128)  0.1 MB   1.085   2.045    —      —      —    0.617   0.520
+#   vae-b8-512           (8,  4096,  1, 512)  4 MB     2.050   3.008  1.750    —      —    1.609   1.593 (1024: 1.588)
+#   wan-480p-16f         (1, 16384, 12, 128)  4 MB    27.747  56.793 12.113 11.136 10.696  8.863   8.798 (streamed 10.258)
+#   wan-long-32k         (1, 32768, 12, 128)  8 MB   119.776 226.464    —   43.946 42.094 34.572     —   (streamed 40.326)
+#   vae-b1-1024          (1, 16384,  1, 512)  16 MB    4.053   5.677  3.238  3.160  3.137  2.992     —   (streamed 3.075; 1024: 3.062)
+#   (* two blocks of 2304 keys; wan-long-32k in two blocks of 16,384: 41.863)
+#
+# The softmax tiles inside the one block (flash_attention.key_split; the same
+# script with --chunk-k): FLUX's 4352 keys in 2 x 2176 (exact, as shipped)
+# 1.620, in 3 x 1536 (padded to 4608 and masked: the split before PR 33)
+# 1.634, in one tile 1.711, in 4 x 1152 (padded) 1.751; FLUX-dev's 4608 in
+# 3 x 1536 (exact) 1.629, in 4 x 1152 (exact) 1.828.
+#
+# At 256 x 256 the kernel loses to XLA at every class; with the row as one key
+# block it wins at every class, by 1.06x (1280 keys) to 3.5x: the online
+# softmax's rescale and the accumulator's read-modify-write are paid once a
+# key block, and with ONE block the state never leaves the loop's values (PR
+# 25's finding at the UNets' classes). Streaming costs 5–27% over one block
+# however long the blocks (wan-long-32k 41.9–43.9 in blocks of 4096 to 16,384
+# keys against 34.6), so a row is one key block as far as VMEM carries it: up
+# to LANE_ALIGNED_ONE_BLOCK_BYTES for one head's K, the largest measured that
+# the kernel's VMEM reckoning (44 MB there, doubled for its limit) keeps
+# under its 100 MB cap. The VAE's 16 MB row reads 2.992 as one block but
+# reckons 78 MB: not taken, it streams PADDED_DIM_BLOCKS' 4096 keys a block
+# (3.160: still 1.8x the 256 x 256 blocks). 512 queries a block are 1–3%
+# faster on a one-block row they divide, 6% slower where they pad it (4352 =
+# 8.5 x 512), and take twice as long to compile (20 s against 6 for one
+# wan-long-32k call, per call site): 256, as PADDED_DIM_BLOCKS. What 512 would
+# give a streamed row (3–8%) and a short row of keys under many queries (16%)
+# is left: no cell runs either.
+LANE_ALIGNED_ONE_BLOCK_BYTES = 8 << 20
+
+
+def lane_aligned_route(seq_k: int, head_dim: int,
+                       itemsize: int) -> tuple[int, int]:
+    """``(block_q, block_k)`` of the fused kernel at a head dim that is a
+    multiple of 128: the row of keys as ONE key block where one head's K is
+    at most LANE_ALIGNED_ONE_BLOCK_BYTES, else streamed; read from the bytes
+    the call shows, whatever model sent it."""
+    block_q, streamed_k = PADDED_DIM_BLOCKS
+    one_block = seq_k * head_dim * itemsize <= LANE_ALIGNED_ONE_BLOCK_BYTES
+    return block_q, seq_k if one_block else streamed_k
+
+
 class Route(NamedTuple):
     backend: str            # "xla" | "xla_chunked" | "pallas"
     block_q: int | None     # the fused kernel's blocks; None in the XLA family
@@ -121,12 +183,14 @@ class Route(NamedTuple):
 
 
 def route(seq_q: int, seq_k: int, head_dim: int, batch_heads: int, *,
-          on_tpu: bool, pinned: str = "auto", chunk_threshold: int) -> Route:
+          on_tpu: bool, pinned: str = "auto", chunk_threshold: int,
+          itemsize: int = 2) -> Route:
     """The backend and blocks of one attention call. A pin other than
     ``auto`` is served as pinned; off a TPU the XLA family; on one the fused
     kernel where the call's row of the shape rule has blocks for it. Inside
     the XLA family the logits are written out whole up to ``chunk_threshold``
-    elements (B·H·S_q·S_k) and in query chunks above."""
+    elements (B·H·S_q·S_k) and in query chunks above. ``itemsize`` is the
+    operands' (2: bfloat16, what the models compute in on the chip)."""
     # The call's row of the shape rule, and the blocks the fused kernel takes
     # there (None: the row leaves the call to the XLA family).
     if is_ragged(seq_q, seq_k):
@@ -134,7 +198,8 @@ def route(seq_q: int, seq_k: int, head_dim: int, batch_heads: int, *,
     elif head_dim % 128 != 0:
         row, blocks = "padded-dim", padded_dim_route(seq_q, seq_k, batch_heads)
     else:
-        row, blocks = "lane-aligned", DEFAULT_BLOCKS
+        row, blocks = "lane-aligned", lane_aligned_route(seq_k, head_dim,
+                                                         itemsize)
 
     def xla_family(rule: str) -> Route:
         chunked = batch_heads * seq_q * seq_k > chunk_threshold

@@ -132,6 +132,11 @@ took the fused flash kernel; ``resolved_backends()`` is the same fact as a
 set). ``pa_attention_padded_total{backend=}`` (PR 26) counts, the same way,
 the calls among them whose sequence length was not a multiple of 128 and was
 padded and masked to reach the kernel (SD3's joint text + image tokens).
+``pa_attention_key_blocks_total{rule=,keys=}`` (PR 33) counts, the same way,
+each call the fused kernel serves by the row of ``tuning.route`` that named
+its blocks and by whether its row of keys is ``one`` key block or
+``streamed``: ``lane-aligned`` / ``one`` moves by 9 while FLUX.1-schnell's
+step program at 3 + 6 blocks compiles.
 
 PNG encoder (PR 29): ``pa_png_images_total`` / ``pa_png_strips_total``
 (utils/png_encode.py ``write_pngs`` — once a save node's call, always on:

@@ -6,6 +6,8 @@ time); without a TPU it exits non-zero and measures nothing:
 
     python scripts/bench_kernels.py            # measure, append KERNEL_BENCH.json
     python scripts/bench_kernels.py --shape sd15-b8-512.self4096,sdxl-b1-1024.self4096
+    python scripts/bench_kernels.py --shape flux-schnell-b1-1024.joint4352 \
+        --blocks 256x4352 --chunk-k 1536   # one combination, 1536-key tiles
     KERNEL_SWEEP=0 python scripts/bench_kernels.py   # default blocks only
 
 Shapes cover the rungs that matter: the benchmark cells' UNet self-attention
@@ -27,6 +29,7 @@ there, with its lines.
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import sys
@@ -35,7 +38,8 @@ import time
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
-# (label, batch, seq, heads, head_dim)
+# (label, batch, seq, heads, head_dim[, keys]): self-attention unless the row
+# names its keys.
 SHAPES = [
     ("flux_1024_joint", 1, 4608, 24, 128),
     ("flux_b4", 4, 4608, 24, 128),
@@ -69,6 +73,15 @@ SHAPES = [
     # ``lane-aligned`` row of ``route`` serves all 36 calls of a 4-step prompt
     # at the cut's 9 blocks.
     ("flux-schnell-b1-1024.joint4352", 1, 4352, 24, 128),
+    # The other lane-aligned classes the rule's table in tuning.py names (PR
+    # 33): FLUX at 512² (256 + 1024 tokens), the VAE decoder's mid-block
+    # attention — one 512-wide head — for 8 images of 512² (sd15's cell) and
+    # one of 1024² (the other three cells), and a short row of keys under
+    # 128-wide heads (WAN's cross-attention over 512 text tokens).
+    ("flux-schnell-b1-512.joint1280", 1, 1280, 24, 128),
+    ("vae-b8-512.mid4096", 8, 4096, 1, 512),
+    ("vae-b1-1024.mid16384", 1, 16384, 1, 512),
+    ("wan_480p_16f.cross512", 1, 16384, 12, 128, 512),
 ]
 
 # Shapes whose sweep is not the grid below: 4352 = 17 x 256, so only 128- and
@@ -80,6 +93,31 @@ COMBOS = {
         (256, 256), (512, 256), (1024, 256),
         (256, 4352), (512, 4352), (1024, 4352),
     ],
+    # The lane-aligned table of tuning.py (PR 33): as shipped before it (256 x
+    # 256), the row as one key block where a head's K and V can be, and the
+    # streamed blocks on both sides of the rule's choice.
+    "flux_1024_joint": [(256, 256), (256, 2304), (256, 4608), (512, 4608)],
+    "flux_b4": [(256, 256), (256, 4608), (512, 4608)],
+    "flux-schnell-b1-512.joint1280": [
+        (256, 256), (128, 1280), (256, 1280), (640, 1280),
+    ],
+    "wan_480p_16f": [
+        (256, 256), (256, 2048), (256, 4096), (512, 4096), (256, 8192),
+        (256, 16384), (512, 16384),
+    ],
+    "wan_long_32k": [
+        (256, 256), (256, 4096), (512, 4096), (256, 8192), (256, 16384),
+        (256, 32768),
+    ],
+    "vae-b8-512.mid4096": [
+        (256, 256), (512, 256), (256, 1024), (512, 1024), (256, 2048),
+        (128, 4096), (256, 4096), (512, 4096), (1024, 4096),
+    ],
+    "vae-b1-1024.mid16384": [
+        (256, 256), (256, 2048), (512, 2048), (256, 4096), (512, 4096),
+        (1024, 4096), (256, 8192), (256, 16384),
+    ],
+    "wan_480p_16f.cross512": [(256, 256), (256, 512), (512, 512)],
 }
 
 BLOCKS_Q = (128, 256, 512)
@@ -105,11 +143,20 @@ def _time_fn(fn, *args, iters=10):
     return sec
 
 
-def _run_shapes(shapes, dev):
+def _run_shapes(shapes, dev, blocks=None, chunk_k=None):
     """Measure the given shapes inline, appending one JSON line each to
-    KERNEL_BENCH.json."""
+    KERNEL_BENCH.json. ``blocks`` stands in for every shape's sweep;
+    ``chunk_k`` for the kernel's keys a softmax tile (its ``_CHUNK_K``, set
+    before anything is traced: how a key block's tile split was measured)."""
     import jax
     import jax.numpy as jnp
+
+    # (the package exports the function under the module's name)
+    fa = importlib.import_module(
+        "comfyui_parallelanything_tpu.ops.pallas.flash_attention")
+    if chunk_k is None:
+        chunk_k = fa._CHUNK_K
+    fa._CHUNK_K = chunk_k
 
     from comfyui_parallelanything_tpu.ops.attention import (
         _chunk_threshold,
@@ -133,12 +180,14 @@ def _run_shapes(shapes, dev):
 
     out_path = os.path.join(evidence_dir(), "KERNEL_BENCH.json")
     sweep = os.environ.get("KERNEL_SWEEP", "1") != "0"
-    for label, b, s, h, d in shapes:
+    for label, b, s, h, d, *keys in shapes:
+        sk = keys[0] if keys else s
         # (B, S, H·D), split into heads inside the timed program: what a
         # model's projections hand over, so no backend is charged (or spared)
         # a relayout of a 40-wide minor dimension that no model pays.
-        q, k, v = (jax.random.normal(key, (b, s, h * d), jnp.bfloat16)
-                   for key in jax.random.split(jax.random.key(0), 3))
+        q, k, v = (jax.random.normal(key, (b, n, h * d), jnp.bfloat16)
+                   for key, n in zip(jax.random.split(jax.random.key(0), 3),
+                                     (s, sk, sk)))
 
         def projected(fn, _h=h, _d=d):
             def run(a, b_, c):
@@ -146,10 +195,12 @@ def _run_shapes(shapes, dev):
                 return fn(split(a), split(b_), split(c)).reshape(a.shape)
             return jax.jit(run)
 
-        rec = {"shape": label, "b": b, "seq": s, "heads": h, "head_dim": d,
-               "platform": dev.platform, "device_kind": dev.device_kind,
+        rec = {"shape": label, "b": b, "seq": s, "keys": sk, "heads": h,
+               "head_dim": d, "platform": dev.platform,
+               "device_kind": dev.device_kind, "chunk_k": chunk_k,
                "ts": time.time()}
-        combos = (COMBOS.get(label) or _combos(s)) if sweep else [(256, 256)]
+        combos = blocks or (
+            (COMBOS.get(label) or _combos(s)) if sweep else [(256, 256)])
         best = None  # (ms, bq, bk)
         for bq, bk in combos:
             try:
@@ -175,7 +226,7 @@ def _run_shapes(shapes, dev):
             )
         except Exception as e:  # noqa: BLE001 — S×S logits OOM at video lengths
             rec["xla_error"] = str(e)[:200]
-        if s % 128 and b * h * s * s > _chunk_threshold():
+        if s % 128 and b * h * s * sk > _chunk_threshold():
             # The third route of a ragged length: XLA with the whole logits
             # tensor in HBM, where it fits.
             try:
@@ -203,13 +254,20 @@ def main() -> None:
         )
     enable_compilation_cache()
 
+    def option(name):
+        return sys.argv[sys.argv.index(name) + 1] if name in sys.argv else None
+
     shapes = SHAPES
-    if "--shape" in sys.argv:
-        labels = sys.argv[sys.argv.index("--shape") + 1].split(",")
+    if option("--shape"):
+        labels = option("--shape").split(",")
         shapes = [sh for sh in SHAPES if sh[0] in labels]
         if len(shapes) != len(labels):
             raise SystemExit(f"unknown shape among {labels!r}")
-    _run_shapes(shapes, dev)
+    blocks = option("--blocks") and [
+        tuple(int(n) for n in pair.split("x"))
+        for pair in option("--blocks").split(",")]
+    chunk_k = option("--chunk-k") and int(option("--chunk-k"))
+    _run_shapes(shapes, dev, blocks, chunk_k)
 
 
 if __name__ == "__main__":

@@ -258,7 +258,8 @@ def test_auto_backend_is_unchanged_at_every_class_of_the_unet_cells(
     128-multiple lengths where the head dim is lane-aligned or the keys and
     logits reach the padded-dim thresholds, XLA otherwise. The ragged
     rule leaves cross-attention's 77 keys where they were, so ``sd15`` and
-    ``sdxl`` trace the programs they did."""
+    ``sdxl`` trace the denoisers they did; the decoder's 512-wide head takes
+    the lane-aligned row's blocks (PR 33)."""
     from comfyui_parallelanything_tpu.ops.pallas import tuning
 
     att = _att()
@@ -269,7 +270,8 @@ def test_auto_backend_is_unchanged_at_every_class_of_the_unet_cells(
     got = att.resolve_route(sq, sk, d, b * h)
     if fused:
         assert got[:3] == ("pallas", *(
-            tuning.PADDED_DIM_BLOCKS if d % 128 else (256, 256)))
+            tuning.PADDED_DIM_BLOCKS if d % 128
+            else tuning.lane_aligned_route(sk, d, 2)))
     else:
         assert got[:3] == ("xla", None, None)
 

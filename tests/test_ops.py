@@ -201,12 +201,33 @@ class TestRoute:
         got = _route(*args, on_tpu=tpu)
         assert got == (backend, *(blocks or (None, None)), rule)
 
+    # LANE_ALIGNED_ONE_BLOCK_BYTES, both sides: 8 MB of one head's K are
+    # 32,768 keys x 128 lanes in bfloat16, 16,384 in float32, 8192 x 512 lanes.
+    # (label, keys, head dim, item size, keys a block)
+    ONE_BLOCK = [
+        ("bf16-32768", 32768, 128, 2, 32768), ("bf16-32896", 32896, 128, 2, 4096),
+        ("f32-16384", 16384, 128, 4, 16384), ("f32-16512", 16512, 128, 4, 4096),
+        ("512wide-8192", 8192, 512, 2, 8192), ("512wide-8320", 8320, 512, 2, 4096),
+    ]
+
+    @pytest.mark.parametrize("label,sk,d,itemsize,block_k", ONE_BLOCK,
+                             ids=[c[0] for c in ONE_BLOCK])
+    def test_a_lane_aligned_row_is_one_key_block_up_to_a_budget_in_bytes(
+            self, label, sk, d, itemsize, block_k):
+        got = _route(4096, sk, d, 12, itemsize=itemsize)
+        assert got == ("pallas", 256, block_k, "lane-aligned")
+        # what attention_local and the planner ask (the planner at bfloat16)
+        att = self._att()
+        assert att.resolve_route(4096, sk, d, 12, itemsize)[1:3] == (
+            (256, block_k) if att._pallas_available() else (None, None))
+
     # A pin is served as pinned, on a TPU or off one, with the rule's blocks
     # where the rule has them at that shape.
     PINS = [
         ("pallas-where-the-rule-says-xla", "pallas", (256, 256, 160, 128), "pallas", (256, 256)),
         ("pallas-at-a-padded-dim-class", "pallas", (4096, 4096, 40, 128), "pallas", (256, 4096)),
         ("pallas-at-a-ragged-class", "pallas", (4173, 4173, 64, 48), "pallas", (384, 4224)),
+        ("pallas-at-a-lane-aligned-class", "pallas", (4352, 4352, 128, 24), "pallas", (256, 4352)),
         ("xla-over-the-threshold", "xla", (4096, 4096, 40, 128), "xla_chunked", None),
         ("xla_chunked-at-a-small-shape", "xla_chunked", (16, 16, 4, 1), "xla_chunked", None),
         ("unknown", "pallas_mosaic", (16, 16, 4, 1), None, None),
@@ -256,7 +277,8 @@ class TestRoute:
         assert att.chunk_config() == {"chunk_elems": 2**27, "degraded": False}
 
     @pytest.mark.parametrize("label", ["sd15-self4096", "sdxl-self1024",
-                                       "sd35m-joint4173"])
+                                       "sd35m-joint4173",
+                                       "flux-schnell-joint4352"])
     def test_the_planner_records_the_same_route(self, monkeypatch, label):
         from test_planner import TestAttentionAxis
 
@@ -466,6 +488,75 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    rtol=tol, atol=tol)
+
+    # A 128-wide-head row as ONE key block walked in several softmax tiles,
+    # the tile shrunk from 2048 keys to 250 (a tile may then be 265 keys): 512
+    # keys divide as 2 x 256 where three 250-key tiles would pad, as FLUX's
+    # 4352 = 2 x 2176; 640 keys have no such split and run padded to 3 x 256,
+    # the last 128 keys masked.
+    # (label, keys, keys a block, keys a tile)
+    ONE_BLOCK_ROWS = [("divides-exactly", 512, 512, 256),
+                      ("padded-and-masked", 640, 768, 256)]
+
+    @pytest.mark.parametrize("label,sk,block,tile", ONE_BLOCK_ROWS,
+                             ids=[c[0] for c in ONE_BLOCK_ROWS])
+    def test_a_row_as_one_key_block_in_several_tiles(self, monkeypatch, label,
+                                                     sk, block, tile):
+        import importlib
+
+        fa = importlib.import_module(
+            "comfyui_parallelanything_tpu.ops.pallas.flash_attention"
+        )
+        monkeypatch.setattr(fa, "_CHUNK_K", 250)
+        assert fa.key_split(sk, sk) == (block, tile)
+        q, k, v = _qkv(b=1, sq=96, sk=sk, h=2, d=128, seed=9)
+
+        def run(q, k, v):
+            return fa._flash_attention(q, k, v, scale=128 ** -0.5, block_q=32,
+                                       block_k=sk, interpret=True)
+
+        got = run(q, k, v)
+        want = _xla_attention(q, k, v, scale=128 ** -0.5)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+        # One step of the key axis, no carried state; K and V are padded and
+        # the keys masked only where the tiles do not divide the row.
+        jaxpr = jax.make_jaxpr(run)(q, k, v)
+        (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        assert call.params["grid_mapping"].grid[3] == 1
+        assert not call.params["grid_mapping"].num_scratch_operands
+        padded = block != sk
+        pads = [e for e in jaxpr.eqns if e.params.get("name") == "_pad"]
+        assert len(pads) == (2 if padded else 0)  # jnp.pad of K and of V
+        assert ("iota" in str(call.params["jaxpr"])) == padded
+
+    # The splits the routed classes run: the padded-dim and ragged rows' as
+    # they were before the lane-aligned row had a rule (PR 33), and the new
+    # row's. (label, keys, block asked for, keys a block, keys a tile)
+    SPLITS = [
+        ("padded-dim-4096", 4096, 4096, 4096, 2048),
+        ("padded-dim-streamed-16384", 16384, 4096, 4096, 2048),
+        ("ragged-4173", 4173, 4224, 4224, 1408),
+        ("ragged-1152", 1152, 1152, 1152, 1152),
+        ("ragged-1101", 1101, 1152, 1104, 1104),
+        ("ragged-4300-pads-past-4352", 4300, 4352, 4608, 1536),
+        ("flux-4352", 4352, 4352, 4352, 2176),
+        ("flux-dev-4608", 4608, 4608, 4608, 1536),
+        ("flux-512sq-1280", 1280, 1280, 1280, 1280),
+        ("one-tile-2176", 2176, 2176, 2176, 2176),
+        ("no-exact-split-2432", 2432, 2432, 2560, 1280),
+        ("cross-77", 77, 256, 80, 80),
+    ]
+
+    @pytest.mark.parametrize("label,sk,asked,block,tile", SPLITS,
+                             ids=[c[0] for c in SPLITS])
+    def test_key_split(self, label, sk, asked, block, tile):
+        from comfyui_parallelanything_tpu.ops.pallas.flash_attention import (
+            key_split,
+        )
+
+        assert key_split(sk, asked) == (block, tile)
+        assert block % tile == 0 and (block == tile or tile % 128 == 0)
 
     @pytest.mark.parametrize("heads,head_dim,group", [
         (8, 40, 8), (10, 64, 2), (20, 64, 2), (8, 80, 8), (8, 160, 4),

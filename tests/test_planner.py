@@ -213,11 +213,23 @@ class TestAttentionAxis:
         ("sd15-cross77", True, 16, 4096, 77, 8, 40, "xla", None),
         ("sd15-self256", True, 16, 256, 256, 8, 160, "xla", None),
         ("sd15-1024sq-self16384", True, 4, 16384, 16384, 8, 40, "pallas", (256, 4096)),
-        ("vae-mid-512wide", True, 8, 4096, 4096, 1, 512, "pallas", (256, 256)),
-        ("flux-joint-128wide", True, 1, 4608, 4608, 24, 128, "pallas", (256, 256)),
-        # the cell flux-schnell-b1-1024.closed-unique's one class (256 T5 +
-        # 4096 image tokens): today's answer, pinned until its perf_opt issue
-        ("flux-schnell-joint4352", True, 1, 4352, 4352, 24, 128, "pallas", (256, 256)),
+        # Head dims that are multiples of 128 (PR 33): one head's row of keys
+        # is ONE key block up to 8 MB of K — the VAE decoder's 512-wide head
+        # at 512² (4 MB), FLUX-dev, four rows of it, the cell
+        # flux-schnell-b1-1024.closed-unique's one class (256 T5 + 4096 image
+        # tokens), FLUX at 512², a short row of keys under many queries, WAN's
+        # 16,384 and 32,768 tokens (4 and 8 MB) — and streams 4096 keys a
+        # block past it: the decoder at 1024² (16 MB), 65,536 tokens.
+        ("vae-mid-512wide", True, 8, 4096, 4096, 1, 512, "pallas", (256, 4096)),
+        ("flux-joint-128wide", True, 1, 4608, 4608, 24, 128, "pallas", (256, 4608)),
+        ("flux-b4", True, 4, 4608, 4608, 24, 128, "pallas", (256, 4608)),
+        ("flux-schnell-joint4352", True, 1, 4352, 4352, 24, 128, "pallas", (256, 4352)),
+        ("flux-schnell-512sq-joint1280", True, 1, 1280, 1280, 24, 128, "pallas", (256, 1280)),
+        ("wan-cross512", True, 1, 16384, 512, 12, 128, "pallas", (256, 512)),
+        ("wan-480p-self16384", True, 1, 16384, 16384, 12, 128, "pallas", (256, 16384)),
+        ("wan-long-self32768", True, 1, 32768, 32768, 12, 128, "pallas", (256, 32768)),
+        ("vae-mid-1024sq", True, 1, 16384, 16384, 1, 512, "pallas", (256, 4096)),
+        ("lane-aligned-65536", True, 1, 65536, 65536, 12, 128, "pallas", (256, 4096)),
         # SD3.5-medium's joint attention (77 text + image tokens): a ragged
         # length goes to the kernel padded and masked from 2^25.8 logits up
         # (the row as one key block), else stays with XLA (PR 26).
@@ -245,7 +257,9 @@ class TestAttentionAxis:
                                           sq, sk, h, d, backend, blocks):
         """``route`` names the backend and blocks of every class from the
         call's shape and the backend alone; ``attention_local`` executes
-        that answer, counted once a trace."""
+        that answer, counted once a trace: by backend, and a fused call by
+        the row of ``route`` that named its blocks and whether its row of
+        keys is one key block."""
         import importlib
 
         import jax
@@ -276,7 +290,13 @@ class TestAttentionAxis:
             return registry.get("pa_attention_route_total",
                                 {"backend": backend}) or 0.0
 
-        before = count()
+        def key_blocks():
+            return {
+                keys: registry.get("pa_attention_key_blocks_total",
+                                   {"rule": chosen.rule, "keys": keys}) or 0.0
+                for keys in ("one", "streamed")}
+
+        before, blocks_before = count(), key_blocks()
         q = jax.ShapeDtypeStruct((b, sq, h, d), jnp.bfloat16)
         kv = jax.ShapeDtypeStruct((b, sk, h, d), jnp.bfloat16)
         # A fresh function each case: a trace is cached on (function, shapes),
@@ -287,6 +307,61 @@ class TestAttentionAxis:
         fn.eval_shape(q, kv, kv)
         assert count() == before + 1  # once a trace, not once a call
         assert calls == ([blocks] if blocks else [])
+        if blocks:
+            blocks_before["one" if blocks[1] >= sk else "streamed"] += 1
+        assert key_blocks() == blocks_before
+
+
+    def test_flux_schnell_traces_nine_one_block_calls_a_step(self, monkeypatch):
+        """The cell flux-schnell-b1-1024.closed-unique's denoiser — published
+        widths, the cut's 3 + 6 blocks, 256 text + 4096 image tokens — holds
+        nine attention calls, each the lane-aligned row's with the row of keys
+        as one key block: what ``pa_attention_key_blocks_total`` reads on
+        ``/metrics`` while the step program compiles."""
+        import importlib
+
+        import jax
+        import jax.numpy as jnp
+
+        from comfyui_parallelanything_tpu.models.flux import (
+            FluxModel, flux_abstract_params, flux_schnell_config,
+        )
+        from comfyui_parallelanything_tpu.utils.metrics import registry
+
+        att = importlib.import_module(
+            "comfyui_parallelanything_tpu.ops.attention"
+        )
+        fa = importlib.import_module(
+            "comfyui_parallelanything_tpu.ops.pallas.flash_attention"
+        )
+        cfg = flux_schnell_config(depth=3, depth_single_blocks=6)
+        shape, txt_len = (1, 128, 128, 16), 256
+        params = flux_abstract_params(cfg, shape, txt_len)
+        monkeypatch.setattr(att, "_pallas_available", lambda: True)
+        calls = []
+        monkeypatch.setattr(
+            fa, "flash_attention",
+            lambda q, k, v, **kw: calls.append(
+                (q.shape, kw["block_q"], kw["block_k"])) or q,
+        )
+
+        def key_blocks():
+            return [registry.get("pa_attention_key_blocks_total",
+                                 {"rule": "lane-aligned", "keys": keys}) or 0.0
+                    for keys in ("one", "streamed")]
+
+        one, streamed = key_blocks()
+        out = jax.eval_shape(
+            lambda p, x, t, c, y: FluxModel(cfg).apply(
+                {"params": p}, x, t, c, y=y),
+            params, jax.ShapeDtypeStruct(shape, jnp.float32),
+            jax.ShapeDtypeStruct((1,), jnp.float32),
+            jax.ShapeDtypeStruct((1, txt_len, cfg.context_in_dim), jnp.float32),
+            jax.ShapeDtypeStruct((1, cfg.vec_in_dim), jnp.float32),
+        )
+        assert out.shape == shape
+        assert calls == [((1, 4352, 24, 128), 256, 4352)] * 9
+        assert key_blocks() == [one + 9, streamed]
 
 
 # ---------------------------------------------------------------------------

@@ -140,6 +140,42 @@ def test_mmdit_classes_compile_with_the_routed_blocks(one_chip, label, b, s, h, 
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
 
 
+# The classes whose head dim is a multiple of 128: FLUX-dev and the cell
+# flux-schnell-b1-1024.closed-unique's joint attention, a WAN 480p clip, and
+# the VAE decoder's one 512-wide head for 8 images of 512² and one of 1024².
+LANE_ALIGNED_CLASSES = [
+    ("flux-dev-1024", 1, 4608, 24, 128),
+    ("flux-schnell-b1-1024.joint4352", 1, 4352, 24, 128),
+    ("wan-32k", 1, 32768, 12, 128),
+    ("vae-b8-512.mid4096", 8, 4096, 1, 512),
+    ("vae-b1-1024.mid16384", 1, 16384, 1, 512),
+]
+
+
+@pytest.mark.parametrize("label,b,s,h,d", LANE_ALIGNED_CLASSES,
+                         ids=[c[0] for c in LANE_ALIGNED_CLASSES])
+def test_lane_aligned_classes_compile_with_the_routed_blocks(one_chip, label,
+                                                             b, s, h, d):
+    """A head's whole K and V as one key block where the rule says they fit
+    (4352 keys in two 2176-key softmax tiles), a streamed block where it says
+    they do not: the VMEM the rule budgets is VMEM the chip's compiler
+    grants, and no row is padded on its way to the kernel."""
+    from comfyui_parallelanything_tpu.ops.pallas.tuning import route
+
+    backend, block_q, block_k, rule = route(s, s, d, b * h, on_tpu=True,
+                                            chunk_threshold=2**27)
+    assert (backend, rule) == ("pallas", "lane-aligned")
+    compiled = flash_attention.lower(
+        *_qkv(one_chip, b, s, h, d), block_q=block_q, block_k=block_k,
+        interpret=False,
+    ).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert " transpose(" not in hlo and " pad(" not in hlo
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
 def test_cross_attention_keys_of_length_77_compile(one_chip):
     q = jax.ShapeDtypeStruct((2, 4096, 8, 40), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((2, 77, 8, 40), jnp.bfloat16, sharding=one_chip)
@@ -167,11 +203,12 @@ def test_batch_sharded_kernels_compile_for_four_chips(topo, which):
         # The chain's UNet step: SD1.5's 4096-token self-attention at CFG
         # batch 16, four rows a chip, with the shape rule's blocks.
         q = jax.ShapeDtypeStruct((16, 4096, 8, 40), jnp.bfloat16, sharding=rows)
-    fn = {
-        "in-repo": lambda q, k, v: flash_attention(q, k, v, interpret=False),
-        "in-repo-unet": lambda q, k, v: flash_attention(
-            q, k, v, block_q=256, block_k=4096, interpret=False),
-    }[which]
+    def fn(q, k, v):
+        # Both rows' blocks at 4096 keys (PR 33): the decoder's 4 MB row and
+        # the UNet's as one key block.
+        return flash_attention(q, k, v, block_q=256, block_k=4096,
+                               interpret=False)
+
     with pytest.raises(NotImplementedError, match="automatically partitioned"):
         jax.jit(fn).lower(q, q, q).compile()
     with mesh_context(mesh):
