@@ -12,10 +12,14 @@ Workloads; select with BENCH_CONFIG (default ``sd15_16`` on a TPU):
 
 - ``sd15_16``  — SD1.5-class UNet, bf16, batch=16, 1024² pixels (128² latents).
 - ``sdxl_8``   — SDXL-class UNet, bf16, batch=8, 1024².
-- ``zimage_21``— Z_Image-class MMDiT, batch=21, 1024² — the reference's own benchmark
-  run (/root/reference/README.md:46-60: 26.00 s/it on one RTX 3090, 12.91 s/it on
-  two GPUs). Z_Image's exact architecture is not public; this rung runs a
-  flux-class proxy (models/flux.py z_image_turbo_config) at matching scale.
+- ``flux_single_heavy_21`` — a FLUX-class MMDiT at 6 double + 26 single blocks
+  (models/flux.py ``flux_single_heavy_config``: no published model), batch=21,
+  1024². It stood in for Z_Image when that architecture was not public
+  (``zimage_21`` until PR 34); it is public now (Tongyi-MAI, Apache-2.0) and
+  nothing like this shape — the published S3-DiT is models/zimage.py and is
+  measured through ``benchmark/`` (the cell ``zimage-turbo-b1-1024.closed-unique``).
+  The reference's own benchmark run (/root/reference/README.md:46-60: 26.00 s/it
+  on one RTX 3090 at batch 21) is matched by NO rung here.
 - ``flux_16``  — FLUX-class MMDiT, batch=16, 1024² (the BASELINE.json north-star
   shape). Full flux-dev (12B) needs FSDP over a v5e-8 pod slice; on a single chip
   this rung runs the dev *topology* at reduced depth so the shape (4096 img tokens
@@ -34,10 +38,10 @@ Workloads; select with BENCH_CONFIG (default ``sd15_16`` on a TPU):
   (SURVEY §7 hard part 1).
 - ``smoke``    — reduced-width SD1.5 topology on CPU (tests only).
 
-``vs_baseline`` is the reference's published single-GPU 26.00 s/it divided by our
-s/it — emitted ONLY on the like-for-like ``zimage_21`` rung; every other rung
-reports ``null`` (dividing the Z_Image baseline by a different workload's s/it is
-cross-workload noise, not a speedup). ``mfu`` is analytic model FLOPs/step (XLA HLO
+``vs_baseline`` — the reference's published single-GPU 26.00 s/it (Z_Image Turbo,
+batch 21) over our s/it — is ``null`` on every rung: no rung runs that model
+at that batch, and dividing the Z_Image baseline by another workload's s/it is
+cross-workload noise, not a speedup. ``mfu`` is analytic model FLOPs/step (XLA HLO
 cost analysis) / s/it / aggregate chip peak bf16 FLOP/s (the peak comes from
 ``utils/roofline.PLATFORM_SPECS``; a chip that table does not list is an error).
 """
@@ -123,7 +127,7 @@ def _bf16_build(build_fn, cfg, **build_kw):
     Two bugs this kills at once: (a) flax ``init`` stores params at the default
     ``param_dtype`` f32, so the "bf16" rung labels were silently benching f32
     weight storage (2x the HBM reads on every matmul — the usual TPU
-    bottleneck); (b) the z-image proxy is 5.77B params = 21.5 GiB at f32, an
+    bottleneck); (b) the 6 + 26-block FLUX-class rung is 5.77B params = 21.5 GiB at f32, an
     init-time OOM on a 16 GiB v5e chip, while its bf16 inference layout
     (10.8 GiB) fits. Weights are zeros: matmul/attention timing is
     value-independent, the same argument as ``_synth_int8_params``."""
@@ -164,19 +168,19 @@ def _rung_sdxl_8(jnp, rng):
             kwargs, "SDXL UNet bf16 batch=8 1024x1024")
 
 
-def _rung_zimage_21(jnp, rng):
-    from comfyui_parallelanything_tpu.models import build_flux, z_image_turbo_config
+def _rung_flux_single_heavy_21(jnp, rng):
+    from comfyui_parallelanything_tpu.models import build_flux, flux_single_heavy_config
 
     batch, latent, ctx_len = 21, 128, 128
-    cfg = z_image_turbo_config(dtype=jnp.bfloat16)
+    cfg = flux_single_heavy_config(dtype=jnp.bfloat16)
     model = _bf16_build(
         build_flux, cfg, sample_shape=(1, 16, 16, 16), txt_len=ctx_len
     )
     # 3 sequential microbatches of 7: 10.8 GiB bf16 weights + full-batch-21
     # activations do not fit a 16 GiB v5e; 21 images per iteration either way.
     return (model, batch, (batch, latent, latent, 16), ctx_len, cfg.context_in_dim,
-            {}, "Z_Image-scale MMDiT bf16 batch=21 (3x7 microbatch) 1024x1024 "
-                "(flux-class proxy; README repro shape)", 3)
+            {}, "FLUX-class MMDiT 6+26 blocks bf16 batch=21 (3x7 microbatch) "
+                "1024x1024 (no published model has this shape)", 3)
 
 
 def _int8_synth_model(jnp, cfg, sample_shape, txt_len, name):
@@ -226,26 +230,25 @@ def _int8_synth_model(jnp, cfg, sample_shape, txt_len, name):
     )
 
 
-def _rung_zimage_21_int8(jnp, rng):
-    """The README-repro shape (batch=21, 1024²) with int8-STORED weights —
-    for a chip whose usable HBM the bf16 rung's weights + overhead alone
-    exceed. Same proxy topology, same 21
-    images per iteration; weights dequantize to bf16 inside jit, so compute
-    is still bf16 and the workload label carries the weight-precision caveat
-    for the vs_baseline claim."""
-    from comfyui_parallelanything_tpu.models import z_image_turbo_config
+def _rung_flux_single_heavy_21_int8(jnp, rng):
+    """The batch=21, 1024² shape with int8-STORED weights — for a chip whose
+    usable HBM the bf16 rung's weights + overhead alone exceed. Same 6 + 26
+    FLUX-class topology, same 21 images per iteration; weights dequantize to
+    bf16 inside jit, so compute is still bf16 and the workload label carries
+    the weight-precision caveat."""
+    from comfyui_parallelanything_tpu.models import flux_single_heavy_config
 
     batch, latent, ctx_len = 21, 128, 128
-    cfg = z_image_turbo_config(dtype=jnp.bfloat16)
+    cfg = flux_single_heavy_config(dtype=jnp.bfloat16)
     model = _int8_synth_model(
         jnp, cfg, sample_shape=(1, 16, 16, 16), txt_len=ctx_len,
-        name="zimage-int8",
+        name="flux-single-heavy-int8",
     )
     return (model, batch, (batch, latent, latent, 16), ctx_len,
             cfg.context_in_dim, {},
-            "Z_Image-scale MMDiT int8 weights/bf16 compute batch=21 "
-            "(3x7 microbatch) 1024x1024 (flux-class proxy; README repro "
-            "shape; NOT weight-precision like-for-like)", 3)
+            "FLUX-class MMDiT 6+26 blocks int8 weights/bf16 compute batch=21 "
+            "(3x7 microbatch) 1024x1024 (no published model has this shape; "
+            "NOT weight-precision like-for-like)", 3)
 
 
 def _rung_flux_16(jnp, rng):
@@ -413,8 +416,8 @@ def _rung_smoke(jnp, rng):
 _RUNGS = {
     "sd15_16": _rung_sd15_16,
     "sdxl_8": _rung_sdxl_8,
-    "zimage_21": _rung_zimage_21,
-    "zimage_21_int8": _rung_zimage_21_int8,
+    "flux_single_heavy_21": _rung_flux_single_heavy_21,
+    "flux_single_heavy_21_int8": _rung_flux_single_heavy_21_int8,
     "flux_16": _rung_flux_16,
     "flux_16_int8": _rung_flux_16_int8,
     "flux_stream": _rung_flux_stream,
@@ -827,14 +830,11 @@ def _run_inner() -> None:
     except Exception:
         pass
 
-    # vs_baseline only on the README-repro-shaped rungs; anything else would
-    # divide the Z_Image baseline by a different workload's s/it. The int8
-    # variant's workload label carries the weight-precision caveat the claim
-    # must keep.
-    vs_baseline = (
-        round(_REF_SINGLE_GPU_S_IT / sec_it, 2)
-        if config_name in ("zimage_21", "zimage_21_int8") else None
-    )
+    # No rung runs the reference's benchmark model (Z_Image Turbo) at its
+    # batch of 21, and dividing its 26.00 s/it by another workload's s/it is
+    # cross-workload noise: null everywhere (_REF_SINGLE_GPU_S_IT stays as the
+    # number a like-for-like rung would divide).
+    vs_baseline = None
 
     from comfyui_parallelanything_tpu.ops.attention import (
         chunk_config,

@@ -1,6 +1,6 @@
 """Text-encoder checkpoints → models/text_encoders.py param trees.
 
-Three source layouts cover the checkpoints the supported model families ship with
+Four source layouts cover the checkpoints the supported model families ship with
 (the reference's host app loads these same towers; conditioning arrives at its
 ``forward(x, t, context)`` boundary pre-encoded, any_device_parallel.py:1287):
 
@@ -9,6 +9,7 @@ Three source layouts cover the checkpoints the supported model families ship wit
 - **OpenCLIP** (``transformer.resblocks.*`` with fused ``in_proj``): SDXL's
   ``conditioner.embedders.1.model`` subtree.
 - **HF T5 encoder** (``encoder.block.*``): FLUX/WAN t5xxl files.
+- **HF Qwen3** (``model.layers.*``): Z-Image's ``qwen_3_4b`` file.
 
 Same conventions as convert.py: fp8/f16/bf16 upcast to f32 numpy, torch (out,in)
 linears → flax (in,out) kernels, consumed-key tracking absent here because text
@@ -23,7 +24,7 @@ from typing import Any
 import numpy as np
 
 from .convert import dense_params, resident, to_numpy, tree_to_jnp
-from .text_encoders import CLIPTextConfig, T5Config
+from .text_encoders import CLIPTextConfig, Qwen3Config, T5Config
 
 
 def _ln(sd: Mapping[str, Any], key: str) -> dict:
@@ -155,5 +156,42 @@ def convert_t5_checkpoint(state_dict: Mapping[str, Any], cfg: T5Config) -> dict:
             "wi_0": dense(f"{t}.layer.1.DenseReluDense.wi_0"),
             "wi_1": dense(f"{t}.layer.1.DenseReluDense.wi_1"),
             "wo": dense(f"{t}.layer.1.DenseReluDense.wo"),
+        }
+    return tree_to_jnp(p)
+
+
+def convert_qwen3_checkpoint(state_dict: Mapping[str, Any], cfg: Qwen3Config) -> dict:
+    """HF ``Qwen3ForCausalLM`` / ``Qwen3Model`` layout (``model.layers.N.*``,
+    any wrapper prefix) → Qwen3Model params, for the ``cfg.output_layers``
+    layers the tower runs: the last layer, ``model.norm`` and a ``lm_head``
+    are left in the file. Matmul kernels and the embedding stay in their
+    resident type (``convert.resident``: bfloat16 from a bfloat16 file or
+    under bfloat16 compute — 7.8 GB, never whole in float32); the RMS scales
+    are float32."""
+    sd = _strip(state_dict, "model.embed_tokens.weight")
+
+    def dense(key):
+        return dense_params(sd, key, cfg.dtype)
+
+    def scale(key):
+        return {"scale": to_numpy(sd[f"{key}.weight"])}
+
+    p: dict[str, Any] = {
+        "embed_tokens": {"embedding": resident(sd["model.embed_tokens.weight"], cfg.dtype)},
+    }
+    for i in range(cfg.output_layers):
+        t = f"model.layers.{i}"
+        p[f"layers_{i}"] = {
+            "input_layernorm": scale(f"{t}.input_layernorm"),
+            "q_proj": dense(f"{t}.self_attn.q_proj"),
+            "k_proj": dense(f"{t}.self_attn.k_proj"),
+            "v_proj": dense(f"{t}.self_attn.v_proj"),
+            "o_proj": dense(f"{t}.self_attn.o_proj"),
+            "q_norm": scale(f"{t}.self_attn.q_norm"),
+            "k_norm": scale(f"{t}.self_attn.k_norm"),
+            "post_attention_layernorm": scale(f"{t}.post_attention_layernorm"),
+            "gate_proj": dense(f"{t}.mlp.gate_proj"),
+            "up_proj": dense(f"{t}.mlp.up_proj"),
+            "down_proj": dense(f"{t}.mlp.down_proj"),
         }
     return tree_to_jnp(p)

@@ -1,7 +1,7 @@
 """FLUX-class MMDiT — flax.linen, bf16, TPU-first. The flagship model family.
 
-Capability target: the reference's headline workloads are FLUX.1 and Z_Image-class
-DiTs (/root/reference/README.md:5), and its pipeline mode walks exactly the block
+Capability target: FLUX.1 is one of the reference's headline workloads
+(/root/reference/README.md:5), and its pipeline mode walks exactly the block
 lists this model exposes — ``double_blocks`` then ``single_blocks``
 (any_device_parallel.py:1156). The config knobs mirror the ctor kwargs the reference
 scrapes off live FLUX models when cloning: ``vec_in_dim``, ``context_in_dim``,
@@ -64,15 +64,13 @@ def flux_schnell_config(**overrides) -> FluxConfig:
     return dataclasses.replace(FluxConfig(guidance_embed=False), **overrides)
 
 
-def z_image_turbo_config(**overrides) -> FluxConfig:
-    """Z_Image-class turbo DiT — the reference's headline benchmark model
-    (/root/reference/README.md:46-60: batch=21 @1024², 26.00 s/it on one RTX 3090).
-
-    Z-Image is a ~6B single-stream-heavy MMDiT distilled for few-step sampling (no
-    CFG pass, no guidance embed). Modeled here as the single-stream-dominant point
-    in the MMDiT family: a handful of double blocks feeding a deep single-block
-    stack at FLUX's hidden width but roughly half the total depth.
-    """
+def flux_single_heavy_config(**overrides) -> FluxConfig:
+    """A FLUX-class MMDiT at 6 double + 26 single blocks, no guidance
+    embedder: the single-stream-heavy point of THIS family at FLUX's widths,
+    5.8 B parameters. No published model has this shape; ``bench.py``'s
+    ``flux_single_heavy_21`` rungs time it at batch 21. (Until PR 34 it
+    carried Z-Image's name as a guess at an architecture that was not public
+    then; the published one is ``models/zimage.py`` and is nothing like it.)"""
     base = FluxConfig(
         depth=6,
         depth_single_blocks=26,

@@ -295,6 +295,32 @@ def load_flux_checkpoint(
     return model
 
 
+def load_zimage_checkpoint(src: Any, cfg=None, name: str = "zimage-turbo") -> DiffusionModel:
+    """Z-Image checkpoint (path or state dict, the published ``transformer/``
+    key spelling) → DiffusionModel. Read in its stored types, kernel by
+    kernel (``convert.resident``), like FLUX: never whole in float32. The
+    main-layer and refiner counts are facts of the file: a depth cut of the
+    published model loads at the depth it has, whatever ``cfg`` says."""
+    import dataclasses
+
+    from .convert_zimage import convert_zimage_checkpoint, zimage_depths
+    from .zimage import build_zimage, zimage_turbo_config
+
+    sd = _resolve_state_dict(src, stored=True)
+    if cfg is None:
+        cfg = zimage_turbo_config()
+    layers, refiners = zimage_depths(sd)
+    if (layers, refiners) != (cfg.n_layers, cfg.n_refiner_layers):
+        get_logger().info(
+            "aligning Z-Image config to checkpoint: %d layers, %d + %d refiner "
+            "layers", layers, refiners, refiners,
+        )
+        cfg = dataclasses.replace(cfg, n_layers=layers, n_refiner_layers=refiners)
+    model = build_zimage(cfg, name=name, params=convert_zimage_checkpoint(sd, cfg))
+    record_resident(name, model.params)
+    return model
+
+
 def load_sd_unet_checkpoint(
     src: Any,
     cfg: UNetConfig,
@@ -377,17 +403,14 @@ def sniff_model_family(state_dict: Mapping[str, Any]) -> str:
         shape = getattr(state_dict[key], "shape", None)
         return None if shape is None else int(shape[axis])
 
+    if has("noise_refiner.") and has("context_refiner.") and has("cap_embedder."):
+        # Z-Image's single-stream transformer in its published key spelling:
+        # two refiner stacks and a caption embedder beside ``layers``.
+        return "zimage-turbo"
     if has("double_blocks."):
-        if has("guidance_in."):
-            return "flux-dev"
-        depth, single = flux_depths(names)
-        # No guidance embed: schnell runs the full 19-double-block stack, and
-        # a depth cut of it (a contiguous block range, one pipeline stage's
-        # share) keeps its published 1 : 2 ratio of double to single blocks;
-        # the z-image proxy (flux.py z_image_turbo_config, depth 6/26) is the
-        # shallow single-stream-dominant point of the family.
-        schnell = depth >= 12 or single == 2 * depth
-        return "flux-schnell" if schnell else "zimage-turbo"
+        # A FLUX-layout file without a guidance embedder is schnell's, at
+        # whatever depth it has (a depth cut is a contiguous block range).
+        return "flux-dev" if has("guidance_in.") else "flux-schnell"
     if has("joint_blocks."):
         if any(".x_block.attn2." in n for n in names):
             return "sd35-medium"  # dual-attention mmdit-x
@@ -449,7 +472,8 @@ def sniff_model_family(state_dict: Mapping[str, Any]) -> str:
         return "sd15"
     raise ValueError(
         "cannot sniff model family: no known diffusion-model key signature "
-        "(double_blocks/joint_blocks/self_attn/input_blocks) in checkpoint"
+        "(noise_refiner/double_blocks/joint_blocks/self_attn/input_blocks) in "
+        "checkpoint"
     )
 
 
@@ -528,6 +552,22 @@ def load_t5_checkpoint(src: Any, cfg=None):
         cfg = t5_xxl_config()
     enc = build_t5_encoder(cfg, params=convert_t5_checkpoint(sd, cfg))
     record_resident("t5", enc.params)
+    return enc
+
+
+def load_qwen3_checkpoint(src: Any, cfg=None):
+    """Qwen3 checkpoint (HF layout) → TextEncoder (Z-Image's text tower). Read
+    in its stored types, kernel by kernel (``convert.resident``): Qwen3-4B is
+    16 GB in float32 and never exists so. The last layer and the final norm
+    stay in the file: the tower hands on the state before the last layer."""
+    from .convert_text import convert_qwen3_checkpoint
+    from .text_encoders import build_qwen3, qwen3_4b_config
+
+    sd = _resolve_state_dict(src, stored=True)
+    if cfg is None:
+        cfg = qwen3_4b_config()
+    enc = build_qwen3(cfg, params=convert_qwen3_checkpoint(sd, cfg))
+    record_resident("qwen3", enc.params)
     return enc
 
 

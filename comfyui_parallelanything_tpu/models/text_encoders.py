@@ -1,4 +1,4 @@
-"""Text encoders (CLIP-L / OpenCLIP-G / T5) — flax.linen, TPU-first.
+"""Text encoders (CLIP-L / OpenCLIP-G / T5 / Qwen3) — flax.linen, TPU-first.
 
 The reference receives ready-made conditioning tensors from its host app (its
 forward convention is ``forward(x, timesteps, context, **kwargs)`` with ``context``
@@ -11,6 +11,10 @@ supported checkpoints condition on:
 - **OpenCLIP-G** (SDXL context + pooled): 32-layer, gelu, penultimate-layer output.
 - **T5 encoder** (FLUX/WAN context): RMSNorm, relative-position-bucket attention
   bias, gated-gelu FFN, bidirectional.
+- **Qwen3** (Z-Image context): the first decoder-only tower — causal,
+  grouped-query heads (32 on 8), per-head q/k RMS norm, half-split rotary
+  positions, SwiGLU; hands on the state before its last layer, at a bucketed
+  length of which the caller keeps the valid tokens.
 
 All take int32 token ids — tokenization is in utils/tokenizer.py (BPE/unigram
 tables load from user-supplied files; this image ships none and has no egress).
@@ -282,6 +286,104 @@ class T5Encoder(nn.Module):
 
 
 # ---------------------------------------------------------------------------
+# Qwen3 (decoder-only, as a conditioning tower)
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class Qwen3Config:
+    vocab_size: int = 151936
+    hidden_size: int = 2560
+    intermediate_size: int = 9728
+    num_layers: int = 36
+    num_heads: int = 32
+    num_kv_heads: int = 8
+    head_dim: int = 128
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+
+    @property
+    def output_layers(self) -> int:
+        """How many of the layers run: the tower hands on
+        ``hidden_states[-2]``, the state BEFORE the last layer, so the last
+        layer and the final norm are neither run nor kept resident."""
+        return self.num_layers - 1
+
+
+def qwen3_4b_config(**overrides) -> Qwen3Config:
+    """Qwen/Qwen3-4B ``config.json`` — Z-Image's text tower."""
+    return dataclasses.replace(Qwen3Config(), **overrides)
+
+
+class _RMSNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.basic import rms_normalize
+
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        return rms_normalize(x, scale, self.eps)
+
+
+class _Qwen3Layer(nn.Module):
+    cfg: Qwen3Config
+
+    @nn.compact
+    def __call__(self, x, rope):
+        from ..ops.attention import grouped_causal_attention
+        from ..ops.rope import apply_rope_halves
+
+        cfg = self.cfg
+        B, S, _ = x.shape
+        H, Hk, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
+
+        h = _RMSNorm(cfg.rms_norm_eps, name="input_layernorm")(x)
+        q = dense(H * D, "q_proj")(h).reshape(B, S, H, D)
+        k = dense(Hk * D, "k_proj")(h).reshape(B, S, Hk, D)
+        v = dense(Hk * D, "v_proj")(h).reshape(B, S, Hk, D)
+        q = _RMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
+        k = _RMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
+        cos, sin = rope
+        a = grouped_causal_attention(
+            apply_rope_halves(q, cos, sin), apply_rope_halves(k, cos, sin), v)
+        x = x + dense(cfg.hidden_size, "o_proj")(a.reshape(B, S, H * D))
+        h = _RMSNorm(cfg.rms_norm_eps, name="post_attention_layernorm")(x)
+        h = nn.silu(dense(cfg.intermediate_size, "gate_proj")(h)) * dense(
+            cfg.intermediate_size, "up_proj")(h)
+        return x + dense(cfg.hidden_size, "down_proj")(h)
+
+
+class Qwen3Model(nn.Module):
+    """The causal Qwen3 stack as a conditioning tower: token ids (B, S) → the
+    residual stream after ``cfg.output_layers`` layers, un-normed (HF's
+    ``hidden_states[-2]`` at 35 of 36). Causal, so a token's state does not
+    depend on what follows it: padding a prompt to a bucket leaves the valid
+    states what they were, and the caller drops the rest."""
+
+    cfg: Qwen3Config
+
+    @nn.compact
+    def __call__(self, tokens):
+        from ..ops.rope import half_rope_freqs
+
+        cfg = self.cfg
+        B, S = tokens.shape
+        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                     name="embed_tokens")(tokens)
+        rope = half_rope_freqs(
+            jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S)),
+            cfg.head_dim, cfg.rope_theta)
+        for i in range(cfg.output_layers):
+            x = _Qwen3Layer(cfg, name=f"layers_{i}")(x, rope)
+        return x
+
+
+# ---------------------------------------------------------------------------
 # Builders (mirror build_flux/build_unet: params= skips init)
 # ---------------------------------------------------------------------------
 
@@ -323,6 +425,15 @@ def build_clip_text(cfg: CLIPTextConfig, rng=None, params=None) -> TextEncoder:
 
 def build_t5_encoder(cfg: T5Config, rng=None, params=None, sample_len=64) -> TextEncoder:
     module = T5Encoder(cfg)
+    if params is None:
+        if rng is None:
+            raise ValueError("need rng to initialize (or pass params=)")
+        params = module.init(rng, jnp.zeros((1, sample_len), jnp.int32))["params"]
+    return TextEncoder(module=module, cfg=cfg, params=params)
+
+
+def build_qwen3(cfg: Qwen3Config, rng=None, params=None, sample_len=32) -> TextEncoder:
+    module = Qwen3Model(cfg)
     if params is None:
         if rng is None:
             raise ValueError("need rng to initialize (or pass params=)")

@@ -375,6 +375,7 @@ class TPUCheckpointLoader:
             flux_dev_config,
             flux_schnell_config,
             flux_vae_config,
+            load_zimage_checkpoint,
             load_flux_checkpoint,
             load_safetensors,
             load_sd_unet_checkpoint,
@@ -386,7 +387,6 @@ class TPUCheckpointLoader:
             sdxl_config,
             sdxl_refiner_config,
             sdxl_vae_config,
-            z_image_turbo_config,
         )
 
         lora = lora_path or None
@@ -413,10 +413,10 @@ class TPUCheckpointLoader:
                 return quantize_model(m)
             return m
 
-        flux_family = family in ("flux-dev", "flux-schnell", "zimage-turbo")
-        # The FLUX families are read in the file's stored types and never pass
-        # through float32 whole (models/loader.load_flux_checkpoint).
-        sd = (open_safetensors if flux_family else load_safetensors)(ckpt_path)
+        stored = family in ("flux-dev", "flux-schnell", "zimage-turbo")
+        # The FLUX families and Z-Image are read in the file's stored types
+        # and never pass through float32 whole (models/loader).
+        sd = (open_safetensors if stored else load_safetensors)(ckpt_path)
         if family.startswith("wan"):
             # WAN family: video DiT + causal 3D VAE (its own checkpoint file —
             # WAN releases don't bundle the VAE with the DiT weights).
@@ -517,11 +517,21 @@ class TPUCheckpointLoader:
                     )
                 model = load_sd_unet_checkpoint(sd, xcfg, lora, lora_strength)
                 vae_cfg = sdxl_vae_config()
+            elif family == "zimage-turbo":
+                if lora:
+                    raise ValueError("LoRA baking is not wired for zimage-turbo")
+                # Looked up at call time: tests shrink models by patching the
+                # package-level preset.
+                from . import models as _models
+
+                model = load_zimage_checkpoint(
+                    sd, _models.zimage_turbo_config(), name=family
+                )
+                vae_cfg = flux_vae_config()  # the FLUX 16-channel autoencoder
             else:
                 cfg = {
                     "flux-dev": flux_dev_config,
                     "flux-schnell": flux_schnell_config,
-                    "zimage-turbo": z_image_turbo_config,
                 }[family]()
                 model = load_flux_checkpoint(
                     sd, cfg, lora, lora_strength, name=family
@@ -532,6 +542,10 @@ class TPUCheckpointLoader:
                 # The host's FluxSchnell sampling settings: a discrete flow
                 # table at shift 1.0 (dev keeps the widget's 1.15).
                 model.sampler_prefs = {"shift": 1.0}
+            elif family == "zimage-turbo":
+                # The host's Z-Image sampling settings: the flow table at
+                # shift 3.0, what the template's ModelSamplingAuraFlow says.
+                model.sampler_prefs = {"shift": 3.0}
         if not load_vae:
             return model, None
         vae_sd = load_safetensors(vae_path) if vae_path else sd
@@ -564,7 +578,7 @@ class TPUCLIPLoader:
             "required": {
                 "encoder_path": ("STRING", {"default": ""}),
                 "encoder_type": (
-                    ["clip-l", "open-clip-g", "open-clip-h", "t5", "umt5"],
+                    ["clip-l", "open-clip-g", "open-clip-h", "t5", "umt5", "qwen3"],
                     {"default": "clip-l"},
                 ),
             },
@@ -588,7 +602,22 @@ class TPUCLIPLoader:
         from .models import load_clip_text_checkpoint, load_t5_checkpoint
         from .utils.tokenizer import CLIPBPETokenizer, load_tokenizer_json
 
-        if encoder_type in ("t5", "umt5"):
+        if encoder_type == "qwen3":
+            # The decoder-only tower: a byte-level BPE table behind the chat
+            # template, no fixed window (``max_len`` is CLIP's 77 unless
+            # set; the tower's own budget is 512 tokens).
+            from .models import load_qwen3_checkpoint
+            from .utils.tokenizer import load_chat_tokenizer_json
+
+            if not tokenizer_json:
+                raise ValueError(
+                    "encoder_type='qwen3' requires tokenizer_json (Qwen's "
+                    "byte-level BPE tokenizer.json)"
+                )
+            enc = load_qwen3_checkpoint(encoder_path)
+            tok = load_chat_tokenizer_json(
+                tokenizer_json, max_len=512 if max_len == 77 else max_len)
+        elif encoder_type in ("t5", "umt5"):
             if not tokenizer_json:
                 raise ValueError(
                     f"encoder_type={encoder_type!r} requires tokenizer_json (no "
@@ -798,7 +827,15 @@ class TPUTextEncode:
         # is the trace's, not the span's).
         with tracing.span("text-encode", cat="graph", tower=tower) as sp:
             ids, mask = tok([text])
-            if tower in ("t5", "umt5"):
+            n_tokens = len(ids[0])
+            if tower == "qwen3":
+                # Causal: the states of the valid tokens do not depend on
+                # the padding after them, so the tower runs at the bucket's
+                # length (one program a bucket) without a mask; the mask is
+                # part of the cache key only.
+                out = cached(mask, lambda: enc(jnp.asarray(ids, jnp.int32)))
+                n_tokens = int(mask[0].sum())  # the VALID count, not the bucket
+            elif tower in ("t5", "umt5"):
                 # ``attention_mask`` False (the flux-dual wire): the source
                 # hands the tower no mask, so padded keys take part.
                 masked = clip.get("attention_mask", True)
@@ -810,11 +847,17 @@ class TPUTextEncode:
             else:
                 out = cached(None, lambda: enc(jnp.asarray(ids, jnp.int32)))
             cache = "miss" if ran else "hit"
-            sp.set(tokens=len(ids[0]), cache=cache)
+            sp.set(tokens=n_tokens, cache=cache)
         registry.counter(
             "pa_text_encode_total", labels={"tower": tower, "cache": cache},
             help="text-tower encodes by tower and embed-cache outcome",
         )
+        if tower == "qwen3":
+            # The single-stream denoiser has no pooled vector: the slot
+            # carries the count of valid tokens a row, by which it replaces
+            # the rest of the bucket with its learned pad token.
+            return ({"context": out, "penultimate": None,
+                     "pooled": jnp.asarray(mask.sum(-1, keepdims=True), jnp.float32)},)
         if tower in ("t5", "umt5"):
             return ({"context": out, "pooled": None},)
         last, penultimate, pooled = out

@@ -28,6 +28,8 @@ File resolution env vars (the stand-ins for ComfyUI's folder_paths):
   carry encoder weights but never tokenizer data).
 - ``PA_T5_TOKENIZER_JSON``: tokenizer for the T5/UMT5 tower
   (``DualCLIPLoader``).
+- ``PA_QWEN_TOKENIZER_JSON``: Qwen's byte-level BPE ``tokenizer.json`` for the
+  Qwen3 tower (``CLIPLoader`` type ``lumina2``: Z-Image).
 """
 
 from __future__ import annotations
@@ -439,7 +441,9 @@ class CLIPLoader:
         "ltxv": "t5",
         "pixart": "t5",
         "cosmos": "t5",
-        "lumina2": "t5",
+        # The type ComfyUI's Z-Image template loads its tower with: which
+        # tower the file holds is read off its keys (``load``).
+        "lumina2": None,
         "hunyuan_video": "clip-l",
     }
 
@@ -460,20 +464,37 @@ class CLIPLoader:
              device: str = "default"):
         from .nodes import TPUCLIPLoader
 
-        tower = self._TYPE_TOWER.get(type)
-        if tower is None:
+        if type not in self._TYPE_TOWER:
             raise ValueError(
                 f"CLIPLoader type {type!r} is not supported — one of "
                 f"{sorted(self._TYPE_TOWER)}"
             )
+        tower = self._TYPE_TOWER[type]
         name = os.path.basename(clip_name).lower()
-        if "umt5" in name:
+        path = resolve_model_file(clip_name, "clip", "text_encoders")
+        if tower is None:
+            tower = _classify_text_tower("", path)
+            if tower != "qwen3":
+                raise ValueError(
+                    f"CLIPLoader type={type!r}: {clip_name!r} holds no Qwen3 "
+                    "tower (model.layers.N.self_attn.q_norm); Z-Image's "
+                    "qwen_3_4b is the tower this type loads here"
+                )
+        elif "umt5" in name:
             tower = "umt5"
         elif "t5" in name:
             tower = "t5" if tower not in ("umt5",) else tower
-        path = resolve_model_file(clip_name, "clip", "text_encoders")
         kw = {}
-        if tower in ("t5", "umt5"):
+        if tower == "qwen3":
+            tok_json = os.environ.get("PA_QWEN_TOKENIZER_JSON", "")
+            if not tok_json:
+                raise ValueError(
+                    f"CLIPLoader type={type!r} loads a Qwen3 tower and needs "
+                    "PA_QWEN_TOKENIZER_JSON (Qwen's byte-level BPE "
+                    "tokenizer.json)"
+                )
+            kw["tokenizer_json"] = tok_json
+        elif tower in ("t5", "umt5"):
             tok_json = os.environ.get("PA_T5_TOKENIZER_JSON", "")
             if not tok_json:
                 raise ValueError(
@@ -498,7 +519,7 @@ class CLIPLoader:
 
 def _classify_text_tower(name: str, path: str | None = None) -> str | None:
     """Which tower a text-encoder file holds: ``t5`` / ``open-clip-g`` /
-    ``clip-l``. Filename markers first (the stock SD3 template ships
+    ``clip-l`` / ``qwen3`` (by key signature only). Filename markers first (the stock SD3 template ships
     clip_l/clip_g/t5xxl); unresolved names fall back to the safetensors key
     signature (header-only — no tensor reads except one embedding shape)."""
     n = os.path.basename(name).lower()
@@ -515,6 +536,8 @@ def _classify_text_tower(name: str, path: str | None = None) -> str | None:
 
         with safe_open(path, framework="numpy") as f:
             keys = set(f.keys())
+            if any(k.endswith("layers.0.self_attn.q_norm.weight") for k in keys):
+                return "qwen3"
             if any(k.startswith("encoder.block.") for k in keys) \
                     or "shared.weight" in keys:
                 return "t5"
@@ -3635,6 +3658,25 @@ class ModelSamplingSD3:
         return (_patch_sampler_prefs(model, shift=float(shift)),)
 
 
+class ModelSamplingAuraFlow(ModelSamplingSD3):
+    """Stock AuraFlow schedule patch — the node ComfyUI's Z-Image template
+    sets its flow shift with (3.0): the same shift on the same flow table as
+    ``ModelSamplingSD3``, under its own stock name."""
+
+    DESCRIPTION = "Stock-name flow-shift patch (AuraFlow / Z-Image templates)."
+
+    @classmethod
+    def INPUT_TYPES(cls):
+        return {"required": {
+            "model": ("MODEL", {}),
+            "shift": ("FLOAT", {"default": 1.73, "min": 0.0, "max": 100.0,
+                                "step": 0.01}),
+        }}
+
+    def patch(self, model, shift: float = 1.73):
+        return super().patch(model, shift)
+
+
 class ModelSamplingFlux:
     """Stock FLUX schedule patch: the resolution-dependent flow shift. Stock
     linearly interpolates the LOG-shift (mu) over the latent token count —
@@ -3848,6 +3890,7 @@ def stock_node_mappings() -> dict[str, type]:
         "RescaleCFG": RescaleCFG,
         "ModelSamplingDiscrete": ModelSamplingDiscrete,
         "ModelSamplingSD3": ModelSamplingSD3,
+        "ModelSamplingAuraFlow": ModelSamplingAuraFlow,
         "ModelSamplingFlux": ModelSamplingFlux,
         "unCLIPCheckpointLoader": unCLIPCheckpointLoader,
         "SamplerCustom": SamplerCustom,

@@ -115,6 +115,42 @@ def _xla_attention(q, k, v, scale):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def grouped_causal_attention(q, k, v) -> jnp.ndarray:
+    """Grouped-query attention on the XLA path: ``q`` (B, S, H, D) against
+    ``k`` / ``v`` (B, S_k, H_kv, D) with H a multiple of H_kv — query head
+    ``h`` reads key/value head ``h // (H / H_kv)``, never repeated in memory —
+    under a causal mask (query ``i`` sees keys ``<= i``), scaled by 1/√D.
+    What a decoder-only text tower takes at its few dozen tokens (Qwen3-4B:
+    32 query heads on 8 key/value heads); the fused kernel has neither the
+    mask nor the grouping, so this never routes to it. Softmax in float32."""
+    B, Sq, H, D = q.shape
+    Sk, Hk = k.shape[1], k.shape[2]
+    if H % Hk:
+        raise ValueError(f"{H} query heads do not group over {Hk} key/value heads")
+    qg = q.reshape(B, Sq, Hk, H // Hk, D)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32) * D ** -0.5
+    keep = jnp.arange(Sk)[None, :] <= jnp.arange(Sq)[:, None] + (Sk - Sq)
+    logits = jnp.where(keep, logits, -jnp.inf)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
+    _count_route("xla")
+    return out.reshape(B, Sq, H, D)
+
+
+def _count_route(backend: str) -> None:
+    """Once a trace, not once a forward: the attention functions run while a
+    program is traced, so the count says which routes the compiled programs
+    hold."""
+    from ..utils.metrics import registry
+
+    _RESOLVED.add(backend)
+    registry.counter(
+        "pa_attention_route_total", labels={"backend": backend},
+        help="attention calls resolved to this backend while a program was "
+             "traced (ops/attention.attention_local)",
+    )
+
+
 # Above this many f32 logits elements (B*H*S_q*S_k; 2**27 ≈ 512 MB) the
 # materializing XLA path is routed to the chunked one. SD-class UNets at 1024²
 # (16k tokens, batch 16) would need 137 GB of logits — far past any HBM — so
@@ -239,17 +275,9 @@ def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
         q.shape[1], k.shape[1], q.shape[-1], q.shape[0] * q.shape[2],
         k.dtype.itemsize,
     )
-    _RESOLVED.add(chosen.backend)
-    # Once a trace, not once a forward: attention_local runs while a program
-    # is traced, so the count says which routes the compiled programs hold.
-    from ..utils.metrics import registry
-
-    registry.counter(
-        "pa_attention_route_total", labels={"backend": chosen.backend},
-        help="attention calls resolved to this backend while a program was "
-             "traced (ops/attention.attention_local)",
-    )
+    _count_route(chosen.backend)
     if chosen.backend == "pallas":
+        from ..utils.metrics import registry
         from .pallas.flash_attention import flash_attention, key_split
         from .pallas.tuning import is_ragged
 

@@ -82,6 +82,16 @@ def padded_dim_route(seq_q: int, seq_k: int,
 #   sd35m-b1-512.joint1101  (2, 1101, 24, 64)  2^25.8       0.696  0.507  0.463  0.413
 #   sd35m-b1-1024.self4096  (2, 4096, 24, 64)  2^29.6       8.398  2.824  2.463  (not ragged)
 #
+# Read at 128-wide heads by PR 34 (Z-Image-Turbo's main layers: 4096 image +
+# 32 caption tokens, 30 heads; my chip run), and NOT retuned:
+#
+#   zimage-b1-1024.joint4128 (1, 4128, 30, 128)  2^29.0     5.228  2.473  2.181  2.048
+#
+# (streamed 4096 keys a block 4.740). The same row padded to 4224 by the
+# caller, on the lane-aligned form, reads 2.072 at 256 queries a block and
+# 1.779 at 384: this row's pad and mask cost 0.27 ms a call there, 2.2 ms of
+# that cell's 122.5 ms step (PERF.md section 6, PR 34).
+#
 # Both ragged classes win, the short one too (1.7x), where sdxl's 1024-token
 # class at 2^25.3 logits lost by 10%: XLA pays for a ragged length as well.
 # Below the short class nothing was measured, so the threshold stands there,
@@ -136,6 +146,7 @@ def ragged_route(seq_q: int, seq_k: int,
 #   wan-480p-16f         (1, 16384, 12, 128)  4 MB    27.747  56.793 12.113 11.136 10.696  8.863   8.798 (streamed 10.258)
 #   wan-long-32k         (1, 32768, 12, 128)  8 MB   119.776 226.464    —   43.946 42.094 34.572     —   (streamed 40.326)
 #   vae-b1-1024          (1, 16384,  1, 512)  16 MB    4.053   5.677  3.238  3.160  3.137  2.992     —   (streamed 3.075; 1024: 3.062)
+#   zimage-b1-1024.refine (1, 4096, 30, 128)  1.0 MB   5.315   9.070    —      —      —    1.691   1.626 (PR 34: read, not retuned)
 #   (* two blocks of 2304 keys; wan-long-32k in two blocks of 16,384: 41.863)
 #
 # The softmax tiles inside the one block (flash_attention.key_split; the same

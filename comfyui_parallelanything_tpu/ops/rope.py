@@ -42,3 +42,21 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     out_odd = x_even * s + x_odd * c
     out = jnp.stack([out_even, out_odd], axis=-1).reshape(xf.shape)
     return out.astype(orig_dtype)
+
+
+def half_rope_freqs(positions: jnp.ndarray, dim: int, theta: float):
+    """cos/sin tables of the half-split convention (HF ``rotate_half``; the
+    Qwen / Llama towers): positions (B, S) → (cos, sin), each (B, S, dim//2)
+    f32, angle ``position · theta^(−2k/dim)`` for pair k."""
+    return axis_rope_freqs(positions[..., None], (dim,), theta)
+
+
+def apply_rope_halves(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """Rotate pairs (x[k], x[k + D/2]): x is (B, S, H, D); cos/sin (B, S, D//2).
+    The half-split convention, against :func:`apply_rope`'s interleaved one."""
+    xf = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    x1, x2 = xf[..., :half], xf[..., half:]
+    c, s = cos[:, :, None, :], sin[:, :, None, :]
+    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    return out.astype(x.dtype)

@@ -138,6 +138,14 @@ its blocks and by whether its row of keys is ``one`` key block or
 ``streamed``: ``lane-aligned`` / ``one`` moves by 9 while FLUX.1-schnell's
 step program at 3 + 6 blocks compiles.
 
+Caption buckets (PR 34): ``pa_caption_bucket_total{tokens=}``
+(models/zimage.py — counted like ``pa_attention_route_total``, once a TRACE
+of the single-stream denoiser, with the padded caption length the program
+was compiled at: the text node pads a chat-templated prompt to a multiple
+of 32 tokens and hands the valid count beside it, so one step program
+serves every caption of a bucket. One value after a run is the evidence
+that it ran ONE step program; a second value moving is a compile).
+
 PNG encoder (PR 29): ``pa_png_images_total`` / ``pa_png_strips_total``
 (utils/png_encode.py ``write_pngs`` — once a save node's call, always on:
 the files written and the row strips deflated for them on the pool's

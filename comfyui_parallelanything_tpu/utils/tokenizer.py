@@ -10,6 +10,9 @@ from user-supplied files:
   reading the standard ``vocab.json`` + ``merges.txt`` pair.
 - ``load_tokenizer_json`` — wraps the HF ``tokenizers`` runtime (present in this
   image) for ``tokenizer.json`` files (T5 and modern CLIP exports).
+- ``load_chat_tokenizer_json`` — the same runtime on a byte-level BPE
+  ``tokenizer.json`` of Qwen's kind behind its chat template, at a bucketed
+  length (Z-Image's Qwen3 tower).
 
 Output convention matches the SD ecosystem: fixed ``max_len`` windows, BOS/EOS
 framing for CLIP, right-padding with a configurable pad id (CLIP-L pads with EOS,
@@ -209,3 +212,51 @@ def load_tokenizer_json(
     return JsonTokenizer(
         Tokenizer.from_file(os.fspath(path)), max_len, eos_id, pad_id
     )
+
+
+# Qwen3's chat template with a generation prompt and thinking left on, as
+# Z-Image's pipeline applies it to one user message.
+QWEN_CHAT_TEMPLATE = "<|im_start|>user\n{}<|im_end|>\n<|im_start|>assistant\n"
+
+
+class ChatBucketTokenizer:
+    """A byte-level BPE ``tokenizer.json`` of Qwen's kind (HF fast format: the
+    split pattern, the byte alphabet, merges by rank, ``<|im_start|>`` /
+    ``<|im_end|>`` as added special tokens) behind the chat template, for a
+    decoder-only tower: no fixed window. ``__call__`` returns (ids, mask)
+    with each text templated, truncated to ``max_len`` tokens and the batch
+    padded with ``pad_id`` to the next multiple of ``bucket`` tokens; the
+    mask marks the VALID tokens, which is all a causal tower's caller keeps."""
+
+    PAD_TOKEN = "<|endoftext|>"
+
+    def __init__(self, tok, bucket: int = 32, max_len: int = 512):
+        self._tok = tok
+        self.bucket, self.max_len = bucket, max_len
+        self.pad_id = tok.token_to_id(self.PAD_TOKEN)
+        if self.pad_id is None:
+            raise ValueError(f"tokenizer.json has no {self.PAD_TOKEN!r} token to pad with")
+
+    def encode(self, text: str) -> list[int]:
+        """Text → the templated prompt's token ids, unpadded."""
+        return self._tok.encode(QWEN_CHAT_TEMPLATE.format(text),
+                                add_special_tokens=False).ids[: self.max_len]
+
+    def __call__(self, texts: str | list[str]) -> tuple[np.ndarray, np.ndarray]:
+        if isinstance(texts, str):
+            texts = [texts]
+        rows = [self.encode(t) for t in texts]
+        width = -(-max(map(len, rows)) // self.bucket) * self.bucket
+        ids = np.full((len(rows), width), self.pad_id, np.int32)
+        mask = np.zeros((len(rows), width), np.int32)
+        for r, row in enumerate(rows):
+            ids[r, : len(row)] = row
+            mask[r, : len(row)] = 1
+        return ids, mask
+
+
+def load_chat_tokenizer_json(path: str | os.PathLike, bucket: int = 32,
+                             max_len: int = 512) -> ChatBucketTokenizer:
+    from tokenizers import Tokenizer
+
+    return ChatBucketTokenizer(Tokenizer.from_file(os.fspath(path)), bucket, max_len)

@@ -82,6 +82,17 @@ SHAPES = [
     ("vae-b8-512.mid4096", 8, 4096, 1, 512),
     ("vae-b1-1024.mid16384", 1, 16384, 1, 512),
     ("wan_480p_16f.cross512", 1, 16384, 12, 128, 512),
+    # Z-Image-Turbo's two attention classes at 1 x 1024² and CFG 1.0 (PR 34),
+    # 30 heads of 128: the main layers' over 4096 image + 32 caption tokens
+    # (16 valid, padded to the model's multiple of 32) — not a 128-multiple,
+    # so the ``ragged`` row takes it, a row measured at 64-wide heads only —
+    # and the noise refiner's over the image tokens alone (``lane-aligned``).
+    # The third row is the first as ``lane-aligned`` would run it if the
+    # CALLER padded it to 4224 (and masked nothing: 96 more keys take part,
+    # so it times the kernel, it is not the model's arithmetic).
+    ("zimage-b1-1024.joint4128", 1, 4128, 30, 128),
+    ("zimage-b1-1024.refine4096", 1, 4096, 30, 128),
+    ("zimage-b1-1024.joint4128-as-4224", 1, 4224, 30, 128),
 ]
 
 # Shapes whose sweep is not the grid below: 4352 = 17 x 256, so only 128- and
@@ -118,6 +129,8 @@ COMBOS = {
         (1024, 4096), (256, 8192), (256, 16384),
     ],
     "wan_480p_16f.cross512": [(256, 256), (256, 512), (512, 512)],
+    "zimage-b1-1024.refine4096": [(256, 256), (256, 4096), (512, 4096)],
+    "zimage-b1-1024.joint4128-as-4224": [(256, 4224), (384, 4224)],
 }
 
 BLOCKS_Q = (128, 256, 512)

@@ -35,7 +35,7 @@ RUNG_GEOMETRY = {
     # bench passes all of these from the shared step-cost accessor.
     "sd15_16": (1_720_000_000, 1.1e13, 4.0e10, 16),
     "sdxl_8": (5_100_000_000, 1.5e13, 6.0e10, 8),
-    "zimage_21": (11_600_000_000, 3.4e13, 9.0e10, 7),
+    "flux_single_heavy_21": (11_600_000_000, 3.4e13, 9.0e10, 7),
     "flux_16_int8": (12_300_000_000, 2.4e13, 8.0e10, 4),
     "wan_video": (2_800_000_000, 8.0e12, 2.5e10, 1),
     "smoke": (120_000_000, 6.0e10, 1.2e9, 8),
@@ -243,6 +243,14 @@ class TestAttentionAxis:
         # past one block's keys the row streams 4096 keys a block
         ("ragged-8269", True, 1, 8269, 8269, 24, 64, "pallas", (256, 4096)),
         ("sd35m-self4096", True, 2, 4096, 4096, 24, 64, "pallas", (256, 4096)),
+        # Z-Image-Turbo's two classes at 1 x 1024² (PR 34), 30 heads of 128:
+        # the main layers' 4096 image + 32 caption tokens are not a
+        # 128-multiple, so the ragged row pads them to 4224 (= 11 x 384) as one
+        # key block — a row measured at 64-wide heads only; the noise
+        # refiner's 4096 image tokens are lane-aligned, one key block. Read
+        # and recorded, not retuned.
+        ("zimage-joint4128", True, 1, 4128, 4128, 30, 128, "pallas", (384, 4224)),
+        ("zimage-refine4096", True, 1, 4096, 4096, 30, 128, "pallas", (256, 4096)),
         ("sd35m-joint4173-cpu", False, 2, 4173, 4173, 24, 64, "xla_chunked", None),
         ("sd15-self4096-cpu", False, 16, 4096, 4096, 8, 40, "xla_chunked", None),
         ("sd15-self1024-cpu", False, 16, 1024, 1024, 8, 80, "xla", None),

@@ -31,10 +31,20 @@ class TestSniffModelFamily:
     def test_flux_dev_vs_schnell_vs_zimage(self):
         assert sniff_model_family(self._flux_keys(dev=True)) == "flux-dev"
         assert sniff_model_family(self._flux_keys(dev=False)) == "flux-schnell"
-        # Z-image proxy: flux layout, no guidance embed, shallow double stack
-        # (flux.py z_image_turbo_config depth 6/26).
+        # A FLUX-layout file without a guidance embedder is a (possibly
+        # depth-cut) schnell whatever its depths: no FLUX-class shape carries
+        # Z-Image's name (PR 34; until then 6 double blocks sniffed as it).
         assert sniff_model_family(
             self._flux_keys(dev=False, depth=6)
+        ) == "flux-schnell"
+        # Z-Image is its published single-stream layout: refiner stacks and a
+        # caption embedder beside ``layers``, bare or prefixed.
+        zimage = {k: np.zeros((1, 1)) for k in (
+            "layers.0.attention.to_q.weight", "noise_refiner.0.attention.to_q.weight",
+            "context_refiner.0.attention.to_q.weight", "cap_embedder.1.weight")}
+        assert sniff_model_family(zimage) == "zimage-turbo"
+        assert sniff_model_family(
+            {f"model.diffusion_model.{k}": v for k, v in zimage.items()}
         ) == "zimage-turbo"
 
     def test_prefixed_full_checkpoint_keys(self):
