@@ -19,15 +19,16 @@ vector; `depth` double-stream blocks (separate img/txt weights, joint attention)
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import attention
-from ..ops.basic import modulate as _modulate, rms_normalize, timestep_embedding
-from ..ops.rope import apply_rope, axis_rope_freqs
+from ..ops.attention import attention, qk_prologue
+from ..ops.basic import modulate as _modulate, timestep_embedding
+from ..ops.rope import axis_rope_freqs
 from .api import DiffusionModel, PipelineSegment, PipelineSpec
 
 
@@ -104,16 +105,46 @@ class Modulation(nn.Module):
         return jnp.split(out[:, None, :], 3 * self.n_sets, axis=-1)
 
 
-class QKNorm(nn.Module):
-    """Per-head RMSNorm on q and k (f32), FLUX-style."""
+class FusedQKV(nn.Module):
+    """``nn.DenseGeneral((3, H, D))`` — the same parameters (``kernel``
+    (in, 3, H, D), ``bias`` (3, H, D)), the same initialisation, the same
+    numbers — computed as ONE flat matmul that writes (B, S, 3·H·D): q, k and
+    v as column blocks of H·D, the layout the q/k prologue and the flash
+    kernel read. A dot with several feature dims is laid out sequence-minor
+    by the TPU compiler and relaid for every consumer (a ``copy`` of the whole
+    output and a separate pass for the bias; ISSUE 35)."""
+
+    heads: int
+    head_dim: int
+    dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, q, k):
-        def rms(x, name):
-            scale = self.param(name, nn.initializers.ones, (x.shape[-1],))
-            return rms_normalize(x, scale)
+    def __call__(self, x):
+        features = (3, self.heads, self.head_dim)
 
-        return rms(q, "query_norm"), rms(k, "key_norm")
+        def flat_draw(rng, shape, dtype=jnp.float32):
+            # DenseGeneral's: the kernel is drawn at its flat (in, 3·H·D) shape.
+            flat = (shape[0], math.prod(features))
+            return nn.linear.default_kernel_init(rng, flat, dtype).reshape(shape)
+
+        kernel = self.param("kernel", flat_draw, (x.shape[-1], *features))
+        bias = self.param("bias", nn.initializers.zeros_init(), features)
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias, dtype=self.dtype)
+        return x @ kernel.reshape(x.shape[-1], -1) + bias.reshape(-1)
+
+
+class QKNorm(nn.Module):
+    """Per-head RMSNorm on q and k (f32), FLUX-style, then the rotary: the
+    q/k prologue (``ops/attention.qk_prologue``) with this module's scales.
+    ``qkv`` is (B, S, N, H, D) with q and k its first two of N; ``rope`` the
+    (cos, sin) of its rows."""
+
+    @nn.compact
+    def __call__(self, qkv, rope):
+        dim = qkv.shape[-1]
+        return qk_prologue(
+            qkv, self.param("query_norm", nn.initializers.ones, (dim,)),
+            self.param("key_norm", nn.initializers.ones, (dim,)), rope=rope)
 
 
 class DoubleBlock(nn.Module):
@@ -134,28 +165,29 @@ class DoubleBlock(nn.Module):
             cfg, 2, name="txt_mod"
         )(vec)
 
-        def qkv(stream, x, name):
-            h = nn.DenseGeneral((3, H, D), dtype=cfg.dtype, name=f"{name}_qkv")(x)
-            q, k, v = h[:, :, 0], h[:, :, 1], h[:, :, 2]
-            q, k = QKNorm(name=f"{name}_norm")(q, k)
-            return q, k, v
+        cos, sin = rope
+        txt_len = txt.shape[1]
+
+        def qkv(x, name, rows):
+            # Each stream is normalised with its own scales and rotated with
+            # its rows of the table, before the two are joined.
+            h = FusedQKV(H, D, cfg.dtype, name=f"{name}_qkv")(x)
+            q, k = QKNorm(name=f"{name}_norm")(
+                h.reshape(*x.shape[:2], 3, H, D), (cos[:, rows], sin[:, rows]))
+            return q, k, h[..., 2 * H * D:].reshape(*x.shape[:2], H, D)
 
         img_n = _modulate(nn.LayerNorm(use_bias=False, use_scale=False, dtype=cfg.dtype,
                                        name="img_norm1")(img), im_shift1, im_scale1)
         txt_n = _modulate(nn.LayerNorm(use_bias=False, use_scale=False, dtype=cfg.dtype,
                                        name="txt_norm1")(txt), tx_shift1, tx_scale1)
-        iq, ik, iv = qkv("img", img_n, "img_attn")
-        tq, tk, tv = qkv("txt", txt_n, "txt_attn")
+        iq, ik, iv = qkv(img_n, "img_attn", slice(txt_len, None))
+        tq, tk, tv = qkv(txt_n, "txt_attn", slice(None, txt_len))
 
         q = jnp.concatenate([tq, iq], axis=1)
         k = jnp.concatenate([tk, ik], axis=1)
         v = jnp.concatenate([tv, iv], axis=1)
-        cos, sin = rope
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
         attn = attention(q, k, v)
         attn = attn.reshape(attn.shape[0], attn.shape[1], -1)
-        txt_len = txt.shape[1]
         txt_attn, img_attn = attn[:, :txt_len], attn[:, txt_len:]
 
         img = img + im_gate1.astype(cfg.dtype) * nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="img_attn_proj")(img_attn)
@@ -194,13 +226,12 @@ class SingleBlock(nn.Module):
                                      name="pre_norm")(x), shift, scale)
         fused = nn.Dense(3 * cfg.hidden_size + mlp_dim, dtype=cfg.dtype, name="linear1")(x_n)
         qkv, mlp = fused[..., : 3 * cfg.hidden_size], fused[..., 3 * cfg.hidden_size :]
-        q, k, v = (
-            qkv.reshape(x.shape[0], x.shape[1], 3, H, D)[:, :, i] for i in range(3)
-        )
-        q, k = QKNorm(name="norm")(q, k)
-        cos, sin = rope
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        v = qkv[..., 2 * cfg.hidden_size:].reshape(x.shape[0], x.shape[1], H, D)
+        # q and k are read from ``linear1``'s output where they lie: its
+        # column blocks of H·D, where the MLP's width is whole ones too.
+        src = qkv if mlp_dim % cfg.hidden_size else fused
+        q, k = QKNorm(name="norm")(
+            src.reshape(x.shape[0], x.shape[1], -1, H, D), rope)
         attn = attention(q, k, v).reshape(x.shape[0], x.shape[1], -1)
         out = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="linear2")(
             jnp.concatenate([attn, nn.gelu(mlp)], axis=-1)

@@ -27,9 +27,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import attention
-from ..ops.basic import modulate as _modulate, rms_normalize, timestep_embedding
+from ..ops.attention import attention, qk_prologue
+from ..ops.basic import modulate as _modulate, timestep_embedding
 from .api import DiffusionModel, PipelineSegment, PipelineSpec
+from .flux import FusedQKV
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,11 +155,15 @@ class _StreamAttnIn(nn.Module):
             name="norm",
         )(x)
         h = _modulate(h, shift, scale)
-        qkv = nn.DenseGeneral((3, H, D), dtype=cfg.dtype, name="qkv")(h)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        flat = FusedQKV(H, D, cfg.dtype, name="qkv")(h)  # (B, S, 3·H·D)
+        qkv = flat.reshape(*x.shape[:2], 3, H, D)
+        q, k = qkv[:, :, 0], qkv[:, :, 1]
+        v = flat[..., 2 * H * D:].reshape(*x.shape[:2], H, D)
         if cfg.qk_norm:
-            q = rms_normalize(q, self.param("ln_q", nn.initializers.ones, (D,)))
-            k = rms_normalize(k, self.param("ln_k", nn.initializers.ones, (D,)))
+            # q and k are read from the projection's output where they lie.
+            q, k = qk_prologue(
+                qkv, self.param("ln_q", nn.initializers.ones, (D,)),
+                self.param("ln_k", nn.initializers.ones, (D,)))
         return h, q, k, v
 
 

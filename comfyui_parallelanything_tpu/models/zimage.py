@@ -44,9 +44,9 @@ from typing import Any
 import flax.linen as nn
 import jax.numpy as jnp
 
-from ..ops.attention import attention
+from ..ops.attention import attention, qk_prologue
 from ..ops.basic import timestep_embedding
-from ..ops.rope import apply_rope, axis_rope_freqs
+from ..ops.rope import axis_rope_freqs
 from .api import DiffusionModel
 from .text_encoders import _RMSNorm
 
@@ -98,6 +98,17 @@ def padded_length(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
 
 
+class _Scale(nn.Module):
+    """The learned ``scale`` of an RMS norm whose arithmetic runs elsewhere
+    (the q/k prologue): the parameter under the name ``_RMSNorm`` gives it."""
+
+    dim: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.dim,))
+
+
 class ZImageBlock(nn.Module):
     """One S3-DiT block; ``modulated`` False is the context refiner's."""
 
@@ -133,10 +144,10 @@ class ZImageBlock(nn.Module):
         h = scaled(_RMSNorm(cfg.norm_eps, name="attention_norm1")(x), scale_a)
         q, k, v = (dense(cfg.dim, n)(h).reshape(B, S, H, D)
                    for n in ("to_q", "to_k", "to_v"))
-        q = _RMSNorm(cfg.norm_eps, name="norm_q")(q)
-        k = _RMSNorm(cfg.norm_eps, name="norm_k")(k)
-        cos, sin = rope
-        a = attention(apply_rope(q, cos, sin), apply_rope(k, cos, sin), v)
+        q, k = qk_prologue(
+            (q, k), _Scale(D, name="norm_q")(), _Scale(D, name="norm_k")(),
+            cfg.norm_eps, rope)
+        a = attention(q, k, v)
         a = dense(cfg.dim, "to_out")(a.reshape(B, S, cfg.dim))
         a = _RMSNorm(cfg.norm_eps, name="attention_norm2")(a)
         x = x + gated(a, gate_a)

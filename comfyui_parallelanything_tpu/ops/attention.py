@@ -252,7 +252,7 @@ def _log_interpreted_once() -> None:
 
 
 def resolve_route(seq_q: int, seq_k: int, head_dim: int, batch_heads: int,
-                  itemsize: int = 2):
+                  itemsize: int = 2, on_tpu: bool | None = None):
     """``route()`` of ops/pallas/tuning.py for a call in this process: on the
     default backend, under the process's pin and its chunk threshold. What
     :func:`attention_local` executes and the planner records (the planner at
@@ -260,7 +260,8 @@ def resolve_route(seq_q: int, seq_k: int, head_dim: int, batch_heads: int,
     from .pallas.tuning import route
 
     return route(
-        seq_q, seq_k, head_dim, batch_heads, on_tpu=_pallas_available(),
+        seq_q, seq_k, head_dim, batch_heads,
+        on_tpu=_pallas_available() if on_tpu is None else on_tpu,
         pinned=_BACKEND, chunk_threshold=_chunk_threshold(), itemsize=itemsize,
     )
 
@@ -271,10 +272,14 @@ def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
     the seq-parallel path would recurse)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    chosen = resolve_route(
-        q.shape[1], k.shape[1], q.shape[-1], q.shape[0] * q.shape[2],
-        k.dtype.itemsize,
-    )
+    shape = (q.shape[1], k.shape[1], q.shape[-1], q.shape[0] * q.shape[2],
+             k.dtype.itemsize)
+    chosen = resolve_route(*shape)
+    if (chosen.backend == "pallas" and chosen.rule != "pinned"
+            and not _mosaic_reach(q.shape[0])):
+        # A partitioned program that a Mosaic call cannot live in: the XLA
+        # family, as off a TPU.
+        chosen = resolve_route(*shape, on_tpu=False)
     _count_route(chosen.backend)
     if chosen.backend == "pallas":
         from ..utils.metrics import registry
@@ -311,6 +316,95 @@ def attention_local(q, k, v, scale: float | None = None) -> jnp.ndarray:
     if chosen.backend == "xla_chunked":
         return _xla_chunked_attention(q, k, v, scale)
     return _xla_attention(q, k, v, scale)
+
+
+def _mosaic_reach(batch: int) -> bool:
+    """Whether a Mosaic call traced here, on operands of ``batch`` rows, can
+    live in the program around it: under a context mesh only where
+    ``flash_attention.over_data_axis`` can shard_map it — the one partitioned
+    axis is ``data`` and divides the batch. A weight-sharded program's batch
+    may not divide, a tensor-parallel one's axis is not ``data``: the
+    partitioner refuses a bare Mosaic call there. The one rule for the flash
+    kernel's calls (:func:`attention_local`) and the q/k prologue's."""
+    from ..parallel.mesh import AXIS_DATA
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return True
+    return all(
+        n == 1 or name in mesh.manual_axes
+        or (name == AXIS_DATA and batch % n == 0)
+        for name, n in dict(mesh.shape).items()
+    )
+
+
+def qk_prologue(qkv, q_scale, k_scale, eps: float = 1e-6, rope=None):
+    """What stands between a block's qkv projection and its attention: the
+    per-head RMS norm of q and of k with their learned ``(D,)`` scales
+    (``ops/basic.rms_normalize``) and, where ``rope = (cos, sin)`` is given,
+    the interleaved-pair rotary (``ops/rope.apply_rope``). Returns ``(q, k)``,
+    each (B, S, H, D) in the operands' dtype.
+
+    ``qkv`` is a pair ``(q, k)`` of (B, S, H, D) arrays, or ONE array
+    (B, S, N, H, D), N >= 2, whose ``[:, :, 0]`` is q and ``[:, :, 1]`` k — a
+    fused projection's output as it was written, so that the kernel reads q
+    and k where they lie and no slice is copied first.
+
+    One entry point for every family; the path is read from the call, as
+    ``tuning.route`` reads attention's: where a Mosaic call can live
+    (:func:`_mosaic_reach`, and not under ``sequence_parallel``), on a TPU (or under a pinned ``pallas``), at
+    ``tuning.qk_prologue_route``'s measured size or over, the one-pass kernel
+    (ops/pallas/qk_prologue.py); otherwise — off a TPU, a text tower's or a
+    context stream's few dozen rows, a sharded program — the jnp functions,
+    untouched. Counted once a trace: ``pa_qk_prologue_total{path, rope}``."""
+    from ..utils.metrics import registry
+    from .basic import rms_normalize
+    from .pallas.tuning import qk_prologue_route
+
+    fused_source = not isinstance(qkv, (tuple, list))
+    like = qkv if fused_source else qkv[0]
+    batch, seq = like.shape[:2]
+    heads, head_dim = like.shape[-2:]
+    # Inside a sequence_parallel context attention() hands its call to the
+    # sequence-parallel program, whose operands are sharded over ``seq``.
+    in_reach = getattr(_SEQ_CTX, "cfg", None) is None and _mosaic_reach(batch)
+    fused = in_reach and qk_prologue_route(
+        batch * seq, heads, head_dim, rope is not None,
+        on_tpu=_pallas_available(), pinned=_BACKEND,
+    )
+    registry.counter(
+        "pa_qk_prologue_total",
+        labels={"path": "fused" if fused else "xla",
+                "rope": "none" if rope is None else "interleaved"},
+        help="q/k prologues (per-head RMS norm, and the rotary where the "
+             "model has one) by the path they took, counted like "
+             "pa_attention_route_total: once a trace "
+             "(ops/attention.qk_prologue)",
+    )
+    if not fused:
+        q, k = (qkv[:, :, 0], qkv[:, :, 1]) if fused_source else qkv
+        q, k = rms_normalize(q, q_scale, eps), rms_normalize(k, k_scale, eps)
+        if rope is not None:
+            from .rope import apply_rope
+
+            q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+        return q, k
+
+    from .pallas.qk_prologue import qk_prologue_call, rope_tables
+
+    interpret = not _pallas_available()
+    if interpret:
+        _log_interpreted_once()
+    # (B, S, ..., H, D) -> (B, S, ...·H·D): the layout the projection wrote.
+    flat = lambda x: x.reshape(batch, seq, -1)  # noqa: E731
+    q_src, k_src = (flat(qkv), None) if fused_source else map(flat, qkv)
+    q, k = qk_prologue_call(
+        q_src, k_src, q_scale, k_scale,
+        None if rope is None else rope_tables(*rope),
+        heads=heads, eps=float(eps), interpret=interpret,
+    )
+    return (q.reshape(batch, seq, heads, head_dim),
+            k.reshape(batch, seq, heads, head_dim))
 
 
 def attention(q, k, v, scale: float | None = None) -> jnp.ndarray:

@@ -186,6 +186,59 @@ def lane_aligned_route(seq_k: int, head_dim: int,
     return block_q, seq_k if one_block else streamed_k
 
 
+# -- The q/k prologue (per-head RMS norm, and the rotary) -------------------------
+# ops/pallas/qk_prologue.py against the jnp functions it stands in for
+# (ops/basic.rms_normalize, ops/rope.apply_rope) as XLA lowers them alone.
+# Measured on the v5e, bfloat16, ms of DEVICE time a call on q AND k, read
+# from the profiler's trace: a call is shorter than its 0.45 ms dispatch, and
+# looped inside one program it is charged the loop's carried copies (0.29 ms
+# read that way where 0.20 is the kernel's). scripts/bench_kernels.py
+# --prologue, my chip run, PR 35; PERF.md §6. "floor" is q and k read and
+# written once at 819 GB/s; the kernel by rows x lanes a grid step; q and k
+# are column blocks of one (B, S, 3·H·D) array (FLUX's single blocks: of
+# linear1's 7·H·D) except Z-Image's, arrays of their own:
+#
+#   (batch, rows, heads, head dim)  rotary  floor   xla    256x4096 256x1024 512x1024 512x512 1024x512
+#   sd35m-b1-1024   (2, 4096, 24,  64)  no  0.123  0.628   0.156   0.157   0.157   0.157   0.158
+#   flux single     (1, 4352, 24, 128) yes  0.131  1.545   0.184   0.188   0.190   0.204   0.207
+#   flux img stream (1, 4096, 24, 128) yes  0.123  1.170   0.174   0.174   0.173   0.183   0.176
+#   zimage joint    (1, 4128, 30, 128) yes  0.155  2.211   0.220   0.236   0.236   0.256   0.265
+#   flux 512² img   (1, 1024, 24, 128) yes  0.031  0.187   0.049   0.047   0.049   0.047   0.049
+#   sd35m-b1-512    (2, 1024, 24,  64)  no  0.031  0.067   0.041   0.041   0.042   0.042   0.042
+#   flux txt stream (1,  256, 24, 128) yes  0.008  0.038   0.016   0.016   0.015   0.015   0.015
+#   sd35m context   (2,   77, 24,  64)  no  0.002  0.019   0.009   0.009   0.009   0.009   0.009
+#   zimage caption  (1,   32, 30, 128) yes  0.001  0.024   0.004   0.005   0.005   0.007   0.007
+#
+# The kernel holds 66–79% of the HBM roofline at the cells' four classes and
+# wins 4.0–9.4x there; it wins at every shorter class too, by less and less
+# in ms: 0.14 and 0.026 a call at the 512² streams, 0.022 at FLUX's 256 text
+# tokens, 0.010 and 0.019 at the 77- and 32-token text streams. Every call
+# site is one more Mosaic kernel to compile (0.4–0.8 s each on the described
+# chip at 256 x 1024; the 77-token stream has 24 sites a program), so the
+# threshold stands at the smallest class measured whose win is above 0.02 ms a
+# call: FLUX's 256 text tokens. 256 rows x 1024 lanes a step is within 2% of
+# the fastest at SD3.5's and both FLUX classes and 7% behind 256 x 4096 at
+# Z-Image's (0.16 ms a step), and compiles in a quarter of 256 x 4096's time
+# (0.6 s a call site against 2.3–4.2).
+QK_PROLOGUE_MIN_ELEMENTS = 256 * 24 * 128
+QK_PROLOGUE_TILE = (256, 1024)  # rows, lanes a grid step
+
+
+def qk_prologue_route(rows: int, heads: int, head_dim: int, rope: bool, *,
+                      on_tpu: bool, pinned: str = "auto") -> bool:
+    """Whether the one-pass kernel serves a q/k prologue of ``rows`` (B·S)
+    rows of ``heads`` heads: the pin as :func:`route` reads it (``pallas``
+    wherever the kernel is written for the shape, an XLA pin never), else on
+    a TPU from QK_PROLOGUE_MIN_ELEMENTS elements a tensor up."""
+    from .qk_prologue import supports
+
+    if pinned in ("xla", "xla_chunked") or not supports(heads, head_dim, rope):
+        return False
+    if pinned == "pallas":
+        return True
+    return on_tpu and rows * heads * head_dim >= QK_PROLOGUE_MIN_ELEMENTS
+
+
 class Route(NamedTuple):
     backend: str            # "xla" | "xla_chunked" | "pallas"
     block_q: int | None     # the fused kernel's blocks; None in the XLA family

@@ -138,6 +138,17 @@ its blocks and by whether its row of keys is ``one`` key block or
 ``streamed``: ``lane-aligned`` / ``one`` moves by 9 while FLUX.1-schnell's
 step program at 3 + 6 blocks compiles.
 
+The q/k prologue (PR 35): ``pa_qk_prologue_total{path=,rope=}``
+(ops/attention.py ``qk_prologue`` — counted like
+``pa_attention_route_total``, once a TRACE: the per-head RMS norm of q and k,
+and the rotary where the model has one, by the path it took — ``fused`` the
+one-pass Pallas kernel (ops/pallas/qk_prologue.py), ``xla`` the jnp
+functions — and by ``rope`` ``interleaved`` / ``none``. While the cells' step
+programs compile: SD3.5-medium 37 ``fused`` / ``none`` (the image stream of
+24 joint and 13 dual attentions) and 24 ``xla`` (77 text tokens); Z-Image at
+8 main layers 10 ``fused`` / ``interleaved`` and 2 ``xla`` (the context
+refiner's 32 tokens); FLUX.1-schnell at 3 + 6 blocks 12 ``fused``).
+
 Caption buckets (PR 34): ``pa_caption_bucket_total{tokens=}``
 (models/zimage.py — counted like ``pa_attention_route_total``, once a TRACE
 of the single-stream denoiser, with the padded caption length the program

@@ -9,6 +9,7 @@ time); without a TPU it exits non-zero and measures nothing:
     python scripts/bench_kernels.py --shape flux-schnell-b1-1024.joint4352 \
         --blocks 256x4352 --chunk-k 1536   # one combination, 1536-key tiles
     KERNEL_SWEEP=0 python scripts/bench_kernels.py   # default blocks only
+    python scripts/bench_kernels.py --prologue       # the q/k prologue's rows
 
 Shapes cover the rungs that matter: the benchmark cells' UNet self-attention
 classes (named ``<cell>.self<tokens>``: what ops/pallas/tuning.py's shape rule
@@ -255,6 +256,164 @@ def _run_shapes(shapes, dev, blocks=None, chunk_k=None):
             f.write(json.dumps(rec) + "\n")
 
 
+# The q/k prologue's classes (ops/pallas/qk_prologue.py): (label, batch, rows,
+# heads, head dim, rotary, column blocks of the source array — 3: a fused qkv
+# projection, 7: FLUX's linear1, 1: q and k are arrays of their own).
+PROLOGUE_SHAPES = [
+    ("sd35m-b1-1024.x4096", 2, 4096, 24, 64, False, 3),
+    ("flux-schnell-b1-1024.single4352", 1, 4352, 24, 128, True, 7),
+    ("flux-schnell-b1-1024.img4096", 1, 4096, 24, 128, True, 3),
+    ("zimage-b1-1024.joint4128", 1, 4128, 30, 128, True, 1),
+    # Short rows, for the threshold: FLUX's 256 T5 tokens, 512² image
+    # streams, SD3.5's 77 text tokens and Z-Image's 32 caption tokens.
+    ("flux-schnell-b1-1024.txt256", 1, 256, 24, 128, True, 3),
+    ("flux-schnell-b1-512.img1024", 1, 1024, 24, 128, True, 3),
+    ("sd35m-b1-512.x1024", 2, 1024, 24, 64, False, 3),
+    ("sd35m-b1-1024.ctx77", 2, 77, 24, 64, False, 3),
+    ("zimage-b1-1024.cap32", 1, 32, 30, 128, True, 1),
+]
+# (rows, lanes) a grid step.
+PROLOGUE_TILES = [(256, 4096), (256, 1024), (512, 1024), (512, 512),
+                  (1024, 512)]
+_HBM_BYTES_PER_S = 819e9  # v5e (benchmark/peaks.json's source)
+
+
+def _device_time(fn, *args, runs: int = 10) -> float:
+    """Mean seconds of DEVICE time a run of the jitted ``fn``, from the
+    profiler's trace (the ``XLA Modules`` line of the chip's plane). The
+    host's clock cannot time a program shorter than its dispatch (0.45 ms a
+    call here), and looping it inside one program charges the loop's carried
+    copies to it."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.block_until_ready(fn(*args))  # compile, warm
+    with tempfile.TemporaryDirectory() as log_dir:
+        jax.profiler.start_trace(log_dir)
+        for _ in range(runs):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        path = sorted(glob.glob(os.path.join(
+            log_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+        data = ProfileData.from_file(path)
+    spans = [ev.duration_ns for plane in data.planes
+             if plane.name == "/device:TPU:0"
+             for line in plane.lines if line.name == "XLA Modules"
+             for ev in line.events]
+    if len(spans) < runs:
+        raise RuntimeError(f"{len(spans)} module runs in the trace, {runs} made")
+    return sum(spans[-runs:]) / runs / 1e9
+
+
+def _run_prologue(shapes, dev, tiles=None):
+    """The q/k prologue as the jnp functions lower it against the one-pass
+    kernel, by rows and lanes a grid step: ms of device time a call on q AND
+    k (:func:`_device_time`), the share of
+    the HBM roofline (q and k read and written once), and how far the two
+    forms' results lie apart. One JSON line a shape, appended to
+    KERNEL_BENCH.json."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bench import evidence_dir
+    from comfyui_parallelanything_tpu.ops.basic import rms_normalize
+    from comfyui_parallelanything_tpu.ops.pallas.qk_prologue import (
+        qk_prologue_call,
+        rope_tables,
+    )
+    from comfyui_parallelanything_tpu.ops.rope import apply_rope, axis_rope_freqs
+
+    out_path = os.path.join(evidence_dir(), "KERNEL_BENCH.json")
+    for label, b, s, h, d, rotary, blocks in shapes:
+        keys = jax.random.split(jax.random.key(0), 5)
+        width = h * d
+        make = lambda key, n: (  # noqa: E731
+            3.0 * jax.random.normal(key, (b, s, n * width))).astype(jnp.bfloat16)
+        srcs = ((make(keys[0], blocks),) if blocks > 1
+                else (make(keys[0], 1), make(keys[1], 1)))
+        scales = tuple(1.0 + 0.1 * jax.random.normal(k, (d,)) for k in keys[2:4])
+        rope = None
+        if rotary:
+            ids = jax.random.randint(keys[4], (b, s, 3), 0, 64)
+            rope = axis_rope_freqs(ids, (d // 4, 3 * d // 8, 3 * d // 8), 256.0)
+
+        def xla_form(*src):
+            if len(src) == 1:
+                view = src[0].reshape(b, s, blocks, h, d)
+                q, k = view[:, :, 0], view[:, :, 1]
+            else:
+                q, k = (x.reshape(b, s, h, d) for x in src)
+            q, k = rms_normalize(q, scales[0]), rms_normalize(k, scales[1])
+            if rope is not None:
+                q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+            return q.reshape(b, s, width), k.reshape(b, s, width)
+
+        def kernel_form(rows, lanes):
+            def run(*src):
+                return qk_prologue_call(
+                    src[0], src[1] if len(src) > 1 else None, *scales,
+                    None if rope is None else rope_tables(*rope), heads=h,
+                    eps=1e-6, block_rows=rows, tile_lanes=lanes,
+                    interpret=False)
+            return run
+
+        def timed(form):
+            # q and k are the program's outputs: nothing of them can be
+            # optimised away, and nothing is copied that a model would not.
+            return _device_time(jax.jit(form), *srcs)
+
+        def exact(*src):
+            # float32 throughout, rounded once: what both forms approximate.
+            f32 = tuple(x.astype(jnp.float32) for x in src)
+            return tuple(x.astype(jnp.bfloat16) for x in xla_form(*f32))
+
+        floor_ms = 4 * b * s * width * 2 / _HBM_BYTES_PER_S * 1e3
+        rec = {"shape": label, "kind": "qk_prologue", "b": b, "rows": s,
+               "heads": h, "head_dim": d, "rope": rotary,
+               "source_blocks": blocks, "platform": dev.platform,
+               "device_kind": dev.device_kind, "floor_ms": round(floor_ms, 4),
+               "ts": time.time()}
+        def host(pair):
+            return [np.asarray(x.astype(jnp.float32)) for x in pair]
+
+        def differ(a, b_):
+            return float(np.mean([np.mean(x != y) for x, y in zip(a, b_)]))
+
+        want, once = host(jax.jit(xla_form)(*srcs)), host(jax.jit(exact)(*srcs))
+        rec["xla_ms"] = round(timed(xla_form) * 1e3, 4)
+        rec["xla_differ_from_exact_share"] = differ(want, once)
+        best = None
+        for rows, lanes in tiles or PROLOGUE_TILES:
+            tag = f"{rows}x{lanes}"
+            try:
+                form = kernel_form(rows, lanes)
+                got = host(jax.jit(form)(*srcs))
+                ms = timed(form) * 1e3
+            except Exception as e:  # noqa: BLE001 — record, keep sweeping
+                rec[f"fused_{tag}_error"] = str(e)[:160]
+                continue
+            rec[f"fused_{tag}_ms"] = round(ms, 4)
+            rec[f"fused_{tag}_max_abs_diff"] = float(
+                max(np.abs(w - g).max() for w, g in zip(want, got)))
+            rec[f"fused_{tag}_differ_share"] = differ(want, got)
+            rec[f"fused_{tag}_differ_from_exact_share"] = differ(once, got)
+            if best is None or ms < best[0]:
+                best = (ms, rows, lanes)
+        if best is not None:
+            rec["fused_ms"], rec["block_rows"], rec["tile_lanes"] = (
+                round(best[0], 4), *best[1:])
+            rec["fused_roofline_share"] = round(floor_ms / best[0], 3)
+            rec["fused_speedup"] = round(rec["xla_ms"] / best[0], 2)
+        print(json.dumps(rec))
+        with open(out_path, "a") as f:
+            f.write(json.dumps(rec) + "\n")
+
+
 def main() -> None:
     import jax
 
@@ -271,7 +430,7 @@ def main() -> None:
         return sys.argv[sys.argv.index(name) + 1] if name in sys.argv else None
 
     shapes = SHAPES
-    if option("--shape"):
+    if option("--shape") and "--prologue" not in sys.argv:
         labels = option("--shape").split(",")
         shapes = [sh for sh in SHAPES if sh[0] in labels]
         if len(shapes) != len(labels):
@@ -280,6 +439,13 @@ def main() -> None:
         tuple(int(n) for n in pair.split("x"))
         for pair in option("--blocks").split(",")]
     chunk_k = option("--chunk-k") and int(option("--chunk-k"))
+    if "--prologue" in sys.argv:
+        # The q/k prologue's rows; --shape and --blocks (rows x lanes a grid
+        # step) narrow them as they narrow attention's.
+        picked = [sh for sh in PROLOGUE_SHAPES
+                  if not option("--shape") or sh[0] in option("--shape").split(",")]
+        _run_prologue(picked, dev, blocks)
+        return
     _run_shapes(shapes, dev, blocks, chunk_k)
 
 
