@@ -96,6 +96,7 @@ Run:  ``python -m comfyui_parallelanything_tpu.server [--port 8188]``
 from __future__ import annotations
 
 import base64
+import contextlib
 import hashlib
 import json
 import os
@@ -298,6 +299,10 @@ class PromptQueue:
         # pid → its per-prompt cooperative Cancel event (progress_scope).
         self.running: dict[str, threading.Event] = {}  # guarded-by: _lock
         self.history: dict[str, dict] = {}  # guarded-by: _lock
+        # Output file (as GET /view resolves it) → the prompt that wrote it;
+        # filled beside the history entry while the tracer is on, read by the
+        # `http-view` span.
+        self.output_owner: dict[str, str] = {}  # guarded-by: _lock
         self.counter = 0
         self._lock = threading.Lock()
         self._listeners: dict = {}  # socket → _WsListener — guarded-by: _lock
@@ -600,143 +605,175 @@ class PromptQueue:
             self.decode_queue.shutdown()
 
     def _run(self) -> None:
+        turn_end = None
         while True:
-            item = self.pending.get()
+            # From the end of one turn to the next item in hand, under the id
+            # of the prompt whose start the wait delayed: with one closed-loop
+            # client this is the client's turn (poll, /history, /view, POST)
+            # seen from the chip's side. admission-wait ends inside it.
+            with tracing.span("worker-idle", cat="server",
+                              start_us=turn_end) as idle:
+                item = self.pending.get()
+                if item is not None:
+                    idle.set(prompt_id=item[0])
             if item is None:
                 self.pending.put(None)  # cascade to sibling workers
                 return
-            pid, prompt, preview, priority, deadline_s, fleet, stage, enq_ts = item
-            cancel_evt = threading.Event()
-            with self._lock:
-                if pid not in self.pending_ids:
-                    continue  # interrupted while queued
-                # Publish under the same lock interrupt()/cancel() set events
-                # under; the event is fresh per prompt, so a stale Cancel
-                # aimed at a previous prompt cannot exist by construction.
-                self.running[pid] = cancel_evt
-            self._emit({"type": "execution_start", "data": {"prompt_id": pid}})
-            t0 = time.monotonic()
-            # SLO admission stage: ingress → worker pickup — the queue wait
-            # a closed-loop client never inflates and an open-loop one does.
-            admission_s = max(0.0, t0 - enq_ts)
-            slo.observe_stage("admission", admission_s)
-            if tracing.on():
-                now_us = tracing.now_us()
-                tracing.record("admission-wait", now_us - admission_s * 1e6,
-                               admission_s * 1e6, cat="server",
-                               prompt_id=pid)
-            # Per-node `executing` + per-step `progress` events — the pair a
-            # stock ComfyUI frontend renders its progress bars from. The node
-            # id rides a cell so the progress hook can tag its events with
-            # whichever node is currently executing.
-            current: dict = {"node": None}
+            turn_end = self._turn(item)
 
-            def on_node(nid, _pid=pid, _cur=current):
-                _cur["node"] = nid
-                self._emit({
-                    "type": "executing",
-                    "data": {"node": nid, "prompt_id": _pid},
-                })
+    def _turn(self, item: tuple) -> float | None:
+        """One turn of a worker: run the prompt, write its history entry.
+        Returns the tracer's clock where the turn ended (None with the
+        tracer off), which is where the worker's next wait starts."""
+        pid, prompt, preview, priority, deadline_s, fleet, stage, enq_ts = item
+        cancel_evt = threading.Event()
+        with self._lock:
+            if pid not in self.pending_ids:
+                return None  # interrupted while queued
+            # Publish under the same lock interrupt()/cancel() set events
+            # under; the event is fresh per prompt, so a stale Cancel
+            # aimed at a previous prompt cannot exist by construction.
+            self.running[pid] = cancel_evt
+        self._emit({"type": "execution_start", "data": {"prompt_id": pid}})
+        t0 = time.monotonic()
+        # SLO admission stage: ingress → worker pickup — the queue wait
+        # a closed-loop client never inflates and an open-loop one does.
+        admission_s = max(0.0, t0 - enq_ts)
+        slo.observe_stage("admission", admission_s)
+        picked_us = None
+        if tracing.on():
+            picked_us = tracing.now_us()
+            tracing.record("admission-wait", picked_us - admission_s * 1e6,
+                           admission_s * 1e6, cat="server",
+                           prompt_id=pid)
+        # Per-node `executing` + per-step `progress` events — the pair a
+        # stock ComfyUI frontend renders its progress bars from. The node
+        # id rides a cell so the progress hook can tag its events with
+        # whichever node is currently executing.
+        current: dict = {"node": None}
 
-            def hook(value, max_value, _pid=pid, _cur=current):
-                self._emit({
-                    "type": "progress",
-                    "data": {"value": value, "max": max_value,
-                             "prompt_id": _pid, "node": _cur["node"]},
-                })
+        def on_node(nid, _pid=pid, _cur=current):
+            _cur["node"] = nid
+            self._emit({
+                "type": "executing",
+                "data": {"node": nid, "prompt_id": _pid},
+            })
 
-            def on_cached(nids, _pid=pid):
-                self._emit({
-                    "type": "execution_cached",
-                    "data": {"nodes": list(nids), "prompt_id": _pid},
-                })
+        def hook(value, max_value, _pid=pid, _cur=current):
+            self._emit({
+                "type": "progress",
+                "data": {"value": value, "max": max_value,
+                         "prompt_id": _pid, "node": _cur["node"]},
+            })
 
-            def preview_hook(latent):
-                # Stock preview frame: >II event-type 1 (PREVIEW_IMAGE) +
-                # image format 2 (PNG), then the PNG bytes. Never let a
-                # preview failure (odd latent rank, PIL hiccup) kill the
-                # prompt — previews are best-effort by contract.
-                import struct
+        def on_cached(nids, _pid=pid):
+            self._emit({
+                "type": "execution_cached",
+                "data": {"nodes": list(nids), "prompt_id": _pid},
+            })
 
-                try:
-                    from .utils.latent_preview import preview_png
+        def preview_hook(latent):
+            # Stock preview frame: >II event-type 1 (PREVIEW_IMAGE) +
+            # image format 2 (PNG), then the PNG bytes. Never let a
+            # preview failure (odd latent rank, PIL hiccup) kill the
+            # prompt — previews are best-effort by contract.
+            import struct
 
-                    png = preview_png(latent)
-                except Exception:  # noqa: BLE001 — preview is best-effort
-                    return
-                self._emit_binary(struct.pack(">II", 1, 2) + png)
-
-            from .serving.scheduler import serving_hints
-
-            # Fault site (utils/faults.py): the straggler rehearsal — an
-            # injected slow-host stalls the prompt worker, not the HTTP
-            # surface, so health polls stay green while latency inflates
-            # (exactly the failure the router's saturation spill must absorb).
-            _slow = faults.check("slow-host", key=pid)
-            if _slow is not None:
-                _slow.sleep()
-            # Role-pool staged dispatch (fleet/roles.py): a router hop
-            # carrying extra_data.pa_stage executes ONE carved stage — the
-            # stage's upstream-closure subgraph with the previous stage's
-            # content-addressed outputs preseeded. A failed carve or handle
-            # resolution degrades to executing the closure (or the whole
-            # graph) locally — bitwise by the fold_in contract, never an
-            # error.
-            exec_graph, preseed, stage_entry = self._stage_setup(prompt, stage)
-            # Inbound distributed-trace context (W3C traceparent shape,
-            # injected by the fleet router into extra_data.fleet): parsed
-            # here so this host's whole span subtree — prompt, node, lane,
-            # step, decode — joins the router's cross-host trace under one
-            # trace_id. Malformed/absent context degrades to local-only.
-            tp = (tracing.parse_traceparent(fleet.get("traceparent"))
-                  if fleet and tracing.on() else None)
             try:
-                # The prompt span is the root of this prompt's trace
-                # timeline; prompt_id on the scope correlates log records and
-                # spans recorded anywhere on (or on behalf of) this thread.
-                with progress_scope(
-                    hook=hook,
-                    preview_hook=preview_hook if preview else None,
-                    interrupt_event=cancel_evt,
-                    prompt_id=pid,
-                ), serving_hints(priority=priority, deadline_s=deadline_s), \
-                        tracing.trace_context(tp), \
-                        tracing.span(
-                            "prompt", cat="server", prompt_id=pid,
-                            # Every span names its host + role: the stitched
-                            # fleet timeline's per-tier filter keys.
-                            host_id=self.host_id, role=self.role,
-                            # Cross-hop correlation: a fleet router stamps
-                            # its own prompt id into extra_data.fleet, so
-                            # this backend-side timeline joins the router's
-                            # fleet-prompt/fleet-hop spans in one export.
-                            **({"origin_prompt_id": fleet.get("origin"),
-                                "router": fleet.get("router")}
-                               if fleet else {}),
-                            **({"trace_id": tp["trace_id"],
-                                "parent_span_id": tp["parent_span_id"]}
-                               if tp else {}),
-                            **({"stage": stage_entry["stage"]}
-                               if stage_entry is not None else {}),
-                        ):
-                    if stage_entry is not None:
-                        # Denoise hosts may pull conds straight off the
-                        # encode tier (models/embed_cache.py remote tier).
-                        from .models.embed_cache import set_remote_sources
+                from .utils.latent_preview import preview_png
 
-                        set_remote_sources(
-                            (stage or {}).get("sources") or ())
-                    try:
-                        results = run_workflow(
-                            exec_graph, class_mappings=self.class_mappings,
-                            outputs=self.cache, on_node=on_node,
-                            on_cached=on_cached, preseed=preseed,
-                        )
-                    finally:
+                png = preview_png(latent)
+            except Exception:  # noqa: BLE001 — preview is best-effort
+                return
+            self._emit_binary(struct.pack(">II", 1, 2) + png)
+
+        from .serving.scheduler import serving_hints
+
+        # Fault site (utils/faults.py): the straggler rehearsal — an
+        # injected slow-host stalls the prompt worker, not the HTTP
+        # surface, so health polls stay green while latency inflates
+        # (exactly the failure the router's saturation spill must absorb).
+        _slow = faults.check("slow-host", key=pid)
+        if _slow is not None:
+            _slow.sleep()
+        # Role-pool staged dispatch (fleet/roles.py): a router hop
+        # carrying extra_data.pa_stage executes ONE carved stage — the
+        # stage's upstream-closure subgraph with the previous stage's
+        # content-addressed outputs preseeded. A failed carve or handle
+        # resolution degrades to executing the closure (or the whole
+        # graph) locally — bitwise by the fold_in contract, never an
+        # error.
+        exec_graph, preseed, stage_entry = self._stage_setup(prompt, stage)
+        # Inbound distributed-trace context (W3C traceparent shape,
+        # injected by the fleet router into extra_data.fleet): parsed
+        # here so this host's whole span subtree — prompt, node, lane,
+        # step, decode — joins the router's cross-host trace under one
+        # trace_id. Malformed/absent context degrades to local-only.
+        tp = (tracing.parse_traceparent(fleet.get("traceparent"))
+              if fleet and tracing.on() else None)
+        # The rest of the turn lies under one of two spans: `prompt` from the
+        # pickup (where admission-wait ends and exec_s starts) while the
+        # graph runs, `prompt-finish` from where that closes, however it
+        # closed, to the turn's end — outputs listed, history written,
+        # events sent. The stack closes it even when that work raises. Each
+        # starts on the clock reading its neighbour ended on, so no instant
+        # of a worker's time lies under no span.
+        root = finish = tracing._NULL
+        with contextlib.ExitStack() as turn:
+            try:
+                try:
+                    # The prompt span is the root of this prompt's trace
+                    # timeline; prompt_id on the scope correlates log records
+                    # and spans recorded anywhere on (or on behalf of) this
+                    # thread.
+                    with progress_scope(
+                        hook=hook,
+                        preview_hook=preview_hook if preview else None,
+                        interrupt_event=cancel_evt,
+                        prompt_id=pid,
+                    ), serving_hints(priority=priority, deadline_s=deadline_s), \
+                            tracing.trace_context(tp), \
+                            tracing.span(
+                                "prompt", cat="server", prompt_id=pid,
+                                start_us=picked_us,
+                                # Every span names its host + role: the stitched
+                                # fleet timeline's per-tier filter keys.
+                                host_id=self.host_id, role=self.role,
+                                # Cross-hop correlation: a fleet router stamps
+                                # its own prompt id into extra_data.fleet, so
+                                # this backend-side timeline joins the router's
+                                # fleet-prompt/fleet-hop spans in one export.
+                                **({"origin_prompt_id": fleet.get("origin"),
+                                    "router": fleet.get("router")}
+                                   if fleet else {}),
+                                **({"trace_id": tp["trace_id"],
+                                    "parent_span_id": tp["parent_span_id"]}
+                                   if tp else {}),
+                                **({"stage": stage_entry["stage"]}
+                                   if stage_entry is not None else {}),
+                            ) as root:
                         if stage_entry is not None:
+                            # Denoise hosts may pull conds straight off the
+                            # encode tier (models/embed_cache.py remote tier).
                             from .models.embed_cache import set_remote_sources
 
-                            set_remote_sources(None)
+                            set_remote_sources(
+                                (stage or {}).get("sources") or ())
+                        try:
+                            results = run_workflow(
+                                exec_graph, class_mappings=self.class_mappings,
+                                outputs=self.cache, on_node=on_node,
+                                on_cached=on_cached, preseed=preseed,
+                            )
+                        finally:
+                            if stage_entry is not None:
+                                from .models.embed_cache import set_remote_sources
+
+                                set_remote_sources(None)
+                finally:
+                    finish = turn.enter_context(tracing.span(
+                        "prompt-finish", cat="server", prompt_id=pid,
+                        start_us=root.end))
                 entry = {
                     "status": {"status_str": "success", "completed": True,
                                "exec_s": round(time.monotonic() - t0, 3)},
@@ -816,6 +853,12 @@ class PromptQueue:
                 tracing.retain_prompt(pid)
             with self._lock:
                 self.history[pid] = entry
+                if tracing.on():
+                    # Whose output each file is, for GET /view's span.
+                    for out in entry["outputs"].values():
+                        for img in out["images"]:
+                            self.output_owner[self._output_path(
+                                img["subfolder"], img["filename"])] = pid
                 if pid in self.pending_ids:
                     self.pending_ids.remove(pid)
                 # The per-prompt Cancel event retires with the prompt: a
@@ -828,6 +871,7 @@ class PromptQueue:
                 "type": "executing", "data": {"node": None, "prompt_id": pid},
             })
             self._emit_status()
+        return finish.end
 
     def _stage_setup(self, prompt: dict, stage) -> tuple:
         """(exec_graph, preseed, stage_entry) for one staged dispatch.
@@ -929,6 +973,11 @@ class PromptQueue:
                 handles[nid] = key
         return handles
 
+    def _output_path(self, subfolder: str, filename: str) -> str:
+        """Where GET /view looks for a history entry's image."""
+        return os.path.normpath(
+            os.path.join(self.output_dir, subfolder, filename))
+
     def _image_outputs(self, prompt: dict, results: dict) -> dict:
         """ComfyUI history shape: per save-node ``{"images": [{filename,
         subfolder, type}]}`` — detected as outputs whose first element is a
@@ -971,8 +1020,23 @@ class _Handler(BaseHTTPRequestHandler):
     # which the fleet router pays per prompt. TCP_NODELAY it.
     disable_nagle_algorithm = True
 
+    # The span of the route being served, where the route has one.
+    _span = tracing._NULL
+
     def log_message(self, fmt, *args):  # quiet by default
         pass
+
+    def _route(self, name: str, prompt_id: str | None = None):
+        """The span of one of the three routes a prompt's client calls
+        (``http-prompt``, ``http-history``, ``http-view``): live on this
+        handler's thread from here to the response written, stamped by
+        ``_send`` with ``status`` and ``bytes``. A handler's thread lives for
+        one connection, so it records into the tracer's shared ring. The
+        harness's and an operator's scrapes (/metrics, /trace, /health,
+        /queue, /ws) are no prompt's work and have none."""
+        self._span = tracing.shared_span(name, cat="server",
+                                         prompt_id=prompt_id)
+        return self._span
 
     def _send(self, code: int, payload, content_type="application/json"):
         body = (json.dumps(payload).encode()
@@ -982,6 +1046,7 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_header("Content-Length", str(len(body)))
         self.end_headers()
         self.wfile.write(body)
+        self._span.set(status=code, bytes=len(body))
 
     def _http_fault(self) -> bool:
         """Fault site (utils/faults.py ``backend-http``): per-request
@@ -1012,6 +1077,7 @@ class _Handler(BaseHTTPRequestHandler):
         return True
 
     def do_GET(self):  # noqa: N802 — http.server API
+        self._span = tracing._NULL  # a kept-alive connection's last route's
         if self._http_fault():
             return
         url = urlparse(self.path)
@@ -1167,28 +1233,43 @@ class _Handler(BaseHTTPRequestHandler):
             trace["role"] = self.q.role
             return self._send(200, trace)
         if parts and parts[0] == "history":
-            # Snapshot under the queue lock: the worker thread inserts entries
+            # Read under the queue lock: the worker thread inserts entries
             # under it, and json.dumps over a dict mutated mid-iteration raises
             # RuntimeError and aborts the connection. (Entries are written once
             # at insert, so a shallow copy is a consistent view.)
+            if len(parts) == 2:
+                with self.q._lock:
+                    entry = self.q.history.get(parts[1])
+                if not entry:
+                    # A poll that misses records nothing: at a poll every
+                    # 20 ms the misses would be nearly all the spans there
+                    # are, and they fall while the device is busy. So the
+                    # span of the poll that hits opens after this lookup.
+                    return self._send(200, {})
+                with self._route("http-history", prompt_id=parts[1]):
+                    return self._send(200, {parts[1]: entry})
             with self.q._lock:
                 snap = dict(self.q.history)
-            if len(parts) == 2:
-                entry = snap.get(parts[1])
-                return self._send(200, {parts[1]: entry} if entry else {})
             return self._send(200, snap)
         if url.path == "/view":
-            qs = parse_qs(url.query)
-            fname = qs.get("filename", [""])[0]
-            sub = qs.get("subfolder", [""])[0]
-            path = os.path.normpath(os.path.join(self.q.output_dir, sub, fname))
-            base = os.path.abspath(self.q.output_dir)
-            if not os.path.abspath(path).startswith(base + os.sep):
-                return self._send(403, {"error": "path escapes output dir"})
-            if not os.path.exists(path):
-                return self._send(404, {"error": "not found"})
-            with open(path, "rb") as f:
-                return self._send(200, f.read(), content_type="image/png")
+            with self._route("http-view") as sp:
+                qs = parse_qs(url.query)
+                path = self.q._output_path(qs.get("subfolder", [""])[0],
+                                           qs.get("filename", [""])[0])
+                if tracing.on():
+                    with self.q._lock:
+                        owner = self.q.output_owner.get(path)
+                    if owner is not None:
+                        sp.set(prompt_id=owner)
+                base = os.path.abspath(self.q.output_dir)
+                if not os.path.abspath(path).startswith(base + os.sep):
+                    return self._send(403,
+                                      {"error": "path escapes output dir"})
+                if not os.path.exists(path):
+                    return self._send(404, {"error": "not found"})
+                with open(path, "rb") as f:
+                    return self._send(200, f.read(),
+                                      content_type="image/png")
         if parts and parts[0] == "object_info":
             from .nodes import NODE_CLASS_MAPPINGS, NODE_DISPLAY_NAME_MAPPINGS
 
@@ -1279,6 +1360,7 @@ class _Handler(BaseHTTPRequestHandler):
             self.q.remove_listener(sock)
 
     def do_POST(self):  # noqa: N802 — http.server API
+        self._span = tracing._NULL  # a kept-alive connection's last route's
         if self._http_fault():
             return
         url = urlparse(self.path)
@@ -1317,37 +1399,8 @@ class _Handler(BaseHTTPRequestHandler):
                 deleted += self.q.cancel(targets)
             return self._send(200, {"deleted": deleted})
         if url.path == "/prompt":
-            try:
-                length = int(self.headers.get("Content-Length", 0))
-                payload = json.loads(self.rfile.read(length) or b"{}")
-                prompt = payload.get("prompt")
-                if not isinstance(prompt, dict) or not prompt:
-                    return self._send(
-                        400, {"error": "body must carry a non-empty "
-                                       '{"prompt": {...}} graph'}
-                    )
-            except (ValueError, json.JSONDecodeError) as e:
-                return self._send(400, {"error": f"bad JSON: {e}"})
-            extra = payload.get("extra_data") or {}
-            preview = bool(extra.get("preview") or payload.get("preview"))
-            try:
-                deadline_s = extra.get("deadline_s")
-                fleet = extra.get("fleet")
-                stage = extra.get("pa_stage")
-                pid, number = self.q.submit(
-                    prompt, preview=preview,
-                    priority=int(extra.get("priority") or 0),
-                    deadline_s=None if deadline_s is None else float(deadline_s),
-                    fleet=fleet if isinstance(fleet, dict) else None,
-                    stage=stage if isinstance(stage, dict) else None,
-                )
-            except DrainingError as e:
-                return self._send(503, {"error": str(e)})
-            except QueueFullError as e:
-                return self._send(429, {"error": str(e)})
-            except (TypeError, ValueError) as e:
-                return self._send(400, {"error": f"bad extra_data: {e}"})
-            return self._send(200, {"prompt_id": pid, "number": number})
+            with self._route("http-prompt"):
+                return self._post_prompt()
         if url.path == "/history/phase":
             # Declared load-phase stamp (utils/timeseries.py): loadgen's
             # open-loop rungs announce themselves so the anomaly sentinel
@@ -1370,6 +1423,43 @@ class _Handler(BaseHTTPRequestHandler):
         if url.path == "/upload/image":
             return self._upload_image()
         return self._send(404, {"error": f"no route {url.path}"})
+
+    def _post_prompt(self):
+        """``POST /prompt``: the body read and parsed, the prompt submitted,
+        the reply written — under the handler's ``http-prompt`` span, which
+        takes the prompt's id once ``submit`` has made it."""
+        try:
+            length = int(self.headers.get("Content-Length", 0))
+            payload = json.loads(self.rfile.read(length) or b"{}")
+            prompt = payload.get("prompt")
+            if not isinstance(prompt, dict) or not prompt:
+                return self._send(
+                    400, {"error": "body must carry a non-empty "
+                                   '{"prompt": {...}} graph'}
+                )
+        except (ValueError, json.JSONDecodeError) as e:
+            return self._send(400, {"error": f"bad JSON: {e}"})
+        extra = payload.get("extra_data") or {}
+        preview = bool(extra.get("preview") or payload.get("preview"))
+        try:
+            deadline_s = extra.get("deadline_s")
+            fleet = extra.get("fleet")
+            stage = extra.get("pa_stage")
+            pid, number = self.q.submit(
+                prompt, preview=preview,
+                priority=int(extra.get("priority") or 0),
+                deadline_s=None if deadline_s is None else float(deadline_s),
+                fleet=fleet if isinstance(fleet, dict) else None,
+                stage=stage if isinstance(stage, dict) else None,
+            )
+        except DrainingError as e:
+            return self._send(503, {"error": str(e)})
+        except QueueFullError as e:
+            return self._send(429, {"error": str(e)})
+        except (TypeError, ValueError) as e:
+            return self._send(400, {"error": f"bad extra_data: {e}"})
+        self._span.set(prompt_id=pid)
+        return self._send(200, {"prompt_id": pid, "number": number})
 
     def _upload_image(self):
         """Stock ``POST /upload/image``: multipart form with an ``image``

@@ -16,7 +16,7 @@ the framework itself:
 
 Instrument families registered against this registry (create-on-first-touch
 — no registration step): ``pa_serving_*`` (serving/), ``pa_compile_*`` /
-``pa_hbm_*`` (utils/telemetry.py, devices/memory.py), ``pa_trace_span_*``
+``pa_hbm_*`` (utils/telemetry.py, devices/memory.py), ``pa_trace_dropped_*``
 (utils/tracing.py), and ``pa_numerics_*`` (utils/numerics.py —
 ``pa_numerics_nonfinite_total{where=}`` / ``pa_numerics_quarantined_total``
 counters at the event sites, plus the ``pa_numerics_sentinel_enabled`` /

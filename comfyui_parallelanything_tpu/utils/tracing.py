@@ -35,10 +35,12 @@ Design rules (the near-zero-overhead contract):
   the submitting thread's tid — per-tid interval nesting is preserved because
   the submitting thread is blocked in ``ticket.result()`` for exactly that
   interval.
-- **metrics stay consistent with traces**: every span close feeds its
-  duration into ``MetricsRegistry`` (``pa_trace_span_seconds{name=...}``
-  histogram), so ``/metrics`` aggregates and ``/trace`` timelines are two
-  views of the same measurements.
+- **threads that live for one call share one ring**: ``http.server`` makes a
+  thread a connection, and a ring (and the tracer's lock) a thread would be
+  a 16,384-slot deque for one span and, past :data:`RETIRED_RING_BUDGET`
+  dead threads, evicted spans. :func:`shared_span` has such a thread record
+  into the tracer's one shared ring instead: nothing is registered, no lock
+  is taken, every row still carries its own tid.
 - **one tree**: every span carries ``parent_span_id`` — the innermost span
   open on its thread when it opened, or, for a span recorded on behalf of
   another thread (``record(..., tid=)``), the id the caller captured at
@@ -86,9 +88,12 @@ DEFAULT_CAPACITY = 16384
 #   per dead thread's whole buffer).
 # - prompt retention: completed prompts snapshotted by :meth:`retain_prompt`
 #   so a fleet collector can stitch a prompt's timeline after its recording
-#   threads' rings have wrapped (one entry per prompt).
+#   threads' rings have wrapped (one entry per prompt). Its entries are
+#   copies, so a tier smaller than what the rings hold counts evictions of
+#   prompts whose every row is still there: sized over the 104 prompts a
+#   45 s window of the fastest benchmark cell serves.
 RETIRED_RING_BUDGET = 256
-PROMPT_RETENTION = 64
+PROMPT_RETENTION = 256
 
 _span_ids = itertools.count(1)
 
@@ -173,6 +178,7 @@ class _NullSpan:
     sites never need a second enabled-check."""
 
     __slots__ = ()
+    end = None
 
     def __enter__(self):
         return self
@@ -191,17 +197,18 @@ class _OpenSpan:
     """One live span on the opening thread's stack; closing (context exit)
     records a completed ``X`` event into that thread's ring buffer."""
 
-    __slots__ = ("_tracer", "_local", "name", "cat", "ts", "attrs", "span_id",
-                 "_ann", "_closed")
+    __slots__ = ("_tracer", "_local", "name", "cat", "ts", "end", "attrs",
+                 "span_id", "_ann", "_closed")
 
-    def __init__(self, tracer, local, name, cat, attrs):
+    def __init__(self, tracer, local, name, cat, attrs, start_us=None):
         self._tracer = tracer
         self._local = local
         self.name = name
         self.cat = cat
         self.attrs = attrs
         self.span_id = next(_span_ids)
-        self.ts = 0.0
+        self.ts = start_us  # None: the clock is read on entry
+        self.end = None  # the tracer's clock where the span closed
         self._ann = None
         self._closed = False
 
@@ -215,7 +222,8 @@ class _OpenSpan:
             self.attrs.setdefault("parent_span_id", stack[-1].span_id)
         stack.append(self)
         self._ann = _annotate(self.name, self.attrs, self.span_id)
-        self.ts = now_us()
+        if self.ts is None:
+            self.ts = now_us()
         return self
 
     def _close(self):
@@ -228,7 +236,8 @@ class _OpenSpan:
         if self._closed:
             # Left once already, or abandoned below: one event per span.
             return False
-        dur = now_us() - self.ts
+        self.end = now_us()
+        dur = self.end - self.ts
         stack = self._local.stack
         if self in stack:
             # A span still open above this one was abandoned by the code it
@@ -289,6 +298,10 @@ class Tracer:
         # Eviction accounting per reason — the local mirror of the
         # pa_trace_dropped_total counter (readable without a metrics scrape).
         self.dropped: dict[str, int] = {}  # guarded-by: _lock
+        # The one ring of the threads that live for one call (shared_span);
+        # export and retention snapshot it under _lock like every other ring.
+        # unguarded: its writers only append, which a deque does atomically
+        self._shared: deque = deque(maxlen=capacity)
         # perf_counter_ns of ts == 0: the trace-event clock's origin.
         self._epoch_ns = time.perf_counter_ns()
 
@@ -304,6 +317,7 @@ class Tracer:
             self._buffers.clear()
             self._retired.clear()
             self._retained.clear()
+            self._shared = deque(maxlen=self.capacity)
             self.dropped = {}
             self._epoch_ns = time.perf_counter_ns()
         self._local = _Local()
@@ -319,6 +333,7 @@ class Tracer:
             self._buffers.clear()
             self._retired.clear()
             self._retained.clear()
+            self._shared.clear()
             self.dropped = {}
 
     # -- recording ----------------------------------------------------------
@@ -349,7 +364,8 @@ class Tracer:
         registry has its own lock; keep the order acyclic)."""
         with self._lock:
             self.dropped[reason] = self.dropped.get(reason, 0) + n
-        # Same lazy-import/never-raise contract as _feed_metrics.
+        # Lazy import: tracing must stay importable without jax (metrics.py
+        # imports jax); a metrics hiccup must never break the traced path.
         try:
             from .metrics import registry
 
@@ -366,29 +382,16 @@ class Tracer:
 
     def _emit(self, local, name, ts, dur, cat, tid, attrs, span_id) -> None:
         self._events(local).append((name, ts, dur, cat, tid, attrs, span_id))
-        self._feed_metrics(name, cat, dur)
-
-    @staticmethod
-    def _feed_metrics(name, cat, dur_us) -> None:
-        # Lazy import: tracing must stay importable without jax (metrics.py
-        # imports jax); a metrics hiccup must never break the traced path.
-        try:
-            from .metrics import registry
-
-            registry.histogram(
-                "pa_trace_span_seconds", dur_us / 1e6,
-                labels={"name": name, "cat": cat},
-                help="span durations from utils/tracing.py (trace/metrics "
-                     "consistency: same measurements, two views)",
-            )
-        except Exception:
-            pass
 
     def span(self, name: str, cat: str = "host",
-             prompt_id: str | None = None, **attrs):
+             prompt_id: str | None = None, start_us: float | None = None,
+             **attrs):
         """Open a nested span on the calling thread (context manager). When
         tracing is disabled this is the single flag check returning the
-        shared null singleton."""
+        shared null singleton. ``start_us`` starts the span at a reading of
+        :func:`now_us` the caller already holds — the ``end`` of the span it
+        follows by definition — so that the two abut exactly and no instant
+        between them is left to no span (None reads the clock on entry)."""
         if not self.enabled:
             return _NULL
         local = self._local
@@ -398,7 +401,20 @@ class Tracer:
             attrs["prompt_id"] = prompt_id
         if local.ctx is not None:
             attrs.setdefault("trace_id", local.ctx["trace_id"])
-        return _OpenSpan(self, local, name, cat, attrs)
+        return _OpenSpan(self, local, name, cat, attrs, start_us)
+
+    def shared_span(self, name: str, cat: str = "host",
+                    prompt_id: str | None = None, **attrs):
+        """:meth:`span` for a thread that lives for one call (an HTTP
+        handler's): a thread that has no ring yet records into the tracer's
+        one shared ring from here on, so it registers nothing, takes no lock
+        and can never push a dead thread's spans off the retired ring."""
+        if not self.enabled:
+            return _NULL
+        local = self._local
+        if local.events is None:
+            local.events = self._shared
+        return self.span(name, cat=cat, prompt_id=prompt_id, **attrs)
 
     def record(self, name: str, ts: float, dur: float, cat: str = "host",
                tid: int | None = None, prompt_id: str | None = None,
@@ -490,6 +506,18 @@ class Tracer:
         finally:
             local.ctx = prev
 
+    def _rings(self):  # caller holds _lock
+        """(tid, thread name, rows) of every ring, each copied in one step:
+        a ring's own thread (and, for the shared one, any handler's) appends
+        without the lock. The retired rings (dead threads whose ident was
+        recycled) and the shared one belong to no one thread, tid 0: their
+        rows carry their own tids, so they render like the live rings'."""
+        for tid, (name, ev) in self._buffers.items():
+            yield tid, name, list(ev)
+        for name, ev in self._retired:
+            yield 0, name, list(ev)
+        yield 0, "shared", list(self._shared)
+
     # -- completed-prompt retention -----------------------------------------
 
     def retain_prompt(self, prompt_id: str | None) -> int:
@@ -504,11 +532,8 @@ class Tracer:
             return 0
         evicted = 0
         with self._lock:
-            rows = []
-            for _tid, (_name, ev) in self._buffers.items():
-                rows.extend(r for r in ev if r[5].get("prompt_id") == prompt_id)
-            for _name, ev in self._retired:
-                rows.extend(r for r in ev if r[5].get("prompt_id") == prompt_id)
+            rows = [r for _tid, _name, ring in self._rings()
+                    for r in ring if r[5].get("prompt_id") == prompt_id]
             if not rows:
                 return 0
             self._retained[prompt_id] = rows
@@ -529,13 +554,7 @@ class Tracer:
         prompt's timeline (spans stamped with that prompt_id)."""
         pid = os.getpid()
         with self._lock:
-            snap = [(tid, name, list(ev))
-                    for tid, (name, ev) in self._buffers.items()]
-            # Retired buffers (dead threads whose ident was recycled): their
-            # rows carry their own tids, so they render identically.
-            snap.extend(
-                (0, name, list(ev)) for name, ev in self._retired
-            )
+            snap = list(self._rings())
             # Completed-prompt retention: rows may duplicate live-buffer rows
             # (retention snapshots, it does not move) — deduped by span_id
             # below, since span ids are process-unique.
@@ -609,8 +628,15 @@ def disable() -> None:
     tracer.disable()
 
 
-def span(name: str, cat: str = "host", prompt_id: str | None = None, **attrs):
-    return tracer.span(name, cat=cat, prompt_id=prompt_id, **attrs)
+def span(name: str, cat: str = "host", prompt_id: str | None = None,
+         start_us: float | None = None, **attrs):
+    return tracer.span(name, cat=cat, prompt_id=prompt_id, start_us=start_us,
+                       **attrs)
+
+
+def shared_span(name: str, cat: str = "host", prompt_id: str | None = None,
+                **attrs):
+    return tracer.shared_span(name, cat=cat, prompt_id=prompt_id, **attrs)
 
 
 def record(name: str, ts: float, dur: float, cat: str = "host",

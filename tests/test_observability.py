@@ -16,6 +16,7 @@
 
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import re
@@ -23,6 +24,7 @@ import subprocess
 import sys
 import threading
 import time
+import urllib.request
 from pathlib import Path
 
 import jax
@@ -92,20 +94,80 @@ class TestTracerCore:
         assert _x_events() == []
         assert tracing.current_span_id() is None
 
-    def test_disabled_hot_paths_allocate_no_spans(self):
+    @pytest.mark.parametrize("path", ["sampler", "server-turn"])
+    def test_disabled_hot_paths_allocate_no_spans(self, path, tmp_path,
+                                                  monkeypatch):
         """An eager sampler run with tracing off must leave the tracer
         untouched — the instrumented hot paths (sampler-run wrapper, step
-        callbacks) are all behind the single flag check."""
-        from comfyui_parallelanything_tpu.sampling.runner import run_sampler
+        callbacks) are all behind the single flag check. The same for a
+        prompt's whole turn through the server (PR 36): the worker's
+        ``worker-idle`` and ``prompt-finish``, the three spanned routes, the
+        map of whose output a file is."""
+        def no_span(*a, **kw):
+            raise AssertionError("a span was built with the tracer off")
 
-        def model(x, t, context=None, **kw):
-            return x * 0.9
+        monkeypatch.setattr(tracing, "_OpenSpan", no_span)
+        if path == "sampler":
+            from comfyui_parallelanything_tpu.sampling.runner import run_sampler
 
-        noise = jnp.ones((1, 4, 4, 4))
-        ctx = jnp.ones((1, 3, 8))
-        out = run_sampler(model, noise, ctx, sampler="euler", steps=2)
-        assert out.shape == noise.shape
+            def model(x, t, context=None, **kw):
+                return x * 0.9
+
+            noise = jnp.ones((1, 4, 4, 4))
+            ctx = jnp.ones((1, 3, 8))
+            out = run_sampler(model, noise, ctx, sampler="euler", steps=2)
+            assert out.shape == noise.shape
+        else:
+            from comfyui_parallelanything_tpu.server import make_server
+
+            srv, q = make_server(port=0, output_dir=str(tmp_path / "out"),
+                                 class_mappings={"Save": _SaveNode},
+                                 trace=False)
+            threading.Thread(target=srv.serve_forever, daemon=True).start()
+            try:
+                base = f"http://127.0.0.1:{srv.server_address[1]}"
+                _serve_prompt(base, q.output_dir, 1)
+                assert _get(base, "/trace")["enabled"] is False
+            finally:
+                srv.shutdown()
+                q.shutdown()
+            assert q.output_owner == {}
+            assert len(tracing.tracer._shared) == 0
         assert tracing.tracer._buffers == {}
+        assert _x_events() == []
+
+    def test_a_span_starts_where_the_one_it_follows_ended(self):
+        """``start_us``: two spans that abut by definition share one clock
+        reading; the null span has no end, and None reads the clock."""
+        assert tracing._NULL.end is None
+        tracing.enable()
+        with tracing.span("first") as a:
+            pass
+        time.sleep(0.002)
+        with tracing.span("second", start_us=a.end) as b:
+            assert b.ts == a.end
+        with tracing.span("third", start_us=None) as c:
+            pass
+        assert c.ts >= b.end > a.end
+        first, second = (next(e for e in _x_events() if e["name"] == n)
+                         for n in ("first", "second"))
+        assert abs(first["ts"] + first["dur"] - second["ts"]) < 2e-3
+        assert second["dur"] >= 2000.0
+
+    @pytest.mark.parametrize("prompts, evicted", [(110, 0), (300, 44)])
+    def test_retention_holds_a_window_of_the_fastest_cell(self, prompts,
+                                                           evicted):
+        """The completed-prompt tier keeps 256 prompts: the 110 that a 45 s
+        window of the benchmark's fastest cell serves evict nothing (a
+        smaller tier counts copies whose originals are all still in the
+        rings); past its budget it counts every row it lets go."""
+        tracing.enable()
+        for i in range(prompts):
+            tracing.record("prompt", 0.0, 1.0, prompt_id=f"p{i}")
+            assert tracing.retain_prompt(f"p{i}") == 1
+        assert tracing.tracer.dropped == (
+            {"prompt-retention": evicted} if evicted else {})
+        assert len(_x_events()) == prompts  # the ring still holds them all
 
     def test_export_shape_and_nesting(self):
         tracing.enable()
@@ -555,46 +617,111 @@ class _EchoNode:
         return (x + 1,)
 
 
+class _SaveNode:
+    """Writes one small file under ``dir`` and returns its path the way the
+    SaveImage family does, so the history entry lists it and ``/view`` serves
+    it."""
+
+    @classmethod
+    def INPUT_TYPES(cls):
+        return {"required": {"x": ("INT", {"default": 0}),
+                             "dir": ("STRING", {"default": ""})}}
+
+    RETURN_TYPES = ("STRING",)
+    FUNCTION = "run"
+
+    def run(self, x, dir):  # noqa: A002 — the graph's input name
+        Path(dir).mkdir(parents=True, exist_ok=True)
+        path = Path(dir) / f"echo_{x:05d}.png"
+        path.write_bytes(b"\x89PNG" + bytes(64))
+        return ([str(path)],)
+
+
+class _BrokenNode:
+    """Raises what ``how`` names: a node's own error, or the cooperative
+    interrupt a Cancel turns into."""
+
+    @classmethod
+    def INPUT_TYPES(cls):
+        return {"required": {"how": ("STRING", {"default": "error"})}}
+
+    RETURN_TYPES = ("INT",)
+    FUNCTION = "run"
+
+    def run(self, how):
+        from comfyui_parallelanything_tpu.utils.progress import Interrupted
+
+        raise Interrupted("stop") if how == "interrupted" else ValueError("boom")
+
+
+@pytest.fixture
+def server(tmp_path):
+    from comfyui_parallelanything_tpu.server import make_server
+
+    srv, q = make_server(
+        port=0, output_dir=str(tmp_path / "out"),
+        class_mappings={"Echo": _EchoNode, "Save": _SaveNode,
+                        "Broken": _BrokenNode},
+        trace=True,
+    )
+    thread = threading.Thread(target=srv.serve_forever, daemon=True)
+    thread.start()
+    base = f"http://127.0.0.1:{srv.server_address[1]}"
+    yield base, q
+    srv.shutdown()
+    q.shutdown()
+
+
+def _get(base, path):
+    """One call on a fresh connection, as the benchmark's client makes it."""
+    with urllib.request.urlopen(base + path, timeout=30) as r:
+        body, ctype = r.read(), r.headers.get("Content-Type", "")
+    return json.loads(body) if "json" in ctype else body
+
+
+def _post_prompt(base, graph) -> str:
+    req = urllib.request.Request(
+        base + "/prompt", data=json.dumps({"prompt": graph}).encode(),
+        headers={"Content-Type": "application/json"}, method="POST",
+    )
+    with urllib.request.urlopen(req, timeout=30) as r:
+        return json.loads(r.read())["prompt_id"]
+
+
+def _await_entry(base, pid, poll_s=0.05) -> dict:
+    t0 = time.time()
+    while time.time() - t0 < 60:
+        hist = _get(base, f"/history/{pid}")
+        if pid in hist:
+            return hist[pid]
+        time.sleep(poll_s)
+    raise AssertionError(f"no history entry for {pid}")
+
+
+def _serve_prompt(base, out_dir, x) -> tuple[str, list]:
+    """One request as the benchmark's client makes it: POST, poll until the
+    entry is there, fetch every image back through /view."""
+    pid = _post_prompt(base, {"1": {
+        "class_type": "Save", "inputs": {"x": x, "dir": out_dir}}})
+    entry = _await_entry(base, pid, poll_s=0.005)
+    assert entry["status"]["status_str"] == "success"
+    images = entry["outputs"]["1"]["images"]
+    for ref in images:
+        png = _get(base, f"/view?filename={ref['filename']}"
+                         f"&subfolder={ref['subfolder']}")
+        assert png.startswith(b"\x89PNG")
+    return pid, images
+
+
 class TestServerTraceEndpoint:
-    @pytest.fixture
-    def server(self, tmp_path):
-        from comfyui_parallelanything_tpu.server import make_server
-
-        srv, q = make_server(
-            port=0, output_dir=str(tmp_path / "out"),
-            class_mappings={"Echo": _EchoNode}, trace=True,
-        )
-        thread = threading.Thread(target=srv.serve_forever, daemon=True)
-        thread.start()
-        base = f"http://127.0.0.1:{srv.server_address[1]}"
-        yield base, q
-        srv.shutdown()
-        q.shutdown()
-
     def test_trace_endpoint_serves_prompt_timeline(self, server):
-        import urllib.request
-
         base, q = server
-
-        def get(path):
-            with urllib.request.urlopen(base + path, timeout=30) as r:
-                return json.loads(r.read())
-
-        body = json.dumps({"prompt": {
+        get = functools.partial(_get, base)
+        pid = _post_prompt(base, {
             "1": {"class_type": "Echo", "inputs": {"x": 1}},
             "2": {"class_type": "Echo", "inputs": {"x": ["1", 0]}},
-        }}).encode()
-        req = urllib.request.Request(
-            base + "/prompt", data=body,
-            headers={"Content-Type": "application/json"}, method="POST",
-        )
-        with urllib.request.urlopen(req, timeout=30) as r:
-            pid = json.loads(r.read())["prompt_id"]
-        t0 = time.time()
-        while time.time() - t0 < 60:
-            if pid in get(f"/history/{pid}"):
-                break
-            time.sleep(0.05)
+        })
+        _await_entry(base, pid)
         trace = get(f"/trace?prompt_id={pid}")
         assert trace["enabled"] is True
         xs = [e for e in trace["traceEvents"] if e.get("ph") == "X"]
@@ -604,8 +731,13 @@ class TestServerTraceEndpoint:
         prompt = next(e for e in xs if e["name"] == "prompt")
         for e in xs:
             assert e["args"]["prompt_id"] == pid
-            assert e["tid"] == prompt["tid"]
-        _assert_nested_per_tid(xs)
+            # the worker's spans lie on its thread; the client's calls each
+            # on the thread of their connection (PR 36)
+            assert (e["tid"] == prompt["tid"]) != e["name"].startswith("http-")
+        # admission-wait is written after the fact from the POST's enqueue
+        # to the pickup, across worker-idle's end: it nests under nothing
+        _assert_nested_per_tid(
+            [e for e in xs if e["name"] != "admission-wait"])
         # unfiltered export includes it too; bogus filter excludes everything
         assert any(
             e.get("args", {}).get("prompt_id") == pid
@@ -613,6 +745,167 @@ class TestServerTraceEndpoint:
         )
         assert [e for e in get("/trace?prompt_id=nope")["traceEvents"]
                 if e.get("ph") == "X"] == []
+
+
+class TestServerTurnSpans:
+    """PR 36: what lies between one ``prompt`` span and the next — the
+    worker's ``prompt-finish`` and ``worker-idle``, and the handlers of the
+    three routes a prompt's client calls."""
+
+    TURN = ("prompt-finish", "worker-idle", "http-prompt", "http-history",
+            "http-view")
+
+    @pytest.fixture
+    def two_prompts(self, server):
+        base, q = server
+        pids = [_serve_prompt(base, q.output_dir, x)[0] for x in (1, 2)]
+        # the worker stamps its wait with the NEXT prompt's id: a third POST
+        # closes the span that follows the second prompt
+        _await_entry(base, _post_prompt(
+            base, {"1": {"class_type": "Echo", "inputs": {"x": 0}}}))
+        return base, q, pids
+
+    @pytest.mark.parametrize("name", TURN)
+    def test_one_span_a_prompt_under_its_id(self, two_prompts, name):
+        base, q, pids = two_prompts
+        for pid in pids:
+            xs = [e for e in _x_events(_get(base, f"/trace?prompt_id={pid}"))
+                  if e["name"] == name]
+            # the polls that missed left nothing: one http-history, the hit
+            assert len(xs) == 1, (name, xs)
+            e = xs[0]
+            assert e["cat"] == "server" and e["dur"] > 0
+            if name.startswith("http-"):
+                assert e["args"]["status"] == 200 and e["args"]["bytes"] > 0
+
+    def test_the_turn_is_covered_from_one_prompt_to_the_next(self, two_prompts):
+        """``prompt`` closed to the next ``prompt`` opened, on the worker's
+        thread: ``prompt-finish``, ``worker-idle`` and the tail of
+        ``admission-wait`` leave none of it bare (ISSUE 36 allows 2 ms) —
+        each starts on the clock reading its neighbour ended on, because the
+        benchmark labels a whole idle gap by the one instant of its middle."""
+        base, q, pids = two_prompts
+        xs = _x_events(_get(base, "/trace"))
+        prompts = sorted((e for e in xs if e["name"] == "prompt"),
+                         key=lambda e: e["ts"])
+        assert [e["args"]["prompt_id"] for e in prompts[:2]] == pids
+        cover = sorted(
+            (e["ts"], e["ts"] + e["dur"]) for e in xs
+            if e["tid"] == prompts[0]["tid"] and e["name"] in (
+                "prompt", "prompt-finish", "worker-idle", "admission-wait"))
+        for a, b in zip(prompts, prompts[1:]):
+            lo, hi = a["ts"] + a["dur"], b["ts"]
+            bare, at = 0.0, lo
+            for s0, s1 in cover:
+                if s1 <= at or s0 >= hi:
+                    continue
+                bare += max(0.0, s0 - at)
+                at = max(at, s1)
+            bare += max(0.0, hi - at)
+            assert bare < 1.0, (bare, hi - lo)  # microseconds: rounding
+        # the client's calls for a prompt fall after its prompt span closed
+        # (the POST of the next one inside the wait that carries ITS id)
+        first = {e["name"]: e for e in xs
+                 if e["args"].get("prompt_id") == pids[1]}
+        idle, post = first["worker-idle"], first["http-prompt"]
+        assert idle["ts"] <= post["ts"] < idle["ts"] + idle["dur"]
+        done = prompts[1]["ts"] + prompts[1]["dur"]
+        assert first["http-history"]["ts"] >= done
+        assert first["http-view"]["ts"] >= first["http-history"]["ts"]
+
+    def test_a_poll_that_misses_and_a_strange_file_record_nothing_to_a_prompt(
+            self, server):
+        base, q = server
+        assert _get(base, "/history/nope") == {}
+        Path(q.output_dir).mkdir(parents=True, exist_ok=True)
+        (Path(q.output_dir) / "stray.png").write_bytes(b"\x89PNG")
+        assert _get(base, "/view?filename=stray.png&subfolder=") == b"\x89PNG"
+        for route in ("/queue", "/health", "/metrics", "/trace", "/history"):
+            _get(base, route)
+        xs = [e for e in _x_events(_get(base, "/trace"))
+              if e["name"].startswith("http-")]
+        # no span for the miss nor for the operator's routes; the file that
+        # no prompt of this server wrote is served under no prompt's id
+        assert [e["name"] for e in xs] == ["http-view"]
+        assert "prompt_id" not in xs[0]["args"]
+
+    @pytest.mark.parametrize("how", ["error", "interrupted"])
+    def test_a_broken_prompt_closes_prompt_finish(self, server, how):
+        base, q = server
+        pid = _post_prompt(base, {"1": {
+            "class_type": "Broken", "inputs": {"how": how}}})
+        entry = _await_entry(base, pid, poll_s=0.005)
+        assert entry["status"]["status_str"] == how
+        # the next prompt's pickup shows the turn's end was reached
+        _await_entry(base, _post_prompt(
+            base, {"1": {"class_type": "Echo", "inputs": {"x": 0}}}))
+        xs = _x_events(_get(base, f"/trace?prompt_id={pid}"))
+        by = {e["name"]: e for e in xs}
+        assert {"prompt", "prompt-finish", "http-prompt",
+                "http-history"} <= set(by)
+        # it starts on the reading `prompt` ended on (the export rounds each
+        # number to a nanosecond)
+        assert abs(by["prompt-finish"]["ts"]
+                   - by["prompt"]["ts"] - by["prompt"]["dur"]) < 3e-3
+        assert tracing.tracer.dropped == {}
+
+    def test_prompt_finish_closes_when_the_finish_work_raises(self, server):
+        """The history write itself failing kills the worker's turn, not the
+        span: it is closed and recorded on the way out."""
+        base, q = server
+
+        def boom(prompt):
+            raise RuntimeError("residency")
+
+        q._mark_warm = boom  # raises inside the try: lands in history
+        pid = _post_prompt(base, {"1": {"class_type": "Echo", "inputs": {"x": 3}}})
+        assert _await_entry(base, pid)["status"]["status_str"] == "error"
+        names = [e["name"] for e in _x_events(_get(base, f"/trace?prompt_id={pid}"))]
+        assert names.count("prompt-finish") == 1
+        # and past every handler: the span still closes, the stack is clean
+        open_at_the_end = []
+
+        def failing_status():
+            open_at_the_end.extend(s.name for s in tracing.tracer._local.stack)
+            raise ZeroDivisionError
+
+        q._emit_status = failing_status
+        with q._lock:
+            q.pending_ids.append("direct")
+        with pytest.raises(ZeroDivisionError):
+            q._turn(("direct", {"1": {"class_type": "Echo", "inputs": {"x": 4}}},
+                     False, 0, None, None, None, time.monotonic()))
+        assert open_at_the_end == ["prompt-finish"]
+        assert tracing.tracer._local.stack == []
+        assert [e["name"] for e in _x_events(prompt_id="direct")].count(
+            "prompt-finish") == 1
+
+    def test_300_calls_on_fresh_connections_drop_no_span(self, server):
+        """``http.server`` makes a thread a connection: the handlers' spans
+        share one ring, so no thread registers one of its own and none is
+        pushed off the retired ring (256 dead threads' worth)."""
+        base, q = server
+        pid, images = _serve_prompt(base, q.output_dir, 7)
+        ref = images[0]
+        before = set(tracing.tracer._buffers)
+        for i in range(150):
+            assert pid in _get(base, f"/history/{pid}")
+            _get(base, f"/view?filename={ref['filename']}"
+                       f"&subfolder={ref['subfolder']}")
+        assert tracing.tracer.dropped == {}
+        assert len(tracing.tracer._retired) == 0
+        assert set(tracing.tracer._buffers) == before  # the worker's alone
+        for _ in range(100):  # the last handler records after its reply
+            xs = _x_events(_get(base, f"/trace?prompt_id={pid}"))
+            names = [e["name"] for e in xs]
+            if names.count("http-view") == 151:
+                break
+            time.sleep(0.01)
+        assert names.count("http-history") == 151
+        assert names.count("http-view") == 151
+        assert len({e["args"]["span_id"] for e in xs}) == len(xs)
+        assert (registry.get("pa_trace_dropped_total",
+                             {"reason": "retired-ring"}) or 0.0) == 0.0
 
 
 # -- PR 24: parents, the profiler bridge, denoise + save stage spans ---------
