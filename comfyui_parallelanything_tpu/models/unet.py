@@ -18,7 +18,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from ..ops.attention import attention
-from ..ops.basic import timestep_embedding
+from ..ops.basic import UpsampleConv, timestep_embedding
 from .api import DiffusionModel
 
 
@@ -261,9 +261,8 @@ class Upsample(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        B, H, W, C = x.shape
-        x = jnp.repeat(jnp.repeat(x, 2, axis=1), 2, axis=2)
-        return nn.Conv(self.channels, (3, 3), padding=1, dtype=self.cfg.dtype)(x)
+        # Named as the nn.Conv it stands for: the checkpoint maps' Conv_0.
+        return UpsampleConv(self.channels, dtype=self.cfg.dtype, name="Conv_0")(x)
 
 
 def _has_attn(cfg: UNetConfig, level: int) -> bool:

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import attention_local
+from ..ops.basic import UpsampleConv
 from .tiling import blend_mask1d, tile_starts
 
 
@@ -137,9 +138,7 @@ class Upsample(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        B, H, W, C = x.shape
-        x = jax.image.resize(x, (B, H * 2, W * 2, C), method="nearest")
-        return nn.Conv(C, (3, 3), padding=1, dtype=self.cfg.dtype, name="conv")(x)
+        return UpsampleConv(x.shape[-1], dtype=self.cfg.dtype, name="conv")(x)
 
 
 class Encoder(nn.Module):

@@ -149,6 +149,15 @@ programs compile: SD3.5-medium 37 ``fused`` / ``none`` (the image stream of
 8 main layers 10 ``fused`` / ``interleaved`` and 2 ``xla`` (the context
 refiner's 32 tokens); FLUX.1-schnell at 3 + 6 blocks 12 ``fused``).
 
+Upsample + convolution pairs (PR 38): ``pa_upsample_conv_total{form=}``
+(ops/basic.py ``upsample2x_conv3x3`` — counted like
+``pa_attention_route_total``, once a TRACE: each nearest ×2 upsample + 3×3
+convolution of a program by the form it took — ``phase`` the four output
+phases' folded 2×2 taps from the low-resolution input, as one convolution of
+the zero-stuffed input; no call takes another form today. While the cells'
+programs compile: 3 a decode program (the autoencoder's three stages), 3 a
+step program of SD1.5's UNet, 2 of SDXL's).
+
 Caption buckets (PR 34): ``pa_caption_bucket_total{tokens=}``
 (models/zimage.py — counted like ``pa_attention_route_total``, once a TRACE
 of the single-stream denoiser, with the padded caption length the program

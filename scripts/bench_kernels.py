@@ -10,6 +10,7 @@ time); without a TPU it exits non-zero and measures nothing:
         --blocks 256x4352 --chunk-k 1536   # one combination, 1536-key tiles
     KERNEL_SWEEP=0 python scripts/bench_kernels.py   # default blocks only
     python scripts/bench_kernels.py --prologue       # the q/k prologue's rows
+    python scripts/bench_kernels.py --upsample       # upsample + 3x3 conv forms
 
 Shapes cover the rungs that matter: the benchmark cells' UNet self-attention
 classes (named ``<cell>.self<tokens>``: what ops/pallas/tuning.py's shape rule
@@ -414,6 +415,188 @@ def _run_prologue(shapes, dev, tiles=None):
             f.write(json.dumps(rec) + "\n")
 
 
+# Nearest x2 upsample + 3x3 convolution (ops/basic.upsample2x_conv3x3): the
+# low-resolution input (label, batch, height, width, channels) of the
+# autoencoder decoder's three stages at 1 x 1024² and at sd15's 8 x 512², and
+# of the UNets' upsamplers at the cells' shapes (CFG doubles the batch).
+UPSAMPLE_SHAPES = [
+    ("vae-b1-1024.up128", 1, 128, 128, 512),
+    ("vae-b1-1024.up256", 1, 256, 256, 512),
+    ("vae-b1-1024.up512", 1, 512, 512, 256),
+    ("vae-b8-512.up64", 8, 64, 64, 512),
+    ("vae-b8-512.up128", 8, 128, 128, 512),
+    ("vae-b8-512.up256", 8, 256, 256, 256),
+    ("sd15-b8-512.up8", 16, 8, 8, 1280),
+    ("sd15-b8-512.up16", 16, 16, 16, 1280),
+    ("sd15-b8-512.up32", 16, 32, 32, 640),
+    ("sdxl-b1-1024.up32", 2, 32, 32, 1280),
+    ("sdxl-b1-1024.up64", 2, 64, 64, 640),
+]
+# Whole decode programs (label, latent shape, configuration's name): what a
+# stage's gain is worth where XLA fuses its neighbours into it.
+UPSAMPLE_DECODERS = [
+    ("vae-b1-1024.decoder", (1, 128, 128, 16), "sd3_vae_config"),
+    ("vae-b8-512.decoder", (8, 64, 64, 4), "sd_vae_config"),
+]
+
+
+def _upsample_forms():
+    """The forms ISSUE 38 asked to be measured, all the same mathematics as
+    ``shipped`` (ops/basic.upsample2x_conv3x3), each ``f(x, kernel, bias,
+    dtype)``: ``plain`` the pair as it was (repeat, then the 3x3 at the high
+    resolution), ``resize`` the same behind ``jax.image.resize``'s gather, as
+    the decoder had it; ``phases`` four 2x2 convolutions, one an output phase,
+    stacked and reshaped into place; ``row-phases`` one 2x3 convolution a row
+    phase with both column phases as 2·O output channels (zero taps: 24
+    tap-products a source pixel) and a reshape; ``one-conv`` a single 2x2
+    convolution over the once-padded input with 4·O output channels and four
+    offset slices."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from comfyui_parallelanything_tpu.ops.basic import upsample2x_conv3x3
+
+    def conv(x, k, padding):
+        return lax.conv_general_dilated(
+            x, k, (1, 1), padding, dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            preferred_element_type=jnp.float32)
+
+    def fold(k):
+        """(3, 3, I, O) -> [a][b] the (2, 2, I, O) window of phase (a, b)."""
+        k = k.astype(jnp.float32)
+        rows = [jnp.stack([k[0], k[1] + k[2]]), jnp.stack([k[0] + k[1], k[2]])]
+        return [[jnp.stack([r[:, 0], r[:, 1] + r[:, 2]], 1),
+                 jnp.stack([r[:, 0] + r[:, 1], r[:, 2]], 1)] for r in rows]
+
+    def biased(y, bias, dtype, repeat=1):
+        if bias is not None:
+            y = y + jnp.tile(bias.astype(jnp.float32), repeat)
+        return y.astype(dtype)
+
+    def interleave(phases):
+        rows = [jnp.stack(r, axis=3) for r in phases]  # (B, H, W, 2, O)
+        y = jnp.stack(rows, axis=2)  # (B, H, 2, W, 2, O)
+        b, h, _, w, _, o = y.shape
+        return y.reshape(b, 2 * h, 2 * w, o)
+
+    def plain(x, kernel, bias, dtype):
+        x = jnp.repeat(jnp.repeat(x.astype(dtype), 2, axis=1), 2, axis=2)
+        return biased(conv(x, kernel.astype(dtype), "SAME"), bias, dtype)
+
+    def resize(x, kernel, bias, dtype):
+        b, h, w, c = x.shape
+        x = jax.image.resize(x.astype(dtype), (b, 2 * h, 2 * w, c), "nearest")
+        return biased(conv(x, kernel.astype(dtype), "SAME"), bias, dtype)
+
+    def phases(x, kernel, bias, dtype):
+        k, x = fold(kernel), x.astype(dtype)
+        return interleave([[
+            biased(conv(x, k[a][b].astype(dtype), ((1 - a, a), (1 - b, b))),
+                   bias, dtype)
+            for b in (0, 1)] for a in (0, 1)])
+
+    def row_phases(x, kernel, bias, dtype):
+        b, h, w, _ = x.shape
+        k = kernel.astype(jnp.float32)
+        zero, out = jnp.zeros_like(k[0, 0]), []
+        for a, rows in enumerate(([k[0], k[1] + k[2]], [k[0] + k[1], k[2]])):
+            kk = jnp.concatenate([
+                jnp.stack([jnp.stack([r[0], r[1] + r[2], zero]) for r in rows]),
+                jnp.stack([jnp.stack([zero, r[0] + r[1], r[2]]) for r in rows]),
+            ], axis=-1).astype(dtype)  # (2, 3, I, 2·O)
+            y = conv(x.astype(dtype), kk, ((1 - a, a), (1, 1)))
+            out.append(biased(y, bias, dtype, 2).reshape(b, h, 2 * w, -1))
+        return jnp.stack(out, axis=2).reshape(b, 2 * h, 2 * w, -1)
+
+    def one_conv(x, kernel, bias, dtype):
+        _, h, w, _ = x.shape
+        k = fold(kernel)
+        o = kernel.shape[-1]
+        kk = jnp.concatenate([k[a][b] for a in (0, 1) for b in (0, 1)], -1)
+        y = biased(conv(x.astype(dtype), kk.astype(dtype), ((1, 1), (1, 1))),
+                   bias, dtype, 4)  # (B, H + 1, W + 1, 4·O)
+        return interleave([[
+            y[:, a:a + h, b:b + w, (2 * a + b) * o:(2 * a + b + 1) * o]
+            for b in (0, 1)] for a in (0, 1)])
+
+    return {"plain": plain, "resize": resize, "shipped": upsample2x_conv3x3,
+            "phases": phases, "row-phases": row_phases, "one-conv": one_conv}
+
+
+def _run_upsample(shapes, decoders, dev):
+    """Every form of :func:`_upsample_forms` at the stages' shapes — ms of
+    device time a call (:func:`_device_time`), the compiled program's
+    temporaries, the largest gap to ``plain`` — and the whole decode programs
+    with each form standing in for the shipped one. One JSON line a shape,
+    appended to KERNEL_BENCH.json."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bench import evidence_dir
+    from comfyui_parallelanything_tpu.models import vae
+    from comfyui_parallelanything_tpu.ops import basic
+
+    forms = _upsample_forms()
+    out_path = os.path.join(evidence_dir(), "KERNEL_BENCH.json")
+
+    def sweep(rec, program):
+        """``program(form)`` -> (jitted function, its arguments), measured for
+        every form: temporaries, device time, the largest gap to the first."""
+        want = None
+        for tag, form in forms.items():
+            try:
+                fn, args = program(form)
+                compiled = fn.lower(*args).compile()
+                rec[f"{tag}_temp_mb"] = round(
+                    compiled.memory_analysis().temp_size_in_bytes / 1e6, 1)
+                rec[f"{tag}_ms"] = round(_device_time(compiled, *args) * 1e3, 4)
+                got = np.asarray(compiled(*args).astype(jnp.float32))
+            except Exception as e:  # noqa: BLE001 — record, keep sweeping
+                rec[f"{tag}_error"] = str(e)[:160]
+                continue
+            want = got if want is None else want
+            rec[f"{tag}_max_abs_diff"] = float(np.abs(got - want).max())
+        print(json.dumps(rec), flush=True)
+        with open(out_path, "a") as f:
+            f.write(json.dumps(rec) + "\n")
+
+    for label, b, h, w, c in shapes:
+        keys = jax.random.split(jax.random.key(0), 3)
+        x = jax.random.normal(keys[0], (b, h, w, c), jnp.bfloat16)
+        kernel = jax.random.normal(keys[1], (3, 3, c, c)) * (9 * c) ** -0.5
+        bias = 0.1 * jax.random.normal(keys[2], (c,))
+        tap_ms = 2 * b * h * w * c * c / 197e12 * 1e3  # a tap-product a pixel
+        sweep({"shape": label, "kind": "upsample_conv", "b": b, "h": h, "w": w,
+               "channels": c, "platform": dev.platform,
+               "device_kind": dev.device_kind, "ts": time.time(),
+               "plain_floor_ms": round(36 * tap_ms, 4),
+               "phase_floor_ms": round(16 * tap_ms, 4)},
+              lambda form: (
+                  jax.jit(lambda x, k, b_: form(x, k, b_, jnp.bfloat16)),
+                  (x, kernel, bias)))
+
+    shipped = basic.upsample2x_conv3x3
+    for label, latent, config in decoders:
+        module = vae.Decoder(getattr(vae, config)())
+        z = jax.random.normal(jax.random.key(1), latent, jnp.float32)
+        params = jax.jit(module.init)(jax.random.key(0), z)
+
+        def decoder(form):
+            # UpsampleConv looks the function up in its module when traced.
+            basic.upsample2x_conv3x3 = form
+            return jax.jit(module.apply), (params, z)
+
+        try:
+            sweep({"shape": label, "kind": "upsample_conv_decoder",
+                   "latent": list(latent), "config": config,
+                   "platform": dev.platform, "device_kind": dev.device_kind,
+                   "ts": time.time()}, decoder)
+        finally:
+            basic.upsample2x_conv3x3 = shipped
+
+
 def main() -> None:
     import jax
 
@@ -430,7 +613,7 @@ def main() -> None:
         return sys.argv[sys.argv.index(name) + 1] if name in sys.argv else None
 
     shapes = SHAPES
-    if option("--shape") and "--prologue" not in sys.argv:
+    if option("--shape") and not {"--prologue", "--upsample"} & set(sys.argv):
         labels = option("--shape").split(",")
         shapes = [sh for sh in SHAPES if sh[0] in labels]
         if len(shapes) != len(labels):
@@ -445,6 +628,14 @@ def main() -> None:
         picked = [sh for sh in PROLOGUE_SHAPES
                   if not option("--shape") or sh[0] in option("--shape").split(",")]
         _run_prologue(picked, dev, blocks)
+        return
+    if "--upsample" in sys.argv:
+        # The upsample + 3x3 forms; --shape narrows stages and decoders alike.
+        def picked(rows):
+            return [r for r in rows
+                    if not option("--shape") or r[0] in option("--shape").split(",")]
+
+        _run_upsample(picked(UPSAMPLE_SHAPES), picked(UPSAMPLE_DECODERS), dev)
         return
     _run_shapes(shapes, dev, blocks, chunk_k)
 

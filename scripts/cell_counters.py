@@ -3,7 +3,8 @@
 ``benchmark/run.py`` (run here unedited, in this process) compares two
 counter families and prints no other; an issue that asks "which path did the
 compiled programs take" wants the counters that are counted once a trace
-(``pa_qk_prologue_total``, ``pa_attention_route_total``, …). This wrapper
+(``pa_qk_prologue_total``, ``pa_attention_route_total``,
+``pa_upsample_conv_total``, …). This wrapper
 listens to the ``/metrics`` texts the harness fetches and, when the run ends,
 prints the samples of the named families from the LAST one (the end of the
 measured window) on standard error; the run's own lines are untouched:

@@ -274,7 +274,7 @@ def _compile_block(monkeypatch, one_chip, module, *args):
     params = shaped(jax.eval_shape(
         lambda *a: module.init(jax.random.key(0), *a), *args))
     fn = jax.jit(lambda p, *a: module.apply(p, *a))
-    return fn.lower(params, *args).compile().as_text()
+    return fn.lower(params, *args).compile()
 
 
 def _prologue_blocks():
@@ -321,7 +321,8 @@ def test_nothing_but_the_prologue_between_projection_and_flash(
     import re
 
     module, args, projection, n_calls, elements = _prologue_blocks()[label]
-    graph = _entry_graph(_compile_block(monkeypatch, one_chip, module, *args))
+    graph = _entry_graph(
+        _compile_block(monkeypatch, one_chip, module, *args).as_text())
 
     def named(pattern, opcode=None):
         return {n for n, (op, _, _, op_name) in graph.items()
@@ -344,3 +345,29 @@ def test_nothing_but_the_prologue_between_projection_and_flash(
         for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", shape):
             size = math.prod(int(d) for d in dims.split(","))
             assert not (dtype == "f32" and size >= elements), (name, shape)
+
+
+# The autoencoder's decode program at 1 x 1024² (every 1024² cell's, once a
+# request), by XLA's own analyses of the compiled program. Before PR 38 its
+# three upsamplers were ``jax.image.resize`` (two gather fusions a stage, a
+# copy, a pad, a copy and a slice) and a 3x3 convolution at the HIGH
+# resolution: 10.16 TFLOP, 1,361,243,136 B of temporaries.
+DECODER_PARENT_TEMP_BYTES = 1_361_243_136
+
+
+def test_decoder_upsamplers_run_at_the_low_resolution(monkeypatch, one_chip):
+    """``ops/basic.upsample2x_conv3x3`` in the decoder's program: no gather is
+    left, the three pairs cost 16 tap-products a source pixel where they cost
+    36 (-1.55 TFLOP), and the program's temporaries did not grow —
+    ``flux-schnell`` runs at 15.1-15.3 GB of the chip's 16."""
+    from comfyui_parallelanything_tpu.models import vae
+
+    compiled = _compile_block(
+        monkeypatch, one_chip, vae.Decoder(vae.sd3_vae_config()),
+        jax.ShapeDtypeStruct((1, 128, 128, 16), jnp.float32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the mid-block attention's flash kernel
+    assert "gather" not in text
+    assert compiled.cost_analysis()["flops"] <= 8.8e12  # parent 10.16e12
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= DECODER_PARENT_TEMP_BYTES
