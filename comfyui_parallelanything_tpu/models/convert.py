@@ -142,25 +142,77 @@ def _lora_pairs(lora_sd: Mapping[str, Any]) -> dict[str, tuple[Any, Any, float |
     return out
 
 
+def _f32(t: Any):
+    """A checkpoint tensor on the default device, widened to float32 there
+    (its stored bytes are what crosses to the device)."""
+    if not isinstance(t, (np.ndarray, jax.Array)):
+        t = to_numpy(t)
+    return jnp.asarray(t).astype(jnp.float32)
+
+
+class _Baked(Mapping):
+    """A state dict with LoRA deltas pending: a key the plan names is baked
+    when it is TAKEN — ``W + scale · delta`` in float32 on the default device,
+    one tensor at a time (a 70 M-element sum is milliseconds there and seconds
+    in numpy) — and every other key comes back as the base holds it (stored
+    type and all). A converter that walks the keys once (every one here does)
+    never holds more than one baked tensor beside its own output."""
+
+    def __init__(self, base: Mapping[str, Any], plan: dict[str, Any]):
+        self._base, self._plan = base, plan
+
+    def __getitem__(self, key):
+        w = self._base[key]
+        delta = self._plan.get(key)
+        if delta is None:
+            return w
+        # Finished before the next is started: dispatch runs ahead of the
+        # device, and fifty tensors' float32 temporaries in flight at once
+        # are gigabytes (15.87 GB at the peak of a run that did not wait).
+        return jax.block_until_ready(_f32(w) + delta())
+
+    def __iter__(self):
+        return iter(self._base)
+
+    def __len__(self):
+        return len(self._base)
+
+
+# Where a LoRA file names its bases under the module path of a wrapper the
+# checkpoint's own keys do not carry (ComfyUI's ``diffusion_model.`` spelling).
+_LORA_WRAPPER_PREFIXES = ("model.diffusion_model.", "diffusion_model.", "transformer.")
+
+
 def bake_lora(
     state_dict: Mapping[str, Any],
     lora_sd: Mapping[str, Any],
     strength: float = 1.0,
-) -> dict[str, np.ndarray]:
+) -> Mapping[str, Any]:
     """Merge LoRA deltas into base weights: ``W += strength · (alpha/r) · up @ down``.
 
-    Returns a new float32 state dict; unmatched LoRA keys are logged and skipped
-    (the reference prints-and-continues on patch failures, 1002-1004). Matching is by
-    base-key prefix with '.weight' appended, tolerating the common ``lora_unet_`` /
+    Returns a new state dict over the same keys in which each touched tensor
+    is a float32 array on the default device — widened from whatever type the
+    base stores, the delta added in float32 — computed when it is taken
+    (``_Baked``); untouched tensors are the base's own. A loader that keeps
+    its kernels resident in 16 bits (``resident``) therefore rounds a baked
+    kernel once, after the sum.
+    Unmatched LoRA keys are logged and skipped (the reference
+    prints-and-continues on patch failures, 1002-1004). Matching is by
+    base-key prefix with '.weight' appended, tolerating a wrapper's module
+    prefix (``diffusion_model.``) and the common ``lora_unet_`` /
     underscore-flattened prefixes by also trying a dot-normalized form.
     """
-    merged = {k: to_numpy(v) for k, v in state_dict.items()}
-    by_normalized = {k.replace(".", "_"): k for k in merged}
+    keys = list(state_dict)
+    present = set(keys)
+    by_normalized = {k.replace(".", "_"): k for k in keys}
     unmatched = []
+    plan: dict[str, Any] = {}
     for base, (down, up, alpha) in _lora_pairs(lora_sd).items():
         target = None
-        for cand in (f"{base}.weight", base):
-            if cand in merged:
+        bare = next((base[len(p):] for p in _LORA_WRAPPER_PREFIXES
+                     if base.startswith(p)), base)
+        for cand in (f"{base}.weight", base, f"{bare}.weight", bare):
+            if cand in present:
                 target = cand
                 break
         if target is None:
@@ -188,29 +240,37 @@ def bake_lora(
         if target is None:
             unmatched.append(base)
             continue
-        down_a, up_a = to_numpy(down), to_numpy(up)
-        rank = down_a.shape[0]
+        rank = int(down.shape[0])
         scale = strength * ((alpha / rank) if alpha is not None else 1.0)
-        w = merged[target]
-        if w.ndim == 4:  # conv: (O, I, kH, kW) with 1x1 or kxk lora
-            delta = np.einsum(
-                "or...,ri...->oi...",
-                up_a.reshape(up_a.shape[0], rank, *up_a.shape[2:]),
-                down_a.reshape(rank, down_a.shape[1], *down_a.shape[2:]),
-            )
-            if delta.shape != w.shape:  # 1x1 lora on kxk conv: broadcast at center
-                unmatched.append(base)
+        shape = tuple(state_dict[target].shape)
+        if len(shape) == 4:  # conv: (O, I, kH, kW) with 1x1 or kxk lora
+            spatial = np.broadcast_shapes(tuple(up.shape[2:]), tuple(down.shape[2:]))
+            if (up.shape[0], down.shape[1], *spatial) != shape:
+                unmatched.append(base)  # 1x1 lora on kxk conv: no center broadcast
                 continue
-            merged[target] = w + scale * delta
+
+            def delta(down=down, up=up, scale=scale, rank=rank):
+                d, u = _f32(down), _f32(up)
+                return scale * jnp.einsum(
+                    "or...,ri...->oi...",
+                    u.reshape(u.shape[0], rank, *u.shape[2:]),
+                    d.reshape(rank, d.shape[1], *d.shape[2:]),
+                    precision=jax.lax.Precision.HIGHEST,
+                )
         else:
-            merged[target] = w + scale * (up_a @ down_a)
+            def delta(down=down, up=up, scale=scale):
+                return scale * jnp.matmul(
+                    _f32(up), _f32(down), precision=jax.lax.Precision.HIGHEST)
+        prev = plan.get(target)
+        plan[target] = delta if prev is None else (
+            lambda a=prev, b=delta: a() + b())
     if unmatched:
         get_logger().warning(
             "bake_lora: %d LoRA key(s) had no base match and were skipped: %s",
             len(unmatched),
             unmatched[:5],
         )
-    return merged
+    return _Baked(state_dict, plan)
 
 
 # --------------------------------------------------------------------------------------

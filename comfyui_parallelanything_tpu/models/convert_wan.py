@@ -39,15 +39,19 @@ from __future__ import annotations
 from collections.abc import Mapping
 from typing import Any
 
-from .convert import linear_kernel, to_numpy, tree_to_jnp
+import jax.numpy as jnp
+
+from .convert import dense_params as _dense, resident, to_numpy, tree_to_jnp
 from .wan import WanConfig
 
 
-def _dense(sd: Mapping[str, Any], key: str, bias: bool = True) -> dict:
-    out = {"kernel": linear_kernel(sd[f"{key}.weight"])}
-    if bias and f"{key}.bias" in sd:
-        out["bias"] = to_numpy(sd[f"{key}.bias"])
-    return out
+def wan_depth(keys) -> int:
+    """How many blocks a WAN-layout file holds, from its key names: a depth
+    cut of a published model — a contiguous block range, one pipeline stage's
+    share — loads at the depth it has."""
+    idx = [int(k.split(".")[1]) for k in keys
+           if k.startswith("blocks.") and k.split(".")[1].isdigit()]
+    return 1 + max(idx) if idx else 0
 
 
 def _rms(sd: Mapping[str, Any], key: str) -> dict:
@@ -60,55 +64,64 @@ def _ln(sd: Mapping[str, Any], key: str) -> dict:
 
 def convert_wan_checkpoint(state_dict: Mapping[str, Any], cfg: WanConfig) -> dict:
     """Official WAN state dict → the param pytree of ``models.wan.WanModel``
-    (pass to ``build_wan(cfg, params=...)``)."""
-    sd = dict(state_dict)
+    (pass to ``build_wan(cfg, params=...)``).
+
+    Matmul kernels stay in their resident type (``convert.resident``: bfloat16
+    from a bfloat16 file or under bfloat16 compute, one tensor at a time —
+    14 B parameters an expert never exist whole in float32); norm scales,
+    biases and the modulation tables are float32. ``state_dict`` is read key
+    by key and never copied, so a lazily baked one (``convert.bake_lora``)
+    bakes a tensor as it is taken."""
+    sd = state_dict
+    dt, f32 = cfg.dtype, jnp.float32
 
     # Conv3d patchify (O, C, pt, ph, pw) → Dense kernel (pt·ph·pw·C, O) in the
     # (pt, ph, pw, C) flattening order of WanModel.prepare.
-    w = to_numpy(sd["patch_embedding.weight"])
+    w = jnp.asarray(resident(sd["patch_embedding.weight"], dt))
     pe_kernel = w.transpose(2, 3, 4, 1, 0).reshape(-1, w.shape[0])
     p: dict[str, Any] = {
         "patch_embedding": {
             "kernel": pe_kernel,
             "bias": to_numpy(sd["patch_embedding.bias"]),
         },
-        "text_in": _dense(sd, "text_embedding.0"),
-        "text_hidden": _dense(sd, "text_embedding.2"),
-        "time_in": _dense(sd, "time_embedding.0"),
-        "time_hidden": _dense(sd, "time_embedding.2"),
-        "time_projection": _dense(sd, "time_projection.1"),
-        "head_proj": _dense(sd, "head.head"),
+        "text_in": _dense(sd, "text_embedding.0", dt),
+        "text_hidden": _dense(sd, "text_embedding.2", dt),
+        # the time path and the head compute in float32 (WanModel.setup)
+        "time_in": _dense(sd, "time_embedding.0", f32),
+        "time_hidden": _dense(sd, "time_embedding.2", f32),
+        "time_projection": _dense(sd, "time_projection.1", f32),
+        "head_proj": _dense(sd, "head.head", f32),
         "head_modulation": {"bias": to_numpy(sd["head.modulation"])},
     }
     if cfg.img_dim is not None:
         p["img_ln_in"] = _ln(sd, "img_emb.proj.0")
-        p["img_in"] = _dense(sd, "img_emb.proj.1")
-        p["img_hidden"] = _dense(sd, "img_emb.proj.3")
+        p["img_in"] = _dense(sd, "img_emb.proj.1", dt)
+        p["img_hidden"] = _dense(sd, "img_emb.proj.3", dt)
         p["img_ln_out"] = _ln(sd, "img_emb.proj.4")
     for i in range(cfg.depth):
         t = f"blocks.{i}"
         p[f"blocks_{i}"] = {
-            "self_q": _dense(sd, f"{t}.self_attn.q"),
-            "self_k": _dense(sd, f"{t}.self_attn.k"),
-            "self_v": _dense(sd, f"{t}.self_attn.v"),
-            "self_o": _dense(sd, f"{t}.self_attn.o"),
+            "self_q": _dense(sd, f"{t}.self_attn.q", dt),
+            "self_k": _dense(sd, f"{t}.self_attn.k", dt),
+            "self_v": _dense(sd, f"{t}.self_attn.v", dt),
+            "self_o": _dense(sd, f"{t}.self_attn.o", dt),
             "self_q_norm": _rms(sd, f"{t}.self_attn.norm_q"),
             "self_k_norm": _rms(sd, f"{t}.self_attn.norm_k"),
-            "cross_q": _dense(sd, f"{t}.cross_attn.q"),
-            "cross_k": _dense(sd, f"{t}.cross_attn.k"),
-            "cross_v": _dense(sd, f"{t}.cross_attn.v"),
-            "cross_o": _dense(sd, f"{t}.cross_attn.o"),
+            "cross_q": _dense(sd, f"{t}.cross_attn.q", dt),
+            "cross_k": _dense(sd, f"{t}.cross_attn.k", dt),
+            "cross_v": _dense(sd, f"{t}.cross_attn.v", dt),
+            "cross_o": _dense(sd, f"{t}.cross_attn.o", dt),
             "cross_q_norm": _rms(sd, f"{t}.cross_attn.norm_q"),
             "cross_k_norm": _rms(sd, f"{t}.cross_attn.norm_k"),
             "norm3": _ln(sd, f"{t}.norm3"),
-            "ffn_in": _dense(sd, f"{t}.ffn.0"),
-            "ffn_out": _dense(sd, f"{t}.ffn.2"),
+            "ffn_in": _dense(sd, f"{t}.ffn.0", dt),
+            "ffn_out": _dense(sd, f"{t}.ffn.2", dt),
             "modulation": to_numpy(sd[f"{t}.modulation"]),
         }
         if cfg.img_dim is not None:
             p[f"blocks_{i}"].update(
-                cross_k_img=_dense(sd, f"{t}.cross_attn.k_img"),
-                cross_v_img=_dense(sd, f"{t}.cross_attn.v_img"),
+                cross_k_img=_dense(sd, f"{t}.cross_attn.k_img", dt),
+                cross_v_img=_dense(sd, f"{t}.cross_attn.v_img", dt),
                 cross_k_img_norm=_rms(sd, f"{t}.cross_attn.norm_k_img"),
             )
     return tree_to_jnp(p)

@@ -2,10 +2,14 @@
 
 WAN2.2's 14B release splits denoising between two full DiT checkpoints: a
 high-noise expert for early steps and a low-noise expert for the rest, switched
-at a fixed flow-time boundary. The reference handles this transparently because
-its host app picks the model per step and the wrapper only patches whichever
-forward it is given (any_device_parallel.py:1450-1451); standalone, this wrapper
-is that per-step selection.
+at a fixed flow-time boundary. In a ComfyUI graph nothing here is needed: the
+template wires two ``KSamplerAdvanced`` nodes, one an expert, and the step
+windows do the switch (nodes.TPUKSamplerAdvanced; the benchmark's
+``wan22-t2v-a14b`` cell runs that graph). This class is the same selection for
+code that drives ONE sampler over both experts — ``pipelines.WanVideoPipeline``
+and library users — where the host app of the reference picks the model per
+step and the wrapper only patches whichever forward it is given
+(any_device_parallel.py:1450-1451).
 
 Design: the samplers are host-side loops (sampling/ddim.py docstring) whose
 timestep values are concrete at each call, so the switch is plain Python — no

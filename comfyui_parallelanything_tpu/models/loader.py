@@ -45,25 +45,245 @@ def params_nbytes(params) -> int:
     )
 
 
-def record_resident(model: str, params) -> None:
-    """``pa_params_resident_bytes{model=,dtype=}``: what a loaded pytree keeps
-    resident, by stored type, set once where a loader hands the pytree over —
-    the load policy (``convert.resident``) readable on a server's ``/metrics``
-    without a trace."""
+def _bytes_by_dtype(params) -> dict[str, int]:
     import jax
-
-    from ..utils.metrics import registry
 
     by_dtype: dict[str, int] = {}
     for leaf in jax.tree.leaves(params):
         name = str(leaf.dtype)
         by_dtype[name] = by_dtype.get(name, 0) + int(leaf.size) * leaf.dtype.itemsize
+    return by_dtype
+
+
+def _set_resident_gauges(model: str, by_dtype: dict[str, int], on_chip: bool) -> None:
+    from ..utils.metrics import registry
+
     for name, nbytes in by_dtype.items():
         registry.gauge(
-            "pa_params_resident_bytes", nbytes,
+            "pa_params_resident_bytes", nbytes if on_chip else 0,
             labels={"model": model, "dtype": name},
-            help="bytes of a loaded model's parameters by stored type",
+            help="bytes of a loaded model's parameters on the chip, by stored "
+                 "type (0 while the residency rule holds the model off the chip)",
         )
+
+
+def _nested_dicts_of_arrays(tree) -> bool:
+    if not isinstance(tree, dict) or not tree:
+        return False
+    return all(
+        _nested_dicts_of_arrays(v) if isinstance(v, dict)
+        else hasattr(v, "dtype") and hasattr(v, "shape")
+        for v in tree.values()
+    )
+
+
+class ModelOffChip(RuntimeError):
+    """Something computed with a model the residency rule had sent off the
+    chip, without asking ``residency.ensure(params)`` first."""
+
+
+class OffChip:
+    """What an evicted model's pytree holds where a tensor was: its shape and
+    type, and ``ModelOffChip`` for whoever computes with it without asking
+    ``residency.ensure`` first — a jitted call (which reads ``weak_type``), a
+    conversion to an array, a method."""
+
+    __slots__ = ("model", "shape", "dtype")
+
+    def __init__(self, model: str, shape, dtype):
+        self.model, self.shape, self.dtype = model, tuple(shape), np.dtype(dtype)
+
+    ndim = property(lambda self: len(self.shape))
+    size = property(lambda self: int(np.prod(self.shape, dtype=np.int64)))
+
+    def __repr__(self) -> str:
+        return (f"<{self.model}: a tensor {self.shape} of a model that is off the "
+                f"chip (models/loader.Residency); residency.ensure(params) "
+                f"brings it back>")
+
+    def __array__(self, *args, **kwargs):
+        raise ModelOffChip(repr(self))
+
+    def __getattr__(self, name):
+        if name.startswith("__"):
+            raise AttributeError(name)  # copy, pickle and numpy's protocols
+        raise ModelOffChip(repr(self))
+
+
+class Residency:
+    """Which loaded models are on the chip: ONE rule, in the loader.
+
+    Every loader hands its pytree over through ``record_resident``, which
+    admits it here; the loaders that read a file in its stored types (the
+    families too large for float32) ask for room BEFORE their first tensor
+    lands (``make_room``), and an entry point that knows what its compiled
+    program needs beside the parameters asks for that (``ensure(params,
+    beside=)``: the video decoder's 6 GB of temporaries). When what is
+    resident plus what comes does not fit the budget, the least recently used
+    other model that its loader can make again (``reload``: the families read
+    from a memory-mapped file in their stored types — a baked LoRA is baked
+    again) leaves the chip: its pytree's dicts are rewritten IN PLACE, so
+    every object that shares the pytree (a ``dataclasses.replace`` copy, a
+    node-cache entry) sees the same thing and the device buffers are freed;
+    the leaves are DROPPED, ``OffChip`` placeholders left in their place, and
+    nothing is copied (11 GB off the chip in no time). A model without a
+    ``reload`` (a float32 family read whole, an in-memory state dict) counts
+    and stays. A model comes back by the same rule when a node asks it to
+    compute again (``ensure``: the text-encode, sampler and decode entry
+    points, and where a pytree is taken to be placed, quantized, merged or
+    factored — one dictionary lookup a node, nothing a step).
+
+    ``budget_bytes`` None takes ``devices.memory.usable_hbm_bytes`` of the
+    first device (90% of what it reports; ``PA_HBM_BUDGET_BYTES`` overrides),
+    which is 0 — no budget, nothing ever moves — on a backend without memory
+    statistics. Each move is a ``model-residency`` span (cat ``graph``; model,
+    event evict / restore, bytes), counts in
+    ``pa_model_residency_total{model,event}`` and is followed by
+    ``pa_params_resident_bytes``. Models are held weakly: one the node cache
+    lets go of is forgotten the next time the rule looks."""
+
+    def __init__(self, budget_bytes: int | None = None):
+        import threading
+
+        self.budget_bytes = budget_bytes
+        self._lock = threading.RLock()
+        self._entries: dict[int, dict] = {}  # id(params) -> entry; guarded-by: _lock
+        self._tick = 0  # guarded-by: _lock
+
+    def budget(self) -> int:
+        if self.budget_bytes is None:
+            import jax
+
+            from ..devices.memory import usable_hbm_bytes
+
+            self.budget_bytes = usable_hbm_bytes(jax.devices()[0])
+        return self.budget_bytes
+
+    def _live(self) -> dict[int, dict]:  # palint: holds _lock
+        """The entries whose model is still alive (the others go)."""
+        for key, entry in list(self._entries.items()):
+            if entry["holder"]() is None:
+                del self._entries[key]
+        return self._entries
+
+    def resident_bytes(self) -> int:
+        with self._lock:
+            return sum(e["bytes"] for e in self._live().values() if e["on_chip"])
+
+    def _touch(self, entry: dict) -> None:  # palint: holds _lock
+        self._tick += 1
+        entry["tick"] = self._tick
+
+    def admit(self, model: str, holder, reload=None) -> None:
+        """A loader's model (anything with a ``params`` pytree of nested
+        dicts), on the chip: registered as most recently used, after room was
+        made beside it. ``reload`` () → the same pytree made again from its
+        source; without one the model counts and never moves."""
+        import weakref
+
+        params = getattr(holder, "params", None)
+        if not _nested_dicts_of_arrays(params):
+            return  # nothing to rewrite in place (quantized leaves, frozen
+            # containers): left alone, never moved
+        by_dtype = _bytes_by_dtype(params)
+        with self._lock:
+            entry = {"model": model, "bytes": sum(by_dtype.values()),
+                     "by_dtype": by_dtype, "on_chip": True, "tick": 0,
+                     "reload": reload, "holder": weakref.ref(holder)}
+            self._entries[id(params)] = entry
+            self._touch(entry)
+            self.make_room(0, keep=entry)
+
+    def make_room(self, incoming: int, keep: dict | None = None) -> None:
+        """Evict least recently used models until ``incoming`` more bytes fit
+        beside what is resident (or nothing else is left to evict)."""
+        budget = self.budget()
+        if not budget:
+            return
+        with self._lock:
+            while self.resident_bytes() + incoming > budget:
+                others = [e for e in self._entries.values()
+                          if e["on_chip"] and e["reload"] and e is not keep]
+                if not others:
+                    return
+                self._move(min(others, key=lambda e: e["tick"]), on_chip=False)
+
+    def ensure(self, params, beside: int = 0) -> None:
+        """A node is about to compute with this pytree: mark it used, bring it
+        back if it was sent out, and make room for the ``beside`` bytes its
+        program needs on top (compiled temporaries, where the entry point
+        knows them)."""
+        with self._lock:
+            entry = self._live().get(id(params))
+            if entry is not None:
+                self._touch(entry)
+                if not entry["on_chip"]:
+                    self.make_room(entry["bytes"] + beside, keep=entry)
+                    self._move(entry, on_chip=True)
+                    return
+            if beside:
+                self.make_room(beside, keep=entry)
+
+    def _move(self, entry: dict, on_chip: bool) -> None:  # palint: holds _lock
+        import time
+
+        import jax
+
+        from ..utils import tracing
+        from ..utils.metrics import registry
+
+        holder = entry["holder"]()
+        if holder is None:  # let go of since the rule last looked
+            self._live()
+            return
+        event = "restore" if on_chip else "evict"
+        t0 = time.perf_counter()
+        with tracing.span("model-residency", cat="graph", model=entry["model"],
+                          event=event, bytes=entry["bytes"]):
+
+            def rewrite(tree: dict, fresh) -> None:
+                for key, value in tree.items():
+                    if isinstance(value, dict):
+                        rewrite(value, fresh and fresh[key])
+                    elif on_chip:
+                        tree[key] = jax.numpy.asarray(fresh[key])
+                    else:
+                        tree[key] = OffChip(entry["model"], value.shape, value.dtype)
+
+            rewrite(holder.params, entry["reload"]() if on_chip else None)
+            if on_chip:
+                jax.block_until_ready(holder.params)
+        entry["on_chip"] = on_chip
+        registry.counter(
+            "pa_model_residency_total",
+            labels={"model": entry["model"], "event": event},
+            help="models moved on and off the chip by the loader's residency "
+                 "rule (models/loader.Residency)",
+        )
+        _set_resident_gauges(entry["model"], entry["by_dtype"], on_chip)
+        get_logger().info(
+            "model residency: %s %s (%.2f GB, %.1f s)", event, entry["model"],
+            entry["bytes"] / 1e9, time.perf_counter() - t0)
+
+
+residency = Residency()
+
+# What this checkout's loaders state of themselves, by name, for a harness
+# that has to ask before it spends minutes on a configuration they could not
+# serve (benchmark/yardstick/reference_wan.py): a WAN file loads at the depth
+# it has and in the types it stores (``load_wan_checkpoint``), and a model
+# that does not fit beside the others leaves the chip (``Residency``).
+CAPABILITIES = frozenset({"wan-depth-from-file", "residency"})
+
+
+def record_resident(model: str, holder, reload=None) -> None:
+    """Where a loader hands a model over: ``pa_params_resident_bytes{model=,
+    dtype=}`` says what its pytree keeps on the chip, by stored type — the
+    load policy (``convert.resident``) readable on a server's ``/metrics``
+    without a trace — and the residency rule admits it (``Residency``;
+    ``reload``: how to make the pytree again from its memory-mapped file)."""
+    _set_resident_gauges(model, _bytes_by_dtype(holder.params), True)
+    residency.admit(model, holder, reload)
 
 
 def pin_params_host(params, device=None):
@@ -205,8 +425,13 @@ def _resolve_state_dict(src: Any, stored: bool = False) -> dict[str, Any]:
     ``stored``: a file's tensors in their stored types (``open_safetensors``)
     instead of upcast to float32."""
     if isinstance(src, (str, os.PathLike)):
-        return open_safetensors(src) if stored else load_safetensors(src)
+        src = open_safetensors(src) if stored else load_safetensors(src)
     if isinstance(src, Mapping):
+        if stored:
+            # A family too large for float32 is about to become resident at
+            # about its stored size: the one check a load makes for room.
+            residency.make_room(
+                sum(int(getattr(v, "nbytes", 0)) for v in src.values()))
         return dict(src)
     raise TypeError(f"expected a path or state dict, got {type(src).__name__}")
 
@@ -290,8 +515,12 @@ def load_flux_checkpoint(
             cfg, depth=depths[0], depth_single_blocks=depths[1]
         )
     sd = _maybe_bake(sd, lora, lora_strength)
-    model = build_flux(cfg, name=name, params=convert_flux_checkpoint(sd, cfg))
-    record_resident(name, model.params)
+
+    def build():
+        return convert_flux_checkpoint(sd, cfg)
+
+    model = build_flux(cfg, name=name, params=build())
+    record_resident(name, model, build if lora is None else None)
     return model
 
 
@@ -316,8 +545,12 @@ def load_zimage_checkpoint(src: Any, cfg=None, name: str = "zimage-turbo") -> Di
             "layers", layers, refiners, refiners,
         )
         cfg = dataclasses.replace(cfg, n_layers=layers, n_refiner_layers=refiners)
-    model = build_zimage(cfg, name=name, params=convert_zimage_checkpoint(sd, cfg))
-    record_resident(name, model.params)
+
+    def build():
+        return convert_zimage_checkpoint(sd, cfg)
+
+    model = build_zimage(cfg, name=name, params=build())
+    record_resident(name, model, build)
     return model
 
 
@@ -333,7 +566,7 @@ def load_sd_unet_checkpoint(
     sd = strip_prefix(_resolve_state_dict(src))
     sd = _maybe_bake(sd, lora, lora_strength)
     model = build_unet(cfg, name=name, params=convert_sd_unet_checkpoint(sd, cfg))
-    record_resident(name, model.params)
+    record_resident(name, model)
     return model
 
 
@@ -517,7 +750,7 @@ def load_vae_checkpoint(
         cfg = sniff_vae_config(sd)
     # convert_vae_checkpoint owns the prefix strip — no pre-strip here.
     vae = build_vae(cfg, params=convert_vae_checkpoint(sd, cfg))
-    record_resident("vae", vae.params)
+    record_resident("vae", vae)
     return vae
 
 
@@ -536,7 +769,7 @@ def load_clip_text_checkpoint(src: Any, cfg=None, open_clip: bool = False):
         cfg = open_clip_g_config() if open_clip else clip_l_config()
     convert = convert_open_clip_checkpoint if open_clip else convert_clip_text_checkpoint
     enc = build_clip_text(cfg, params=convert(sd, cfg))
-    record_resident("open-clip" if open_clip else "clip-text", enc.params)
+    record_resident("open-clip" if open_clip else "clip-text", enc)
     return enc
 
 
@@ -550,8 +783,12 @@ def load_t5_checkpoint(src: Any, cfg=None):
     sd = _resolve_state_dict(src, stored=True)
     if cfg is None:
         cfg = t5_xxl_config()
-    enc = build_t5_encoder(cfg, params=convert_t5_checkpoint(sd, cfg))
-    record_resident("t5", enc.params)
+
+    def build():
+        return convert_t5_checkpoint(sd, cfg)
+
+    enc = build_t5_encoder(cfg, params=build())
+    record_resident("t5", enc, build)
     return enc
 
 
@@ -566,8 +803,12 @@ def load_qwen3_checkpoint(src: Any, cfg=None):
     sd = _resolve_state_dict(src, stored=True)
     if cfg is None:
         cfg = qwen3_4b_config()
-    enc = build_qwen3(cfg, params=convert_qwen3_checkpoint(sd, cfg))
-    record_resident("qwen3", enc.params)
+
+    def build():
+        return convert_qwen3_checkpoint(sd, cfg)
+
+    enc = build_qwen3(cfg, params=build())
+    record_resident("qwen3", enc, build)
     return enc
 
 
@@ -583,9 +824,16 @@ def load_wan_checkpoint(
     ``convert_wan_checkpoint`` by default (with ``lora`` baked before
     conversion, like the other families); pass ``params_converter``
     (state_dict, cfg) -> params for repacked layouts, or a pre-converted param
-    pytree as ``src`` (lora is not supported for pre-converted pytrees)."""
+    pytree as ``src`` (lora is not supported for pre-converted pytrees).
+
+    A file is read in its stored types and each kernel taken to its resident
+    type on its own (``convert.resident``), a LoRA's delta added to a kernel
+    as it is taken (``convert.bake_lora``): an expert is never whole in
+    float32. The block count is a fact of the file: a depth cut of a
+    published model loads at the depth it has, whatever ``cfg`` says."""
     import jax
 
+    build = None
     if params_converter is not None:
         params = params_converter(
             _maybe_bake(dict(_resolve_state_dict(src)), lora, lora_strength), cfg
@@ -600,20 +848,31 @@ def load_wan_checkpoint(
         # leaf (bf16/fp8 storage dtypes included), same as the file-load path.
         params = jax.tree.map(to_numpy, src)
     else:
-        from .convert_wan import convert_wan_checkpoint
+        import dataclasses
+
+        from .convert_wan import convert_wan_checkpoint, wan_depth
+
+        sd = _resolve_state_dict(src, stored=True)
+        depth = wan_depth(sd)
+        if depth and depth != cfg.depth:
+            get_logger().info(
+                "aligning WAN config to checkpoint: %d blocks", depth)
+            cfg = dataclasses.replace(cfg, depth=depth)
+
+        def build(cfg=cfg):
+            return convert_wan_checkpoint(_maybe_bake(sd, lora, lora_strength), cfg)
 
         try:
-            params = convert_wan_checkpoint(
-                _maybe_bake(dict(_resolve_state_dict(src)), lora, lora_strength),
-                cfg,
-            )
+            params = build()
         except KeyError as e:
             raise ValueError(
                 f"state dict is not the official Wan2.x layout (missing {e}); "
                 "pass params_converter=(state_dict, cfg) -> params for repacked "
                 "layouts, or a pre-converted param pytree"
             ) from e
-    return build_wan(cfg, name=name, params=params)
+    model = build_wan(cfg, name=name, params=params)
+    record_resident(name, model, build)
+    return model
 
 
 def load_wan_vae_checkpoint(src: Any, cfg=None):
@@ -638,7 +897,9 @@ def load_wan_vae_checkpoint(src: Any, cfg=None):
         raise ValueError(
             f"state dict is not the official Wan2.x VAE layout (missing {e})"
         ) from e
-    return build_video_vae(cfg, params=params)
+    vae = build_video_vae(cfg, params=params)
+    record_resident("video-vae", vae)
+    return vae
 
 
 def load_mmdit_checkpoint(src: Any, cfg, lora: Any = None,
@@ -678,5 +939,5 @@ def load_mmdit_checkpoint(src: Any, cfg, lora: Any = None,
             cfg, x_block_self_attn_layers=attn2_layers, qk_norm=has_qk_norm
         )
     model = build_mmdit(cfg, name=name, params=convert_mmdit_checkpoint(sd, cfg))
-    record_resident(name, model.params)
+    record_resident(name, model)
     return model

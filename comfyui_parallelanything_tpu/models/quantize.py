@@ -146,6 +146,9 @@ def quantize_model(model, min_size: int = 2**16, dtype=jnp.bfloat16):
     def apply(params, *args, **kwargs):
         return base_apply(dequantize_params(params, dtype), *args, **kwargs)
 
+    from .loader import residency
+
+    residency.ensure(model.params)  # quantized from its tensors
     q_params = quantize_params(model.params, min_size)
 
     # Pipeline staging: stage programs receive per-stage sub-pytrees and call

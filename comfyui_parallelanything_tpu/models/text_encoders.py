@@ -411,6 +411,9 @@ class TextEncoder:
         return self._jit_cache
 
     def __call__(self, tokens, **kw):
+        from .loader import residency
+
+        residency.ensure(self.params)  # back on the chip if it was sent out
         return self._jitted()(self.params, tokens, **kw)
 
 

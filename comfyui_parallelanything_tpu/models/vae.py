@@ -317,7 +317,9 @@ class VAE:
         # A latent straight out of a device chain is still sharded over it:
         # the partitioned program must be called under that mesh.
         from ..parallel.mesh import mesh_context, sharded_mesh_of
+        from .loader import residency
 
+        residency.ensure(self.params)  # back on the chip if it was sent out
         with mesh_context(sharded_mesh_of(x)):
             return self._jitted(method)(self.params, x, *rest)
 

@@ -11,21 +11,27 @@ k+1 latent frames (a single image, T=1, encodes to one latent frame — the vide
 VAE subsumes the image case). All temporal convolutions are *causal* (front-
 padded only), so frame t's latent never depends on frames > t.
 
-TPU-first choices versus the torch original's streaming design: the torch
-implementation processes 4-frame chunks with a per-conv feature cache (a mutable
-device-pinned structure of exactly the kind SURVEY §2c's `clear_flux_caches`
-exists to clean up). Here the whole clip is one fixed-shape program — causality
-comes from explicit front padding, XLA sees static shapes, and there is no cache
-state at all. Memory at large resolutions is bounded by `decode_tiled` (spatial
-tiling with blended overlaps, one compiled program per tile shape), which works
-for video because spatial convs never mix across tiles' interiors beyond the
-overlap and temporal convs are tile-local.
+The published decoder (``wan/modules/vae2_1.py``) walks the latent frames one at
+a time, every causal convolution carrying the last two frames of its own input
+from one call to the next, and its temporal up-sampler is NOT a plain causal
+convolution: the first frame passes as it is, ``time_conv`` runs over the frames
+after it with zeros (not the first frame) as their history, and each of those
+frames becomes two. ``VideoAutoencoderKL.decode`` is that arithmetic as one
+fixed-shape program over the whole clip (explicit front padding, no cache
+state): its last stage holds every pixel frame at 96 channels at once, 3.8 GB a
+tensor at 49 frames of 832 x 480, so it serves small clips, the tests and the
+encoder's round trip. ``VideoVAE.decode`` is the same arithmetic bounded in
+time: the first latent frame, then a ``lax.scan`` over the others with the
+convolutions' histories as the carry (``_History``), one compiled program a
+latent shape (XLA module ``jit_video_decode``) whose live set is one latent
+frame's four pixel frames whatever the clip's length. Both forms give the same
+frames (tests/test_wan_reference.py). ``decode_tiled`` bounds memory in space on
+top of that (blended overlaps, one program a tile shape).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import flax.linen as nn
@@ -34,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import attention_local
-from ..ops.basic import rms_normalize
+from ..ops.basic import rms_normalize, upsample2x_conv3x3
 from .tiling import blend_mask1d, tile_starts
 
 # Per-channel latent statistics of the WAN 16-channel VAE (the published
@@ -100,10 +106,34 @@ class _RMSNormC(nn.Module):
         return y
 
 
+class _History:
+    """The carried state of the time-bounded decode: each causal convolution's
+    last ``kt - 1`` input frames, in call order. ``carried`` None is the first
+    latent frame, whose history is zeros (the same front padding the
+    whole-clip program writes); the convolutions leave what the next latent
+    frame needs in ``kept``, which the scan hands back as ``carried``."""
+
+    def __init__(self, carried=None):
+        self.first = carried is None
+        self._carried = list(carried or ())
+        self.kept: list = []
+
+    def front(self, x, n: int):
+        """The ``n`` frames in front of ``x`` (B, T, H, W, C)."""
+        if self.first:
+            return jnp.zeros(x.shape[:1] + (n,) + x.shape[2:], x.dtype)
+        return self._carried[len(self.kept)]
+
+    def keep(self, frames):
+        self.kept.append(frames)
+
+
 class CausalConv3d(nn.Module):
     """3D conv on NTHWC with causal (front-only) time padding and SAME spatial
     padding. With time stride s and kernel kt, front pad kt-1 gives
-    T → (T-1)//s + 1 — exactly the first-frame-preserving schedule."""
+    T → (T-1)//s + 1 — exactly the first-frame-preserving schedule. Under a
+    ``history`` (the time-bounded decode) the front is the carried frames of
+    this convolution's own input instead of zeros."""
 
     features: int
     kernel: tuple[int, int, int] = (3, 3, 3)
@@ -111,13 +141,18 @@ class CausalConv3d(nn.Module):
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, history: _History | None = None):
         kt, kh, kw = self.kernel
+        pad_t = kt - 1
+        if history is not None and pad_t:
+            x = jnp.concatenate([history.front(x, pad_t).astype(x.dtype), x], axis=1)
+            history.keep(x[:, -pad_t:])
+            pad_t = 0
         x = jnp.pad(
             x,
             (
                 (0, 0),
-                (kt - 1, 0),
+                (pad_t, 0),
                 (kh // 2, kh // 2),
                 (kw // 2, kw // 2),
                 (0, 0),
@@ -134,14 +169,14 @@ class VideoResBlock(nn.Module):
     out_ch: int
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, history: _History | None = None):
         cfg = self.cfg
         h = _RMSNormC(name="norm1")(x)
         h = nn.silu(h)
-        h = CausalConv3d(self.out_ch, dtype=cfg.dtype, name="conv1")(h)
+        h = CausalConv3d(self.out_ch, dtype=cfg.dtype, name="conv1")(h, history)
         h = _RMSNormC(name="norm2")(h)
         h = nn.silu(h)
-        h = CausalConv3d(self.out_ch, dtype=cfg.dtype, name="conv2")(h)
+        h = CausalConv3d(self.out_ch, dtype=cfg.dtype, name="conv2")(h, history)
         if x.shape[-1] != self.out_ch:
             x = CausalConv3d(
                 self.out_ch, kernel=(1, 1, 1), dtype=cfg.dtype, name="shortcut"
@@ -190,36 +225,70 @@ class SpatialDownsample(nn.Module):
         return h
 
 
+class _FrameUpsampleConv(nn.Module):
+    """``nn.Conv(features, (1, 3, 3), padding=(0, 1, 1))`` on the nearest ×2
+    (H, W) upsample of every frame, through ``ops.basic.upsample2x_conv3x3``
+    frame by frame: the upsampled clip is never made. The parameters are that
+    convolution's (``kernel`` (1, 3, 3, C, features), ``bias``), so the
+    checkpoint keys stay."""
+
+    features: int
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, H, W, C = x.shape
+        kernel = self.param(
+            "kernel", nn.linear.default_kernel_init, (1, 3, 3, C, self.features))
+        bias = self.param("bias", nn.initializers.zeros_init(), (self.features,))
+        y = upsample2x_conv3x3(
+            x.reshape(B * T, H, W, C), kernel[0], bias, self.dtype)
+        return y.reshape(B, T, 2 * H, 2 * W, self.features)
+
+
+def _two_frames(h):
+    """(B, T, H, W, 2C) → (B, 2T, H, W, C): each frame's two halves of the
+    channels become two frames, in order."""
+    B, T, H, W, C2 = h.shape
+    return (
+        h.reshape(B, T, H, W, 2, C2 // 2)
+        .transpose(0, 1, 4, 2, 3, 5)
+        .reshape(B, 2 * T, H, W, C2 // 2)
+    )
+
+
 class SpatialUpsample(nn.Module):
-    """Nearest 2× on H,W + 3×3 conv halving channels; in temporal mode a causal
-    time conv emits two frames per input frame and the first duplicate is
-    dropped, so T latent frames → 2T-1 pixel-side frames (inverse of the causal
-    downsample schedule)."""
+    """Nearest 2× on H,W + 3×3 conv halving channels. In temporal mode time
+    doubles BEFORE that, by the published rule (``Resample.forward``,
+    ``upsample3d``): the clip's first frame passes as it is; ``time_conv``
+    runs over the frames after it, with zeros — not the first frame — as
+    their history, and each of them becomes two. T latent-side frames →
+    2T−1 (inverse of the causal downsample schedule)."""
 
     cfg: VideoVAEConfig
     temporal: bool = False
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, history: _History | None = None):
         cfg = self.cfg
-        B, T, H, W, C = x.shape
+        C = x.shape[-1]
         if self.temporal:
-            h = CausalConv3d(
+            time_conv = CausalConv3d(
                 2 * C, kernel=(3, 1, 1), dtype=cfg.dtype, name="time_conv"
-            )(x)
-            # (B,T,H,W,2C) → interleave the two C-halves along time → (B,2T,…,C)
-            h = (
-                h.reshape(B, T, H, W, 2, C)
-                .transpose(0, 1, 4, 2, 3, 5)
-                .reshape(B, 2 * T, H, W, C)
             )
-            x = h[:, 1:]  # first frame contributes once
-            T = 2 * T - 1
-        x = jax.image.resize(x, (B, T, 2 * H, 2 * W, x.shape[-1]), method="nearest")
-        return nn.Conv(
-            x.shape[-1] // 2, (1, 3, 3), padding=(0, 1, 1),
-            dtype=cfg.dtype, name="conv",
-        )(x)
+            if history is None:
+                rest = x[:, 1:]
+                if rest.shape[1]:
+                    x = jnp.concatenate(
+                        [x[:, :1], _two_frames(time_conv(rest))], axis=1
+                    )
+            elif history.first:
+                # The clip's first frame: as it is, and zeros in front of the
+                # frame after it.
+                history.keep(jnp.zeros(x.shape[:1] + (2,) + x.shape[2:], x.dtype))
+            else:
+                x = _two_frames(time_conv(x, history))
+        return _FrameUpsampleConv(C // 2, dtype=cfg.dtype, name="conv")(x)
 
 
 class VideoEncoder(nn.Module):
@@ -251,31 +320,35 @@ class VideoEncoder(nn.Module):
 class VideoDecoder(nn.Module):
     """Mirror of the encoder. Channel plan follows the WAN decoder: each
     upsample halves channels, so the first block of every post-upsample level
-    re-expands from half the previous level's width."""
+    re-expands from half the previous level's width. ``history``: the
+    time-bounded decode's carried state (``_History``); None is the whole
+    clip at once."""
 
     cfg: VideoVAEConfig
 
     @nn.compact
-    def __call__(self, z):
+    def __call__(self, z, history: _History | None = None):
         cfg = self.cfg
         ch = cfg.base_channels * cfg.channel_mult[-1]
-        h = CausalConv3d(ch, dtype=cfg.dtype, name="conv_in")(z.astype(cfg.dtype))
-        h = VideoResBlock(cfg, ch, name="mid_block_1")(h)
+        h = CausalConv3d(ch, dtype=cfg.dtype, name="conv_in")(
+            z.astype(cfg.dtype), history)
+        h = VideoResBlock(cfg, ch, name="mid_block_1")(h, history)
         h = FrameAttnBlock(cfg, name="mid_attn_1")(h)
-        h = VideoResBlock(cfg, ch, name="mid_block_2")(h)
+        h = VideoResBlock(cfg, ch, name="mid_block_2")(h, history)
         temporal_up = tuple(reversed(cfg.temporal_downsample))
         n = len(cfg.channel_mult)
         for j, level in enumerate(reversed(range(n))):
             ch = cfg.base_channels * cfg.channel_mult[level]
             for i in range(cfg.num_res_blocks + 1):
-                h = VideoResBlock(cfg, ch, name=f"up_{level}_block_{i}")(h)
+                h = VideoResBlock(cfg, ch, name=f"up_{level}_block_{i}")(h, history)
             if j != n - 1:
                 h = SpatialUpsample(
                     cfg, temporal=temporal_up[j], name=f"up_{level}_upsample"
-                )(h)
+                )(h, history)
         h = _RMSNormC(name="norm_out")(h)
         h = nn.silu(h)
-        return CausalConv3d(cfg.in_channels, dtype=cfg.dtype, name="conv_out")(h)
+        return CausalConv3d(cfg.in_channels, dtype=cfg.dtype, name="conv_out")(
+            h, history)
 
 
 class VideoAutoencoderKL(nn.Module):
@@ -310,10 +383,13 @@ class VideoAutoencoderKL(nn.Module):
         sd = jnp.asarray(self.cfg.latent_std, z.dtype)
         return (z - mu) / sd
 
-    def decode(self, z):
+    def decode(self, z, history: _History | None = None):
+        """Normalized latent clip → pixel clip in [-1, 1] convention, the
+        whole clip in one pass; with ``history`` the latent frames ``z`` holds
+        continue the clip whose state it carries (``VideoVAE.decode``)."""
         mu = jnp.asarray(self.cfg.latent_mean, z.dtype)
         sd = jnp.asarray(self.cfg.latent_std, z.dtype)
-        return self.decoder(self.post_quant_conv(z * sd + mu))
+        return self.decoder(self.post_quant_conv(z * sd + mu), history)
 
     def __call__(self, x, rng=None):
         return self.decode(self.encode(x, rng))
@@ -338,11 +414,83 @@ class VideoVAE:
             )
         return fn
 
+    def _resident_params(self):
+        from .loader import residency
+
+        residency.ensure(self.params)  # back on the chip if it was sent out
+        return self.params
+
     def encode(self, x, rng=None):
-        return self._jitted(VideoAutoencoderKL.encode)(self.params, x, rng)
+        return self._jitted(VideoAutoencoderKL.encode)(
+            self._resident_params(), x, rng)
+
+    def _decode_program(self, params, z):
+        """The decode bounded in time, one program a latent shape, compiled
+        where it is first asked for so that what it needs beside the
+        parameters is known before it runs: the clip's first latent frame
+        through the decoder with zeros as every convolution's history, then a
+        ``lax.scan`` over the other latent frames with the histories as the
+        carry — four pixel frames a scan step, the same arithmetic as
+        ``VideoAutoencoderKL.decode`` on the whole clip."""
+        if not hasattr(self, "_decode_compiled"):
+            object.__setattr__(self, "_decode_compiled", {})
+        key = (z.shape, z.dtype, getattr(z, "sharding", None))
+        program = self._decode_compiled.get(key)
+        if program is not None:
+            return program
+        module = VideoAutoencoderKL(self.cfg)
+
+        def video_decode(params, z):
+            from ..utils.metrics import registry
+
+            B, T = z.shape[:2]
+            registry.counter(
+                "pa_video_decode_total",
+                labels={"frames": str(self.cfg.temporal_factor * (T - 1) + 1),
+                        "form": "scan" if T > 1 else "frame"},
+                help="video decodes by pixel frames and form, counted once a "
+                     "trace like pa_upsample_conv_total (scan: the first latent "
+                     "frame, then a scan over the others with carried "
+                     "convolution histories; frame: a one-frame clip)",
+            )
+
+            def run(z_t, carried):
+                history = _History(carried)
+                frames = module.apply(
+                    {"params": params}, z_t, history,
+                    method=VideoAutoencoderKL.decode,
+                )
+                return frames, tuple(history.kept)
+
+            first, state = run(z[:, :1], None)
+            if T == 1:
+                return first
+
+            def step(state, z_t):
+                frames, state = run(z_t[:, None], state)
+                return state, frames
+
+            _, rest = jax.lax.scan(step, state, jnp.moveaxis(z[:, 1:], 1, 0))
+            rest = jnp.moveaxis(rest, 0, 1)  # (B, T-1, frames a step, H, W, C)
+            rest = rest.reshape((B, -1) + rest.shape[3:])
+            return jnp.concatenate([first, rest], axis=1)
+
+        program = jax.jit(video_decode).lower(params, z).compile()
+        self._decode_compiled[key] = program
+        return program
 
     def decode(self, z):
-        return self._jitted(VideoAutoencoderKL.decode)(self.params, z)
+        """Normalized latent clip (B, T, H/8, W/8, z) → pixel clip
+        (B, 4(T−1)+1, H, W, 3), memory bounded in time (``_decode_program``).
+        The program's temporaries (6 GB at 13 x 60 x 104) are asked of the
+        loader's residency rule like a load's bytes, before it runs."""
+        from .loader import residency
+
+        z = jnp.asarray(z)
+        params = self._resident_params()
+        program = self._decode_program(params, z)
+        residency.ensure(params, beside=program.memory_analysis().temp_size_in_bytes)
+        return program(params, z)
 
     @property
     def spatial_factor(self) -> int:
@@ -363,9 +511,7 @@ class VideoVAE:
         f = self.spatial_factor
         t_out = self.cfg.temporal_factor * (T - 1) + 1
         stride = tile - overlap
-        decode = functools.partial(
-            self._jitted(VideoAutoencoderKL.decode), self.params
-        )
+        decode = self.decode
         th, tw = min(tile, H), min(tile, W)
         mask = (
             blend_mask1d(th, overlap, f)[:, None]

@@ -19,13 +19,14 @@ exists for.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import attention
+from ..ops.attention import attention, count_qk_prologue
 from ..ops.basic import modulate as _modulate, rms_normalize, timestep_embedding
 from ..ops.rope import apply_rope, axis_rope_freqs
 from .api import DiffusionModel, PipelineSegment, PipelineSpec
@@ -161,6 +162,11 @@ class WanBlock(nn.Module):
         k = _RMSNorm(cfg.qk_norm_eps, name="self_k_norm")(k).reshape(B, S, H, D)
         v = v.reshape(B, S, H, D)
         cos, sin = rope
+        # The fused q/k prologue (ops/attention.qk_prologue) norms a HEAD;
+        # this norm runs over the full width, so norm and rotary take the jnp
+        # functions — counted where the other families' prologues are, once a
+        # trace.
+        count_qk_prologue(fused=False, rope=True)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         attn = attention(q, k, v).reshape(B, S, -1)
@@ -457,8 +463,12 @@ def build_wan(
     name="wan",
     params=None,
 ) -> DiffusionModel:
-    """Build a WAN DiffusionModel; ``params`` skips initialization (load path)."""
-    module = WanModel(cfg)
+    """Build a WAN DiffusionModel; ``params`` skips initialization (load path).
+    Two models of one configuration — WAN2.2's two experts — share ONE module
+    and ``apply`` function, and so one traced and compiled step program
+    between them: jax's caches are keyed on the function, whichever jitted
+    wrapper (``DiffusionModel.__call__`` names one a model) calls it."""
+    module, apply, spec = _wan_program(cfg)
     if params is None:
         if rng is None:
             raise ValueError("need rng to initialize (or pass params=)")
@@ -473,15 +483,22 @@ def build_wan(
                 (sample_shape[0], 257, cfg.img_dim), jnp.float32
             )
         params = module.init(rng, x, t, ctx, **kwargs)["params"]
-
-    def apply(params, x, timesteps, context=None, **kw):
-        return module.apply({"params": params}, x, timesteps, context, **kw)
-
     return DiffusionModel(
         apply=apply,
         params=params,
         name=name,
         config=cfg,
         block_lists={"blocks": cfg.depth},
-        pipeline_spec=_wan_pipeline_spec(module, cfg),
+        pipeline_spec=spec,
     )
+
+
+@functools.lru_cache(maxsize=None)
+def _wan_program(cfg: WanConfig):
+    """(module, apply, pipeline spec) of one configuration, made once."""
+    module = WanModel(cfg)
+
+    def apply(params, x, timesteps, context=None, **kw):
+        return module.apply({"params": params}, x, timesteps, context, **kw)
+
+    return module, apply, _wan_pipeline_spec(module, cfg)

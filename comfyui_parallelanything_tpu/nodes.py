@@ -413,13 +413,16 @@ class TPUCheckpointLoader:
                 return quantize_model(m)
             return m
 
-        stored = family in ("flux-dev", "flux-schnell", "zimage-turbo")
-        # The FLUX families and Z-Image are read in the file's stored types
-        # and never pass through float32 whole (models/loader).
+        stored = (family in ("flux-dev", "flux-schnell", "zimage-turbo")
+                  or family.startswith("wan"))
+        # The FLUX families, Z-Image and WAN are read in the file's stored
+        # types and never pass through float32 whole (models/loader).
         sd = (open_safetensors if stored else load_safetensors)(ckpt_path)
         if family.startswith("wan"):
             # WAN family: video DiT + causal 3D VAE (its own checkpoint file —
             # WAN releases don't bundle the VAE with the DiT weights).
+            import os as _os
+
             from .models import (
                 load_wan_checkpoint,
                 load_wan_vae_checkpoint,
@@ -431,7 +434,8 @@ class TPUCheckpointLoader:
             # Variant sniffing within the family: i2v checkpoints carry extra
             # in-channels (36 = latent + frame mask + cond latent) and the
             # WAN2.1-style ones add the CLIP-vision branch (img_emb.* — its
-            # proj.1 Linear's input width is the CLIP hidden size).
+            # proj.1 Linear's input width is the CLIP hidden size). The depth
+            # is a fact of the file too (``load_wan_checkpoint``).
             import dataclasses as _dc
 
             pe = sd.get("patch_embedding.weight")
@@ -446,15 +450,23 @@ class TPUCheckpointLoader:
                 ),
             )
             with load_ctx:
-                model = load_wan_checkpoint(sd, wcfg, lora, lora_strength)
+                # Named for the FILE: two experts of one family are two
+                # programs' worth of counters and spans (``denoise`` /
+                # pa_denoiser_calls_total{program=}) behind one compiled step.
+                model = load_wan_checkpoint(
+                    sd, wcfg, lora, lora_strength,
+                    name=_os.path.splitext(_os.path.basename(ckpt_path))[0]
+                    + ("+lora" if lora else ""),
+                )
                 model = maybe_quant(model)
             if not load_vae:
                 return model, None
             if not vae_path:
                 raise ValueError(
-                    "wan checkpoints don't bundle a VAE — set vae_path to the "
-                    "Wan VAE safetensors file (convert the official .pth once "
-                    "with safetensors.torch.save_file)"
+                    "WAN releases keep the autoencoder in a file of its own "
+                    "(Wan2.1_VAE): set vae_path to it (safetensors) — or, in "
+                    "a stock graph, load the denoiser with UNETLoader and the "
+                    "autoencoder with VAELoader, which need no vae_path"
                 )
             return model, load_wan_vae_checkpoint(vae_path)
         with load_ctx:
@@ -844,6 +856,8 @@ class TPUTextEncode:
                     lambda: enc(jnp.asarray(ids, jnp.int32),
                                 mask=jnp.asarray(mask) if masked else None),
                 )
+                if masked:
+                    n_tokens = int(mask[0].sum())  # the VALID count
             else:
                 out = cached(None, lambda: enc(jnp.asarray(ids, jnp.int32)))
             cache = "miss" if ran else "hit"
@@ -858,6 +872,11 @@ class TPUTextEncode:
             # the rest of the bucket with its learned pad token.
             return ({"context": out, "penultimate": None,
                      "pooled": jnp.asarray(mask.sum(-1, keepdims=True), jnp.float32)},)
+        if tower == "umt5":
+            # The WAN wire: the states of the valid tokens, zeros after them
+            # (the published pipeline cuts each prompt's states at its length
+            # and the model pads them back with zeros to its text length).
+            out = out * jnp.asarray(mask, out.dtype)[..., None]
         if tower in ("t5", "umt5"):
             return ({"context": out, "pooled": None},)
         last, penultimate, pooled = out
@@ -1737,6 +1756,14 @@ class TPUKSamplerAdvanced:
             lora=lora,
             **kwargs,
         )
+        last = float(sigmas[-1])
+        if (return_with_leftover_noise == "enable" and 0.0 < last < 1.0
+                and getattr(model_cfg, "prediction", "eps") == "flow"):
+            # Stock hands a flow model's leftover-noise latent on divided by
+            # (1 − σ_end) (``inverse_noise_scaling``); the next window's
+            # noising, σ·0 + (1 − σ)·latent with its noise disabled, multiplies
+            # it back: the second run continues from this run's state at σ_end.
+            out = out / (1.0 - last)
         return ({"samples": out},)
 
 

@@ -852,10 +852,21 @@ class LoraLoader:
         against the base-most model, so a LoRA stack is still ONE delegate."""
         from .models.lora import factorize_bake
 
+        import jax
+
         base = (getattr(model, "lora_delegate", None) or {}).get("base", model)
         if not isinstance(getattr(base, "params", None), dict) \
                 or not isinstance(getattr(patched, "params", None), dict):
             return None
+        if any(leaf.dtype.itemsize < 4 and leaf.ndim >= 2
+               for leaf in jax.tree.leaves(patched.params)):
+            # A bake rounded into 16-bit resident kernels (convert.resident)
+            # is not low-rank against its base any more: the delta carries
+            # the rounding of every element. No exact factors, so bake only.
+            return None
+        from .models.loader import residency
+
+        residency.ensure(base.params)  # the delta is taken from the base's tensors
         factors = factorize_bake(base.params, patched.params)
         return {"base": base, "factors": factors} if factors else None
 
@@ -2924,6 +2935,10 @@ class ModelMergeSimple:
                 raise ValueError(f"leaf shapes differ: {a.shape} vs {b.shape}")
             return a * r + b * (1.0 - r)
 
+        from .models.loader import residency
+
+        residency.ensure(model1.params)
+        residency.ensure(model2.params)
         try:
             merged = jax.tree.map(lerp, model1.params, model2.params)
         except (ValueError, TypeError) as e:

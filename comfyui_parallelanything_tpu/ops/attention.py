@@ -338,6 +338,23 @@ def _mosaic_reach(batch: int) -> bool:
     )
 
 
+def count_qk_prologue(fused: bool, rope: bool) -> None:
+    """``pa_qk_prologue_total{path, rope}``, once a trace: here for every
+    prologue that passes :func:`qk_prologue`, and from a model whose norm the
+    entry point does not serve (WAN's runs over the full width), as ``xla``."""
+    from ..utils.metrics import registry
+
+    registry.counter(
+        "pa_qk_prologue_total",
+        labels={"path": "fused" if fused else "xla",
+                "rope": "interleaved" if rope else "none"},
+        help="q/k prologues (per-head RMS norm, and the rotary where the "
+             "model has one) by the path they took, counted like "
+             "pa_attention_route_total: once a trace "
+             "(ops/attention.qk_prologue)",
+    )
+
+
 def qk_prologue(qkv, q_scale, k_scale, eps: float = 1e-6, rope=None):
     """What stands between a block's qkv projection and its attention: the
     per-head RMS norm of q and of k with their learned ``(D,)`` scales
@@ -357,7 +374,6 @@ def qk_prologue(qkv, q_scale, k_scale, eps: float = 1e-6, rope=None):
     (ops/pallas/qk_prologue.py); otherwise — off a TPU, a text tower's or a
     context stream's few dozen rows, a sharded program — the jnp functions,
     untouched. Counted once a trace: ``pa_qk_prologue_total{path, rope}``."""
-    from ..utils.metrics import registry
     from .basic import rms_normalize
     from .pallas.tuning import qk_prologue_route
 
@@ -372,15 +388,7 @@ def qk_prologue(qkv, q_scale, k_scale, eps: float = 1e-6, rope=None):
         batch * seq, heads, head_dim, rope is not None,
         on_tpu=_pallas_available(), pinned=_BACKEND,
     )
-    registry.counter(
-        "pa_qk_prologue_total",
-        labels={"path": "fused" if fused else "xla",
-                "rope": "none" if rope is None else "interleaved"},
-        help="q/k prologues (per-head RMS norm, and the rotary where the "
-             "model has one) by the path they took, counted like "
-             "pa_attention_route_total: once a trace "
-             "(ops/attention.qk_prologue)",
-    )
+    count_qk_prologue(fused, rope is not None)
     if not fused:
         q, k = (qkv[:, :, 0], qkv[:, :, 1]) if fused_source else qkv
         q, k = rms_normalize(q, q_scale, eps), rms_normalize(k, k_scale, eps)

@@ -92,6 +92,22 @@ def padded_dim_route(seq_q: int, seq_k: int,
 # 1.779 at 384: this row's pad and mask cost 0.27 ms a call there, 2.2 ms of
 # that cell's 122.5 ms step (PERF.md section 6, PR 34).
 #
+# Read past RAGGED_ONE_BLOCK by PR 39 (Wan2.2-T2V-A14B's space-time
+# self-attention at 49 frames of 832 x 480 and its cross-attention on 512 text
+# rows; my chip run), and NOT retuned — by (queries x keys) a block, where the
+# last streamed block is masked and 20,352 is the padded row as ONE block:
+#
+#   wan22-480p.self20280 (1, 20280, 40, 128)   2^34.0  xla 219.9 (chunked)
+#       256x4096 63.011 (as routed)  384x4096 60.816  512x4096 60.849
+#       256x2048 66.932  256x8192 72.158  256x20352 47.837 (floor 42.76)
+#   wan22-480p.cross512  (1, 20280 x 512 keys, 40, 128)  2^28.6
+#       xla 8.204 (chunked, as routed: 512 keys < PADDED_DIM_MIN_KEYS)
+#       xla whole 6.723  256x512 3.563  512x512 3.131  1024x512 2.962
+#
+# The streamed row is 3.5x faster than XLA and a third slower than one block
+# of 5.2 MB of K a head; the cross class would be 2.8x faster on the kernel
+# (PERF.md section 7, "Left by PR 39").
+#
 # Both ragged classes win, the short one too (1.7x), where sdxl's 1024-token
 # class at 2^25.3 logits lost by 10%: XLA pays for a ragged length as well.
 # Below the short class nothing was measured, so the threshold stands there,

@@ -982,6 +982,9 @@ def _unwrap_model(model) -> tuple[Callable[..., Any], Any]:
     apply_fn = getattr(model, "apply", None)
     params = getattr(model, "params", None)
     if callable(apply_fn) and params is not None:
+        from ..models.loader import residency
+
+        residency.ensure(params)  # placed from its tensors: back on the chip first
         return apply_fn, params
     raise TypeError(
         "model must be (apply_fn, params) or expose .apply/.params; "

@@ -197,6 +197,11 @@ def run_sampler(
     (timestep-indexed, not sigma-driven) rejects it."""
     use_cfg = cfg_scale != 1.0 and uncond_context is not None
     eff_cfg = cfg_scale if use_cfg else 1.0
+    # The loader's residency rule: once a run, never a step — a model that
+    # was sent off the chip comes back before its first forward.
+    from ..models.loader import residency
+
+    residency.ensure(getattr(model, "params", None))
     # Per-request LoRA (round 16): ``lora`` maps param paths to low-rank
     # (a, b) factor pairs (models/lora.py extract_lora_factors). The inline
     # paths run the eagerly merged model; the serving path submits the BASE

@@ -179,7 +179,15 @@ once a tower a call, always on: the embed cache's outcome, ``hit`` or
 ``pa_params_resident_bytes{model=,dtype=}`` (models/loader.py
 ``record_resident`` — a gauge set once where a loader hands its pytree
 over: the bytes it keeps resident by stored type, bfloat16 kernels for the
-FLUX and T5 load paths, float32 for the others).
+FLUX and T5 load paths, float32 for the others; 0 while the loader's
+residency rule holds the model off the chip).
+
+Model residency (PR 39): ``pa_model_residency_total{model=,event=}``
+(models/loader.py ``Residency._move`` — one count a move of a model between
+the chip and off it, ``evict`` or ``restore``, beside the ``model-residency``
+span). Video decodes (PR 39): ``pa_video_decode_total{frames=,form=}``
+(models/video_vae.py ``VideoVAE._decode_program`` — once a trace, by the
+clip's pixel frames and ``scan`` / ``frame``).
 """
 
 from __future__ import annotations

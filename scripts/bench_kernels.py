@@ -95,6 +95,14 @@ SHAPES = [
     ("zimage-b1-1024.joint4128", 1, 4128, 30, 128),
     ("zimage-b1-1024.refine4096", 1, 4096, 30, 128),
     ("zimage-b1-1024.joint4128-as-4224", 1, 4224, 30, 128),
+    # Wan2.2-T2V-A14B at 49 frames of 832 x 480 (PR 39): 13 x 30 x 52 = 20,280
+    # space-time tokens, 40 heads of 128. The self-attention is ragged and
+    # past RAGGED_ONE_BLOCK, so the `ragged` row streams 4096-key blocks and
+    # masks the last; the cross-attention's 512 text rows are under
+    # PADDED_DIM_MIN_KEYS and stay with the XLA family (in query chunks: 415 M
+    # logits a call).
+    ("wan22-480p.self20280", 1, 20280, 40, 128),
+    ("wan22-480p.cross512", 1, 20280, 40, 128, 512),
 ]
 
 # Shapes whose sweep is not the grid below: 4352 = 17 x 256, so only 128- and
@@ -133,6 +141,11 @@ COMBOS = {
     "wan_480p_16f.cross512": [(256, 256), (256, 512), (512, 512)],
     "zimage-b1-1024.refine4096": [(256, 256), (256, 4096), (512, 4096)],
     "zimage-b1-1024.joint4128-as-4224": [(256, 4224), (384, 4224)],
+    # as routed (256 x 4096), other query and key blocks beside it, and the
+    # whole padded row as one key block (5.2 MB of K a head)
+    "wan22-480p.self20280": [(256, 4096), (384, 4096), (512, 4096), (256, 2048),
+                             (256, 8192), (256, 20352)],
+    "wan22-480p.cross512": [(256, 512), (512, 512), (1024, 512)],
 }
 
 BLOCKS_Q = (128, 256, 512)
@@ -241,9 +254,10 @@ def _run_shapes(shapes, dev, blocks=None, chunk_k=None):
             )
         except Exception as e:  # noqa: BLE001 — S×S logits OOM at video lengths
             rec["xla_error"] = str(e)[:200]
-        if s % 128 and b * h * s * sk > _chunk_threshold():
+        if s % 128 and _chunk_threshold() < b * h * s * sk < 2 ** 31:
             # The third route of a ragged length: XLA with the whole logits
-            # tensor in HBM, where it fits.
+            # tensor in HBM, where it fits (8 GB of float32 logits at most:
+            # past it nothing is tried).
             try:
                 rec["xla_plain_ms"] = round(_time_fn(
                     projected(lambda a, b_, c: _xla_attention(a, b_, c, d**-0.5)),

@@ -4,7 +4,9 @@
 counter families and prints no other; an issue that asks "which path did the
 compiled programs take" wants the counters that are counted once a trace
 (``pa_qk_prologue_total``, ``pa_attention_route_total``,
-``pa_upsample_conv_total``, …). This wrapper
+``pa_upsample_conv_total``, ``pa_video_decode_total``, …) or of the loader's
+residency rule (``pa_model_residency_total``, ``pa_params_resident_bytes``).
+This wrapper
 listens to the ``/metrics`` texts the harness fetches and, when the run ends,
 prints the samples of the named families from the LAST one (the end of the
 measured window) on standard error; the run's own lines are untouched:

@@ -4,7 +4,8 @@ The torch reference below follows the public Wan2.1 causal 3D VAE design in its
 non-streaming single-clip form: causal (front-padded) 3D convs, channel RMS norms
 (``F.normalize·√C·γ``), per-frame single-head mid attention, (0,1)×(0,1)-padded
 stride-2 spatial resampling, and the 2×-channel time conv whose halves interleave
-along time on upsampling (first frame emitted once). Exported in the official
+along time on upsampling (the published first-frame rule: the first frame passes
+as it is, the frames after it are convolved with zeros as their history). Exported in the official
 ``encoder.downsamples.{seq}`` / ``decoder.upsamples.{seq}`` flat-Sequential key
 layout and converted with ``convert_wan_vae.py``.
 
@@ -145,10 +146,15 @@ class TUpsample(tnn.Module):
     def forward(self, x):
         b, c, t, hh, ww = x.shape
         if self.temporal:
-            h = self.time_conv(x)  # (b, 2c, t, hh, ww)
-            h = h.reshape(b, 2, c, t, hh, ww)
-            h = torch.stack((h[:, 0], h[:, 1]), dim=3)  # (b, c, t, 2, hh, ww)
-            x = h.reshape(b, c, 2 * t, hh, ww)[:, :, 1:]  # first frame once
+            # The published rule (Resample.forward, upsample3d): the first
+            # frame passes as it is; time_conv runs over the frames after it
+            # with zeros as their history; each of those becomes two.
+            first, rest = x[:, :, :1], x[:, :, 1:]
+            if t > 1:
+                h = self.time_conv(rest)  # (b, 2c, t-1, hh, ww)
+                h = h.reshape(b, 2, c, t - 1, hh, ww)
+                h = torch.stack((h[:, 0], h[:, 1]), dim=3)  # (b, c, t-1, 2, ...)
+                x = torch.cat([first, h.reshape(b, c, 2 * (t - 1), hh, ww)], 2)
             t = 2 * t - 1
         h = x.permute(0, 2, 1, 3, 4).reshape(b * t, c, hh, ww)
         h = self.resample(h)
