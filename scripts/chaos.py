@@ -211,6 +211,47 @@ class _Fleet:
             q.shutdown()
 
 
+class _Armed(threading.Thread):
+    """A fault armed on STATE, not on a timer: polls ``condition`` and calls
+    ``action`` once, the first time it holds — so the fault lands where the
+    phase says it does (a prompt in flight on the victim) however fast or
+    loaded the machine is. ``fired`` says that it has landed; ``cancel`` disarms
+    it (the poll also gives up at ``DEADLINE_S``, and the phase then fails its
+    gate: a fault that never landed proves nothing)."""
+
+    DEADLINE_S, POLL_S = 120.0, 0.005
+
+    def __init__(self, condition, action):
+        super().__init__(daemon=True)
+        self._condition, self._action = condition, action
+        self._cancelled = threading.Event()
+        self.fired = False
+
+    def run(self) -> None:
+        t0 = time.monotonic()
+        while not self._cancelled.is_set():
+            if time.monotonic() - t0 > self.DEADLINE_S:
+                return
+            if self._condition():
+                self._action()
+                self.fired = True  # only now: the next fault waits for this one
+                return
+            self._cancelled.wait(self.POLL_S)
+
+    def cancel(self) -> None:
+        self._cancelled.set()
+        self.join(timeout=30)
+
+
+def _mid_denoise(router, host_id: str | None = None) -> bool:
+    """A prompt is on a backend right now (on ``host_id``, where one is
+    named): dispatched, not yet collected."""
+    stats = router.stats()
+    if host_id is not None:
+        return stats["router_inflight"].get(host_id, 0) >= 1
+    return stats["prompts"].get("inflight", 0) >= 1
+
+
 def default_plan(seed: int) -> dict:
     """The seeded chaos schedule: one 5xx on a prompt dispatch (the router
     must walk on / retry, never count it lost), one slow-host stall (the
@@ -369,13 +410,18 @@ def _fleet_chaos(*, n_backends: int = 2, clients: int = 3,
     chaos_dir = os.path.join(root, "chaos")
     fleet = _Fleet(os.path.join(root, "c"), n_backends, chaos_dir,
                    journal=True, lease_ttl_s=lease_ttl_s)
-    timers = [
-        # Mid-run, not at the edges: roughly one closed-loop wave in.
-        threading.Timer(work_s * 1.5, fleet.kill_router),
-        threading.Timer(work_s * 2.5, fleet.kill_backend, args=(0,)),
-    ]
+    victim_q = fleet.backends[0][3]
+    # The primary dies with a prompt mid-denoise on a backend, and backend 0
+    # after it with a prompt of its own running — or, if none reaches it
+    # while no router is up, once the standby has taken over.
+    router_kill = _Armed(lambda: _mid_denoise(fleet.router), fleet.kill_router)
+    backend_kill = _Armed(
+        lambda: router_kill.fired and (victim_q.running
+                                       or fleet.standby.active),
+        lambda: fleet.kill_backend(0))
+    arms = [router_kill, backend_kill]
     try:
-        for t in timers:
+        for t in arms:
             t.start()
         chaos = run_load(
             fleet.base, g, clients=clients, requests=requests, timeout=240,
@@ -384,7 +430,7 @@ def _fleet_chaos(*, n_backends: int = 2, clients: int = 3,
             fallback_bases=[fleet.standby_base],
         )
     finally:
-        for t in timers:
+        for t in arms:
             t.cancel()
         fleet.stop()
         os.environ.pop("PA_FAULT_PLAN", None)
@@ -413,6 +459,10 @@ def _fleet_chaos(*, n_backends: int = 2, clients: int = 3,
 
     # -- gates ---------------------------------------------------------------
     failures: list[str] = []
+    for arm, what in zip(arms, ("router", "backend")):
+        if not arm.fired:
+            failures.append(f"the {what} was never killed (no prompt was "
+                            "in flight to kill it under)")
     if chaos.get("prompts_lost"):
         failures.append(f"prompts_lost={chaos['prompts_lost']} (must be 0)")
     if chaos["completed"] != total:
@@ -556,7 +606,9 @@ def run_partition_chaos(*, n_backends: int = 3, clients: int = 3,
         ]})
         faults.reload()
 
-    timer = threading.Timer(work_s * 1.5, arm)
+    # Armed when the victim has a prompt in flight: what fails over is that
+    # prompt, not whatever a timer happened to catch.
+    timer = _Armed(lambda: _mid_denoise(fleet.router, victim_id), arm)
     fired = 0.0
     try:
         timer.start()
@@ -600,6 +652,9 @@ def run_partition_chaos(*, n_backends: int = 3, clients: int = 3,
             f"p95 {chaos['latency_p95_s']}s exceeds bound {p95_bound:.2f}s "
             f"(baseline {baseline['latency_p95_s']}s)"
         )
+    if not timer.fired:
+        failures.append("the partition was never armed (the victim never "
+                        "had a prompt in flight)")
     if fired <= 0:
         failures.append("network-partition never fired (injection unproven)")
     if beat_drops <= 0:
@@ -609,8 +664,8 @@ def run_partition_chaos(*, n_backends: int = 3, clients: int = 3,
     failovers = (chaos.get("fleet") or {}).get("failovers")
     if not failovers:
         failures.append(
-            "no failover recorded — the victim's in-flight prompts were "
-            "never failed over (partition landed between waves?)"
+            "no failover recorded — the victim's in-flight prompt was "
+            "never failed over"
         )
     return {
         "phase": "partition",

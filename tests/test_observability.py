@@ -698,6 +698,19 @@ def _await_entry(base, pid, poll_s=0.05) -> dict:
     raise AssertionError(f"no history entry for {pid}")
 
 
+def _await_span(base, pid, name) -> None:
+    """A span is recorded where it closes: ``prompt-finish`` present means the
+    worker's turn for ``pid`` is over — the history entry that a client polls
+    for is written INSIDE that turn, so a fast client can be ahead of it."""
+    t0 = time.time()
+    while time.time() - t0 < 60:
+        if any(e["name"] == name
+               for e in _x_events(_get(base, f"/trace?prompt_id={pid}"))):
+            return
+        time.sleep(0.005)
+    raise AssertionError(f"no {name} span for {pid}")
+
+
 def _serve_prompt(base, out_dir, x) -> tuple[str, list]:
     """One request as the benchmark's client makes it: POST, poll until the
     entry is there, fetch every image back through /view."""
@@ -758,7 +771,12 @@ class TestServerTurnSpans:
     @pytest.fixture
     def two_prompts(self, server):
         base, q = server
-        pids = [_serve_prompt(base, q.output_dir, x)[0] for x in (1, 2)]
+        pids = []
+        for x in (1, 2):
+            pids.append(_serve_prompt(base, q.output_dir, x)[0])
+            # the next POST is to fall inside the worker's wait, which opens
+            # where this turn ends: wait for that, not for a while
+            _await_span(base, pids[-1], "prompt-finish")
         # the worker stamps its wait with the NEXT prompt's id: a third POST
         # closes the span that follows the second prompt
         _await_entry(base, _post_prompt(

@@ -50,7 +50,7 @@ class TestMicrobatchedPipeline:
         got = pm(x, t, ctx, y=y)
         assert pm._pipeline_runner is not None
         assert pm._pipeline_runner.n_stages > 1  # stages actually placed
-        want = model.apply(model.params, x, t, ctx, y=y)
+        want = jax.jit(model.apply)(model.params, x, t, ctx, y=y)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
         )
@@ -64,7 +64,7 @@ class TestMicrobatchedPipeline:
         )
         x, t, ctx, y = _inputs(7, seed=2)
         got = pm(x, t, ctx, y=y)
-        want = model.apply(model.params, x, t, ctx, y=y)
+        want = jax.jit(model.apply)(model.params, x, t, ctx, y=y)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
         )
@@ -93,7 +93,7 @@ class TestMicrobatchedPipeline:
         pm._pipeline_runner = Spy()
         got = pm(x, t, ctx, y=y)
         assert seen == [3, 3, 3]  # uniform chunk shapes (7 -> 9 padded)
-        want = model.apply(model.params, x, t, ctx, y=y)
+        want = jax.jit(model.apply)(model.params, x, t, ctx, y=y)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
         )
@@ -120,7 +120,7 @@ class TestMicrobatchedPipeline:
         )
         x, t, ctx, y = _inputs(4, seed=3)  # batch 4 < mb 8 -> DP path
         got = pm(x, t, ctx, y=y)
-        want = model.apply(model.params, x, t, ctx, y=y)
+        want = jax.jit(model.apply)(model.params, x, t, ctx, y=y)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
         )
@@ -130,7 +130,7 @@ class TestMicrobatchedPipeline:
         x, t, ctx, y = _inputs(8, seed=4)
         got = pm(x, t, ctx, y=y)
         assert pm._pipeline_runner is None  # DP, not pipeline
-        want = model.apply(model.params, x, t, ctx, y=y)
+        want = jax.jit(model.apply)(model.params, x, t, ctx, y=y)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
         )

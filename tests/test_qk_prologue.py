@@ -243,14 +243,12 @@ def test_sd35m_step_holds_37_fused_and_24_xla_prologues(monkeypatch):
             jax.ShapeDtypeStruct((2,), jnp.float32),
             jax.ShapeDtypeStruct((2, 77, cfg.context_in_dim), jnp.float32),
             jax.ShapeDtypeStruct((2, cfg.pooled_dim), jnp.float32))
-    params = jax.eval_shape(
-        lambda x, t, c, y: module.init(jax.random.key(0), x, t, c, y=y)["params"],
-        *args)
 
     def trace():
+        # ``init`` walks the forward pass once to shape the parameters: that
+        # one abstract walk is the step's, and no second one is paid for
         return jax.eval_shape(
-            lambda p, x, t, c, y: module.apply({"params": p}, x, t, c, y=y),
-            params, *args)
+            lambda x, t, c, y: module.init(jax.random.key(0), x, t, c, y=y), *args)
 
     moved = _denoiser_counts(monkeypatch, trace)
     assert moved == {("fused", "none"): 37, ("xla", "none"): 24}
@@ -294,12 +292,10 @@ def test_zimage_step_holds_ten_fused_and_two_xla_prologues(monkeypatch):
     args = (jax.ShapeDtypeStruct((1, 128, 128, 16), jnp.float32),
             jax.ShapeDtypeStruct((1,), jnp.float32),
             jax.ShapeDtypeStruct((1, 32, cfg.cap_feat_dim), jnp.float32))
-    params = jax.eval_shape(
-        lambda *a: module.init(jax.random.key(0), *a)["params"], *args)
 
-    def trace():
+    def trace():  # the parameters' shapes and the counts from one walk
         return jax.eval_shape(
-            lambda p, *a: module.apply({"params": p}, *a), params, *args)
+            lambda *a: module.init(jax.random.key(0), *a), *args)
 
     moved = _denoiser_counts(monkeypatch, trace)
     assert moved == {("fused", "interleaved"): 10, ("xla", "interleaved"): 2}

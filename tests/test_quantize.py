@@ -91,8 +91,8 @@ class TestQuantizedModel:
     def test_forward_close_to_full_precision(self, flux_model):
         qm = quantize_model(flux_model, min_size=2**10, dtype=jnp.float32)
         x, t, ctx, y = self._inputs(2)
-        full = np.asarray(flux_model.apply(flux_model.params, x, t, ctx, y=y))
-        quant = np.asarray(qm.apply(qm.params, x, t, ctx, y=y))
+        full = np.asarray(jax.jit(flux_model.apply)(flux_model.params, x, t, ctx, y=y))
+        quant = np.asarray(jax.jit(qm.apply)(qm.params, x, t, ctx, y=y))
         # int8 weights: relative output error stays in the few-percent regime.
         scale = np.abs(full).mean() + 1e-6
         assert np.abs(quant - full).mean() / scale < 0.05
@@ -123,7 +123,7 @@ class TestQuantizedModel:
         out = pm(x, t, ctx, y=y)
         assert out.shape == (8, 8, 8, 4)
         assert len(out.sharding.device_set) == 8
-        single = np.asarray(qm.apply(qm.params, x, t, ctx, y=y))
+        single = np.asarray(jax.jit(qm.apply)(qm.params, x, t, ctx, y=y))
         np.testing.assert_allclose(np.asarray(out), single, rtol=2e-3, atol=2e-3)
 
     def test_parallelized_fsdp(self, flux_model, cpu_devices):
@@ -173,7 +173,7 @@ class TestQuantizedModel:
         out = pm(x, t, ctx, y=y)
         assert out.shape == (1, 8, 8, 4)
         assert pm._pipeline_runner is not None and pm._pipeline_runner.n_stages >= 2
-        single = np.asarray(qm.apply(qm.params, x, t, ctx, y=y))
+        single = np.asarray(jax.jit(qm.apply)(qm.params, x, t, ctx, y=y))
         np.testing.assert_allclose(np.asarray(out), single, rtol=2e-3, atol=2e-3)
 
     def test_compile_loop_on_quantized_model(self, flux_model):

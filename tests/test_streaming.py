@@ -83,7 +83,7 @@ class TestStreamedMatchesResident:
         the bare apply, within bf16-scale tolerances (CLAUDE.md)."""
         batch = 8
         x, t, ctx, y = _flux_inputs(batch)
-        bare = flux_model.apply(flux_model.params, x, t, ctx, y=y)
+        bare = jax.jit(flux_model.apply)(flux_model.params, x, t, ctx, y=y)
         resident = parallelize(
             flux_model, DeviceChain.even([f"cpu:{i}" for i in range(8)])
         )
@@ -105,7 +105,7 @@ class TestStreamedMatchesResident:
         x = jax.random.normal(jax.random.key(1), (2, 16, 16, 4))
         t = jnp.linspace(900.0, 1.0, 2)
         ctx = jax.random.normal(jax.random.key(2), (2, 7, 48))
-        want = unet_model.apply(unet_model.params, x, t, ctx)
+        want = jax.jit(unet_model.apply)(unet_model.params, x, t, ctx)
         pm = _stream_pm(unet_model)
         got = pm(x, t, ctx)
         np.testing.assert_allclose(
@@ -115,7 +115,7 @@ class TestStreamedMatchesResident:
 
     def test_overlap_off_debug_mode(self, flux_model):
         x, t, ctx, y = _flux_inputs(2)
-        want = flux_model.apply(flux_model.params, x, t, ctx, y=y)
+        want = jax.jit(flux_model.apply)(flux_model.params, x, t, ctx, y=y)
         pm = _stream_pm(flux_model, stream_overlap=False)
         got = pm(x, t, ctx, y=y)
         np.testing.assert_allclose(
@@ -128,7 +128,7 @@ class TestStreamedMatchesResident:
         x, t, ctx, y = _flux_inputs(1)
         pm = _stream_pm(flux_model)
         got = pm(x, t, ctx, y=y)
-        want = flux_model.apply(flux_model.params, x, t, ctx, y=y)
+        want = jax.jit(flux_model.apply)(flux_model.params, x, t, ctx, y=y)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-3, atol=1e-4
         )
@@ -207,7 +207,7 @@ class TestStreamDemotion:
         got = pm(x, t, ctx, y=y)
         assert pm._stream_runner is not first
         assert pm._stream_runner.n_stages > n0
-        want = flux_model.apply(flux_model.params, x, t, ctx, y=y)
+        want = jax.jit(flux_model.apply)(flux_model.params, x, t, ctx, y=y)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-3, atol=1e-4
         )
@@ -282,7 +282,7 @@ class TestRoutingAndGuards:
         assert pm.traceable() is None  # no one-program path may exist
         x, t, ctx, y = _flux_inputs(2)
         got = pm.single(x, t, ctx, y=y)  # escape hatch streams too
-        want = flux_model.apply(flux_model.params, x, t, ctx, y=y)
+        want = jax.jit(flux_model.apply)(flux_model.params, x, t, ctx, y=y)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-3, atol=1e-4
         )

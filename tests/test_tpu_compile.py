@@ -3,10 +3,13 @@ own compiler for a DESCRIBED ``v5e:2x2`` chip (nothing is attached, nothing
 runs): what interpret mode cannot show — a slice not aligned to the tiling,
 more VMEM than a kernel may use — fails here, at no chip time.
 
-The topology is described inside a module-scoped fixture and nowhere else:
-only one process at a time may load the TPU's library, so this must never
-happen while a module is imported, and every such test lives in THIS file
-(a second file could land on another xdist worker, whose fixture would skip).
+The topology is described inside the module-scoped fixture ``topo`` and
+nowhere else — never while a module is imported. The whole blocks and the
+decoder compiled the same way are in ``test_compile_tpu_blocks.py`` and
+``test_compile_tpu_decoder.py`` (files of their own, named to start early: one
+of them is the longest test there is); they take ``topo`` from here. Several
+files mean several xdist workers loading the TPU's library at once, which it
+refuses unless told otherwise: the fixture tells it.
 """
 
 import jax
@@ -35,6 +38,8 @@ def topo():
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # one lock file a machine otherwise: the second worker's fixture would skip
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
     try:
         desc = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
@@ -216,158 +221,3 @@ def test_batch_sharded_kernels_compile_for_four_chips(topo, which):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and "all-gather" not in hlo
     assert compiled.output_shardings.spec == P("data")
-
-
-# -- The q/k prologue (ops/pallas/qk_prologue.py) --------------------------------
-# One block of each transformer family at its cell's shapes, with the backend
-# reading as a TPU so that ops/attention.qk_prologue and attention() take the
-# routes they take on the chip. What ISSUE 35 found between the qkv projection
-# and the flash kernel — float32 copies of q and k, relaid twice — is what
-# this guards against: nothing may stand there but the prologue's one call and
-# the bf16 concatenation of the streams.
-
-
-def _entry_graph(hlo: str) -> dict:
-    """``{name: (opcode, shape, operands, op_name)}`` of the optimized HLO's
-    ENTRY computation."""
-    import re
-
-    graph = {}
-    for line in hlo[hlo.index("ENTRY"):].splitlines()[1:]:
-        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\((.*)", line)
-        if not m:
-            continue
-        name, shape, opcode, rest = m.groups()
-        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
-        op_name = re.search(r'op_name="([^"]*)"', line)
-        graph[name] = (opcode, shape, operands, op_name.group(1) if op_name else "")
-    return graph
-
-
-def _reach(graph: dict, starts: set, forward: bool) -> set:
-    users = {}
-    for name, (_, _, operands, _) in graph.items():
-        for op in operands:
-            users.setdefault(op, []).append(name)
-    seen, todo = set(), list(starts)
-    while todo:
-        node = todo.pop()
-        for nxt in (users.get(node, []) if forward else graph[node][2]):
-            if nxt in graph and nxt not in seen:
-                seen.add(nxt)
-                todo.append(nxt)
-    return seen
-
-
-def _compile_block(monkeypatch, one_chip, module, *args):
-    import importlib
-
-    att = importlib.import_module("comfyui_parallelanything_tpu.ops.attention")
-    monkeypatch.setattr(att, "_pallas_available", lambda: True)
-
-    def shaped(tree):
-        return jax.tree.map(
-            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip),
-            tree)
-
-    args = shaped(args)
-    params = shaped(jax.eval_shape(
-        lambda *a: module.init(jax.random.key(0), *a), *args))
-    fn = jax.jit(lambda p, *a: module.apply(p, *a))
-    return fn.lower(params, *args).compile()
-
-
-def _prologue_blocks():
-    from comfyui_parallelanything_tpu.models import flux, mmdit, zimage
-
-    bf16, f32 = jnp.bfloat16, jnp.float32
-    S = jax.ShapeDtypeStruct
-
-    def rope(rows):
-        return (S((1, rows, 64), f32), S((1, rows, 64), f32))
-
-    return {
-        # (module, args, projections' op_name, prologue calls, rows x width)
-        "sd35m-joint-dual": (
-            mmdit.JointBlock(mmdit.sd35_medium_config(), dual_attn=True),
-            (S((2, 4096, 1536), bf16), S((2, 77, 1536), bf16), S((2, 1536), bf16)),
-            r"x_attn_in2?/qkv/", 2, 2 * 4096 * 1536),
-        "flux-double": (
-            flux.DoubleBlock(flux.FluxConfig()),
-            (S((1, 4096, 3072), bf16), S((1, 256, 3072), bf16),
-             S((1, 3072), bf16), rope(4352)),
-            r"(img|txt)_attn_qkv/", 2, 4096 * 3072),
-        "flux-single": (
-            flux.SingleBlock(flux.FluxConfig()),
-            (S((1, 4352, 3072), bf16), S((1, 3072), bf16), rope(4352)),
-            r"linear1/", 1, 4352 * 3072),
-        "zimage-main": (
-            zimage.ZImageBlock(zimage.zimage_turbo_config()),
-            (S((1, 4128, 3840), bf16), rope(4128), S((1, 256), f32)),
-            r"to_[qk]/", 1, 4128 * 3840),
-    }
-
-
-@pytest.mark.parametrize(
-    "label", ["sd35m-joint-dual", "flux-double", "flux-single", "zimage-main"])
-def test_nothing_but_the_prologue_between_projection_and_flash(
-        monkeypatch, one_chip, label):
-    """The prologue's custom call is in the block's program, reads what the
-    projection wrote and writes what the flash kernel (or the streams' bf16
-    concatenation before it) reads: on every path from a q/k projection
-    through the prologue to the flash kernel's call there is NO ``copy`` or
-    ``transpose`` and no float32 array of rows x H·D elements or more."""
-    import math
-    import re
-
-    module, args, projection, n_calls, elements = _prologue_blocks()[label]
-    graph = _entry_graph(
-        _compile_block(monkeypatch, one_chip, module, *args).as_text())
-
-    def named(pattern, opcode=None):
-        return {n for n, (op, _, _, op_name) in graph.items()
-                if re.search(pattern, op_name) and (opcode is None or op == opcode)}
-
-    prologues = named(r"qk_prologue", "custom-call")
-    flashes = named(r"flash_attention", "custom-call")
-    projections = named(projection + r".*dot_general")
-    assert len(prologues) == n_calls and flashes and projections
-    # Every prologue reads a projection's output as it was written ...
-    before = _reach(graph, projections, True) & _reach(graph, prologues, False)
-    # ... and the flash kernel reads the prologue's.
-    after = _reach(graph, prologues, True) & _reach(graph, flashes, False)
-    for call in prologues:
-        assert set(graph[call][2]) & (projections | before), call
-    assert after or all(set(graph[f][2]) & prologues for f in flashes)
-    for name in before | after:
-        opcode, shape, _, op_name = graph[name]
-        assert opcode not in ("copy", "transpose"), (name, shape, op_name)
-        for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", shape):
-            size = math.prod(int(d) for d in dims.split(","))
-            assert not (dtype == "f32" and size >= elements), (name, shape)
-
-
-# The autoencoder's decode program at 1 x 1024² (every 1024² cell's, once a
-# request), by XLA's own analyses of the compiled program. Before PR 38 its
-# three upsamplers were ``jax.image.resize`` (two gather fusions a stage, a
-# copy, a pad, a copy and a slice) and a 3x3 convolution at the HIGH
-# resolution: 10.16 TFLOP, 1,361,243,136 B of temporaries.
-DECODER_PARENT_TEMP_BYTES = 1_361_243_136
-
-
-def test_decoder_upsamplers_run_at_the_low_resolution(monkeypatch, one_chip):
-    """``ops/basic.upsample2x_conv3x3`` in the decoder's program: no gather is
-    left, the three pairs cost 16 tap-products a source pixel where they cost
-    36 (-1.55 TFLOP), and the program's temporaries did not grow —
-    ``flux-schnell`` runs at 15.1-15.3 GB of the chip's 16."""
-    from comfyui_parallelanything_tpu.models import vae
-
-    compiled = _compile_block(
-        monkeypatch, one_chip, vae.Decoder(vae.sd3_vae_config()),
-        jax.ShapeDtypeStruct((1, 128, 128, 16), jnp.float32))
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text  # the mid-block attention's flash kernel
-    assert "gather" not in text
-    assert compiled.cost_analysis()["flops"] <= 8.8e12  # parent 10.16e12
-    mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes <= DECODER_PARENT_TEMP_BYTES

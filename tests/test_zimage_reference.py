@@ -14,7 +14,6 @@ hold the program to it inside tier-1."""
 
 import json
 import os
-import sys
 import threading
 import time
 
@@ -23,47 +22,24 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-_BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                      "benchmark")
-if _BENCH not in sys.path:
-    sys.path.insert(0, _BENCH)
-
-import run  # noqa: E402 — the benchmark's own file loading and preset swap
-from yardstick import (client, reference_sd, reference_zimage, safetensors_io,  # noqa: E402
-                       synth, traffic)
+from twins import (  # noqa: F401 — a fixture; benchmark/ on the path
+    _BENCH, _counted, _float32_image, _rel, _twin, _twin_file, twin_files)
+from yardstick import client, reference_sd, reference_zimage, safetensors_io, traffic
 
 CELL = "zimage-turbo-tiny.closed-unique"
 DENOISER = "models/diffusion_models/z_image_turbo_bf16.safetensors"
 TOWER = "models/text_encoders/qwen_3_4b.safetensors"
 
 
-def _twin(tmp_path, monkeypatch, cell_name, dtype, seed=11):
-    """A tiny twin's files and tokenizer tables from a seed under ``tmp_path``
-    and the program's presets swapped for the twin's sizes → (cell, what a
-    reference is built from, its keywords)."""
-    cell = run.load_cell(cell_name)
-    config = cell["config_data"]
-    run.apply_program_presets(config, monkeypatch.setattr, dtype)
-    ref_args, ref_kw, env, _ = run.synthesize(config, str(tmp_path), seed)
-    for k, v in {**env, "PA_TOKENIZER_JSON": ""}.items():
-        monkeypatch.setenv(k, v)
-    return cell, ref_args, ref_kw
-
-
 @pytest.fixture
-def tiny(tmp_path, monkeypatch):
-    return _twin(tmp_path, monkeypatch, CELL, jnp.float32)
+def tiny(twin_files, monkeypatch):
+    return _twin(twin_files, monkeypatch, CELL, jnp.float32)
 
 
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want - want.mean()))
-
-
-def _counted(name, **labels):
-    from comfyui_parallelanything_tpu.utils.metrics import registry
-
-    return registry.get(name, labels) or 0.0
+@pytest.fixture(scope="module")
+def float32_image(twin_files):
+    """(request 0, its float32 reference image), once for this file."""
+    return _float32_image(twin_files, CELL, reference_zimage)
 
 
 def test_tiny_zimage_forward_equals_the_reference_in_float32(tiny):
@@ -91,21 +67,22 @@ def test_tiny_zimage_forward_equals_the_reference_in_float32(tiny):
     states = jax.random.normal(keys[1], (2, 32, m["cap_feat_dim"]), jnp.float32)
     sigma = jnp.asarray([0.75, 0.25], jnp.float32)
     valid = 21
-    got = model.apply(model.params, x, sigma, states,
+    got = jax.jit(model.apply)(model.params, x, sigma, states,
                       y=jnp.full((2, 1), float(valid), jnp.float32))
     w = reference_sd.load_weights(safetensors_io.read(path))
-    want = -reference_zimage.zimage("float32", w, m, jnp.transpose(x, (0, 3, 1, 2)),
-                                    1.0 - sigma, states[:, :valid])
+    # (one program each side, not a walk that compiles every operation alone)
+    want = -jax.jit(lambda x, t, c: reference_zimage.zimage("float32", w, m, x, t, c))(
+        jnp.transpose(x, (0, 3, 1, 2)), 1.0 - sigma, states[:, :valid])
     want = jnp.transpose(want, (0, 2, 3, 1))
     assert got.shape == want.shape == x.shape
     assert _rel(got, want) < 1e-4, _rel(got, want)
     # the rows past the valid count are the pad token's, whatever they held
     junk = states.at[:, valid:].set(7.0)
-    again = model.apply(model.params, x, sigma, junk,
+    again = jax.jit(model.apply)(model.params, x, sigma, junk,
                         y=jnp.full((2, 1), float(valid), jnp.float32))
     np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
     # and another count is another caption
-    other = model.apply(model.params, x, sigma, states,
+    other = jax.jit(model.apply)(model.params, x, sigma, states,
                         y=jnp.full((2, 1), 20.0, jnp.float32))
     assert _rel(other, want) > 1e-3
 
@@ -172,10 +149,11 @@ def test_the_tokenizer_and_chat_template_id_for_id_with_the_harness(tiny):
     assert ids[0][0] == 151644 and ids[0][mask[0].sum() - 5] == 151645
 
 
-def _serve(cell, tmp_path, graphs):
+def _serve(cell, graphs):
     from comfyui_parallelanything_tpu.server import make_server
 
-    srv, q = make_server(port=0, output_dir=str(tmp_path / "output"), trace=True)
+    # where the twin's variables send SaveImage's files
+    srv, q = make_server(port=0, output_dir=os.environ["PA_OUTPUT_DIR"], trace=True)
     thread = threading.Thread(target=srv.serve_forever, daemon=True)
     thread.start()
     base = f"http://127.0.0.1:{srv.server_address[1]}"
@@ -192,7 +170,7 @@ def _serve(cell, tmp_path, graphs):
     return res, spans
 
 
-def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, tmp_path):
+def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, float32_image):
     """ComfyUI's Z-Image-Turbo graph posted to ``server.py``: UNETLoader on a
     depth-cut file in the published key spelling, CLIPLoader type lumina2
     (the Qwen3 tower, picked from the file's keys), VAELoader,
@@ -211,14 +189,14 @@ def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, tmp_
     sched = traffic.Schedule(cell["mix"], 5, 10)
     graphs = [traffic.fill_graph(cell["template"], cell["mix"], sched.request(i))
               for i in (0, 1)]
-    (res, again), spans = _serve(cell, tmp_path, graphs)
+    (res, again), spans = _serve(cell, graphs)
     assert res.ok, res.error
     assert again.ok and again.images != res.images
     served = np.stack([client.decode_png(p) for p in res.images]).astype(np.float32) / 255.0
     req = reference_zimage.describe(graphs[0])
     assert (req["steps"], req["cfg"], req["scheduler"], req["shift"]) == (8, 1.0, "simple", 3.0)
-    want = reference_zimage.Reference(cell["config_data"], *ref_args, "float32",
-                                      **ref_kw).images(req, [0])
+    assert req == float32_image[0]
+    want = float32_image[1]
     assert served.shape == want.shape == (1, 192, 192, 3)
     assert _rel(served, want) < 1e-2, _rel(served, want)
 
@@ -291,12 +269,16 @@ def test_a_repeated_text_is_a_hit_and_the_wire_carries_the_valid_count(tiny):
             for e in events[-2:]] == [("qwen3", 11, "miss"), ("qwen3", 11, "hit")]
 
 
-def test_cliploader_lumina2_refuses_a_file_without_a_qwen3_tower(tmp_path, monkeypatch):
+def test_cliploader_lumina2_refuses_a_file_without_a_qwen3_tower(twin_files, tmp_path,
+                                                                  monkeypatch):
     """The type no longer maps to T5: a T5 file under it is refused by name."""
     from comfyui_parallelanything_tpu.nodes_compat import CLIPLoader
 
-    cell, _, ref_kw = _twin(tmp_path, monkeypatch, "flux-schnell-tiny.closed-unique",
-                            jnp.float32)
+    # the FLUX twin's T5 file alone, where the loader looks a name up
+    path = _twin_file(twin_files, tmp_path, monkeypatch,
+                      "flux-schnell-tiny.closed-unique", "text_t5", jnp.float32, home=CELL)
+    assert path == str(tmp_path / "models/text_encoders/t5xxl_fp16.safetensors")
+    monkeypatch.setenv("PA_MODELS_DIR", str(tmp_path / "models"))
     with pytest.raises(ValueError, match="no Qwen3 tower"):
         CLIPLoader().load("t5xxl_fp16.safetensors", "lumina2")
     assert CLIPLoader._TYPE_TOWER["lumina2"] is None
@@ -316,7 +298,8 @@ RESIDENT = [
 
 @pytest.mark.parametrize("cell_name,loader,sizes,dtype,kernels", RESIDENT,
                          ids=[f"{r[1]}-{jnp.dtype(r[3]).name}" for r in RESIDENT])
-def test_what_a_loader_keeps_resident(tmp_path, monkeypatch, cell_name, loader,
+def test_what_a_loader_keeps_resident(twin_files, tmp_path, monkeypatch, cell_name,
+                                      loader,
                                       sizes, dtype, kernels):
     """The load policy by the path a family takes: Z-Image's and Qwen3's
     matmul kernels and the embedding stay in bfloat16 as their files store
@@ -328,11 +311,8 @@ def test_what_a_loader_keeps_resident(tmp_path, monkeypatch, cell_name, loader,
     from comfyui_parallelanything_tpu import models
     from comfyui_parallelanything_tpu.utils.metrics import registry
 
-    cell, ref_args, ref_kw = _twin(tmp_path, monkeypatch, cell_name, dtype)
-    config = cell["config_data"]
-    spec = next(s for s in synth.checkpoint_files(config)
-                if any(p["sizes"] == sizes for p in s["parts"]))
-    path = ref_kw.get("files", {}).get(spec["file"], ref_args[0])
+    path = _twin_file(twin_files, tmp_path, monkeypatch, cell_name, sizes, dtype,
+                      home=CELL)
     load = {
         "zimage": lambda: models.load_zimage_checkpoint(path, models.zimage_turbo_config()),
         "qwen3": lambda: models.load_qwen3_checkpoint(path),
@@ -408,14 +388,13 @@ def test_grouped_causal_attention_is_plain_attention_with_heads_repeated():
         grouped_causal_attention(q, k[:, :, :1].repeat(4, 2), v[:, :, :1].repeat(4, 2))
 
 
-def test_lower_precisions_open_the_gap_the_limits_stand_in(tiny):
+def test_lower_precisions_open_the_gap_the_limits_stand_in(tiny, float32_image):
     cell, ref_args, ref_kw = tiny
-    sched = traffic.Schedule(cell["mix"], 5, 10)
-    req = reference_zimage.describe(
-        traffic.fill_graph(cell["template"], cell["mix"], sched.request(0)))
+    req, float32 = float32_image
     img = {p: reference_zimage.Reference(cell["config_data"], *ref_args, p,
                                          **ref_kw).images(req, [0])
-           for p in ("float32", "bfloat16", "int8")}
+           for p in ("bfloat16", "int8")}
+    img["float32"] = float32
     g = {p: _rel(img[p], img["float32"]) for p in ("bfloat16", "int8")}
     assert 2e-3 < g["bfloat16"] < g["int8"], g
 
