@@ -162,9 +162,11 @@ def convert_t5_checkpoint(state_dict: Mapping[str, Any], cfg: T5Config) -> dict:
 
 def convert_qwen3_checkpoint(state_dict: Mapping[str, Any], cfg: Qwen3Config) -> dict:
     """HF ``Qwen3ForCausalLM`` / ``Qwen3Model`` layout (``model.layers.N.*``,
-    any wrapper prefix) → Qwen3Model params, for the ``cfg.output_layers``
-    layers the tower runs: the last layer, ``model.norm`` and a ``lm_head``
-    are left in the file. Matmul kernels and the embedding stay in their
+    any wrapper prefix; Qwen2.5-VL's language model spells it the same, with
+    biases on q / k / v and no q/k norms: ``cfg.qkv_bias`` / ``cfg.qk_norm``)
+    → Qwen3Model params, for the ``cfg.output_layers`` layers the tower runs:
+    without ``cfg.final_norm`` the last layer and ``model.norm`` are left in
+    the file, a ``lm_head`` and a ``visual`` tower always. Matmul kernels and the embedding stay in their
     resident type (``convert.resident``: bfloat16 from a bfloat16 file or
     under bfloat16 compute — 7.8 GB, never whole in float32); the RMS scales
     are float32."""
@@ -187,11 +189,14 @@ def convert_qwen3_checkpoint(state_dict: Mapping[str, Any], cfg: Qwen3Config) ->
             "k_proj": dense(f"{t}.self_attn.k_proj"),
             "v_proj": dense(f"{t}.self_attn.v_proj"),
             "o_proj": dense(f"{t}.self_attn.o_proj"),
-            "q_norm": scale(f"{t}.self_attn.q_norm"),
-            "k_norm": scale(f"{t}.self_attn.k_norm"),
             "post_attention_layernorm": scale(f"{t}.post_attention_layernorm"),
             "gate_proj": dense(f"{t}.mlp.gate_proj"),
             "up_proj": dense(f"{t}.mlp.up_proj"),
             "down_proj": dense(f"{t}.mlp.down_proj"),
         }
+        if cfg.qk_norm:
+            p[f"layers_{i}"]["q_norm"] = scale(f"{t}.self_attn.q_norm")
+            p[f"layers_{i}"]["k_norm"] = scale(f"{t}.self_attn.k_norm")
+    if cfg.final_norm:
+        p["norm"] = scale("model.norm")
     return tree_to_jnp(p)

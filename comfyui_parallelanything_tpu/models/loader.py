@@ -272,8 +272,12 @@ residency = Residency()
 # that has to ask before it spends minutes on a configuration they could not
 # serve (benchmark/yardstick/reference_wan.py): a WAN file loads at the depth
 # it has and in the types it stores (``load_wan_checkpoint``), and a model
-# that does not fit beside the others leaves the chip (``Residency``).
-CAPABILITIES = frozenset({"wan-depth-from-file", "residency"})
+# that does not fit beside the others leaves the chip (``Residency``);
+# ``qwen-image``: the double-stream family at the depth its file has and its
+# Qwen2.5-VL tower load (``load_qwen_image_checkpoint``,
+# ``load_qwen25vl_checkpoint``) and a one-frame latent decodes through the
+# video autoencoder.
+CAPABILITIES = frozenset({"wan-depth-from-file", "residency", "qwen-image"})
 
 
 def record_resident(model: str, holder, reload=None) -> None:
@@ -554,6 +558,36 @@ def load_zimage_checkpoint(src: Any, cfg=None, name: str = "zimage-turbo") -> Di
     return model
 
 
+def load_qwen_image_checkpoint(src: Any, cfg=None, lora: Any = None,
+                               lora_strength: float = 1.0,
+                               name: str = "qwen-image") -> DiffusionModel:
+    """Qwen-Image checkpoint (path or state dict, the published
+    ``transformer/`` key spelling) → DiffusionModel. Read in its stored types,
+    kernel by kernel (``convert.resident``), a LoRA's delta added to a kernel
+    as it is taken (``convert.bake_lora``), like WAN: never whole in float32.
+    The block count is a fact of the file: a depth cut of the published model
+    loads at the depth it has, whatever ``cfg`` says."""
+    import dataclasses
+
+    from .convert_qwen_image import convert_qwen_image_checkpoint, qwen_image_depth
+    from .qwen_image import build_qwen_image, qwen_image_config
+
+    sd = _resolve_state_dict(src, stored=True)
+    if cfg is None:
+        cfg = qwen_image_config()
+    depth = qwen_image_depth(sd)
+    if depth and depth != cfg.depth:
+        get_logger().info("aligning Qwen-Image config to checkpoint: %d blocks", depth)
+        cfg = dataclasses.replace(cfg, depth=depth)
+
+    def build():
+        return convert_qwen_image_checkpoint(_maybe_bake(sd, lora, lora_strength), cfg)
+
+    model = build_qwen_image(cfg, name=name, params=build())
+    record_resident(name, model, build)
+    return model
+
+
 def load_sd_unet_checkpoint(
     src: Any,
     cfg: UNetConfig,
@@ -623,8 +657,9 @@ def sniff_model_family(state_dict: Mapping[str, Any]) -> str:
     the compat shim (nodes_compat.py) sniffs it off the file the way the host
     loader the reference defers to does. Keys may be bare or under the full
     checkpoint's ``model.diffusion_model.`` prefix."""
-    pfx = "model.diffusion_model."
-    names = {k[len(pfx):] if k.startswith(pfx) else k: k for k in state_dict}
+    pfx = ("model.diffusion_model.", "transformer.")
+    names = {next((k[len(p):] for p in pfx if k.startswith(p)), k): k
+             for k in state_dict}
 
     def has(prefix: str) -> bool:
         return any(n.startswith(prefix) for n in names)
@@ -636,6 +671,11 @@ def sniff_model_family(state_dict: Mapping[str, Any]) -> str:
         shape = getattr(state_dict[key], "shape", None)
         return None if shape is None else int(shape[axis])
 
+    if has("transformer_blocks.") and has("txt_norm.") and any(
+            ".attn.add_q_proj." in n for n in names):
+        # Qwen-Image's double-stream transformer in its published key
+        # spelling (a ``transformer.`` wrapper tolerated), at whatever depth.
+        return "qwen-image"
     if has("noise_refiner.") and has("context_refiner.") and has("cap_embedder."):
         # Z-Image's single-stream transformer in its published key spelling:
         # two refiner stacks and a caption embedder beside ``layers``.
@@ -705,7 +745,8 @@ def sniff_model_family(state_dict: Mapping[str, Any]) -> str:
         return "sd15"
     raise ValueError(
         "cannot sniff model family: no known diffusion-model key signature "
-        "(noise_refiner/double_blocks/joint_blocks/self_attn/input_blocks) in "
+        "(transformer_blocks/noise_refiner/double_blocks/joint_blocks/self_attn/"
+        "input_blocks) in "
         "checkpoint"
     )
 
@@ -809,6 +850,32 @@ def load_qwen3_checkpoint(src: Any, cfg=None):
 
     enc = build_qwen3(cfg, params=build())
     record_resident("qwen3", enc, build)
+    return enc
+
+
+def load_qwen25vl_checkpoint(src: Any, cfg=None):
+    """Qwen2.5-VL checkpoint (HF layout) → TextEncoder: the language model of
+    Qwen-Image's tower, read like Qwen3's. The ``visual.*`` tower and a
+    ``lm_head`` stay in the file — text-to-image never runs them — and count
+    for nothing: not in the room asked for, not in
+    ``pa_params_resident_bytes``."""
+    from .convert_text import convert_qwen3_checkpoint
+    from .text_encoders import build_qwen3, qwen25_vl_7b_config
+
+    if isinstance(src, (str, os.PathLike)):
+        src = open_safetensors(src)
+    sd = _resolve_state_dict(
+        {k: v for k, v in src.items()
+         if not k.startswith(("visual.", "model.visual.", "lm_head."))},
+        stored=True)
+    if cfg is None:
+        cfg = qwen25_vl_7b_config()
+
+    def build():
+        return convert_qwen3_checkpoint(sd, cfg)
+
+    enc = build_qwen3(cfg, params=build())
+    record_resident("qwen25vl", enc, build)
     return enc
 
 

@@ -302,18 +302,38 @@ class Qwen3Config:
     rope_theta: float = 1e6
     rms_norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
+    # What tells the causal towers of this one class apart: Qwen3 norms q and
+    # k per head and has no biases; Qwen2 / Qwen2.5(-VL)'s language model has
+    # biases on q, k and v and no such norms. ``final_norm``: which state is
+    # handed on (``output_layers``).
+    qkv_bias: bool = False
+    qk_norm: bool = True
+    final_norm: bool = False
 
     @property
     def output_layers(self) -> int:
-        """How many of the layers run: the tower hands on
-        ``hidden_states[-2]``, the state BEFORE the last layer, so the last
-        layer and the final norm are neither run nor kept resident."""
-        return self.num_layers - 1
+        """How many of the layers run. Without ``final_norm`` the tower hands
+        on ``hidden_states[-2]``, the state BEFORE the last layer, so the last
+        layer and the final norm are neither run nor kept resident; with it,
+        the last layer's state after ``model.norm`` (``hidden_states[-1]``)."""
+        return self.num_layers if self.final_norm else self.num_layers - 1
 
 
 def qwen3_4b_config(**overrides) -> Qwen3Config:
     """Qwen/Qwen3-4B ``config.json`` — Z-Image's text tower."""
     return dataclasses.replace(Qwen3Config(), **overrides)
+
+
+def qwen25_vl_7b_config(**overrides) -> Qwen3Config:
+    """The language model of Qwen/Qwen2.5-VL-7B-Instruct (``config.json``) —
+    Qwen-Image's text tower: 7.07 B parameters, the last layer's normed
+    states. Text alone: the three ``mrope`` components are the token's
+    position, which is the plain rotary."""
+    base = Qwen3Config(
+        vocab_size=152064, hidden_size=3584, intermediate_size=18944,
+        num_layers=28, num_heads=28, num_kv_heads=4, head_dim=128,
+        qkv_bias=True, qk_norm=False, final_norm=True)
+    return dataclasses.replace(base, **overrides)
 
 
 class _RMSNorm(nn.Module):
@@ -339,15 +359,16 @@ class _Qwen3Layer(nn.Module):
         B, S, _ = x.shape
         H, Hk, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
-        def dense(width, name):
-            return nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
+        def dense(width, name, bias=False):
+            return nn.Dense(width, use_bias=bias, dtype=cfg.dtype, name=name)
 
         h = _RMSNorm(cfg.rms_norm_eps, name="input_layernorm")(x)
-        q = dense(H * D, "q_proj")(h).reshape(B, S, H, D)
-        k = dense(Hk * D, "k_proj")(h).reshape(B, S, Hk, D)
-        v = dense(Hk * D, "v_proj")(h).reshape(B, S, Hk, D)
-        q = _RMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
-        k = _RMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
+        q = dense(H * D, "q_proj", cfg.qkv_bias)(h).reshape(B, S, H, D)
+        k = dense(Hk * D, "k_proj", cfg.qkv_bias)(h).reshape(B, S, Hk, D)
+        v = dense(Hk * D, "v_proj", cfg.qkv_bias)(h).reshape(B, S, Hk, D)
+        if cfg.qk_norm:
+            q = _RMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
+            k = _RMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
         cos, sin = rope
         a = grouped_causal_attention(
             apply_rope_halves(q, cos, sin), apply_rope_halves(k, cos, sin), v)
@@ -359,11 +380,13 @@ class _Qwen3Layer(nn.Module):
 
 
 class Qwen3Model(nn.Module):
-    """The causal Qwen3 stack as a conditioning tower: token ids (B, S) → the
-    residual stream after ``cfg.output_layers`` layers, un-normed (HF's
-    ``hidden_states[-2]`` at 35 of 36). Causal, so a token's state does not
-    depend on what follows it: padding a prompt to a bucket leaves the valid
-    states what they were, and the caller drops the rest."""
+    """The causal Qwen stack as a conditioning tower: token ids (B, S) → the
+    residual stream after ``cfg.output_layers`` layers — un-normed (HF's
+    ``hidden_states[-2]`` at 35 of 36: Qwen3-4B for Z-Image) or, with
+    ``cfg.final_norm``, the last layer's through ``model.norm`` (Qwen2.5-VL
+    for Qwen-Image). Causal, so a token's state does not depend on what
+    follows it: padding a prompt to a bucket leaves the valid states what
+    they were, and the caller drops the rest."""
 
     cfg: Qwen3Config
 
@@ -380,6 +403,8 @@ class Qwen3Model(nn.Module):
             cfg.head_dim, cfg.rope_theta)
         for i in range(cfg.output_layers):
             x = _Qwen3Layer(cfg, name=f"layers_{i}")(x, rope)
+        if cfg.final_norm:
+            x = _RMSNorm(cfg.rms_norm_eps, name="norm")(x)
         return x
 
 

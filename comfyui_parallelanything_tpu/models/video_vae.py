@@ -481,12 +481,18 @@ class VideoVAE:
 
     def decode(self, z):
         """Normalized latent clip (B, T, H/8, W/8, z) → pixel clip
-        (B, 4(T−1)+1, H, W, 3), memory bounded in time (``_decode_program``).
+        (B, 4(T−1)+1, H, W, 3), memory bounded in time (``_decode_program``);
+        an image latent (B, H/8, W/8, z) → the image (B, H, W, 3).
         The program's temporaries (6 GB at 13 x 60 x 104) are asked of the
         loader's residency rule like a load's bytes, before it runs."""
         from .loader import residency
 
         z = jnp.asarray(z)
+        if z.ndim == 4:
+            # An IMAGE latent (B, H/8, W/8, z): a clip of one frame, which the
+            # causal decoder takes by its first-frame path (Qwen-Image's
+            # autoencoder is this architecture on one frame) → (B, H, W, 3).
+            return self.decode(z[:, None])[:, 0]
         params = self._resident_params()
         program = self._decode_program(params, z)
         residency.ensure(params, beside=program.memory_analysis().temp_size_in_bytes)

@@ -317,7 +317,8 @@ _MODEL_FAMILIES = (
     "sd15", "sd15-inpaint", "sd21", "sd21-v", "sd21-inpaint", "sd21-unclip",
     "sdxl", "sdxl-inpaint", "sdxl-refiner",
     "sd3-medium", "sd35-medium", "sd35-large",
-    "flux-dev", "flux-schnell", "zimage-turbo", "wan-1.3b", "wan-14b",
+    "flux-dev", "flux-schnell", "zimage-turbo", "qwen-image", "wan-1.3b",
+    "wan-14b",
 )
 
 
@@ -413,16 +414,50 @@ class TPUCheckpointLoader:
                 return quantize_model(m)
             return m
 
-        stored = (family in ("flux-dev", "flux-schnell", "zimage-turbo")
+        stored = (family in ("flux-dev", "flux-schnell", "zimage-turbo", "qwen-image")
                   or family.startswith("wan"))
-        # The FLUX families, Z-Image and WAN are read in the file's stored
-        # types and never pass through float32 whole (models/loader).
+        # The FLUX families, Z-Image, Qwen-Image and WAN are read in the
+        # file's stored types and never pass through float32 whole
+        # (models/loader).
         sd = (open_safetensors if stored else load_safetensors)(ckpt_path)
+
+        def named_for_the_file() -> str:
+            # Two loads of one family (WAN's experts, a LoRA-baked copy beside
+            # its base) are two programs' worth of counters and spans
+            # (``denoise`` / pa_denoiser_calls_total{program=}).
+            import os as _os
+
+            return (_os.path.splitext(_os.path.basename(ckpt_path))[0]
+                    + ("+lora" if lora else ""))
+
+        if family == "qwen-image":
+            # Qwen-Image: the double-stream denoiser alone — its releases keep
+            # the autoencoder (the WAN2.1 architecture on one frame) in a file
+            # of its own, as WAN's do. The preset is looked up at call time:
+            # tests shrink models by patching the package-level one.
+            from . import models as _models
+
+            with load_ctx:
+                model = _models.load_qwen_image_checkpoint(
+                    sd, _models.qwen_image_config(), lora, lora_strength,
+                    name=named_for_the_file())
+                model = maybe_quant(model)
+            # The host's Qwen-Image sampling settings: the flow table at
+            # shift 3.1, what the template's ModelSamplingAuraFlow says.
+            model.sampler_prefs = {"shift": 3.1}
+            if not load_vae:
+                return model, None
+            if not vae_path:
+                raise ValueError(
+                    "Qwen-Image keeps its autoencoder in a file of its own "
+                    "(qwen_image_vae): set vae_path to it — or, in a stock "
+                    "graph, load the denoiser with UNETLoader and the "
+                    "autoencoder with VAELoader"
+                )
+            return model, _models.load_wan_vae_checkpoint(vae_path)
         if family.startswith("wan"):
             # WAN family: video DiT + causal 3D VAE (its own checkpoint file —
             # WAN releases don't bundle the VAE with the DiT weights).
-            import os as _os
-
             from .models import (
                 load_wan_checkpoint,
                 load_wan_vae_checkpoint,
@@ -450,14 +485,9 @@ class TPUCheckpointLoader:
                 ),
             )
             with load_ctx:
-                # Named for the FILE: two experts of one family are two
-                # programs' worth of counters and spans (``denoise`` /
-                # pa_denoiser_calls_total{program=}) behind one compiled step.
+                # Named for the FILE: both experts behind one compiled step.
                 model = load_wan_checkpoint(
-                    sd, wcfg, lora, lora_strength,
-                    name=_os.path.splitext(_os.path.basename(ckpt_path))[0]
-                    + ("+lora" if lora else ""),
-                )
+                    sd, wcfg, lora, lora_strength, name=named_for_the_file())
                 model = maybe_quant(model)
             if not load_vae:
                 return model, None
@@ -590,7 +620,8 @@ class TPUCLIPLoader:
             "required": {
                 "encoder_path": ("STRING", {"default": ""}),
                 "encoder_type": (
-                    ["clip-l", "open-clip-g", "open-clip-h", "t5", "umt5", "qwen3"],
+                    ["clip-l", "open-clip-g", "open-clip-h", "t5", "umt5", "qwen3",
+                     "qwen25vl"],
                     {"default": "clip-l"},
                 ),
             },
@@ -614,21 +645,31 @@ class TPUCLIPLoader:
         from .models import load_clip_text_checkpoint, load_t5_checkpoint
         from .utils.tokenizer import CLIPBPETokenizer, load_tokenizer_json
 
-        if encoder_type == "qwen3":
-            # The decoder-only tower: a byte-level BPE table behind the chat
+        if encoder_type in ("qwen3", "qwen25vl"):
+            # The decoder-only towers: a byte-level BPE table behind a chat
             # template, no fixed window (``max_len`` is CLIP's 77 unless
-            # set; the tower's own budget is 512 tokens).
-            from .models import load_qwen3_checkpoint
-            from .utils.tokenizer import load_chat_tokenizer_json
+            # set; Qwen3's own budget is 512 tokens, Qwen-Image's pipeline gives
+            # Qwen2.5-VL 1024 + the 34 of its system prompt, whose states the
+            # encode node cuts off).
+            from .models import load_qwen3_checkpoint, load_qwen25vl_checkpoint
+            from .utils.tokenizer import (
+                QWEN_CHAT_TEMPLATE,
+                QWEN_IMAGE_CHAT_TEMPLATE,
+                load_chat_tokenizer_json,
+            )
 
             if not tokenizer_json:
                 raise ValueError(
-                    "encoder_type='qwen3' requires tokenizer_json (Qwen's "
-                    "byte-level BPE tokenizer.json)"
+                    f"encoder_type={encoder_type!r} requires tokenizer_json "
+                    "(Qwen's byte-level BPE tokenizer.json)"
                 )
-            enc = load_qwen3_checkpoint(encoder_path)
+            vl = encoder_type == "qwen25vl"
+            enc = (load_qwen25vl_checkpoint if vl else load_qwen3_checkpoint)(
+                encoder_path)
             tok = load_chat_tokenizer_json(
-                tokenizer_json, max_len=512 if max_len == 77 else max_len)
+                tokenizer_json,
+                max_len=(1058 if vl else 512) if max_len == 77 else max_len,
+                template=QWEN_IMAGE_CHAT_TEMPLATE if vl else QWEN_CHAT_TEMPLATE)
         elif encoder_type in ("t5", "umt5"):
             if not tokenizer_json:
                 raise ValueError(
@@ -840,13 +881,22 @@ class TPUTextEncode:
         with tracing.span("text-encode", cat="graph", tower=tower) as sp:
             ids, mask = tok([text])
             n_tokens = len(ids[0])
-            if tower == "qwen3":
+            dropped = None
+            if tower in ("qwen3", "qwen25vl"):
                 # Causal: the states of the valid tokens do not depend on
                 # the padding after them, so the tower runs at the bucket's
                 # length (one program a bucket) without a mask; the mask is
                 # part of the cache key only.
                 out = cached(mask, lambda: enc(jnp.asarray(ids, jnp.int32)))
                 n_tokens = int(mask[0].sum())  # the VALID count, not the bucket
+                if tower == "qwen25vl":
+                    # Qwen-Image's wire: the states from the first token of
+                    # the user's text to the last valid one — the system
+                    # prompt's are cut off (by position: the tokenizer's
+                    # ``prefix_length``) and no padding goes on.
+                    dropped = tok.prefix_length(ids[0])
+                    out = out[:, dropped:n_tokens]
+                    n_tokens -= dropped
             elif tower in ("t5", "umt5"):
                 # ``attention_mask`` False (the flux-dual wire): the source
                 # hands the tower no mask, so padded keys take part.
@@ -862,6 +912,8 @@ class TPUTextEncode:
                 out = cached(None, lambda: enc(jnp.asarray(ids, jnp.int32)))
             cache = "miss" if ran else "hit"
             sp.set(tokens=n_tokens, cache=cache)
+            if dropped is not None:
+                sp.set(dropped=dropped)
         registry.counter(
             "pa_text_encode_total", labels={"tower": tower, "cache": cache},
             help="text-tower encodes by tower and embed-cache outcome",
@@ -872,6 +924,8 @@ class TPUTextEncode:
             # the rest of the bucket with its learned pad token.
             return ({"context": out, "penultimate": None,
                      "pooled": jnp.asarray(mask.sum(-1, keepdims=True), jnp.float32)},)
+        if tower == "qwen25vl":
+            return ({"context": out, "pooled": None},)
         if tower == "umt5":
             # The WAN wire: the states of the valid tokens, zeros after them
             # (the published pipeline cuts each prompt's states at its length

@@ -29,7 +29,8 @@ File resolution env vars (the stand-ins for ComfyUI's folder_paths):
 - ``PA_T5_TOKENIZER_JSON``: tokenizer for the T5/UMT5 tower
   (``DualCLIPLoader``).
 - ``PA_QWEN_TOKENIZER_JSON``: Qwen's byte-level BPE ``tokenizer.json`` for the
-  Qwen3 tower (``CLIPLoader`` type ``lumina2``: Z-Image).
+  Qwen3 tower (``CLIPLoader`` type ``lumina2``: Z-Image) and the Qwen2.5-VL
+  tower (``CLIPLoader`` type ``qwen_image``).
 """
 
 from __future__ import annotations
@@ -444,6 +445,9 @@ class CLIPLoader:
         # The type ComfyUI's Z-Image template loads its tower with: which
         # tower the file holds is read off its keys (``load``).
         "lumina2": None,
+        # ComfyUI's Qwen-Image template: the Qwen2.5-VL tower, checked against
+        # the file's keys likewise.
+        "qwen_image": None,
         "hunyuan_video": "clip-l",
     }
 
@@ -474,22 +478,28 @@ class CLIPLoader:
         path = resolve_model_file(clip_name, "clip", "text_encoders")
         if tower is None:
             tower = _classify_text_tower("", path)
-            if tower != "qwen3":
+            want, what = {
+                "lumina2": ("qwen3", "Qwen3 tower (model.layers.N.self_attn.q_norm); "
+                                     "Z-Image's qwen_3_4b"),
+                "qwen_image": ("qwen25vl", "Qwen2.5-VL language model (model.layers.N."
+                                           "self_attn.q_proj.bias, no q_norm); "
+                                           "Qwen-Image's qwen_2.5_vl_7b"),
+            }[type]
+            if tower != want:
                 raise ValueError(
-                    f"CLIPLoader type={type!r}: {clip_name!r} holds no Qwen3 "
-                    "tower (model.layers.N.self_attn.q_norm); Z-Image's "
-                    "qwen_3_4b is the tower this type loads here"
+                    f"CLIPLoader type={type!r}: {clip_name!r} holds no {what} "
+                    "is the tower this type loads here"
                 )
         elif "umt5" in name:
             tower = "umt5"
         elif "t5" in name:
             tower = "t5" if tower not in ("umt5",) else tower
         kw = {}
-        if tower == "qwen3":
+        if tower in ("qwen3", "qwen25vl"):
             tok_json = os.environ.get("PA_QWEN_TOKENIZER_JSON", "")
             if not tok_json:
                 raise ValueError(
-                    f"CLIPLoader type={type!r} loads a Qwen3 tower and needs "
+                    f"CLIPLoader type={type!r} loads a Qwen tower and needs "
                     "PA_QWEN_TOKENIZER_JSON (Qwen's byte-level BPE "
                     "tokenizer.json)"
                 )
@@ -519,7 +529,7 @@ class CLIPLoader:
 
 def _classify_text_tower(name: str, path: str | None = None) -> str | None:
     """Which tower a text-encoder file holds: ``t5`` / ``open-clip-g`` /
-    ``clip-l`` / ``qwen3`` (by key signature only). Filename markers first (the stock SD3 template ships
+    ``clip-l`` / ``qwen3`` / ``qwen25vl`` (the last two by key signature only). Filename markers first (the stock SD3 template ships
     clip_l/clip_g/t5xxl); unresolved names fall back to the safetensors key
     signature (header-only — no tensor reads except one embedding shape)."""
     n = os.path.basename(name).lower()
@@ -538,6 +548,11 @@ def _classify_text_tower(name: str, path: str | None = None) -> str | None:
             keys = set(f.keys())
             if any(k.endswith("layers.0.self_attn.q_norm.weight") for k in keys):
                 return "qwen3"
+            if any(k.endswith("layers.0.mlp.gate_proj.weight") for k in keys) and any(
+                    k.endswith("layers.0.self_attn.q_proj.bias") for k in keys):
+                # a SwiGLU decoder with Qwen2's biases on q / k / v and no
+                # q/k norm (CLIP's HF layout has such biases and no gate)
+                return "qwen25vl"
             if any(k.startswith("encoder.block.") for k in keys) \
                     or "shared.weight" in keys:
                 return "t5"
@@ -644,7 +659,9 @@ class VAELoader:
     """Stock external-VAE loader: (vae_name) → VAE. Resolves through
     $PA_MODELS_DIR/vae; the file's key layout picks the family — WAN's causal
     3D video VAE (``encoder.downsamples``/``decoder.upsamples`` flat
-    Sequentials) vs the AutoencoderKL image families (sniffed by
+    Sequentials; Qwen-Image's autoencoder is that architecture and those keys,
+    and ``VAEDecode`` takes its 4-D image latent through the decoder's
+    one-frame path) vs the AutoencoderKL image families (sniffed by
     sniff_vae_config: latent width, SDXL scaling). Host-provided builtin
     (any_device_parallel.py:1473-1483)."""
 

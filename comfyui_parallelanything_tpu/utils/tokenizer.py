@@ -219,28 +219,61 @@ def load_tokenizer_json(
 QWEN_CHAT_TEMPLATE = "<|im_start|>user\n{}<|im_end|>\n<|im_start|>assistant\n"
 
 
+# Qwen-Image's: a SYSTEM prompt before the user's message
+# (``pipeline_qwenimage.py``'s ``prompt_template_encode``). The states of
+# everything through ``<|im_start|>user\n`` are cut off before the denoiser
+# sees them (``ChatBucketTokenizer.prefix_length``).
+QWEN_IMAGE_CHAT_TEMPLATE = (
+    "<|im_start|>system\nDescribe the image by detailing the color, shape, size, "
+    "texture, quantity, text, spatial relationships of the objects and "
+    "background:<|im_end|>\n<|im_start|>user\n{}<|im_end|>\n<|im_start|>assistant\n")
+
+
 class ChatBucketTokenizer:
     """A byte-level BPE ``tokenizer.json`` of Qwen's kind (HF fast format: the
     split pattern, the byte alphabet, merges by rank, ``<|im_start|>`` /
-    ``<|im_end|>`` as added special tokens) behind the chat template, for a
+    ``<|im_end|>`` as added special tokens) behind a chat ``template``, for a
     decoder-only tower: no fixed window. ``__call__`` returns (ids, mask)
     with each text templated, truncated to ``max_len`` tokens and the batch
     padded with ``pad_id`` to the next multiple of ``bucket`` tokens; the
-    mask marks the VALID tokens, which is all a causal tower's caller keeps."""
+    mask marks the VALID tokens, which is all a causal tower's caller keeps.
+    ``prefix_length`` says how many of a row's leading states a template with
+    a system prompt has the caller drop."""
 
     PAD_TOKEN = "<|endoftext|>"
+    TURN_TOKEN = "<|im_start|>"
 
-    def __init__(self, tok, bucket: int = 32, max_len: int = 512):
+    def __init__(self, tok, bucket: int = 32, max_len: int = 512,
+                 template: str = QWEN_CHAT_TEMPLATE):
         self._tok = tok
-        self.bucket, self.max_len = bucket, max_len
+        self.bucket, self.max_len, self.template = bucket, max_len, template
         self.pad_id = tok.token_to_id(self.PAD_TOKEN)
         if self.pad_id is None:
             raise ValueError(f"tokenizer.json has no {self.PAD_TOKEN!r} token to pad with")
+        self._turn_id = tok.token_to_id(self.TURN_TOKEN)
+        # How many turns open before the user's text: the states through the
+        # last of them and the two tokens after it (``user``, a newline) are
+        # the template's own, not the prompt's.
+        self._turns_before_text = template.split("{}")[0].count(self.TURN_TOKEN)
 
     def encode(self, text: str) -> list[int]:
         """Text → the templated prompt's token ids, unpadded."""
-        return self._tok.encode(QWEN_CHAT_TEMPLATE.format(text),
+        return self._tok.encode(self.template.format(text),
                                 add_special_tokens=False).ids[: self.max_len]
+
+    def prefix_length(self, row) -> int:
+        """How many leading states of a templated row stand before the user's
+        text, found BY POSITION as ComfyUI finds it — the turn that holds the
+        text opens at the template's last ``<|im_start|>`` before it, and the
+        role and the newline follow — so it holds under any table of merges
+        (the published pipeline's constant 34 is this count under the
+        published table). 0 for a template of one turn: nothing is cut."""
+        if self._turns_before_text < 2:
+            return 0
+        turns = [i for i, t in enumerate(row) if t == self._turn_id]
+        if len(turns) < self._turns_before_text:
+            return 0
+        return turns[self._turns_before_text - 1] + 3
 
     def __call__(self, texts: str | list[str]) -> tuple[np.ndarray, np.ndarray]:
         if isinstance(texts, str):
@@ -256,7 +289,9 @@ class ChatBucketTokenizer:
 
 
 def load_chat_tokenizer_json(path: str | os.PathLike, bucket: int = 32,
-                             max_len: int = 512) -> ChatBucketTokenizer:
+                             max_len: int = 512,
+                             template: str = QWEN_CHAT_TEMPLATE) -> ChatBucketTokenizer:
     from tokenizers import Tokenizer
 
-    return ChatBucketTokenizer(Tokenizer.from_file(os.fspath(path)), bucket, max_len)
+    return ChatBucketTokenizer(Tokenizer.from_file(os.fspath(path)), bucket,
+                               max_len, template)

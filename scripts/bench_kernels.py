@@ -103,6 +103,13 @@ SHAPES = [
     # logits a call).
     ("wan22-480p.self20280", 1, 20280, 40, 128),
     ("wan22-480p.cross512", 1, 20280, 40, 128, 512),
+    # Qwen-Image at 1 x 1328² and CFG 1.0 (PR 42): 83 x 83 image tokens + the
+    # fixed text's 10 (ISSUE 42 reckoned 12: the seeded table gives 10), 24
+    # heads of 128. Ragged, and its 6,912 padded keys are past
+    # RAGGED_ONE_BLOCK, so the `ragged` row streams two 4096-key blocks, the
+    # second a third real keys — between the 4,352 keys the one-block rule was
+    # measured to and the video cell's 20,280.
+    ("qwen-image-b1-1328.joint6899", 1, 6899, 24, 128),
 ]
 
 # Shapes whose sweep is not the grid below: 4352 = 17 x 256, so only 128- and
@@ -146,6 +153,11 @@ COMBOS = {
     "wan22-480p.self20280": [(256, 4096), (384, 4096), (512, 4096), (256, 2048),
                              (256, 8192), (256, 20352)],
     "wan22-480p.cross512": [(256, 512), (512, 512), (1024, 512)],
+    # as routed (384 x 4096: 6912 = 18 x 384), 256 queries beside it, and the
+    # whole padded row as ONE key block (1.8 MB of K a head), for the issue
+    # that will set RAGGED_ONE_BLOCK by bytes
+    "qwen-image-b1-1328.joint6899": [(384, 4096), (256, 4096), (384, 6912),
+                                     (256, 6912), (384, 3456)],
 }
 
 BLOCKS_Q = (128, 256, 512)

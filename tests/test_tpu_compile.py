@@ -221,3 +221,63 @@ def test_batch_sharded_kernels_compile_for_four_chips(topo, which):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and "all-gather" not in hlo
     assert compiled.output_shardings.spec == P("data")
+
+
+# -- Qwen-Image at its cell's shapes (qwen-image-b1-1328.closed) -------------------
+# One block and one tower layer, each compiled for the described chip with the
+# shipped routes and nothing run: what the cell's first chip call would
+# otherwise find out at a minute a program. (The one-frame decode at 166 x 166
+# is not here: its compile for the described chip takes this sandbox over 16
+# minutes — the 1024² image decoder's takes 350 s in
+# ``test_compile_tpu_decoder.py`` — so its temporaries are read on the chip,
+# where the program compiles in the warm-up: PERF.md section 5.)
+
+
+def test_a_qwen_image_block_compiles_at_6889_and_10_tokens(monkeypatch, one_chip):
+    """``models/flux.DoubleBlock`` at Qwen-Image's widths on 83 x 83 image
+    tokens and the fixed text's 10: the flash kernel on the ``ragged`` row
+    (6,899 keys, streamed 4096 a block) and the image stream's q/k prologue
+    are in the program; the text stream's 10 rows stay with XLA. The row is
+    padded where the route pads it — inside the kernels' own calls — and
+    nowhere else: no ``pad`` of 6,899 rows or more stands in the program
+    outside them."""
+    import math
+    import re
+
+    from comfyui_parallelanything_tpu.models import flux, qwen_image
+    from tests.test_compile_tpu_blocks import _compile_block, _entry_graph
+
+    S, bf16, f32 = jax.ShapeDtypeStruct, jnp.bfloat16, jnp.float32
+    cfg = qwen_image.qwen_image_config()
+    compiled = _compile_block(
+        monkeypatch, one_chip, flux.DoubleBlock(cfg),
+        S((1, 6889, 3072), bf16), S((1, 10, 3072), bf16), S((1, 3072), bf16),
+        (S((1, 6899, 64), f32), S((1, 6899, 64), f32)))
+    text = compiled.as_text()
+    graph = _entry_graph(text)
+    calls = [op_name for op, _, _, op_name in graph.values() if op == "custom-call"]
+    assert sum("flash_attention" in n for n in calls) >= 1
+    assert sum("qk_prologue" in n for n in calls) == 1  # the image stream's
+    for line in text.splitlines():
+        if re.search(r"= \S+ pad\(", line) and not re.search(
+                r"flash_attention|qk_prologue", line):
+            for dims in re.findall(r"\w+\[([\d,]+)\]", line.split(" pad(")[0]):
+                assert math.prod(int(d) for d in dims.split(",")) < 6899 * 3072, line
+
+
+def test_a_qwen25vl_tower_layer_compiles_at_the_cells_bucket(monkeypatch, one_chip):
+    """One layer of the causal tower in its Qwen2.5-VL configuration (biases
+    on q / k / v, no q/k norms, 28 query on 4 key/value heads) at the cell's
+    64-token bucket: 233,057,792 parameters a layer, as the issue reckons."""
+    from comfyui_parallelanything_tpu.models import text_encoders
+    from tests.test_compile_tpu_blocks import _compile_block
+
+    S = jax.ShapeDtypeStruct
+    cfg = text_encoders.qwen25_vl_7b_config()
+    layer = text_encoders._Qwen3Layer(cfg)
+    args = (S((1, 64, 3584), jnp.bfloat16),
+            (S((1, 64, 64), jnp.float32), S((1, 64, 64), jnp.float32)))
+    shapes = jax.eval_shape(lambda *a: layer.init(jax.random.key(0), *a), *args)
+    assert sum(l.size for l in jax.tree.leaves(shapes)) == 233_057_792
+    assert "q_norm" not in shapes["params"] and "bias" in shapes["params"]["k_proj"]
+    assert _compile_block(monkeypatch, one_chip, layer, *args).as_text()

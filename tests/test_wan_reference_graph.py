@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from twins import _counted, _rel, twin_files  # noqa: F401 — a fixture; benchmark/ on the path
+from twins import _counted, _moved, _rel, _residency_events, twin_files  # noqa: F401 — a fixture; benchmark/ on the path
 from wan_twin import _file, fresh_residency, tiny  # noqa: F401 — fixtures
 from yardstick import reference_wan, traffic
 
@@ -121,17 +121,8 @@ def test_ksampler_advanced_hands_leftover_noise_on_as_stock_does(tiny, fresh_res
 # -- the residency rule ----------------------------------------------------------------
 
 
-def _residency_events():
-    from comfyui_parallelanything_tpu.utils.metrics import registry
-
-    m = registry._metrics.get("pa_model_residency_total") or {"values": {}}
-    return {tuple(sorted(dict(k).items())): v for k, v in m["values"].items()}
 
 
-def _moved(before):
-    now = _residency_events()
-    return {dict(k)["model"] + ":" + dict(k)["event"]: v - before.get(k, 0.0)
-            for k, v in now.items() if v != before.get(k, 0.0)}
 
 
 def test_nothing_moves_when_everything_fits(tiny, tmp_path, monkeypatch):

@@ -14,8 +14,6 @@ hold the program to it inside tier-1."""
 
 import json
 import os
-import threading
-import time
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +21,7 @@ import numpy as np
 import pytest
 
 from twins import (  # noqa: F401 — a fixture; benchmark/ on the path
-    _BENCH, _counted, _float32_image, _rel, _twin, _twin_file, twin_files)
+    _BENCH, _counted, _float32_image, _rel, _serve, _twin, _twin_file, twin_files)
 from yardstick import client, reference_sd, reference_zimage, safetensors_io, traffic
 
 CELL = "zimage-turbo-tiny.closed-unique"
@@ -149,25 +147,6 @@ def test_the_tokenizer_and_chat_template_id_for_id_with_the_harness(tiny):
     assert ids[0][0] == 151644 and ids[0][mask[0].sum() - 5] == 151645
 
 
-def _serve(cell, graphs):
-    from comfyui_parallelanything_tpu.server import make_server
-
-    # where the twin's variables send SaveImage's files
-    srv, q = make_server(port=0, output_dir=os.environ["PA_OUTPUT_DIR"], trace=True)
-    thread = threading.Thread(target=srv.serve_forever, daemon=True)
-    thread.start()
-    base = f"http://127.0.0.1:{srv.server_address[1]}"
-    try:
-        res = [client.run_request(base, g, cell["template"]["output_node"], i,
-                                  time.perf_counter(), 0.02, 600)
-               for i, g in enumerate(graphs)]
-        spans = client.http(base, "/trace")
-    finally:
-        srv.shutdown()
-        srv.server_close()
-        q.shutdown()
-        thread.join(timeout=30)
-    return res, spans
 
 
 def test_the_whole_tiny_graph_through_the_server_equals_the_reference(tiny, float32_image):

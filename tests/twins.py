@@ -9,6 +9,8 @@ a test that needs a changed file writes a copy under its ``tmp_path``."""
 
 import os
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -19,7 +21,7 @@ if _BENCH not in sys.path:
     sys.path.insert(0, _BENCH)
 
 import run  # noqa: E402 — the benchmark's own file loading and preset swap
-from yardstick import synth, traffic  # noqa: E402
+from yardstick import client, synth, traffic  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -94,3 +96,46 @@ def _float32_image(twin_files, cell_name, reference):
         traffic.fill_graph(cell["template"], cell["mix"], sched.request(0)))
     return req, reference.Reference(cell["config_data"], *ref_args, "float32",
                                     **ref_kw).images(req, [0])
+
+
+def _serve(cell, graphs):
+    """``graphs`` posted one after another to a ``server.py`` of their own
+    (tracer on; ``SaveImage``'s files go where the twin's variables send
+    them) → (each request's result, the server's spans). The tracer is left
+    as it was found: a worker runs the next file of tests in this process."""
+    from comfyui_parallelanything_tpu.server import make_server
+    from comfyui_parallelanything_tpu.utils import tracing
+
+    was_on = tracing.on()
+    srv, q = make_server(port=0, output_dir=os.environ["PA_OUTPUT_DIR"], trace=True)
+    thread = threading.Thread(target=srv.serve_forever, daemon=True)
+    thread.start()
+    base = f"http://127.0.0.1:{srv.server_address[1]}"
+    try:
+        res = [client.run_request(base, g, cell["template"]["output_node"], i,
+                                  time.perf_counter(), 0.02, 600)
+               for i, g in enumerate(graphs)]
+        spans = client.http(base, "/trace")
+    finally:
+        srv.shutdown()
+        srv.server_close()
+        q.shutdown()
+        thread.join(timeout=30)
+        if not was_on:
+            tracing.disable()
+    return res, spans
+
+
+def _residency_events():
+    from comfyui_parallelanything_tpu.utils.metrics import registry
+
+    m = registry._metrics.get("pa_model_residency_total") or {"values": {}}
+    return {tuple(sorted(dict(k).items())): v for k, v in m["values"].items()}
+
+
+def _moved(before):
+    """``{"<model>:<event>": count}`` of the residency rule's moves since
+    ``before`` (a ``_residency_events()``)."""
+    now = _residency_events()
+    return {dict(k)["model"] + ":" + dict(k)["event"]: v - before.get(k, 0.0)
+            for k, v in now.items() if v != before.get(k, 0.0)}
